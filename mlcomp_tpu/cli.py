@@ -432,8 +432,16 @@ def _cmd_serve(args: argparse.Namespace) -> int:
         phase=args.phase,
     )
     if args.warmup:
+        import time
+
+        t0 = time.perf_counter()
         n = service.warmup()
-        print(json.dumps({"event": "warmup", "programs": n}), flush=True)
+        print(json.dumps({
+            "event": "warmup", "programs": n,
+            # compiles dominate: this is what a warm persistent compile
+            # cache (utils/compile_cache.py) takes away
+            "seconds": round(time.perf_counter() - t0, 3),
+        }), flush=True)
     serve_http(
         service, host=args.host, port=args.port,
         model_name=str(model_cfg.get("name", "model")),
@@ -526,6 +534,7 @@ def _cmd_fleet(args: argparse.Namespace) -> int:
             serve_argv += extra.split()
         launcher = SubprocessLauncher(
             serve_argv, host=args.host, log_dir=args.log_dir,
+            chips=args.chips, port_base=lo,
         )
         port_range = (lo, hi)
     metrics = Registry()
@@ -568,7 +577,14 @@ def _cmd_fleet(args: argparse.Namespace) -> int:
             managers.append(ReplicaManager(
                 SubprocessLauncher(
                     base_argv + extra, host=args.host,
-                    log_dir=args.log_dir,
+                    log_dir=args.log_dir, chips=args.chips,
+                    port_base=prange[0],
+                    # the decode set's chips start after the prefill
+                    # set's
+                    chip_offset=(
+                        0 if set_name == "prefill"
+                        else n_prefill * args.chips
+                    ),
                 ),
                 ReplicaSpec(
                     target=target,
@@ -1139,8 +1155,13 @@ def main(argv=None) -> int:
     )
     fl.add_argument("--db", default="mlcomp.sqlite",
                     help="store for --scheduler mode")
-    fl.add_argument("--chips", type=int, default=0,
-                    help="chips per replica task (--scheduler mode)")
+    fl.add_argument(
+        "--chips", type=int, default=0,
+        help="chips per replica.  --scheduler mode: the replica task's"
+        " chip claim.  Subprocess launcher: replica k (by port slot)"
+        " is pinned to chips [k*N, (k+1)*N) of this host so replicas"
+        " never race for a chip; 0 pins nothing (CPU hosts)",
+    )
     fl.add_argument(
         "--serve-arg", action="append", default=[],
         help="extra flag(s) appended to each replica's `serve` command"
@@ -1164,6 +1185,13 @@ def main(argv=None) -> int:
     fl.set_defaults(fn=_cmd_fleet)
 
     args = p.parse_args(argv)
+    if argv is None:
+        # a process entry point (console script / python -m), not an
+        # in-process call: place the persistent compile cache before
+        # any subcommand imports JAX
+        from mlcomp_tpu.utils.compile_cache import place_compile_cache
+
+        place_compile_cache()
     from mlcomp_tpu.dag.graph import DagValidationError
     from mlcomp_tpu.utils.config import ConfigError
 

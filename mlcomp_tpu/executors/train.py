@@ -39,8 +39,6 @@ class TrainExecutor(Executor):
     name = "train"
 
     def work(self, ctx: ExecutionContext) -> Optional[Dict[str, Any]]:
-        import jax
-
         from mlcomp_tpu.io.checkpoint import latest_step, restore_checkpoint, save_checkpoint
         from mlcomp_tpu.io.storage import ModelStorage
         from mlcomp_tpu.train.loop import Trainer
@@ -97,9 +95,14 @@ class TrainExecutor(Executor):
             cfg["trace"] = {"path": str(Path(ckpt_dir) / "trace.json")}
 
         trainer = Trainer(cfg)
+        from mlcomp_tpu.utils.chips import device_summary
+
+        dev = device_summary()
         ctx.log(
             f"model={cfg['model'].get('name')} params={trainer.n_params:,} "
-            f"devices={len(jax.devices())} mesh={dict(zip(trainer.mesh.axis_names, trainer.mesh.devices.shape))}"
+            f"platform={dev['platform']} device_kind={dev['device_kind']!r} "
+            f"devices={dev['count']} "
+            f"mesh={dict(zip(trainer.mesh.axis_names, trainer.mesh.devices.shape))}"
         )
 
         # resume if a checkpoint exists (restart-safe training tasks)

@@ -122,7 +122,10 @@ class ReplicaSpec:
     # restart budget (a replica that HAS answered healthy since its
     # last (re)start gets no grace — its death is detected at the
     # normal unhealthy_after bound)
-    startup_grace_s: float = 180.0
+    # 900: a 1.2B replica on a v5e binds only after restore + warmup,
+    # about 300 s on a cold compile cache (PR 22's chip run — at the
+    # old 180 s the manager kill-looped all four replicas)
+    startup_grace_s: float = 900.0
 
     def __post_init__(self):
         if self.target < 0:
@@ -210,13 +213,27 @@ class SubprocessLauncher:
     """Replicas as ``mlcomp-tpu serve`` children on this host — the
     ``mlcomp-tpu fleet`` single-host shape.  ``serve_argv`` is the flag
     tail after ``serve`` (model/ckpt/batcher flags); host/port are
-    appended per replica, so the caller must not pass them."""
+    appended per replica, so the caller must not pass them.
+
+    ``chips`` > 0 pins every replica to its own chips: a TPU chip
+    belongs to one process, so unpinned replicas race for all of the
+    host's chips — the first wins them and the rest cannot start.  The
+    replica on port ``port_base + k`` holds chips
+    ``[chip_offset + k*chips, chip_offset + (k+1)*chips)``: the port
+    slot is the replica's stable index (a restart on a freed port
+    reuses that slot's chips), and this parent never asks JAX how many
+    chips exist — a slot past the host's last chip fails in the child,
+    loudly.  ``chips=0`` (CPU hosts, tests) pins nothing."""
 
     def __init__(self, serve_argv: List[str], host: str = "127.0.0.1",
-                 log_dir: Optional[str] = None):
+                 log_dir: Optional[str] = None, chips: int = 0,
+                 port_base: int = 0, chip_offset: int = 0):
         self.serve_argv = list(serve_argv)
         self.host = host
         self.log_dir = log_dir
+        self.chips = int(chips)
+        self.port_base = int(port_base)
+        self.chip_offset = int(chip_offset)
 
     def spawn(self, name: str, port: int) -> _ProcHandle:
         import subprocess
@@ -231,6 +248,15 @@ class SubprocessLauncher:
             sys.executable, "-m", "mlcomp_tpu.cli", "serve",
             *self.serve_argv, "--host", self.host, "--port", str(port),
         ]
+        env = None
+        if self.chips > 0:
+            from mlcomp_tpu.utils.chips import chip_visibility_env
+
+            first = self.chip_offset + (port - self.port_base) * self.chips
+            env = dict(os.environ)
+            env.update(chip_visibility_env(
+                range(first, first + self.chips)
+            ))
         log_path = None
         log_fh = subprocess.DEVNULL
         if self.log_dir:
@@ -240,7 +266,7 @@ class SubprocessLauncher:
         try:
             proc = subprocess.Popen(
                 argv, stdout=log_fh, stderr=subprocess.STDOUT,
-                start_new_session=True,
+                start_new_session=True, env=env,
             )
         finally:
             if log_fh is not subprocess.DEVNULL:

@@ -13,6 +13,7 @@ from pathlib import Path
 from typing import Any, Optional
 
 import jax
+import numpy as np
 import orbax.checkpoint as ocp
 
 
@@ -82,48 +83,68 @@ def latest_step(directory: str | Path) -> Optional[int]:
         return mgr.latest_step()
 
 
+def _saved_tree(directory: Path, step: int) -> dict:
+    """Metadata tree of the state saved at ``step``: which top-level
+    keys exist (``ema_params`` is None when the run tracked no EMA) and
+    every leaf's shape/dtype — read without touching array bytes."""
+    meta = ocp.StandardCheckpointer().metadata(
+        Path(directory) / str(step) / "default"
+    )
+    return meta.item_metadata.tree
+
+
+def _weight_keys(saved: dict) -> list:
+    """The top-level keys of a saved state that an eval consumer reads:
+    the EMA weights when the run tracked them (they ARE the eval
+    weights; the raw params are then not read at all), else the params;
+    plus ``model_state`` / ``step`` where the checkpoint really holds
+    them — a partial restore hands back the item's own values for a key
+    it does not find, so only present keys may be asked for."""
+    keys = ["ema_params" if saved.get("ema_params") else "params"]
+    return keys + [
+        k for k in ("model_state", "step") if saved.get(k) not in (None, {})
+    ]
+
+
 def restore_eval_state(directory: str | Path, state: Any, step: Optional[int] = None):
     """Weights-only restore for eval/infer/generate tasks.
 
-    Reads the saved tree WITHOUT a target, so the on-disk optimizer state
-    — whose structure depends on the TRAIN task's optimizer config (adamw
-    + grad-clip chains etc.) — is ignored entirely instead of failing the
+    Reads only the weight subtrees, so the on-disk optimizer state —
+    whose structure depends on the TRAIN task's optimizer config (adamw
+    + grad-clip chains etc.) — is never read instead of failing the
     structure match.  Downstream stages therefore never need to repeat
     the train stage's optimizer config.  When the checkpoint carries EMA
     weights they become the restored params (same policy as
     ``restore_checkpoint`` grafting into a non-EMA target).  Restored
     arrays are placed onto the shardings of ``state``'s arrays.
     """
+    from orbax.checkpoint import checkpoint_utils
+
     directory = Path(directory).absolute()
     with _mgr(directory) as mgr:
         step = step if step is not None else mgr.latest_step()
         if step is None:
             raise FileNotFoundError(f"no checkpoints under {directory}")
-        raw = None
-        try:
-            # targeted partial restore: transforms={} + a partial item
-            # drops unmatched saved keys (opt_state — potentially several
-            # times the param bytes) WITHOUT materializing them; restored
-            # arrays land directly on the item's shardings
-            item = {
-                "params": state.params,
-                "model_state": state.model_state,
-                "step": state.step,
-            }
-            probe_ema = {**item, "ema_params": state.params}
-            try:
-                raw = mgr.restore(
-                    step,
-                    args=ocp.args.PyTreeRestore(item=probe_ema, transforms={}),
-                )
-            except ValueError:
-                raw = mgr.restore(
-                    step, args=ocp.args.PyTreeRestore(item=item, transforms={})
-                )
-        except Exception:
-            # orbax API variance: fall back to an untargeted full read
-            # (correct, but materializes the saved opt_state on host too)
-            raw = mgr.restore(step)
+
+        template = {
+            "params": state.params, "ema_params": state.params,
+            "model_state": state.model_state, "step": state.step,
+        }
+        item = {
+            k: template[k] for k in _weight_keys(_saved_tree(directory, step))
+        }
+        # partial restore: saved keys the item does not name (opt_state
+        # — potentially several times the param bytes) are never read,
+        # and restored arrays land directly on the shardings of the
+        # item's arrays.  A 1.2B model beside its own template leaves no
+        # room on a 16 GB chip for anything else, so there is no
+        # untargeted full-read fallback: it would put the optimizer
+        # state on the device too.
+        raw = mgr.restore(step, args=ocp.args.PyTreeRestore(
+            item=item,
+            restore_args=checkpoint_utils.construct_restore_args(item),
+            partial_restore=True,
+        ))
 
     def place(old, new):
         arr = jax.numpy.asarray(new)
@@ -150,32 +171,26 @@ def read_weights(directory: str | Path, step: Optional[int] = None) -> dict:
 
     Selects only the weight subtrees via a metadata-derived partial
     restore so the saved opt_state — potentially several times the param
-    bytes — is never materialized; falls back to a full read on orbax
-    API variance (correct, just heavier)."""
+    bytes — is never materialized."""
     directory = Path(directory).absolute()
     with _mgr(directory) as mgr:
         step = step if step is not None else mgr.latest_step()
         if step is None:
             raise FileNotFoundError(f"no checkpoints under {directory}")
-        raw = None
-        try:
-            meta = mgr.item_metadata(step)
-            item = {
-                k: jax.tree.map(
-                    lambda m: jax.ShapeDtypeStruct(m.shape, m.dtype),
-                    meta[k],
-                )
-                for k in ("params", "ema_params", "model_state", "step")
-                if isinstance(meta, dict) and meta.get(k) is not None
-            }
-            if "params" in item:
-                raw = mgr.restore(
-                    step, args=ocp.args.PyTreeRestore(item=item, transforms={})
-                )
-        except Exception:
-            raw = None
-        if raw is None:
-            raw = mgr.restore(step)
+        saved = _saved_tree(directory, step)
+        item = {
+            k: jax.tree.map(
+                lambda m: jax.ShapeDtypeStruct(m.shape, m.dtype), saved[k]
+            )
+            for k in _weight_keys(saved)
+        }
+        raw = mgr.restore(step, args=ocp.args.PyTreeRestore(
+            item=item,
+            restore_args=jax.tree.map(
+                lambda _: ocp.RestoreArgs(restore_type=np.ndarray), item
+            ),
+            partial_restore=True,
+        ))
     return {
         "params": raw.get("ema_params") or raw["params"],
         "model_state": raw.get("model_state") or {},

@@ -342,17 +342,12 @@ def _gather_leaf(pages, table, impl: str = "auto"):
     ``impl``: "lax" (jnp.take — everywhere), "pallas" (TPU DMA-copy
     kernel), "auto" (pallas on TPU, else lax).
     """
-    import jax
     import jax.numpy as jnp
 
     if impl == "auto":
-        try:
-            impl = (
-                "pallas"
-                if jax.devices()[0].platform == "tpu" else "lax"
-            )
-        except Exception:
-            impl = "lax"
+        from mlcomp_tpu.ops.pallas import on_tpu
+
+        impl = "pallas" if on_tpu() else "lax"
     if impl == "lax":
         return jnp.take(pages, table, axis=0)
     if impl != "pallas":
@@ -379,27 +374,35 @@ def _gather_leaf_pallas(pages, table, interpret: bool = False):
     for d in rest:
         R *= d
     S, MP = table.shape
-    pages2 = pages.reshape(P, R)
+    # the payload rides as whole (rows, 128) lane tiles: the TPU
+    # lowering refuses a (1, R) block over a (P, R) array (its last
+    # two block dims must be (8, 128)-divisible or span the array's),
+    # while a block spanning the trailing (rows, lanes) dims is legal
+    # for every leaf family.  Payloads that are not a lane multiple
+    # keep one row.
+    tile = (R // 128, 128) if R % 128 == 0 else (1, R)
+    pages3 = pages.reshape((P,) + tile)
 
     def copy_kernel(tbl_ref, page_ref, out_ref):
-        # blocks: page_ref (1, R) at physical page tbl[s, p],
-        # out_ref (1, 1, R) at logical (s, p) — a pure DMA copy
+        # blocks: page_ref (1, *tile) at physical page tbl[s, p],
+        # out_ref (1, 1, *tile) at logical (s, p) — a pure DMA copy
         out_ref[0, 0] = page_ref[0]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(S, MP),
         in_specs=[
-            pl.BlockSpec((1, R), lambda s, p, tbl: (tbl[s, p], 0)),
+            pl.BlockSpec((1,) + tile, lambda s, p, tbl: (tbl[s, p], 0, 0)),
         ],
         out_specs=pl.BlockSpec(
-            (1, 1, R), lambda s, p, tbl: (s, p, 0)
+            (1, 1) + tile, lambda s, p, tbl: (s, p, 0, 0)
         ),
     )
     out = pl.pallas_call(
         copy_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, MP, R), pages.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, MP) + tile, pages.dtype),
         interpret=interpret,
-    )(table, pages2)
+        name="page_gather",
+    )(table, pages3)
     return out.reshape((S, MP) + rest)

@@ -9,7 +9,7 @@ result JSON back.  What the boundary buys:
 - a segfaulting / OOM-killed / fault-injected executor takes down only
   this process — the worker loop reaps the corpse and routes the task
   into the normal retry machinery;
-- chip pinning is real: the parent sets ``TPU_VISIBLE_DEVICES`` before
+- chip pinning is real: the parent sets ``TPU_VISIBLE_CHIPS`` before
   exec, so concurrent tasks on one host each see only their chips;
 - multi-host tasks get a fresh JAX runtime per attempt:
   ``init_distributed()`` (parallel/distributed.py) reads the
@@ -105,7 +105,13 @@ def run_spec(spec_path: str) -> int:
 
 
 def main(argv=None) -> int:
-    argv = argv if argv is not None else sys.argv[1:]
+    if argv is None:
+        # process entry point: same compile cache as the worker's
+        # other children (utils/compile_cache.py)
+        from mlcomp_tpu.utils.compile_cache import place_compile_cache
+
+        place_compile_cache()
+        argv = sys.argv[1:]
     if len(argv) != 1:
         print("usage: python -m mlcomp_tpu.scheduler.child <spec.json>",
               file=sys.stderr)

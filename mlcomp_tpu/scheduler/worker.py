@@ -246,6 +246,10 @@ class Worker:
         self._adopt_orphaned_tasks()
         self._sweep_stale_scratch()
         if load_jax_executors:
+            # registers the executor classes only: their modules import
+            # jax inside work(), so this parent never initialises a JAX
+            # backend and never holds a chip its task children need
+            # (tests/test_isolation.py asserts it)
             from mlcomp_tpu import executors
 
             executors.load_all()
@@ -517,10 +521,11 @@ class Worker:
         )
         env["MLCOMP_TPU_CHIP_IDS"] = ",".join(map(str, ids))
         if ids and chips < self.chips:
-            # pin only when the task takes a strict subset — restricting a
-            # full-host task buys nothing and some runtimes (forwarded
-            # single-chip tunnels) reject visibility filters
-            env["TPU_VISIBLE_DEVICES"] = ",".join(map(str, ids))
+            # pin only when the task takes a strict subset of the
+            # host's chips: a full-host task needs no filter
+            from mlcomp_tpu.utils.chips import chip_visibility_env
+
+            env.update(chip_visibility_env(ids))
         if gang:
             env["MLCOMP_TPU_COORDINATOR"] = gang["coordinator"]
             env["MLCOMP_TPU_NUM_PROCESSES"] = str(gang["hosts"])

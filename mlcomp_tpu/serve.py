@@ -126,6 +126,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from mlcomp_tpu.engine import DeadlineExceeded, NotCoordinator, _fail_future
+from mlcomp_tpu.utils.chips import device_summary
 from mlcomp_tpu.utils.trace import (
     filter_export,
     make_trace_id,
@@ -1112,6 +1113,10 @@ class GenerationService:
             # to prefill replicas and page handoffs to decode replicas
             # off this field (the registry mirrors it)
             "phase": self.phase,
+            # the devices THIS process holds (platform, device_kind,
+            # count, visible chips): launchers and smoke tests learn
+            # the device here instead of touching JAX themselves
+            "device": device_summary(),
         }
         if self.engine is not None:
             # the engine is the single counter of continuous-mode

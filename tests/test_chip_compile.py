@@ -1,0 +1,184 @@
+"""Ahead-of-time compiles of the main path's Pallas kernels for a
+described TPU v5e, at the 1.2B shapes ``chip_smoke.py`` runs them at.
+
+The TPU's compiler is installed here and compiles for a chip that is
+described and not attached (``jax.experimental.topologies``), so what
+it refuses — a slice not aligned to the tiling, a block shape the
+lowering rejects, too much VMEM — fails HERE, at no chip time.
+Interpret-mode tests cannot see any of that: the paged kernels and the
+page gather passed every one of them and were refused by Mosaic.
+
+Rules this file keeps (on-chip-measurement guide, section 2):
+
+- the topology, shardings and shapes are built inside a module-scoped,
+  non-autouse fixture — never at import, in a ``skipif`` or in
+  ``parametrize`` arguments — so every xdist worker collects the same
+  tests and only the worker that RUNS this file loads the TPU library;
+- the compiles run in the test's own process, in this one file;
+- the persistent compilation cache is off around them (a compile for a
+  described device is written to it but cannot be read back).
+
+Nothing runs: these say a kernel builds, not that it is right or fast.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+# 1.2B serving/train geometry (configs/lm_1p2b.yml, chip_smoke.py)
+B, H, DH, L, T = 8, 16, 128, 2304, 128
+S_TRAIN, B_TRAIN = 4096, 2
+HIDDEN, MLP, VOCAB = 2048, 8192, 32768
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """``shape(dims, dtype)`` -> ShapeDtypeStruct on one described v5e
+    chip; skips when the topology cannot be described here."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield lambda dims, dtype: jax.ShapeDtypeStruct(
+            dims, dtype, sharding=one_chip
+        )
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_on)
+        compilation_cache.reset_cache()
+
+
+def _compiles_to_a_kernel(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+def test_flash_attention_causal_s4096(chip, grad):
+    from mlcomp_tpu.ops.pallas.flash_attention import flash_attention
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    qkv = [chip((B_TRAIN, S_TRAIN, H, DH), jnp.bfloat16)] * 3
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
+    text = _compiles_to_a_kernel(fn, *qkv)
+    # the kernel carries its trace name (GET /profile matches on it)
+    assert "flash_fwd_kernel" in text
+    if grad:
+        assert "flash_dq_kernel" in text and "flash_dkv_kernel" in text
+
+
+def _dense_cache(chip):
+    kv = chip((B, H, L, DH), jnp.int8)
+    scale = chip((B, H, 1, L), jnp.bfloat16)
+    bounds = chip((B,), jnp.int32)
+    return kv, scale, kv, scale, bounds, bounds
+
+
+def test_decode_attention_dense_int8_kv(chip):
+    from mlcomp_tpu.ops.pallas.decode_attention import decode_attention
+
+    _compiles_to_a_kernel(
+        functools.partial(decode_attention, interpret=False),
+        chip((B, H, DH), jnp.bfloat16), *_dense_cache(chip),
+    )
+
+
+def test_decode_attention_chunk_sq5(chip):
+    from mlcomp_tpu.ops.pallas.decode_attention import decode_attention_chunk
+
+    _compiles_to_a_kernel(
+        functools.partial(decode_attention_chunk, interpret=False),
+        chip((B, 5, H, DH), jnp.bfloat16), *_dense_cache(chip),
+    )
+
+
+@pytest.mark.parametrize("d,n,norm", [
+    (HIDDEN, MLP, False),      # mlp up/gate
+    (MLP, HIDDEN, False),      # mlp down
+    (HIDDEN, VOCAB, False),    # lm head
+    (HIDDEN, HIDDEN, False),   # attention projections
+    (HIDDEN, MLP, True),       # RMSNorm folded into the kernel prologue
+    (HIDDEN, VOCAB, True),
+], ids=["mlp_up", "mlp_down", "lm_head", "attn_proj", "mlp_up_norm",
+        "lm_head_norm"])
+def test_quant_matmul(chip, d, n, norm):
+    from mlcomp_tpu.ops.pallas.quant_matmul import quant_matmul
+
+    def fn(x, q8, scale, g):
+        return quant_matmul(
+            x, q8, scale, interpret=False, norm_scale=g if norm else None
+        )
+
+    _compiles_to_a_kernel(
+        fn, chip((B, d), jnp.bfloat16), chip((d, n), jnp.int8),
+        chip((n,), jnp.float32), chip((d,), jnp.float32),
+    )
+
+
+def _paged_pool(chip):
+    mp = L // T
+    pages = B * mp + 2          # + the reserved NULL and GRAVE pages
+    kv = chip((pages, H, T, DH), jnp.int8)
+    scale = chip((pages, H, 1, T), jnp.bfloat16)
+    table = chip((B, mp), jnp.int32)
+    bounds = chip((B,), jnp.int32)
+    return kv, scale, kv, scale, table, bounds, bounds
+
+
+@pytest.mark.parametrize("fetch", ["double", "rolled"])
+def test_paged_decode_attention_page128(chip, fetch):
+    from mlcomp_tpu.ops.pallas.decode_attention import paged_decode_attention
+
+    _compiles_to_a_kernel(
+        functools.partial(
+            paged_decode_attention, interpret=False, fetch=fetch
+        ),
+        chip((B, H, DH), jnp.bfloat16), *_paged_pool(chip),
+    )
+
+
+@pytest.mark.parametrize("fetch", ["double", "rolled"])
+def test_paged_decode_attention_chunk_page128(chip, fetch):
+    from mlcomp_tpu.ops.pallas.decode_attention import (
+        paged_decode_attention_chunk,
+    )
+
+    _compiles_to_a_kernel(
+        functools.partial(
+            paged_decode_attention_chunk, interpret=False, fetch=fetch
+        ),
+        chip((B, 5, H, DH), jnp.bfloat16), *_paged_pool(chip),
+    )
+
+
+@pytest.mark.parametrize("leaf", ["int8_kv", "bf16_kv", "bf16_scale"])
+def test_page_gather_page128(chip, leaf):
+    from mlcomp_tpu.kvpool.layout import _gather_leaf_pallas
+
+    kv, scale, _, _, table, _, _ = _paged_pool(chip)
+    pages = {
+        "int8_kv": kv,
+        "bf16_kv": chip(kv.shape, jnp.bfloat16),
+        "bf16_scale": scale,
+    }[leaf]
+    _compiles_to_a_kernel(_gather_leaf_pallas, pages, table)

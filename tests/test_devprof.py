@@ -6,9 +6,13 @@ Three layers of evidence:
   to exactly the planes/lines/events/names written — the walker's
   varint/length-delimited/map handling is pinned without any profiler
   in the loop;
-- the SHIPPED capture fixture (``tests/data/cpu_capture.xplane.pb``, a
-  real ``jax.profiler`` CPU capture) parses and attributes: device
-  lanes found, busy time positive, kernel names resolved;
+- the SHIPPED capture fixtures parse and attribute: device lanes
+  found, busy time positive, kernel names resolved —
+  ``tests/data/cpu_capture.xplane.pb`` (a real ``jax.profiler`` CPU
+  capture under the installed JAX) and
+  ``tests/data/tpu_v5e_capture.xplane.pb`` (the ``/device:TPU:0``
+  plane of a capture taken on a v5e chip: flash forward, int8-KV
+  decode attention and the int8 matmul at the 1.2B shapes);
 - a LIVE capture produced in-test under ``JAX_PLATFORMS=cpu`` parses
   the same way — the fixture can't go stale silently;
 - and the package ships no TensorFlow import anywhere (the whole point
@@ -145,6 +149,27 @@ def test_shipped_cpu_fixture_parses_and_attributes():
     t0s = [s[0] for s in spans]
     assert min(t0s) == 0.0  # spans are capture-relative
     assert all(d > 0 for _, d, _ in spans)
+
+
+def test_shipped_tpu_fixture_selects_xla_ops_and_names_kernels():
+    """The real-chip capture: the device lane is the TPU plane's
+    "XLA Ops" line (not "XLA Modules"/"Async XLA Ops", which would
+    double count), and the Pallas kernels appear under the names their
+    pallas_call sites give them — what GET /profile and chip_smoke.py
+    match on."""
+    planes = devprof.load_xspace(os.path.join(
+        os.path.dirname(FIXTURE), "tpu_v5e_capture.xplane.pb"
+    ))
+    lanes = devprof.device_lines(planes)
+    assert [(p.name, ln.name) for p, ln in lanes] == [
+        ("/device:TPU:0", "XLA Ops")
+    ]
+    att = devprof.attribution(planes)
+    assert att["device_time_ms"] == pytest.approx(1.8745, abs=1e-3)
+    by_name = {k["name"]: k for k in att["kernels"]}
+    for kernel in ("flash_fwd_kernel_tri", "decode_attention",
+                   "quant_matmul"):
+        assert by_name[kernel]["total_ms"] > 0, kernel
 
 
 def test_live_cpu_capture_parses(tmp_path):

@@ -175,8 +175,16 @@ def test_eos_during_overlapped_admission():
     frees and its stream terminates correctly, B's insert still lands,
     and everything matches the staged path."""
     model, params = _model_and_params()
-    # A stops at its second greedy token (deterministic reference)
-    eos_a = _reference(model, params, IDS_A, 2)[1]
+    # A (prompt IDS_B) stops at its first greedy token, after token 0,
+    # that no earlier token equals: an EOS that is also token 0 would
+    # fire before B is even submitted (IDS_A's greedy continuation
+    # under the installed JAX is one repeated token, so it cannot be
+    # the stopper).  Deterministic reference.
+    ref_a = _reference(model, params, IDS_B, 12)
+    n_eos = next(
+        i for i in range(1, len(ref_a)) if ref_a[i] not in ref_a[:i]
+    )
+    eos_a = ref_a[n_eos]
     results = {}
     for fused in (True, False):
         eng = _share_fns(
@@ -188,18 +196,18 @@ def test_eos_during_overlapped_admission():
         )
         try:
             qa: "queue.Queue" = queue.Queue()
-            fa = eng.submit(IDS_A, 12, eos_id=eos_a, stream=qa)
+            fa = eng.submit(IDS_B, 12, eos_id=eos_a, stream=qa)
             qa.get(timeout=300)           # A is decoding
-            fb = eng.submit(IDS_B, 6)     # 6+ chunks of 2: a long prefill
+            fb = eng.submit(IDS_A, 6)     # chunks of 2: a long prefill
             ra = fa.result(timeout=300)
             rb = fb.result(timeout=300)
         finally:
             _FNS[("eos", 1, 2)].update(eng._fns)
             eng.close()
-        assert ra["ids"][-1] == eos_a and len(ra["ids"]) == 2, ra
+        assert ra["ids"] == ref_a[:n_eos + 1], ra
         results[fused] = (ra["ids"], rb["ids"])
     assert results[True] == results[False]
-    assert results[True][1] == _reference(model, params, IDS_B, 6)
+    assert results[True][1] == _reference(model, params, IDS_A, 6)
 
 
 def test_fused_prefill_fault_fails_only_the_admission():

@@ -240,7 +240,7 @@ def test_spec_engine_warns_past_gemv_row_budget():
 
 
 def test_spec_net_gain_surfaced_and_pure_loss_warns_once():
-    """Spec honesty (BENCH_r05: acceptance_tokens_per_row 1.0 while the
+    """Spec honesty (acceptance_tokens_per_row 1.0 while the
     knob cost throughput): a spec engine's stats() carries a "spec"
     block with the measured acceptance and spec_net_gain (<= 0 = pure
     loss), the service lifts it to the top level for /healthz, and the

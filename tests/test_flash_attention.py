@@ -322,3 +322,48 @@ def test_kv_stop_only_right_padding():
         q, k, v, mask=_window_mask(b, s, np.zeros(b, np.int64), np.asarray(stop))
     )
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+@pytest.mark.parametrize("bounded", [False, True])
+def test_dispatch_under_mesh_is_a_shard_map_island(monkeypatch, bounded):
+    """Under a dp x tp mesh the dispatch wraps the kernel in a shard_map
+    island (the chip's compiler refuses a bare Mosaic call with sharded
+    operands): outputs and grads match the reference, sharded inputs
+    stay sharded."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from mlcomp_tpu.ops.attention import dot_product_attention
+    from mlcomp_tpu.parallel.mesh import MeshSpec, make_mesh, set_current_mesh
+
+    monkeypatch.setenv("MLCOMP_TPU_FLASH", "1")
+    mesh = make_mesh(MeshSpec(dp=2, tp=2), devices=jax.devices()[:4])
+    set_current_mesh(mesh)
+    try:
+        rng = np.random.default_rng(0)
+        sh = NamedSharding(mesh, P(("dp", "fsdp"), None, "tp", None))
+        q, k, v = (
+            jax.device_put(
+                jnp.asarray(rng.standard_normal((2, 128, 4, 32)), jnp.float32),
+                sh,
+            )
+            for _ in range(3)
+        )
+        kw = {"kv_stop": jnp.asarray([128, 100], jnp.int32)} if bounded else {}
+
+        def loss(fn):
+            return lambda q, k, v: fn(q, k, v, causal=True, **kw).sum()
+
+        out = jax.jit(
+            lambda q, k, v: dot_product_attention(q, k, v, causal=True, **kw)
+        )(q, k, v)
+        assert out.sharding.is_equivalent_to(sh, out.ndim)
+        ref = reference_attention(q, k, v, causal=True, **kw)
+        np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+        g = jax.jit(jax.grad(loss(dot_product_attention), argnums=(0, 1, 2)))(
+            q, k, v
+        )
+        g_ref = jax.grad(loss(reference_attention), argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(g, g_ref):
+            np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5)
+    finally:
+        set_current_mesh(None)

@@ -98,7 +98,7 @@ def test_hard_death_consumes_retry_then_succeeds(store, tmp_path):
 
 def test_chip_pinning_env(store, tmp_path):
     """A task taking a strict subset of the worker's chips sees only its
-    chip ids in TPU_VISIBLE_DEVICES; MLCOMP_TPU_CHIP_IDS is always set."""
+    chip ids in TPU_VISIBLE_CHIPS; MLCOMP_TPU_CHIP_IDS is always set."""
     out = tmp_path / "env.txt"
     dag_id = _submit(
         store,
@@ -107,7 +107,7 @@ def test_chip_pinning_env(store, tmp_path):
             executor="shell",
             args={
                 "command":
-                f"echo \"ids=$MLCOMP_TPU_CHIP_IDS vis=$TPU_VISIBLE_DEVICES\""
+                f"echo \"ids=$MLCOMP_TPU_CHIP_IDS vis=$TPU_VISIBLE_CHIPS\""
                 f" > {out}"
             },
             resources=ResourceSpec(chips=2),
@@ -118,6 +118,18 @@ def test_chip_pinning_env(store, tmp_path):
     assert w.run_once() is True
     assert _row(store, dag_id, "pin")["status"] == TaskStatus.SUCCESS.value
     assert out.read_text().strip() == "ids=0,1 vis=0,1"
+
+
+def test_worker_parent_initialises_no_backend(store, tmp_path):
+    """The worker parent loads the JAX executors (registration only) and
+    must never initialise a backend: on a TPU host a parent that holds
+    the chip starves every task child it spawns."""
+    from jax._src import xla_bridge
+
+    before = set(xla_bridge._backends)
+    Worker(store, name="nojax-w", chips=1, workdir=str(tmp_path),
+           isolate=True, load_jax_executors=True)
+    assert set(xla_bridge._backends) == before
 
 
 def test_stop_kills_running_child(store, tmp_path):
