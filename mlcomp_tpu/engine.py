@@ -145,6 +145,7 @@ import time
 import warnings
 from collections import deque
 from concurrent.futures import Future
+from contextlib import contextmanager
 from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -252,8 +253,8 @@ class _Admission:
 
     __slots__ = ("req", "s_bucket", "chunk", "n_chunks", "next_chunk",
                  "row", "positions", "kv_mask", "cache", "last_logits",
-                 "capture_lo", "skip_capture", "fused_any", "stall_ms",
-                 "page_lease", "handoff")
+                 "capture_lo", "skip_capture", "chunks_run", "fused_chunks",
+                 "stall_ms", "page_lease", "handoff")
 
     def __init__(self, req, s_bucket, chunk, first_chunk):
         self.req = req
@@ -272,7 +273,8 @@ class _Admission:
         self.skip_capture = False       # trie already holds the FULL
         # prompt (retry storm): re-capturing would fetch rows only to
         # dedup to zero new tokens
-        self.fused_any = False          # any chunk rode a decode dispatch
+        self.chunks_run = 0             # prefill chunks this admission ran
+        self.fused_chunks = 0           # ... of which rode a decode dispatch
         # host-observed decode-stream stall this admission imposed
         # (staged chunks + the insert boundary, counted only while
         # decode rows were active) — the admission_stall_ms histogram
@@ -935,15 +937,18 @@ class DecodeEngine:
         # by the loop thread; close()'s normal path touches it only
         # after the join.
         self._inflight: Deque[Tuple[Any, float, int, int]] = deque()  # guarded_by: loop [writes]
-        # overlap accounting: hidden_ms is host work done between a
-        # dispatch's issue and the host blocking on its outputs (the
-        # time the pipeline hid behind device compute), wait_ms the
-        # blocked remainder; inflight_sum/issued is the mean in-flight
-        # depth at issue (occupancy)
+        # the loop thread's time, booked by _account at the stamps its
+        # spans share: host_ms is every stretch outside resolve's
+        # blocked fetch (wait_ms) and outside the idle poll
+        # (idle_wait, booked nowhere); hidden_ms is the part of
+        # host_ms spent while at least one dispatch was in flight;
+        # inflight_sum/issued is the mean in-flight depth at issue
+        # (occupancy)
         self._pstats = {  # guarded_by: loop [writes]
-            "issued": 0, "hidden_ms": 0.0, "wait_ms": 0.0,
+            "issued": 0, "host_ms": 0.0, "hidden_ms": 0.0, "wait_ms": 0.0,
             "inflight_sum": 0, "peak_inflight": 0,
         }
+        self._t_acct = time.perf_counter()  # guarded_by: loop [writes]
         # per-request latency reservoirs (most recent ~2k requests;
         # warmup submissions excluded): time-to-first-token and the
         # per-token decode interval behind the stats() percentiles.
@@ -1782,7 +1787,6 @@ class DecodeEngine:
         }
         p = dict(self._pstats)  # snapshot: the loop thread mutates it
         done = self._stats["dispatches"]
-        busy = p["hidden_ms"] + p["wait_ms"]
         out["pipeline"] = {
             "depth": self.pipeline_depth,
             "inflight": len(self._inflight),
@@ -1792,14 +1796,20 @@ class DecodeEngine:
             # synchronous, pipeline_depth = fully overlapped
             "occupancy": round(p["inflight_sum"] / p["issued"], 3)
             if p["issued"] else None,
-            # host ms per dispatch the pipeline HID behind device
-            # compute vs the ms it still blocked for outputs
+            # the loop thread's ms per dispatch outside the blocked
+            # fetch and the idle poll (the spans' boundary minus
+            # resolve and idle_wait), the part of it spent while a
+            # dispatch was in flight, and the ms blocked for outputs
+            "host_ms_per_dispatch": round(p["host_ms"] / done, 3)
+            if done else None,
             "host_hidden_ms_per_dispatch": round(p["hidden_ms"] / done, 3)
             if done else None,
             "resolve_wait_ms_per_dispatch": round(p["wait_ms"] / done, 3)
             if done else None,
-            "overlap_efficiency": round(p["hidden_ms"] / busy, 4)
-            if busy > 0 else None,
+            # hidden / host: 1.0 = the device had a dispatch queued
+            # under every host millisecond
+            "overlap_efficiency": round(p["hidden_ms"] / p["host_ms"], 4)
+            if p["host_ms"] > 0 else None,
         }
         out["latency"] = {
             # "samples" is the WINDOW the percentiles summarize (the
@@ -1947,8 +1957,11 @@ class DecodeEngine:
         p = dict(self._pstats)
         ctr("mlcomp_engine_pipeline_issued_total",
             "Dispatches issued into the pipeline", p["issued"])
+        ctr("mlcomp_engine_pipeline_host_ms_total",
+            "Loop-thread ms outside the blocked fetch and the idle poll",
+            p["host_ms"])
         ctr("mlcomp_engine_pipeline_hidden_ms_total",
-            "Host ms hidden behind in-flight device compute",
+            "Host ms spent while a dispatch was in flight",
             p["hidden_ms"])
         ctr("mlcomp_engine_pipeline_wait_ms_total",
             "Host ms blocked on dispatch outputs", p["wait_ms"])
@@ -1958,10 +1971,9 @@ class DecodeEngine:
             "Dispatches currently in flight", len(self._inflight))
         gau("mlcomp_engine_pipeline_peak_inflight",
             "Peak in-flight dispatch depth", p["peak_inflight"])
-        busy = p["hidden_ms"] + p["wait_ms"]
         gau("mlcomp_engine_pipeline_overlap_efficiency",
-            "hidden_ms / (hidden_ms + wait_ms) since start",
-            p["hidden_ms"] / busy if busy > 0 else 0.0)
+            "hidden_ms / host_ms since start",
+            p["hidden_ms"] / p["host_ms"] if p["host_ms"] > 0 else 0.0)
         ctr("mlcomp_engine_trace_events_dropped_total",
             "Flight-recorder ring evictions", self.recorder.dropped)
         ctr("mlcomp_engine_profile_captures_total",
@@ -1972,12 +1984,12 @@ class DecodeEngine:
             gau("mlcomp_engine_device_time_ms_per_dispatch",
                 "Device-lane busy ms per dispatch (last capture, else "
                 "the steady-state estimate: dispatch wall minus "
-                "measured host work)",
+                "uncovered host work)",
                 dev["device_time_ms_per_dispatch"])
         if dev["host_overhead_ms_per_dispatch"] is not None:
             gau("mlcomp_engine_host_overhead_ms_per_dispatch",
                 "Non-device ms per dispatch (capture host gap, else "
-                "the pipeline's measured hidden host work)",
+                "the loop's host ms that no in-flight dispatch covered)",
                 dev["host_overhead_ms_per_dispatch"])
         if dev["roofline_utilization"] is not None:
             gau("mlcomp_engine_roofline_utilization",
@@ -3570,9 +3582,11 @@ class DecodeEngine:
             adm.stall_ms += (time.perf_counter() - t0) * 1e3
         adm.last_logits = logits
         adm.next_chunk += 1
+        adm.chunks_run += 1
         self._stats["prefill_chunks"] += 1
         if adm.next_chunk >= adm.n_chunks:
-            self._complete_admission()
+            with self._admission_complete_span(adm):
+                self._complete_admission()
 
     def _prep_fused_chunk(self, adm: _Admission) -> Tuple[Any, Any]:
         """Host half of a fused chunk: slice and upload this chunk's
@@ -3597,6 +3611,63 @@ class DecodeEngine:
         lo = adm.next_chunk * c
         return (self._dev(adm.row[:, lo:lo + c]),
                 self._dev(adm.positions[:, lo:lo + c]))
+
+    # ------------------------------------------- loop spans and clock
+
+    def _loop_span(self, name: str, t_open: float,
+                   t_close: Optional[float] = None, **args) -> float:
+        """One span on the ``engine.loop`` track between two
+        ``perf_counter`` stamps, and the closing stamp back.  The
+        spans that tile a boundary (``maintenance``, ``admission_tick``,
+        ``issue``, ``resolve``, ``unpack``) hand that stamp to the next
+        one as its opening, so consecutive spans leave no gap and a
+        reader's self time (duration minus children) is exact."""
+        if t_close is None:
+            t_close = time.perf_counter()
+        rec = self.recorder
+        rec.complete(
+            name, rec.to_trace_us(t_open), (t_close - t_open) * 1e6,
+            track="engine.loop", **args,
+        )
+        return t_close
+
+    def _account(self, now: float,
+                 into: Optional[str] = "host_ms") -> None:  # graftcheck: runs-on(loop)
+        """Book the loop thread's time since the last stamp into
+        ``_pstats``: ``host_ms`` (and ``hidden_ms`` too when a dispatch
+        was in flight over that stretch — call BEFORE ``_inflight``
+        changes), ``wait_ms`` for resolve's blocked fetch, ``None`` for
+        the idle poll (waiting for traffic is nobody's cost)."""
+        dt = (now - self._t_acct) * 1e3
+        self._t_acct = now
+        if into is None:
+            return
+        p = self._pstats
+        p[into] += dt
+        if into == "host_ms" and self._inflight:
+            p["hidden_ms"] += dt
+
+    @contextmanager
+    def _idle_wait(self):  # graftcheck: runs-on(loop)
+        """The ``idle_wait`` span: a blocked wait for traffic on an idle
+        engine, kept out of ``host_ms``."""
+        t = time.perf_counter()
+        self._account(t)
+        try:
+            yield
+        finally:
+            self._account(self._loop_span("idle_wait", t), None)
+
+    def _admission_complete_span(self, adm: _Admission):
+        """The ``admission_complete`` span: the admission's last
+        boundary (final drain + insert/export/import) — the one stall
+        the fused path keeps."""
+        return self.recorder.span(
+            "admission_complete", track="engine.loop",
+            rid=adm.req.get("rid", 0), chunks=adm.chunks_run,
+            fused_chunks=adm.fused_chunks,
+            trace_id=adm.req.get("trace_id"),
+        )
 
     def _drain_inflight(self) -> None:  # graftcheck: runs-on(loop)
         """Resolve every in-flight dispatch (the recorded join_drain).
@@ -3856,18 +3927,22 @@ class DecodeEngine:
     def _device_summary(self) -> Dict[str, Any]:
         """The device/host split behind ``stats()["device"]`` and the
         roofline gauges: the last capture's measured attribution when
-        one exists, else the cheap steady-state ESTIMATE —
-        ``dispatch_wall − known host costs``, where the known host cost
-        is the pipeline's measured hidden (host-work) ms per dispatch.
-        The estimate is honest only when the pipeline saturates (the
-        resolve wait is then device-bound); captures are ground truth."""
+        one exists, else the cheap steady-state ESTIMATE from the loop
+        thread's own books (``_account``): the dispatch wall is the
+        loop's busy time per dispatch (``host_ms + wait_ms``), the host
+        overhead the part of ``host_ms`` that no in-flight dispatch
+        covered (``host_ms − hidden_ms``: the estimate's stand-in for a
+        capture's host gap), and the device time what is left.  The
+        estimate is honest only when the pipeline saturates (an
+        in-flight dispatch is then a running one); captures are ground
+        truth."""
         p = dict(self._pstats)
         done = self._stats["dispatches"]
         roof_ms = self._roofline_ms()
         ss = None
         if done:
-            wall = (p["hidden_ms"] + p["wait_ms"]) / done
-            host = p["hidden_ms"] / done
+            wall = (p["host_ms"] + p["wait_ms"]) / done
+            host = (p["host_ms"] - p["hidden_ms"]) / done
             dev_est = max(wall - host, 0.0)
             ss = {
                 "dispatch_wall_ms": round(wall, 3),
@@ -3996,6 +4071,7 @@ class DecodeEngine:
         req = adm.req
         s_bucket = adm.s_bucket
         decoding = any(s is not None for s in self._host)
+        exported = adm.handoff is None and self.prefill_only
         t0 = time.perf_counter()
         self._busy_since = t0
         try:
@@ -4007,10 +4083,17 @@ class DecodeEngine:
                 self._insert_admission(jnp, adm, req, s_bucket)
         finally:
             self._busy_since = None
+        if req.get("rid") and not exported:
+            # the row is on the device carry: admit -> inserted is how
+            # long this request held the engine's one admission lane
+            self.recorder.async_instant(
+                "inserted", req["rid"], cat="req", chunks=adm.chunks_run,
+                fused_chunks=adm.fused_chunks,
+            )
         if decoding:
             adm.stall_ms += (time.perf_counter() - t0) * 1e3
         self._hist_stall.observe(adm.stall_ms)
-        if adm.fused_any:
+        if adm.fused_chunks:
             self._stats["admissions_overlapped"] += 1
         self._stats["prefills"] += 1
         self._adm = None
@@ -4446,7 +4529,8 @@ class DecodeEngine:
         # stall the runtime later recovered from — its verdict stands
         _set_result(req["future"], result)
 
-    def _issue_dispatch(self, fused=None) -> None:  # graftcheck: runs-on(loop)
+    def _issue_dispatch(self, fused=None,
+                        t_open: Optional[float] = None) -> float:  # graftcheck: runs-on(loop)
         """Issue ONE dispatch and return WITHOUT blocking on its
         outputs: one device call (state device-carried + donated),
         nothing per-slot uploaded.  The donated carry chains device-
@@ -4461,8 +4545,24 @@ class DecodeEngine:
         ``_prep_fused_chunk``) makes this a FUSED dispatch: the same
         program also runs one prefill chunk against the admission's
         carried cache, advancing the admission without a dedicated
-        dispatch — the decode stream never pauses for it."""
+        dispatch — the decode stream never pauses for it.
+
+        The whole call is the ``issue`` span (lazy page growth, the
+        device call, the in-flight bookkeeping), opened at ``t_open``
+        — the stamp that closed the loop's previous span — and its
+        closing stamp is returned for the next one."""
+        if t_open is None:
+            t_open = time.perf_counter()
         seq = next(self._dispatch_seq)
+        try:
+            self._issue(seq, fused)
+        finally:
+            t_close = self._loop_span(
+                "issue", t_open, seq=seq, fused=fused is not None,
+            )
+        return t_close
+
+    def _issue(self, seq: int, fused) -> None:  # graftcheck: runs-on(loop)
         # lazy decode-page growth BEFORE the issue: the dispatch about
         # to go out (plus everything already in flight) must find every
         # cache slot it can write backed by a page
@@ -4482,31 +4582,26 @@ class DecodeEngine:
                     inflight=len(self._inflight) + 1, fused=True,
                 )
                 with self.recorder.span(
-                    "issue", track="engine.loop", seq=seq, fused=True,
+                    "prefill_chunk", track="engine.loop",
+                    chunk=adm.next_chunk, of=adm.n_chunks,
+                    rid=adm.req.get("rid", 0), fused=True, seq=seq,
+                    trace_id=adm.req.get("trace_id"),
                 ):
-                    with self.recorder.span(
-                        "prefill_chunk", track="engine.loop",
-                        chunk=adm.next_chunk, of=adm.n_chunks,
-                        rid=adm.req.get("rid", 0), fused=True, seq=seq,
-                        trace_id=adm.req.get("trace_id"),
-                    ):
-                        (self._dstate, packed, logits,
-                         adm.cache) = self._fused_dispatch_fn(adm.chunk)(
-                            self.variables, self._dstate, adm.cache,
-                            chunk, positions, adm.kv_mask,
-                        )
+                    (self._dstate, packed, logits,
+                     adm.cache) = self._fused_dispatch_fn(adm.chunk)(
+                        self.variables, self._dstate, adm.cache,
+                        chunk, positions, adm.kv_mask,
+                    )
                 adm.last_logits = logits
                 adm.next_chunk += 1
-                adm.fused_any = True
+                adm.chunks_run += 1
+                adm.fused_chunks += 1
                 self._stats["prefill_chunks"] += 1
                 self._stats["fused_chunks"] += 1
             else:
-                with self.recorder.span(
-                    "issue", track="engine.loop", seq=seq,
-                ):
-                    self._dstate, packed = self._dispatch_fn()(
-                        self.variables, self._dstate
-                    )
+                self._dstate, packed = self._dispatch_fn()(
+                    self.variables, self._dstate
+                )
         finally:
             self._busy_since = None
         pr = self._profile
@@ -4521,9 +4616,9 @@ class DecodeEngine:
         # between issues, and the lazy page allocator's lookahead must
         # price the in-flight window by what each dispatch will
         # actually advance, not by the current knob
-        self._inflight.append(
-            (packed, time.perf_counter(), seq, self._steps_hi())
-        )
+        t_issued = time.perf_counter()
+        self._account(t_issued)  # before _inflight grows: see _account
+        self._inflight.append((packed, t_issued, seq, self._steps_hi()))
         p = self._pstats
         p["issued"] += 1
         p["inflight_sum"] += len(self._inflight)
@@ -4538,30 +4633,29 @@ class DecodeEngine:
                 "dispatch", seq, cat="disp", inflight=len(self._inflight),
             )
 
-    def _process_oldest(self) -> None:  # graftcheck: runs-on(loop)
+    def _process_oldest(self,
+                        t_open: Optional[float] = None) -> float:  # graftcheck: runs-on(loop)
         """Block on the OLDEST in-flight dispatch's packed outputs and
         run the host half: stream/bookkeep its tokens, retire finished
         rows.  FIFO processing keeps step numbering, stream order, and
         slot retirement identical to the synchronous loop at any
-        pipeline depth."""
-        packed, t_issue, seq, _steps = self._inflight.popleft()
-        t_block = time.perf_counter()
-        self._busy_since = t_block
+        pipeline depth.
+
+        Two spans share the stamp between them: ``resolve`` (opened at
+        ``t_open``, like ``issue``) is the blocked fetch, ``unpack``
+        the host half; unpack's closing stamp is returned."""
+        if t_open is None:
+            t_open = time.perf_counter()
+        self._account(t_open)  # before _inflight shrinks: see _account
+        packed, _t_issued, seq, _steps = self._inflight.popleft()
+        self._busy_since = t_open
         try:
             _inject_fault("engine.resolve")  # chaos: slow readback
-            # the resolve span's duration IS the blocked wait; the time
-            # the pipeline hid (issue -> block) rides as an arg
-            with self.recorder.span(
-                "resolve", track="engine.loop", seq=seq,
-                hidden_ms=round((t_block - t_issue) * 1e3, 3),
-            ):
-                arr = np.asarray(packed)  # (3, K, slots) f32, 1 transfer
+            arr = np.asarray(packed)  # (3, K, slots) f32, 1 transfer
         finally:
             self._busy_since = None
-        t_done = time.perf_counter()
-        p = self._pstats
-        p["hidden_ms"] += (t_block - t_issue) * 1e3
-        p["wait_ms"] += (t_done - t_block) * 1e3
+            t_done = self._loop_span("resolve", t_open, seq=seq)
+            self._account(t_done, "wait_ms")
         prc = self._profile
         if prc is not None and prc["profiler"].active:
             # the np.asarray above fetched the dispatch's packed
@@ -4574,12 +4668,13 @@ class DecodeEngine:
         toks = arr[0].astype(np.int32)
         lps = arr[1]
         valid = arr[2] > 0.5
+        n_tokens = int(valid.sum())
         self._stats["dispatches"] += 1
         # "steps" counts device FORWARDS (a spec dispatch is ONE verify
         # forward however many packed rows it returns); emitted_tokens /
         # steps is then the live tokens-per-forward (acceptance) rate
         self._stats["steps"] += 1 if self.spec_k else toks.shape[0]
-        self._stats["emitted_tokens"] += int(valid.sum())
+        self._stats["emitted_tokens"] += n_tokens
         if self.spec_k is not None:
             # spec honesty: a live row emits >= 1 token per verify
             # forward, so rows-with-any-valid is the per-forward live
@@ -4610,6 +4705,7 @@ class DecodeEngine:
                 if sl.remaining <= 0 or tok == sl.req["eos_id"]:
                     self._finish(i)
                     self._release_slot_pages(i)
+        return self._loop_span("unpack", t_done, seq=seq, tokens=n_tokens)
 
     def _maybe_warn_spec_loss(self) -> None:
         """One-time operator warning when MEASURED acceptance makes
@@ -4697,10 +4793,11 @@ class DecodeEngine:
         new: List[Dict[str, Any]] = []
         ctrls: List[Dict[str, Any]] = []
         try:
-            item = (
-                self._queue.get(timeout=block_s) if block_s
-                else self._queue.get_nowait()
-            )
+            if block_s:
+                with self._idle_wait():
+                    item = self._queue.get(timeout=block_s)
+            else:
+                item = self._queue.get_nowait()
             while True:
                 # skip poison pills and futures submit's close/broken
                 # race check already failed (their request must not be
@@ -4977,7 +5074,11 @@ class DecodeEngine:
         from mlcomp_tpu.parallel.distributed import ChannelClosed
 
         try:
-            rec = dist.recv()
+            if idle:
+                with self._idle_wait():
+                    rec = dist.recv()
+            else:
+                rec = dist.recv()
         except ChannelClosed:
             return False
         if rec.get("stop"):
@@ -5024,7 +5125,14 @@ class DecodeEngine:
                 if not self.fused_admission:
                     self._drain_inflight()
                 try:
-                    self._start_admission(req)
+                    # the admission's host set-up (padded row, mask
+                    # upload, cache lookups, the fresh prefill cache)
+                    with self.recorder.span(
+                        "admission_start", track="engine.loop",
+                        rid=req.get("rid", 0),
+                        trace_id=req.get("trace_id"),
+                    ):
+                        self._start_admission(req)
                 except Exception as e:
                     self._fail_queued(req, e)
         if self._adm is not None and self._dist is None:
@@ -5073,14 +5181,21 @@ class DecodeEngine:
             # error, never the joiner's — then the one
             # remaining synchronous boundary, whose insert/
             # export/import faults are admission-scoped
-            self._drain_inflight()
-            try:
-                self._complete_admission()
-            except Exception as e:
-                self._fail_admission(e)
+            with self._admission_complete_span(adm):
+                self._drain_inflight()
+                try:
+                    self._complete_admission()
+                except Exception as e:
+                    self._fail_admission(e)
         return issued
 
     def _loop_body(self) -> None:  # graftcheck: runs-on(loop)
+        # every iteration is one ``boundary`` span on the engine.loop
+        # track, tiled without a gap by maintenance | admission_tick |
+        # issue | (resolve unpack)*: each child opens at the stamp that
+        # closed the one before (``t``), and the next boundary opens
+        # where this one closed (``t0``)
+        t0 = self._t_acct = time.perf_counter()
         while not (self._stop.is_set() or self._exit_loop.is_set()):
             if self._broken is not None:
                 # engine-level failure (donated buffers may be gone):
@@ -5127,9 +5242,11 @@ class DecodeEngine:
                     # head request fits the page budget, shrink to the
                     # floor at quiesce
                     self._elastic_tick()
+                t = self._loop_span("maintenance", t0)
                 issued = self._admission_tick()
+                t = self._loop_span("admission_tick", t)
                 if not issued and any(s is not None for s in self._host):
-                    self._issue_dispatch()
+                    t = self._issue_dispatch(t_open=t)
                     issued = True
                 # steady state keeps pipeline_depth dispatches in
                 # flight (resolve down to depth-1 after each issue);
@@ -5140,7 +5257,8 @@ class DecodeEngine:
                     issued and (self._adm is None or self.fused_admission)
                 ) else 0
                 while len(self._inflight) > keep:
-                    self._process_oldest()
+                    t = self._process_oldest(t_open=t)
+                t0 = self._loop_span("boundary", t0, t)
             except Exception as e:  # engine-level failure
                 self._broken = e
                 if self._unhealthy_reason is None:

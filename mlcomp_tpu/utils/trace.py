@@ -1,26 +1,24 @@
-"""Tracing: host-side span tracer + device profiler hooks.
+"""Tracing: the host-side span recorder.
 
 The reference has no tracing subsystem (task logs in the DB are its only
-observability); this module gives the TPU build two layers the reference
-lacks:
-
-- ``Tracer`` — a lightweight host-side span recorder (wall-clock, thread
-  aware) that serializes to Chrome trace-event JSON, viewable in
-  ``chrome://tracing`` / Perfetto.  The Trainer wraps epochs, data loading
-  and step dispatch in spans when ``cfg["trace"]`` is set; executors can
-  add their own via ``get_tracer()``.  With ``max_events`` set the
-  recorder becomes a bounded RING: the newest N events are kept and the
-  oldest silently evicted (``dropped`` counts them) — the always-on
-  flight-recorder mode the serving engine runs, exportable on demand via
-  ``export(last_ms=...)`` (``GET /trace`` on the serve daemon).
-- ``device_profile`` — a context manager around ``jax.profiler`` tracing,
-  producing a TensorBoard-loadable device profile (XLA op timeline, HBM
-  usage) for the hot path.  Host spans tell you WHERE time goes between
-  steps; the device profile tells you where it goes inside one.
+observability); this module gives the TPU build ``Tracer`` — a
+lightweight host-side span recorder (wall-clock, thread aware) that
+serializes to Chrome trace-event JSON, viewable in ``chrome://tracing``
+/ Perfetto.  The Trainer wraps epochs, data loading and step dispatch
+in spans when ``cfg["trace"]`` is set; executors can add their own via
+``get_tracer()``.  With ``max_events`` set the recorder becomes a
+bounded RING: the newest N events are kept and the oldest silently
+evicted (``dropped`` counts them) — the always-on flight-recorder mode
+the serving engine runs, exportable on demand via
+``export(last_ms=...)`` (``GET /trace`` on the serve daemon).
 
 Host spans deliberately measure *dispatch* time under JAX's async
 execution: a long ``step`` span means the host blocked (queue full, sync
-fetch) — itself a signal.  Use ``device_profile`` for on-chip truth.
+fetch) — itself a signal.  On-chip truth comes from a device capture
+(``obs/devprof.py``, ``GET /profile``); the ``clock_sync`` record every
+export carries is what places these spans beside one: the recorder's
+epoch as a ``time.perf_counter()`` reading, the clock a consumer stamps
+around its own capture.
 
 Track model: every event carries the recording thread's id, so worker
 threads show as separate Perfetto tracks for free.  Named logical
@@ -329,8 +327,19 @@ class Tracer:
         # which process epoch a windowed export came from.
         export_unix_us = time.time() * 1e6
         export_trace_us = self._now_us()
+        # the same contract for a consumer that holds the events alone
+        # (otherData does not travel with them): the epoch every ``ts``
+        # counts from, on this host's monotonic clock and as unix time —
+        # perf_counter_s(event) = epoch_perf_counter_s + ts / 1e6
+        clock_sync = {
+            "name": "clock_sync", "ph": "M", "pid": pid, "tid": 0,
+            "args": {
+                "epoch_perf_counter_s": self._t0,
+                "epoch_unix_us": export_unix_us - export_trace_us,
+            },
+        }
         return {
-            "traceEvents": meta + evs,
+            "traceEvents": meta + evs + [clock_sync],
             "displayTimeUnit": "ms",
             "otherData": {
                 "dropped_events": dropped,
@@ -381,6 +390,11 @@ class _NullTracer(Tracer):
     def _async(self, ph, name, aid, cat, track, args) -> None:
         pass
 
+    def export(self, last_ms: Optional[float] = None) -> Dict[str, Any]:
+        body = super().export(last_ms)
+        body["traceEvents"] = []  # nothing recorded, no clock to sync
+        return body
+
     def save(self, path: Optional[str] = None) -> str:
         raise ValueError("null tracer has nothing to save")
 
@@ -405,24 +419,3 @@ def set_tracer(tracer: Optional[Tracer]) -> None:
 def get_tracer() -> Tracer:
     """The installed tracer, or a no-op one."""
     return _current[0] if _current else _NULL
-
-
-@contextmanager
-def device_profile(log_dir: str, host_tracer_level: int = 2):
-    """Capture a JAX/XLA device profile into ``log_dir`` (TensorBoard
-    'profile' plugin format: op timeline, HBM, roofline)."""
-    import jax
-
-    jax.profiler.start_trace(log_dir, host_tracer_level=host_tracer_level)
-    try:
-        yield log_dir
-    finally:
-        jax.profiler.stop_trace()
-
-
-def annotate(name: str):
-    """Named region visible in the device profile's host track — use around
-    code inside a profiled section (cheap; no-op outside profiling)."""
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)

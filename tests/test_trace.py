@@ -123,6 +123,45 @@ def test_filter_export_by_trace_id_and_rid():
     assert [e for e in empty["traceEvents"] if e["ph"] != "M"] == []
 
 
+@pytest.mark.parametrize("how", ["whole", "last_ms", "one_request"])
+def test_export_carries_one_clock_sync_on_the_perf_counter_clock(how):
+    """Every export (whole ring, trailing window, one request's events)
+    carries exactly one ``clock_sync`` record inside ``traceEvents``,
+    and its epoch puts a span back on ``time.perf_counter()``: a
+    consumer that stamped that clock around its own work (a device
+    capture) can place the recorder's events beside it."""
+    tr = Tracer(max_events=64)
+    tr.async_begin("request", 7, cat="req", trace_id="ab" * 16)
+    t_before = time.perf_counter()
+    with tr.span("insert", track="engine.loop", rid=7):
+        pass
+    t_after = time.perf_counter()
+    tr.complete("issue", tr.to_trace_us(t_before),
+                (t_after - t_before) * 1e6, track="engine.loop", rid=7)
+    body = {
+        "whole": lambda: tr.export(),
+        "last_ms": lambda: tr.export(last_ms=60000),
+        "one_request": lambda: filter_export(tr.export(), rid=7),
+    }[how]()
+    sync = [e for e in body["traceEvents"] if e["name"] == "clock_sync"]
+    assert len(sync) == 1 and sync[0]["ph"] == "M"
+    epoch = sync[0]["args"]["epoch_perf_counter_s"]
+    by_name = {e["name"]: e for e in body["traceEvents"] if e["ph"] == "X"}
+    span = by_name["insert"]
+    assert t_before <= epoch + span["ts"] / 1e6
+    assert epoch + (span["ts"] + span["dur"]) / 1e6 <= t_after
+    # a span recorded from the caller's own stamps comes back on them
+    given = by_name["issue"]
+    assert epoch + given["ts"] / 1e6 == pytest.approx(t_before, abs=1e-6)
+    assert epoch + (given["ts"] + given["dur"]) / 1e6 == pytest.approx(
+        t_after, abs=1e-6
+    )
+    # the same epoch as unix time: what otherData's offset already said
+    assert sync[0]["args"]["epoch_unix_us"] == pytest.approx(
+        body["otherData"]["clock_offset_us"], abs=1.0
+    )
+
+
 def test_trainer_writes_trace(tmp_path):
     from mlcomp_tpu.train.loop import Trainer
 

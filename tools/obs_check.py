@@ -58,6 +58,7 @@ import os
 import re
 import sys
 import threading
+import time
 import urllib.request
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -82,6 +83,7 @@ DOCUMENTED_SERVE_METRICS = [
     "mlcomp_engine_pipeline_inflight",
     "mlcomp_engine_pipeline_peak_inflight",
     "mlcomp_engine_pipeline_issued_total",
+    "mlcomp_engine_pipeline_host_ms_total",
     "mlcomp_engine_pipeline_hidden_ms_total",
     "mlcomp_engine_pipeline_wait_ms_total",
     "mlcomp_engine_pipeline_overlap_efficiency",
@@ -442,6 +444,14 @@ def run(n_requests: int = 3) -> dict:
             s2b["mlcomp_engine_dispatch_k_changes_total"][""] > changes0
         ), "adaptive-K gauge never moved under the burst"
 
+        # the last request resolves one boundary before the loop reads
+        # the dispatch still in flight behind it: let the pipeline
+        # empty, or the export holds a dispatch span not yet closed
+        for _ in range(200):
+            if not json.loads(get("/healthz"))["engine"]["pipeline"][
+                    "inflight"]:
+                break
+            time.sleep(0.01)
         trace = json.loads(get("/trace?last_ms=600000"))
         evs = trace["traceEvents"]
         assert isinstance(evs, list) and evs, "empty trace"
@@ -455,9 +465,12 @@ def run(n_requests: int = 3) -> dict:
         )
         assert begins and begins == ends, (begins, ends)
         names = {e["name"] for e in evs}
-        for want in ("issue", "resolve", "request", "first_token",
+        for want in ("boundary", "maintenance", "admission_tick",
+                     "issue", "resolve", "unpack", "admission_start",
+                     "admission_complete",
+                     "request", "inserted", "first_token",
                      "prefill_chunk", "insert", "prefix_cache.lookup",
-                     "kv_registry.lookup"):
+                     "kv_registry.lookup", "clock_sync"):
             assert want in names, f"missing trace span {want!r}"
         # the /profile capture merged a DEVICE track: a named
         # engine.device thread whose complete spans sit inside the
