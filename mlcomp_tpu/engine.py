@@ -943,10 +943,12 @@ class DecodeEngine:
         # (idle_wait, booked nowhere); hidden_ms is the part of
         # host_ms spent while at least one dispatch was in flight;
         # inflight_sum/issued is the mean in-flight depth at issue
-        # (occupancy)
+        # (occupancy); rows_attended/rows_total is the share of the
+        # carry's rows that held a request when a dispatch went out
         self._pstats = {  # guarded_by: loop [writes]
             "issued": 0, "host_ms": 0.0, "hidden_ms": 0.0, "wait_ms": 0.0,
             "inflight_sum": 0, "peak_inflight": 0,
+            "rows_attended": 0, "rows_total": 0,
         }
         self._t_acct = time.perf_counter()  # guarded_by: loop [writes]
         # per-request latency reservoirs (most recent ~2k requests;
@@ -1811,6 +1813,16 @@ class DecodeEngine:
             "overlap_efficiency": round(p["hidden_ms"] / p["host_ms"], 4)
             if p["host_ms"] > 0 else None,
         }
+        out["attention"] = {
+            # slot rows that held a request at issue, over all rows of
+            # all dispatches: the rest are handed an empty window and
+            # cost the decode attention neither a fetch nor compute
+            "rows_attended": p["rows_attended"],
+            "rows_total": p["rows_total"],
+            "rows_attended_share": round(
+                p["rows_attended"] / p["rows_total"], 4
+            ) if p["rows_total"] else None,
+        }
         out["latency"] = {
             # "samples" is the WINDOW the percentiles summarize (the
             # deque, capped at its maxlen); "lifetime_samples" is the
@@ -1965,6 +1977,13 @@ class DecodeEngine:
             p["hidden_ms"])
         ctr("mlcomp_engine_pipeline_wait_ms_total",
             "Host ms blocked on dispatch outputs", p["wait_ms"])
+        ctr("mlcomp_engine_attention_rows_attended_total",
+            "Slot rows holding a request at issue, summed over "
+            "dispatches (the decode attention walks only these)",
+            p["rows_attended"])
+        ctr("mlcomp_engine_attention_rows_total",
+            "Slot rows in the carry at issue, summed over dispatches",
+            p["rows_total"])
         gau("mlcomp_engine_pipeline_depth", "Configured pipeline depth",
             self.pipeline_depth)
         gau("mlcomp_engine_pipeline_inflight",
@@ -3200,9 +3219,13 @@ class DecodeEngine:
                 done_now = live & (
                     (tok == eos_row) | (remaining <= 0)
                 )
+                # a row that holds no request attends nothing: with
+                # no valid slot its window is empty, so the attention
+                # neither fetches nor computes its stale buffer (the
+                # KV write at its frozen cursor stays)
                 logits, kv2 = forward(
                     kv, tok[:, None], positions[:, None], cursors,
-                    kv_mask,
+                    kv_mask & live[:, None],
                 )
                 carry2 = (
                     kv2, logits[:, -1].astype(jnp.float32),
@@ -3286,7 +3309,8 @@ class DecodeEngine:
                 tuple(dstate["pages"]) if fused_kv else dstate["cache"]
             )
             logits, kv_out = forward(
-                kv0, seq, pos, dstate["cursors"], kv_mask
+                kv0, seq, pos, dstate["cursors"],
+                kv_mask & live0[:, None],   # as the scan core's one_step
             )
             lg = logits.astype(jnp.float32)               # (slots, K+1, V)
             greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
@@ -4622,6 +4646,11 @@ class DecodeEngine:
         p = self._pstats
         p["issued"] += 1
         p["inflight_sum"] += len(self._inflight)
+        # rows whose window the attention walks in this dispatch, by
+        # the host's slot mirror (a row that retires inside an
+        # in-flight dispatch still counts until its tokens are read)
+        p["rows_attended"] += sum(1 for sl in self._host if sl is not None)
+        p["rows_total"] += len(self._host)
         if len(self._inflight) > p["peak_inflight"]:
             p["peak_inflight"] = len(self._inflight)
         # the dispatch's LIFETIME (issue -> outputs read) as an async

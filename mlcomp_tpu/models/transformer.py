@@ -80,6 +80,18 @@ class RMSNorm(nn.Module):
         return rmsnorm(x, scale, self.dtype)
 
 
+def _window_start(kv_mask, b):
+    """Per-row first valid cache slot of a LEFT-padded ``kv_mask``
+    (B, L) — the int8 kernels' ``kv_start``.  A row with no valid slot
+    starts at L, past any stop: its window is empty and the kernels
+    neither fetch nor compute it (the engine masks rows that hold no
+    request that way)."""
+    if kv_mask is None:
+        return jnp.zeros((b,), jnp.int32)
+    first = jnp.argmax(kv_mask.astype(jnp.int32), axis=1).astype(jnp.int32)
+    return jnp.where(jnp.any(kv_mask, axis=1), first, kv_mask.shape[1])
+
+
 def _row_cursor_dus(buf, upd, cur, seq_axis):
     """Write ``upd[r]`` into ``buf`` at row r's cursor slot(s) —
     per-row ``dynamic_update_slice`` in a ``fori_loop``, NOT a batched
@@ -389,12 +401,7 @@ class SelfAttention(nn.Module):
             vs_i, rows, pos, vs_.reshape(b * s, hkv, 1).astype(sdt)
         )
 
-        if kv_mask is not None:
-            row_start = jnp.argmax(
-                kv_mask.astype(jnp.int32), axis=1
-            ).astype(jnp.int32)
-        else:
-            row_start = jnp.zeros((b,), jnp.int32)
+        row_start = _window_start(kv_mask, b)
         qp = (
             jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, dhp - dh)))
             if dhp != dh else q
@@ -683,12 +690,7 @@ class SelfAttention(nn.Module):
                 )[:, :, None, :]
                 cks.value = jnp.where(hit, ks_dense.astype(sdt), cks.value)
                 cvs.value = jnp.where(hit, vs_dense.astype(sdt), cvs.value)
-            if kv_mask is not None:
-                row_start = jnp.argmax(
-                    kv_mask.astype(jnp.int32), axis=1
-                ).astype(jnp.int32)
-            else:
-                row_start = jnp.zeros((b,), jnp.int32)
+            row_start = _window_start(kv_mask, b)
             if s == 1:
                 return flash(row_start, cur + 1)
             return chunk_attend(row_start, cur + 1)
@@ -751,12 +753,7 @@ class SelfAttention(nn.Module):
             )
         index.value = i + s
 
-        if kv_mask is not None:
-            start = jnp.argmax(kv_mask.astype(jnp.int32), axis=1).astype(
-                jnp.int32
-            )
-        else:
-            start = jnp.zeros((b,), jnp.int32)
+        start = _window_start(kv_mask, b)
 
         if s == 1:
             return flash(start, i + 1)

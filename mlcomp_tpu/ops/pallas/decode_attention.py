@@ -2,9 +2,8 @@
 
 At serving batch sizes the decode step is KV-bandwidth-bound: every new
 token re-reads the whole (B, L, Hkv, dh) cache while computing a single
-query row per sequence (measured in bench.py's decode line: at B=8 /
-S=2304 the bf16 KV read is ~2.4 GB/step and dwarfs the weight traffic —
-the int8-WEIGHT kernel loses there for exactly that reason).  Storing
+query row per sequence (at B=8 / S=2304 the bf16 KV read is ~2.4
+GB/step by its shapes, more than the weights).  Storing
 the cache int8 halves those bytes, but only if the dequantize happens
 after the block is already in VMEM — the same argument as
 quant_matmul.py, applied to the other big decode tensor.  XLA cannot:
@@ -19,36 +18,42 @@ a jnp ``k8 * ks`` prefix materializes the bf16 copy in HBM every step
   (G, BLK) logit block, not the (BLK, dh) keys; the V scale folds into
   the probability row before the p@V matmul.  Dequantization never
   touches HBM.
-- cache layout (B, Hkv, L, dh) / scales (B, Hkv, 1, L); the grid is
-  (B, L/BLK) — ALL KV heads ride in each block as one batched
-  dot_general.  A single query row makes every matmul tiny, so grid
-  steps must be few and fat: the first cut of this kernel ran a
-  (B, Hkv, L/BLK) grid and lost 2.7x to XLA on pure per-step overhead
-  (640 steps x ~1 us); folding the head axis into the block cuts the
-  step count Hkv-fold and amortizes the same bytes.  Online softmax
-  (m, l, acc VMEM scratch) carries across KV steps — the flash recipe
-  with a single query block.
+- cache layout (B, Hkv, L, dh) / scales (B, Hkv, 1, L); ALL KV heads
+  of a stretch of tokens ride one batched dot_general.  A single query
+  row makes every matmul tiny, so per-step overhead, not bandwidth, is
+  the design constraint at decode shapes: the first cut ran a
+  (B, Hkv, L/BLK) grid and lost to XLA on grid steps alone (another
+  toolchain, another shape; not measured on this chip).  Online softmax
+  (m, l, acc VMEM scratch) carries across a row's steps — the flash
+  recipe with a single query block.
 - GQA: the G = H/Hkv query heads of a group ride the sublane axis of
   one (G, dh) block (padded to 8 sublanes), so shared KV heads are
   read once per group, never replicated.
 - valid-slot masking via scalar-prefetched per-row windows
   [kv_start, kv_stop): generation's LEFT-padded ragged prompts make
   invalid slots a prefix, so a window is exact (models/generation.py
-  contract).  Blocks fully outside a row's window are clamped in the
-  K/V index maps to the nearest live block — the pipeline elides the
-  repeated HBM copy (flash_attention.py's copy-skip trick) — and their
-  compute is pl.when-skipped.  Because kv_stop is the decode cursor,
-  the not-yet-generated tail of the buffer costs no bandwidth.
+  contract), and because kv_stop is the decode cursor the
+  not-yet-generated tail of the buffer costs nothing.
+- the single-token kernel (``decode_attention``) WALKS the window: the
+  cache stays in HBM, one grid step holds a block of rows' queries and
+  outputs, and a loop a row brings the window's granules
+  (``auto_block_kv`` tokens each) through a double buffer — granule i+1, or the
+  next live row's first, lands while granule i computes.  A row whose
+  window is empty (the engine hands one to every slot that holds no
+  request) starts no copy and computes nothing.  The multi-query chunk
+  kernel and the paged twins still sweep a (B, L/BLK) BlockSpec grid
+  of fat blocks (``KV_BLOCK_BUDGET``): blocks outside a row's window
+  are clamped in the index maps to the nearest live block of that row,
+  so the pipeline elides the copy, and their compute is
+  pl.when-skipped.
 
-Measured on v5e (B=8, Hkv=16, L=2304 buffer, window 2100, dh=128,
-marginal fori_loop timing): 116.5 us/op vs 285.3 us for the XLA bf16
-masked-buffer path — 2.45x, an effective 648 GB/s on the int8 stream
-(~79% of the 819 GB/s roofline counted over the FULL buffer; the
-clamped index maps actually read only the live window, so true
-utilization is higher).  The first cut of this kernel ran a
-(B, Hkv, L/BLK) grid and measured 0.36x — per-grid-step overhead, not
-bandwidth, is the design constraint at decode shapes; see the layout
-note above.
+Measured on one v5e through the benchmark (InternLM2-1.8B serve cells:
+B 48, H 16, Hkv 8, dh 128, L 2560; ``kv8_decode_attn_roofline`` counts
+the LIVE keys and values): the (B, L/640) sweep this kernel replaced
+read 12.3% of that roofline in ``batch-offline`` and took the same
+~205-215 us a layer call with 10 of 48 rows live as with all 48
+(ledger, PR 24 and PR 25).  This kernel's readings, and the granule
+sweep behind ``KV_BLOCK_BUDGET``: PERF.md, PR 26.
 
 The upstream reference has no decode path at all (its infer stage is a
 batch forward); this kernel is part of the serving surface the TPU
@@ -72,14 +77,24 @@ NEG_INF = -1e30
 LANES = 128
 SUBLANES = 8
 
-# K+V block bytes per grid step, single-buffered.  Thin blocks pay
-# per-grid-step overhead (the original finding: blk 256 = 74.3% of the
-# live-window roofline at B=8/Hkv=16/dh=128/l_buf=2304), but VERY fat
-# blocks lose the pipeline's fill/drain amortization: the late round-4
-# sweep measured blk 384 (1.57 MB K+V, 6 steps/row) at 89.5% vs 768
-# (3.1 MB, 3 steps) at 82.0%.  ~2 MB per step is the sweet spot the
-# quant_matmul sweeps found too.
+# K+V bytes of one block: a grid step of the BlockSpec-swept kernels
+# (chunk and paged) and one GRANULE of the single-token kernel's walk,
+# single-buffered.  For the walk, measured on one v5e at B 48 / Hkv 8 /
+# L 2560 (PERF.md, PR 26): a loop trip costs ~0.42 us + ~2.2 ns a token
+# whatever the granule, so thin granules lose on trips what they save
+# on a window's edges: 128 / 256 / 512 / 640 tokens took 46 / 39 / 36 /
+# 33 us at 10 live rows of ~560 tokens, 106 / 100 / 82 / 98 us at 48
+# rows of ~240, and 672 / 470 / 375 / 360 us over the whole buffer
+# (377 for the block sweep it replaced); at Hkv 16 / L 2304, whole
+# buffer, 128 / 256 / 384 / 768 took 147 / 115 / 106 / 106 us (109).
+# The largest block under ~2 MB (640 and 384 there) is never slower a
+# token than that sweep and within 17% of the best granule elsewhere.
+# For the chunk and paged kernels the value comes from another
+# toolchain's sweeps and is not measured on this chip.
 KV_BLOCK_BUDGET = 2 * 1024 * 1024 + 128 * 1024
+
+# rows whose queries and outputs ride one grid step of that kernel
+ROWS_PER_STEP = 64
 
 
 def auto_block_kv(l_buf: int, h_kv: int, dh: int) -> int:
@@ -99,8 +114,9 @@ def pick_buffer_len(s: int, h_kv: int, dh: int) -> int:
 
     The cache allocator must pick lengths the kernel can tile well: a
     buffer of 2176 slots (= 128 x 17) has no divisor between 128 and
-    itself, so the kernel degrades to 17 thin grid steps per row —
-    profiled 157 us/call vs ~100 at a fat block.  Up to a few extra
+    itself, so the BlockSpec-swept kernels degrade to 17 thin grid
+    steps per row (half again as slow on another toolchain; not
+    measured on this chip).  Up to a few extra
     padding blocks (beyond the decode cursor: masked AND clamp-skipped,
     so they cost bytes only at rest) buy a fat-block length."""
     base = -(-s // LANES) * LANES
@@ -165,50 +181,128 @@ def _flash_block_update(
     )
 
 
-def _flash_finalize(o_ref, acc_ref, l_ref):
+def _flash_finalize(o_ref, acc_ref, l_ref, row=0):
     l = l_ref[:, :, :1]
-    o_ref[0] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)).astype(
+    o_ref[row] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)).astype(
         o_ref.dtype
     )
 
 
 def _kernel(
     start_ref, stop_ref,  # scalar prefetch: (B,) int32 each
-    q_ref, k_ref, ks_ref, v_ref, vs_ref,
+    q_ref, k_hbm, ks_hbm, v_hbm, vs_hbm,
     o_ref,
+    k_buf, ks_buf, v_buf, vs_buf, sem, slot_ref,
     acc_ref, m_ref, l_ref,
-    *, scale: float, block_kv: int,
+    *, scale: float, granule: int,
 ):
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    nk = pl.num_programs(1)
+    """One grid step a BLOCK OF ROWS, whose queries and outputs sit in
+    VMEM; K, V and their scales stay in HBM.  Each row walks the
+    granules its window covers and nothing else: granule i+1's copies
+    fly while granule i's flash update runs, and a row's last trip
+    starts the first granule of the next row that has one, so a
+    one-granule row does not expose its fetch either.  A row whose
+    window is empty starts no copy, computes nothing and costs one
+    trip of a scalar loop."""
+    nb, _, l_buf, _ = k_hbm.shape
+    rows = q_ref.shape[0]
+    row0 = pl.program_id(0) * rows
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+    def span(r):
+        """Row r's clamped window and the granules it covers:
+        (lo, hi, first granule, how many)."""
+        lo = jnp.maximum(start_ref[r], 0)
+        hi = jnp.minimum(stop_ref[r], l_buf)
+        g0 = lo // granule
+        n = jnp.where(hi > lo, (hi + granule - 1) // granule - g0, 0)
+        return lo, hi, g0, n
 
-    lo = start_ref[b]
-    hi = stop_ref[b]
-    live = (j * block_kv < hi) & ((j + 1) * block_kv > lo)
-
-    def mask_fn(shape):
-        cols = j * block_kv + jax.lax.broadcasted_iota(jnp.int32, shape, 2)
-        return (cols >= lo) & (cols < hi)
-
-    @pl.when(live)
-    def _step():
-        q = q_ref[0]                               # (Hkv, Gp, dh)
-        _flash_block_update(
-            q, k_ref[0].astype(q.dtype), ks_ref[0],
-            v_ref[0].astype(q.dtype), vs_ref[0],
-            mask_fn, scale, acc_ref, m_ref, l_ref,
+    def next_row(r):
+        """The first row >= r whose window is not empty (nb: none)."""
+        return jax.lax.while_loop(
+            lambda i: (i < nb) & (span(jnp.minimum(i, nb - 1))[3] == 0),
+            lambda i: i + 1, r,
         )
 
-    @pl.when(j == nk - 1)
-    def _finalize():
-        _flash_finalize(o_ref, acc_ref, l_ref)
+    def copies(r, g, slot):
+        # the start and the wait halves build the SAME descriptors, so
+        # each slot's semaphore always balances
+        cols = pl.ds(pl.multiple_of(g * granule, granule), granule)
+        return [
+            pltpu.make_async_copy(src, dst.at[slot], sem.at[slot])
+            for src, dst in (
+                (k_hbm.at[r, :, cols, :], k_buf),
+                (v_hbm.at[r, :, cols, :], v_buf),
+                (ks_hbm.at[r, :, cols], ks_buf),
+                (vs_hbm.at[r, :, cols], vs_buf),
+            )
+        ]
+
+    def start_first(r, slot):
+        @pl.when(r < nb)
+        def _start():
+            for cp in copies(r, span(r)[2], slot):
+                cp.start()
+
+    @pl.when(row0 == 0)
+    def _prologue():
+        slot_ref[0] = 0
+        start_first(next_row(0), 0)
+
+    def row(j, carry):
+        r = row0 + j
+        lo, hi, g0, n = span(r)
+
+        @pl.when(n == 0)
+        def _empty():
+            o_ref[j] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+
+        @pl.when(n > 0)
+        def _walk():
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+            m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[:] = jnp.zeros_like(l_ref)
+            slot0 = slot_ref[0]
+            q = q_ref[j]                           # (Hkv, Gp, dh)
+
+            def trip(i, carry):
+                slot = jax.lax.rem(slot0 + i, 2)
+                g = g0 + i
+
+                @pl.when(i + 1 < n)
+                def _next_granule():
+                    for cp in copies(r, g + 1, 1 - slot):
+                        cp.start()
+
+                @pl.when(i + 1 == n)
+                def _next_row():
+                    start_first(next_row(r + 1), 1 - slot)
+
+                for cp in copies(r, g, slot):
+                    cp.wait()
+
+                def mask_fn(shape):
+                    cols = g * granule + jax.lax.broadcasted_iota(
+                        jnp.int32, shape, 2
+                    )
+                    return (cols >= lo) & (cols < hi)
+
+                _flash_block_update(
+                    q, k_buf[slot].astype(q.dtype),
+                    ks_buf[slot][:, None, :],
+                    v_buf[slot].astype(q.dtype),
+                    vs_buf[slot][:, None, :],
+                    mask_fn, scale, acc_ref, m_ref, l_ref,
+                )
+                return carry
+
+            jax.lax.fori_loop(0, n, trip, 0)
+            slot_ref[0] = jax.lax.rem(slot0 + n, 2)
+            _flash_finalize(o_ref, acc_ref, l_ref, j)
+
+        return carry
+
+    jax.lax.fori_loop(0, rows, row, 0)
 
 
 def decode_attention(
@@ -231,8 +325,12 @@ def decode_attention(
     scale-write stream; the kernel upcasts in VMEM).  The singleton
     keeps the scale block TPU-tileable at zero byte cost;
     kv_start/kv_stop: (B,) int32 valid-slot windows (default: the whole
-    buffer).  L and dh must be lane multiples (the cache allocator
-    rounds L up; dh pads).  Returns (B, H, dh) in q.dtype.
+    buffer).  Cost follows the windows: a row is fetched and computed
+    in granules of ``block_kv`` tokens (default :func:`auto_block_kv`)
+    from its window's first to its last, and a row whose window is
+    empty (start >= stop) costs nothing and returns zeros.  L and dh
+    must be lane multiples (the cache allocator rounds L up; dh pads).
+    Returns (B, H, dh) in q.dtype.
     """
     b, h, dh = q.shape
     _, h_kv, l_buf, _ = k8.shape
@@ -252,19 +350,18 @@ def decode_attention(
         interpret = interpret_default()
     scale = scale if scale is not None else 1.0 / (dh**0.5)
     if block_kv is None:
-        blk = auto_block_kv(l_buf, h_kv, dh)
+        granule = auto_block_kv(l_buf, h_kv, dh)
     else:
-        blk = next(
+        granule = next(
             (bl for bl in (block_kv, 512, 256, LANES)
              if bl <= block_kv and bl % LANES == 0 and l_buf % bl == 0),
             None,
         )
-        if blk is None:
+        if granule is None:
             raise ValueError(
-                f"block_kv={block_kv}: need a lane-multiple block "
+                f"block_kv={block_kv}: need a lane-multiple granule "
                 f"(>= {LANES}) dividing the cache length {l_buf}"
             )
-    nk = l_buf // blk
 
     rep = h // h_kv
     gp = max(SUBLANES, -(-rep // SUBLANES) * SUBLANES)
@@ -282,44 +379,45 @@ def decode_attention(
         else jnp.broadcast_to(kv_stop, (b,)).astype(jnp.int32)
     )
 
-    def _clamp(b_, j, start_ref, stop_ref):
-        # clamp dead steps onto the nearest live block: unchanged index
-        # => the pipeline skips the HBM->VMEM copy
-        lo_b = jnp.minimum(start_ref[b_] // blk, nk - 1)
-        hi_b = jnp.maximum((stop_ref[b_] - 1) // blk, lo_b)
-        return jnp.clip(j, lo_b, hi_b)
-
-    def kvj(b_, j, start_ref, stop_ref):
-        return (b_, 0, _clamp(b_, j, start_ref, stop_ref), 0)
-
-    def ksj(b_, j, start_ref, stop_ref):
-        return (b_, 0, 0, _clamp(b_, j, start_ref, stop_ref))
-
+    # rows a grid step: their queries and outputs are one VMEM block
+    # (16 KB a row at Hkv 8), so a row costs no pipeline step of its own
+    rows = max(d for d in range(1, min(b, ROWS_PER_STEP) + 1) if b % d == 0)
+    row = pl.BlockSpec((rows, h_kv, gp, dh), lambda i, *_: (i, 0, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, block_kv=blk),
+        functools.partial(_kernel, scale=scale, granule=granule),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b, nk),
-            in_specs=[
-                pl.BlockSpec((1, h_kv, gp, dh), lambda b_, j, *_: (b_, 0, 0, 0)),
-                pl.BlockSpec((1, h_kv, blk, dh), kvj),
-                pl.BlockSpec((1, h_kv, 1, blk), ksj),
-                pl.BlockSpec((1, h_kv, blk, dh), kvj),
-                pl.BlockSpec((1, h_kv, 1, blk), ksj),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, h_kv, gp, dh), lambda b_, j, *_: (b_, 0, 0, 0)
-            ),
+            grid=(b // rows,),
+            in_specs=[row, hbm, hbm, hbm, hbm],
+            out_specs=row,
             scratch_shapes=[
+                # two granule slots: one computes while the other lands
+                pltpu.VMEM((2, h_kv, granule, dh), k8.dtype),
+                pltpu.VMEM((2, h_kv, granule), ks.dtype),
+                pltpu.VMEM((2, h_kv, granule, dh), v8.dtype),
+                pltpu.VMEM((2, h_kv, granule), vs.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),  # slot of the next first granule
                 pltpu.VMEM((h_kv, gp, dh), jnp.float32),
                 pltpu.VMEM((h_kv, gp, LANES), jnp.float32),
                 pltpu.VMEM((h_kv, gp, LANES), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, h_kv, gp, dh), q.dtype),
+        # the slot parity and the prefetched first granule carry from
+        # one grid step to the next: the steps run in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
         interpret=interpret,
         name="decode_attention",
-    )(start, stop, qg, k8, ks, v8, vs)
+        # the scales as (B, Hkv, L): a (Hkv, granule) slice is whole
+        # tiles, where Mosaic pads a (.., 1, L) bf16 memref to two rows
+        # and refuses the one-row slice; the 3-D view is also XLA's own
+        # layout for the (B, Hkv, 1, L) cache, so the reshape is free
+    )(start, stop, qg, k8, ks.reshape(b, h_kv, l_buf), v8,
+      vs.reshape(b, h_kv, l_buf))
     return out[:, :, :rep].reshape(b, h, dh)
 
 

@@ -87,20 +87,27 @@ def test_flash_attention_causal_s4096(chip, grad):
         assert "flash_dq_kernel" in text and "flash_dkv_kernel" in text
 
 
-def _dense_cache(chip):
-    kv = chip((B, H, L, DH), jnp.int8)
-    scale = chip((B, H, 1, L), jnp.bfloat16)
-    bounds = chip((B,), jnp.int32)
+def _dense_cache(chip, b=B, h_kv=H, l_buf=L):
+    kv = chip((b, h_kv, l_buf, DH), jnp.int8)
+    scale = chip((b, h_kv, 1, l_buf), jnp.bfloat16)
+    bounds = chip((b,), jnp.int32)
     return kv, scale, kv, scale, bounds, bounds
 
 
-def test_decode_attention_dense_int8_kv(chip):
+# (slots, KV heads, buffer): chip_smoke.py's 1.2B daemon, and the
+# benchmark's serve cells (InternLM2-1.8B, GQA 16/8, 48 slots of
+# 2048 + 256 + 1 tokens rounded to 2560)
+@pytest.mark.parametrize("b,h_kv,l_buf", [(B, H, L), (48, 8, 2560)],
+                         ids=["smoke_1p2b", "cell_internlm2_1p8b"])
+def test_decode_attention_dense_int8_kv(chip, b, h_kv, l_buf):
     from mlcomp_tpu.ops.pallas.decode_attention import decode_attention
 
-    _compiles_to_a_kernel(
+    text = _compiles_to_a_kernel(
         functools.partial(decode_attention, interpret=False),
-        chip((B, H, DH), jnp.bfloat16), *_dense_cache(chip),
+        chip((b, H, DH), jnp.bfloat16), *_dense_cache(chip, b, h_kv, l_buf),
     )
+    # the benchmark's roofline reader matches the op by this name
+    assert "decode_attention" in text
 
 
 def test_decode_attention_chunk_sq5(chip):

@@ -85,6 +85,13 @@ def _overlapped_workload(model, params, fused, depth=2, prefill_chunk=4,
         ra = fa.result(timeout=300)
         rb = fb.result(timeout=300)
         st = eng.stats()
+        # the flight recorder's own account of the chunks that rode a
+        # decode dispatch (``prefill_chunk`` spans with ``fused``)
+        st["recorded_fused_chunks"] = sum(
+            1 for e in eng.recorder.events
+            if e["name"] == "prefill_chunk" and e.get("ph") == "X"
+            and e["args"].get("fused")
+        )
     finally:
         if fns_key is not None:
             _FNS[fns_key].update(eng._fns)
@@ -111,7 +118,12 @@ def test_fused_bit_identical_to_staged(kv_quant):
     # (no double count), and the overlapped admission is recorded
     assert st_f["prefill_chunks"] == st_s["prefill_chunks"]
     assert st_f["prefills"] == st_s["prefills"] == 2
-    assert st_f["fused_chunks"] == 2        # B's two run chunks
+    # how many of B's two run chunks rode a dispatch is the scheduler's
+    # business (a chunk rides only while A decodes, and under load A
+    # can finish first): the counter must agree with the recorded spans
+    assert 1 <= st_f["fused_chunks"] <= 2
+    assert st_f["fused_chunks"] == st_f["recorded_fused_chunks"]
+    assert st_s["recorded_fused_chunks"] == 0
     assert st_f["admissions_overlapped"] == 1
     assert st_s["fused_chunks"] == 0
     assert st_s["admissions_overlapped"] == 0

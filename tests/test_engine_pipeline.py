@@ -244,6 +244,78 @@ def test_pipeline_overlap_metrics_and_latency_percentiles():
         svc.close()
 
 
+@pytest.mark.parametrize("depth", [1, 2])
+def test_retired_and_unused_rows_are_handed_an_empty_window(
+        depth, monkeypatch):
+    """A row that holds no request costs the decode attention nothing:
+    from the dispatch after a request finishes, its slot's window is
+    empty (start >= stop) in every layer and step, as a never-used
+    slot's is throughout; the row still decoding beside it returns the
+    greedy tokens and logprobs of a run in which that slot never held a
+    request; and ``rows_attended_share`` counts the empty rows out."""
+    import mlcomp_tpu.ops.pallas.decode_attention as da
+
+    seen = []                        # (start, stop) per kernel call
+    real = da.decode_attention
+
+    def spy(q, k8, ks, v8, vs, kv_start=None, kv_stop=None, **kw):
+        jax.debug.callback(
+            lambda a, b: seen.append((np.asarray(a), np.asarray(b))),
+            kv_start, kv_stop, ordered=True,
+        )
+        return real(q, k8, ks, v8, vs, kv_start=kv_start,
+                    kv_stop=kv_stop, **kw)
+
+    monkeypatch.setattr(da, "decode_attention", spy)
+    model, params = _model_and_params(kv_quant=True)
+    rs = np.random.RandomState(26)
+    ids_long, ids_short = rs.randint(1, 64, 6).tolist(), [7, 8, 9]
+
+    def engine():
+        return DecodeEngine(
+            model, {"params": params}, slots=3, prompt_buckets=(16,),
+            max_new_cap=16, steps_per_dispatch=2, pipeline_depth=depth,
+        )
+
+    eng = engine()
+    try:
+        stream: "queue.Queue" = queue.Queue()
+        f_long = eng.submit(ids_long, 14, logprobs=True, stream=stream)
+        stream.get(timeout=300)              # slot 0 is decoding
+        eng.submit(ids_short, 2).result(timeout=300)   # slot 1, retired
+        mark = len(seen)
+        with_neighbour = f_long.result(timeout=300)
+        st = eng.stats()
+    finally:
+        eng.close()
+    after = seen[mark:]
+    assert len(after) >= 2 * 2               # >= one dispatch: 2 layers x K
+    assert after[0][0][0] < after[0][1][0]   # the live row's window
+    assert all(start[1] >= stop[1] for start, stop in after)  # retired
+    assert all(start[2] >= stop[2] for start, stop in seen)  # never used
+    # the retired row WAS attended while it held its request
+    assert any(start[1] < stop[1] for start, stop in seen[:mark])
+
+    att = st["attention"]
+    assert att["rows_total"] == 3 * st["pipeline"]["issued"]
+    # by the host's mirror: at least the rows the device saw live at a
+    # dispatch's first step, and never the unused slot
+    first_steps = seen[::2 * 2]
+    device_live = sum(int((a < b).sum()) for a, b in first_steps)
+    assert device_live <= att["rows_attended"] <= 2 * st["pipeline"]["issued"]
+    assert att["rows_attended_share"] == round(
+        att["rows_attended"] / att["rows_total"], 4
+    )
+
+    eng = engine()
+    try:
+        alone = eng.submit(ids_long, 14, logprobs=True).result(timeout=300)
+    finally:
+        eng.close()
+    assert with_neighbour["ids"] == alone["ids"]
+    assert with_neighbour["logprobs"] == alone["logprobs"]
+
+
 def test_report_server_serving_proxy_lifts_latency_and_pipeline():
     """/api/serving lifts the daemon's latency percentiles and
     pipeline overlap metrics to the top level of its payload."""

@@ -115,32 +115,96 @@ def test_chunk_kernel_matches_reference(h, h_kv, dh, s_q):
 
 
 def test_chunk_kernel_agrees_with_single_token_kernel():
-    """S == 1 chunk must match decode_attention exactly (same math,
-    same block walk)."""
+    """S == 1 chunk against decode_attention: the same arithmetic core
+    and, by default, the same partition of the online softmax (the
+    walk's granule is the chunk kernel's block), so BIT-equal; equal to
+    float rounding when the walk is handed a thinner granule."""
     from mlcomp_tpu.ops.pallas.decode_attention import (
         decode_attention_chunk,
     )
 
     rng = np.random.default_rng(2)
-    b, h, h_kv, dh, l_buf = 2, 8, 4, 128, 256
+    b, h, h_kv, dh, l_buf = 2, 8, 4, 128, 1024
     q = jnp.asarray(rng.normal(size=(b, h, dh)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(b, h_kv, l_buf, dh)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(b, h_kv, l_buf, dh)), jnp.float32)
     k8, ks = quantize_kv(k)
     v8, vs = quantize_kv(v)
     start = jnp.asarray([0, 11], jnp.int32)
-    stop = jnp.asarray([97, 64], jnp.int32)
-    a = decode_attention(
-        q, k8, ks[:, :, None, :], v8, vs[:, :, None, :],
-        kv_start=start, kv_stop=stop,
-    )
+    stop = jnp.asarray([897, 64], jnp.int32)
+    operands = (k8, ks[:, :, None, :], v8, vs[:, :, None, :])
     c = decode_attention_chunk(
-        q[:, None], k8, ks[:, :, None, :], v8, vs[:, :, None, :],
-        kv_start=start, kv_stop0=stop,
+        q[:, None], *operands, kv_start=start, kv_stop0=stop,
+    )[:, 0]
+    default = decode_attention(q, *operands, kv_start=start, kv_stop=stop)
+    np.testing.assert_array_equal(np.asarray(default), np.asarray(c))
+    thin = decode_attention(
+        q, *operands, kv_start=start, kv_stop=stop, block_kv=128,
     )
-    np.testing.assert_allclose(
-        np.asarray(a), np.asarray(c[:, 0]), atol=1e-5
+    assert not np.array_equal(np.asarray(thin), np.asarray(c))
+    np.testing.assert_allclose(np.asarray(thin), np.asarray(c), atol=1e-5)
+
+
+# rows of (start, stop) against a 2560-slot buffer; G is the kernel's
+# granule there (640 at 8 KV heads, 512 at 16), L the buffer
+_RAGGED = {
+    "empty_between_live": lambda G, L: [
+        (3, 700), (L, 41), (G, G + 1), (90, 90), (500, 20), (2 * G, L),
+    ],
+    "one_token": lambda G, L: [
+        (0, 1), (G - 1, G), (G, G + 1), (L - 1, L), (777, 778),
+    ],
+    "granule_edges": lambda G, L: [
+        (G, 3 * G), (G - 1, 3 * G + 1), (G + 1, 3 * G - 1),
+        (0, G), (2 * G, 2 * G + 1), (L - G, L),
+    ],
+    "whole_buffer": lambda G, L: [(0, L), (0, L)],
+}
+
+
+@pytest.mark.parametrize("case", sorted(_RAGGED))
+@pytest.mark.parametrize("h,h_kv", [(16, 8), (16, 16)], ids=["gqa", "mha"])
+def test_decode_kernel_walks_ragged_windows(h, h_kv, case):
+    """The kernel walks each row's own granules: every column of every
+    window is attended (float reference over the same int8 bytes), a
+    row with an empty window returns exact zeros whatever its bounds,
+    and what its neighbours return does not depend on it."""
+    from mlcomp_tpu.ops.pallas.decode_attention import auto_block_kv
+
+    dh, l_buf = 128, 2560
+    granule = auto_block_kv(l_buf, h_kv, dh)
+    assert granule <= l_buf // 4
+    win = _RAGGED[case](granule, l_buf)
+    b = len(win)
+    rng = np.random.default_rng(3)
+    q = jnp.asarray(rng.normal(size=(b, h, dh)), jnp.bfloat16)
+    k8, ks = quantize_kv(
+        jnp.asarray(rng.normal(size=(b, h_kv, l_buf, dh)), jnp.float32)
     )
+    v8, vs = quantize_kv(
+        jnp.asarray(rng.normal(size=(b, h_kv, l_buf, dh)), jnp.float32)
+    )
+    ks, vs = ks.astype(jnp.bfloat16), vs.astype(jnp.bfloat16)
+    operands = (k8, ks[:, :, None, :], v8, vs[:, :, None, :])
+    start, stop = (jnp.asarray(x, jnp.int32) for x in zip(*win))
+    scale = 1.0 / dh**0.5
+    out = np.asarray(decode_attention(
+        q, *operands, kv_start=start, kv_stop=stop, scale=scale,
+    ).astype(jnp.float32))
+    ref = np.asarray(_reference(
+        q, k8, ks.astype(jnp.float32), v8, vs.astype(jnp.float32),
+        start, stop, scale,
+    ))
+    np.testing.assert_allclose(out, ref, atol=2e-2)
+    empty = np.asarray(start >= stop)
+    assert (out[empty] == 0.0).all()
+    if empty.any():
+        # the same live rows beside rows that DO hold a window
+        filled = np.asarray(decode_attention(
+            q, *operands, kv_start=jnp.where(empty, 0, start),
+            kv_stop=jnp.where(empty, l_buf, stop), scale=scale,
+        ).astype(jnp.float32))
+        np.testing.assert_array_equal(out[~empty], filled[~empty])
 
 
 def test_chunk_kernel_tiles_wide_chunks():

@@ -357,18 +357,24 @@ def test_paged_kernel_bit_exact_vs_dense(chunk):
     skipped and unmapped pages poisoned with NaN scale bytes (a
     skipped page's garbage must never reach the accumulator — the
     interpret-mode unit that catches in-kernel DMA/masking bugs the
-    engine matrix would only surface as diverged tokens)."""
+    engine matrix would only surface as diverged tokens).  The dense
+    single-token kernel walks granules of the paged kernel's block by
+    default (the same partition of the online softmax: bit-exact) and
+    agrees to float rounding when handed a thinner one."""
     from mlcomp_tpu.ops.pallas.decode_attention import (
         decode_attention,
         decode_attention_chunk,
+        paged_block_kv,
         paged_decode_attention,
         paged_decode_attention_chunk,
         quantize_kv,
     )
 
     rng = np.random.RandomState(0)
-    B, H, HKV, DH, L, T = 2, 4, 2, 128, 128, 32
+    B, H, HKV, DH, L, T = 2, 8, 4, 128, 1024, 32
     MP = L // T
+    assert paged_block_kv(L, HKV, DH, T) == L
+    lo_hi = ((5, 900), (40, 41))
     k8, ks = quantize_kv(jnp.asarray(
         rng.randn(B, HKV, L, DH).astype(np.float32)
     ))
@@ -377,7 +383,7 @@ def test_paged_kernel_bit_exact_vs_dense(chunk):
     ))
     ks4 = ks[:, :, None, :].astype(jnp.bfloat16)
     vs4 = vs[:, :, None, :].astype(jnp.bfloat16)
-    start = jnp.asarray(np.array([5, 40], np.int32))
+    start = jnp.asarray(np.array([lo for lo, _ in lo_hi], np.int32))
     # pages: permuted physical placement; UNMAPPED pages poisoned
     P = RESERVED_PAGES + B * MP
     perm = rng.permutation(B * MP)
@@ -402,7 +408,7 @@ def test_paged_kernel_bit_exact_vs_dense(chunk):
     if chunk:
         S = 3
         q = jnp.asarray(rng.randn(B, S, H, DH).astype(np.float32))
-        stop0 = jnp.asarray(np.array([100, 41], np.int32))
+        stop0 = jnp.asarray(np.array([hi for _, hi in lo_hi], np.int32))
         dense = decode_attention_chunk(
             q, k8, ks4, v8, vs4, kv_start=start, kv_stop0=stop0
         )
@@ -412,14 +418,21 @@ def test_paged_kernel_bit_exact_vs_dense(chunk):
         )
     else:
         q = jnp.asarray(rng.randn(B, H, DH).astype(np.float32))
-        stop = jnp.asarray(np.array([100, 41], np.int32))
+        stop = jnp.asarray(np.array([hi for _, hi in lo_hi], np.int32))
         dense = decode_attention(
             q, k8, ks4, v8, vs4, kv_start=start, kv_stop=stop
+        )
+        np.testing.assert_allclose(
+            np.asarray(decode_attention(
+                q, k8, ks4, v8, vs4, kv_start=start, kv_stop=stop,
+                block_kv=256,
+            )),
+            np.asarray(dense), atol=1e-5,
         )
         # NULL out every page fully outside the window: the kernel
         # must skip them (no DMA) and still match
         tbl2 = table.copy()
-        for b, (lo, hi) in enumerate(zip((5, 40), (100, 41))):
+        for b, (lo, hi) in enumerate(lo_hi):
             for p in range(MP):
                 if (p + 1) * T <= lo or p * T >= hi:
                     tbl2[b, p] = NULL_PAGE

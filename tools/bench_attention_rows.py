@@ -1,0 +1,28 @@
+"""Run one benchmark cell and print, beside its result line, the
+engine's ``stats()["attention"]`` block at every ``stats()`` call the
+harness makes (one before the timed window, one after the drain), so
+``rows_attended_share`` of the window is the difference of the two:
+
+    python tools/bench_attention_rows.py --workload chat-steady \\
+        --seed 7 --seconds 50 --trace 0
+
+Arguments are ``benchmark.run``'s; nothing under ``benchmark/`` is
+touched and the run makes no call it would not make anyway."""
+import json
+import runpy
+import sys
+
+from mlcomp_tpu.serve import GenerationService
+
+_stats = GenerationService.stats
+
+
+def stats(self):
+    out = _stats(self)
+    print("attention_rows " + json.dumps(out["engine"]["attention"]),
+          file=sys.stderr, flush=True)
+    return out
+
+
+GenerationService.stats = stats
+runpy.run_module("benchmark.run", run_name="__main__")

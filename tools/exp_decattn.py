@@ -1,57 +1,164 @@
-"""decode_attention block sweep at bench shapes (B=8, Hkv=16, dh=128,
-l_buf=2304): blk=256 (today's largest divisor of 2304) runs 9 grid
-steps/call; blk=768 runs 3.  Marginal fori_loop timing, one process."""
+"""decode_attention granule sweep on the chip, at the serve cells'
+geometry (B 48, H 16, Hkv 8, dh 128, L 2560) over window sets shaped
+like the two cells' traffic, plus the 1.2B smoke's full buffer (B 8,
+Hkv 16, L 2304).  Marginal timing: one jitted ``fori_loop`` a variant
+with a traced trip count, run at two counts; us a call is the slope.
+
+    python tools/exp_decattn.py [--parent DIR] [--out FILE]
+
+``--parent`` names a checkout whose ``decode_attention`` is timed
+beside this tree's, on the windows that tree's engine would hand it
+(a retired row keeps its stale window) and on this tree's (empty).
+"""
+import argparse
+import importlib.util
+import json
 import statistics
 import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from mlcomp_tpu.ops.pallas.decode_attention import decode_attention
 
-B, HKV, DH, LBUF = 8, 16, 128, 2304
-key = jax.random.PRNGKey(0)
-k8 = jax.random.randint(key, (B, HKV, LBUF, DH), -127, 127, jnp.int8)
-v8 = jax.random.randint(jax.random.fold_in(key, 1), (B, HKV, LBUF, DH), -127, 127, jnp.int8)
-ks = jax.random.uniform(jax.random.fold_in(key, 2), (B, HKV, 1, LBUF), jnp.float32) * 0.01
-vs = jax.random.uniform(jax.random.fold_in(key, 3), (B, HKV, 1, LBUF), jnp.float32) * 0.01
-start = jnp.zeros((B,), jnp.int32)
-stop = jnp.full((B,), 2200, jnp.int32)
-
-CASES = {"blk256": 256, "blk768": 768, "blk1152": 1152}
-N_LO, N_HI = 64, 512
+N_LO, N_HI, REPEATS = 64, 448, 5
+BUCKETS = (256, 512, 1024, 2048)
 
 
-def looped(blk, n):
-    def body(i, q):
-        o = decode_attention(q, k8, ks, v8, vs, kv_start=start,
-                             kv_stop=stop, block_kv=blk)
-        return (o * 1e-3 + q * 0.5).astype(q.dtype)
+def windows(case, b, l_buf, rng):
+    """(start, stop, live) per row: a prompt of p tokens is left-padded
+    to its bucket and n tokens have been decoded behind it."""
+    def row(p, n):
+        bucket = next(x for x in BUCKETS if x >= p)
+        return bucket - p, min(bucket + n, l_buf)
 
-    return jax.jit(lambda q: jax.lax.fori_loop(0, n, body, q))
+    def chat():
+        p = int(np.clip(rng.lognormal(np.log(384), 0.8), 32, 2048))
+        return row(p, int(rng.integers(0, 96)))
+
+    def offline():
+        p = int(np.clip(rng.lognormal(np.log(96), 0.6), 16, 256))
+        return row(p, int(rng.integers(0, 224)))
+
+    if case in ("steady", "steady_long"):
+        # 10 live rows; the rest retired (a stale window) or never used
+        live = np.zeros(b, bool)
+        live[rng.choice(b, 10, replace=False)] = True
+        win = [chat() if live[i] or i % 5 else (0, 1) for i in range(b)]
+        if case == "steady_long":   # with a 2048-token prompt in it
+            win[int(np.flatnonzero(live)[0])] = (0, 2048 + 128)
+    elif case == "offline":
+        live = np.ones(b, bool)
+        win = [offline() for _ in range(b)]
+    elif case == "full":
+        live = np.ones(b, bool)
+        win = [(0, l_buf)] * b
+    else:
+        raise ValueError(case)
+    start, stop = (np.array(x, np.int32) for x in zip(*win))
+    return start, stop, live
 
 
-q0 = jax.random.normal(jax.random.fold_in(key, 9), (B, HKV, DH), jnp.bfloat16)
-fns = {}
-for nm, blk in CASES.items():
-    for n in (N_LO, N_HI):
-        fns[(nm, n)] = looped(blk, n)
-for kk, fn in fns.items():
-    t0 = time.perf_counter()
-    float(fn(q0)[0, 0, 0])
-    print(f"  {kk}: {time.perf_counter()-t0:.1f}s", flush=True)
+def looped(fn, start, stop, **kw):
+    """``(q, operands, n)`` -> q after n calls.  The cache rides as an
+    argument: closed over, it is a constant of the program, and the
+    first sweep spent most of 827 s compiling 760 MB executables."""
+    start, stop = jnp.asarray(start), jnp.asarray(stop)
 
-times = {k: [] for k in fns}
-for _ in range(7):
-    for kk, fn in fns.items():
-        t0 = time.perf_counter()
-        float(fn(q0)[0, 0, 0])
-        times[kk].append(time.perf_counter() - t0)
+    def run(q, operands, n):
+        def body(i, q):
+            o = fn(q, *operands, kv_start=start, kv_stop=stop, **kw)
+            return (o * 1e-3 + q * 0.5).astype(q.dtype)
 
-roof = 2 * B * HKV * 2200 * DH / 819e9 * 1e6  # live-window K+V int8 bytes
-print(f"\nlive-window roofline {roof:.1f} us/call")
-for nm in CASES:
-    t_lo = statistics.median(times[(nm, N_LO)])
-    t_hi = statistics.median(times[(nm, N_HI)])
-    per = (t_hi - t_lo) / (N_HI - N_LO) * 1e6
-    print(f"{nm:8s}: {per:8.2f} us/call ({roof/per*100:5.1f}% of live roofline)")
+        return jax.lax.fori_loop(0, n, body, q)
+
+    return jax.jit(run)
+
+
+def us_a_call(fn, q, operands):
+    float(fn(q, operands, 2)[0, 0, 0])           # compile
+    t = {n: [] for n in (N_LO, N_HI)}
+    for _ in range(REPEATS):
+        for n in t:
+            t0 = time.perf_counter()
+            float(fn(q, operands, n)[0, 0, 0])
+            t[n].append(time.perf_counter() - t0)
+    lo, hi = (statistics.median(t[n]) for n in (N_LO, N_HI))
+    return (hi - lo) / (N_HI - N_LO) * 1e6
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--parent")
+    ap.add_argument("--out", default="chiprun_out/decattn_sweep.json")
+    ap.add_argument("--tiny", action="store_true",
+                    help="a CPU rehearsal of the control flow: no timing")
+    args = ap.parse_args()
+    if args.tiny:
+        global N_LO, N_HI, REPEATS
+        N_LO, N_HI, REPEATS = 1, 2, 1
+    parent = None
+    if args.parent:
+        spec = importlib.util.spec_from_file_location(
+            "parent_decode_attention",
+            f"{args.parent}/mlcomp_tpu/ops/pallas/decode_attention.py",
+        )
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        parent = mod.decode_attention
+
+    dev = jax.devices()[0]
+    print("device", dev.platform, dev.device_kind, flush=True)
+    results = []
+    geometries = [
+        ("cell", 48, 16, 8, 2560, ("steady", "steady_long", "offline", "full"),
+         (128, 256, 512, 640)),
+        ("smoke", 8, 16, 16, 2304, ("full",), (128, 256, 384, 768)),
+    ]
+    if args.tiny:
+        geometries = [("tiny", 12, 4, 2, 2560, ("steady", "offline"), (128,))]
+    for name, b, h, hkv, l_buf, cases, granules in geometries:
+        dh = 128
+        key = jax.random.PRNGKey(0)
+        kv = [jax.random.randint(jax.random.fold_in(key, i),
+                                 (b, hkv, l_buf, dh), -127, 127, jnp.int8)
+              for i in (0, 1)]
+        sc = [(jax.random.uniform(jax.random.fold_in(key, i),
+                                  (b, hkv, 1, l_buf)) * 0.01
+               ).astype(jnp.bfloat16) for i in (2, 3)]
+        operands = (kv[0], sc[0], kv[1], sc[1])
+        q = jax.random.normal(jax.random.fold_in(key, 9), (b, h, dh),
+                              jnp.bfloat16)
+        for case in cases:
+            start, stop, live = windows(
+                case, b, l_buf, np.random.default_rng(26)
+            )
+            empty = np.where(live, start, l_buf).astype(np.int32)
+            live_tokens = int(((stop - start) * live).sum())
+            roof = live_tokens * hkv * (2 * dh + 4) / 819e9 * 1e6
+            variants = {}
+            if parent is not None:
+                variants["parent_stale"] = looped(parent, start, stop)
+                variants["parent_empty"] = looped(parent, empty, stop)
+            for g in granules:
+                variants[f"g{g}"] = looped(
+                    decode_attention, empty, stop, block_kv=g
+                )
+            variants["default"] = looped(decode_attention, empty, stop)
+            for vname, fn in variants.items():
+                us = us_a_call(fn, q, operands)
+                results.append({
+                    "geometry": name, "case": case, "variant": vname,
+                    "us_a_call": us, "live_rows": int(live.sum()),
+                    "live_tokens": live_tokens, "roofline_us": roof,
+                })
+                print(f"{name:5s} {case:11s} {vname:12s} {us:8.2f} us "
+                      f"({roof / us * 100:5.1f}% of the live-KV roofline, "
+                      f"{live_tokens} live tokens)", flush=True)
+    with open(args.out, "w") as f:
+        json.dump({"device": dev.device_kind, "results": results}, f, indent=1)
+
+
+if __name__ == "__main__":
+    main()
