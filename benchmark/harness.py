@@ -239,15 +239,25 @@ class TracedSlice:
         return trace
 
 
+# every number a run compared for ``correct``, beside its limit: the
+# result line carries them under ``compared`` (its last key) and
+# ``run.py`` prints them as the last lines of standard error
+_COMPARED: Dict[str, Dict[str, float]] = {}
+
+
+def compare(name: str, value: float, limit: float) -> bool:
+    """One number against its limit, printed and kept for the result."""
+    good = bool(value <= limit)
+    _COMPARED[name] = {"value": value, "limit": limit}
+    log(f"correct.{name}", {"value": value, "limit": limit, "ok": good})
+    return good
+
+
 def judge(readings: Dict[str, float], limits: Dict[str, float]) -> bool:
-    """Print each number compared beside its limit; all must hold."""
-    ok = True
-    for name, limit in limits.items():
-        value = readings[name]
-        good = value <= limit
-        ok = ok and good
-        log(f"correct.{name}", {"value": value, "limit": limit, "ok": good})
-    return ok
+    """Each of the mix's limits against its reading; all must hold."""
+    held = [compare(name, readings[name], limit)
+            for name, limit in limits.items()]
+    return all(held)
 
 
 def result_line(cell, dev: Dict[str, Any], trace: bool, correct: bool,
@@ -299,4 +309,6 @@ def result_line(cell, dev: Dict[str, Any], trace: bool, correct: bool,
         metrics = {}
     out["metrics"] = metrics
     out["device"] = device
+    out["compared"] = dict(_COMPARED)
+    _COMPARED.clear()  # one process may run more than one cell (tests)
     return out

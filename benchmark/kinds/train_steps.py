@@ -93,10 +93,13 @@ def program_readings(trainer, cell, seed: int) -> Dict[str, Any]:
     import jax
     import jax.numpy as jnp
 
-    from benchmark.reference.check_train import _fac_state, leaf_stat
+    from benchmark.reference.check_train import (
+        _fac_state, leaf_stat, one_kind,
+    )
 
     M = cells.architecture(cell.config)
     d = M.dims_of(cell.config)
+    kind = one_kind(M, d)
     key = W.seed_key(seed)
     params = trainer.state.params
     fac = _fac_state(trainer.state.opt_state)
@@ -109,7 +112,8 @@ def program_readings(trainer, cell, seed: int) -> Dict[str, Any]:
 
     @jax.jit
     def of_layer(key, i, p, v_row, v_col, v):
-        w0 = M.program_layer(M.layer_weights(key, i, d, jnp.float32))
+        w0 = M.program_layer(
+            M.layer_weights(key, i, d, jnp.float32, kind), kind)
         return numbers(w0, p, v_row, v_col, v)
 
     @jax.jit
@@ -118,12 +122,13 @@ def program_readings(trainer, cell, seed: int) -> Dict[str, Any]:
         return numbers(w0, p, v_row, v_col, v)
 
     dnorm, gstat = [], []
+    of_kind = lambda w: M.program_layer(w, kind)  # noqa: E731
     for i in range(d["layers"]):
-        n = M.layer_key(i)
+        n = M.layer_key(i, d)
         dn, gs = of_layer(key, jnp.int32(i), params[n], fac.v_row[n],
                           fac.v_col[n], fac.v[n])
-        dnorm += _canonical(M.program_layer, dn, M.LAYER_LEAVES)
-        gstat += _canonical(M.program_layer, gs, M.LAYER_LEAVES)
+        dnorm += _canonical(of_kind, dn, M.LAYER_LEAVES)
+        gstat += _canonical(of_kind, gs, M.LAYER_LEAVES)
     top = list(M.program_top({n: n for n in M.TOP_LEAVES}))
     sub = lambda t: {k: t[k] for k in top}  # noqa: E731
     dn, gs = of_top(key, sub(params), sub(fac.v_row), sub(fac.v_col),
@@ -164,8 +169,13 @@ def run(cell, seed: int, seconds: float, trace: bool, control: bool,
     # the reference first, while the device is empty; its time is not set-up
     t_ref = time.perf_counter()
     ref = train_reference(cfg, seed, rows, e)
-    ctl = train_reference(cfg, seed, rows, e, round_fn=quant.fp8) \
-        if control else None
+    # the control: every product's operands at the nearest precision
+    # below the one the configuration states (bfloat16 -> float8)
+    ctl = train_reference(
+        cfg, seed, rows, e,
+        round_fn=quant.operand_round(
+            quant.below("operands", quant.stated(cfg, "operands"))),
+    ) if control else None
     gc.collect()
     reference_s = time.perf_counter() - t_ref
     log("reference_s", reference_s)
@@ -193,8 +203,8 @@ def run(cell, seed: int, seconds: float, trace: bool, control: bool,
         for leaf in jax.tree.leaves(state.params):
             leaf.delete()
         t1 = time.perf_counter()
-        params = cells.architecture(cfg).program_params(
-            seed, d, jnp.float32, shardings=where)
+        params = W.program_params(
+            cells.architecture(cfg), seed, d, jnp.float32, shardings=where)
         jax.block_until_ready(params)
         log("setup.program_params_s", time.perf_counter() - t1)
         W.check_layout(params, abstract)
@@ -259,7 +269,9 @@ def run(cell, seed: int, seconds: float, trace: bool, control: bool,
     finally:
         shutil.rmtree(work, ignore_errors=True)
     readings = compare(prog, ref)
-    ok = H.judge(readings, mix["limits"]) and lowered == 0 and finite
+    ok = H.judge(readings, mix["limits"])
+    ok = H.compare("programs_lowered_in_window", lowered, 0) and ok
+    ok = H.compare("losses_not_finite", int(not finite), 0) and ok
     if ctl is not None:
         for k, v in compare(ctl, ref).items():
             log(f"control.{k}", v)

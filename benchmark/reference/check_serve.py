@@ -2,9 +2,10 @@
 
 Runs after the window has closed and the service's state is freed.  The
 reference regenerates each layer's weights from the seed, rounds them to
-int8 as the configuration states, and runs every sampled request's
-prompt together with its served tokens through the full forward pass in
-float32 — once, teacher-forced, no cache.  Two numbers come out:
+the precision the configuration states (``rounding.weights``), and runs
+every sampled request's prompt together with its served tokens through
+the full forward pass in float32 — once, teacher-forced, no cache.  Two
+numbers come out:
 
 - ``max_logit_gap``: the widest gap by which a served (greedy) token's
   reference logit lies below the reference's best at that position;
@@ -13,13 +14,20 @@ float32 — once, teacher-forced, no cache.  Two numbers come out:
 
 With ``control=True`` the same prompts and tokens also go through the
 forward pass at the nearest precision below each one the configuration
-states: once with int4 weights (``control.*``), once with int8 weights
-and the keys and values rounded to int4 (``control_kv.*``).  A control's
-numbers are the gap of the token it puts first, and the distance of its
-log-probabilities from the reference's.
+states (``quant.BELOW``): once with the weights a step down
+(``control.*``: int4 under int8, int8 under bfloat16), and, where the
+configuration states one for the keys and values, once with the stated
+weights and the keys and values a step down (``control_kv.*``).  A
+control's numbers are the gap of the token it puts first, and the
+distance of its log-probabilities from the reference's.
 
-The architecture (forward pass, seeded weights) is the file the
-configuration's ``reference`` key names.
+The architecture is the file the configuration's ``reference`` key
+names.  Its layers need not be alike: the comparison walks
+``layer_kinds(d)`` with a Python index and compiles one program a KIND
+(the layer's index is traced inside it), so a model of 24 like layers
+compiles one layer program and one with a dense first layer, two kinds
+of attention and routed experts compiles a handful.  Shapes may differ
+between kinds, never within one.
 """
 
 from __future__ import annotations
@@ -32,15 +40,24 @@ import numpy as np
 
 from benchmark import cells
 from benchmark import weights as W
-from benchmark.reference.quant import kv_int4, quantize_leaves
+from benchmark.reference import quant
+from benchmark.reference.quant import quantize_leaves
 
-# rows that go through a layer together: two 2304-token rows of float32
-# scores per kv group fit the chip beside the reference's weights
-ROWS_PER_BLOCK = 2
 
-# (log name, weights' qmax, rounding of keys and values)
-REFERENCE = ("", 127, None)
-CONTROLS = (("control", 7, None), ("control_kv", 127, kv_int4))
+def passes_of(cfg: Dict[str, Any], control: bool):
+    """(log name, weights' qmax, rounding of keys and values) of the
+    reference and, with ``control``, of each control."""
+    weights = quant.stated(cfg, "weights")
+    kv = quant.stated(cfg, "kv")
+    stated = quant.weight_qmax(weights)
+    out = [("", stated, None)]
+    if control:
+        out.append(("control",
+                    quant.weight_qmax(quant.below("weights", weights)), None))
+        if kv is not None:
+            out.append(("control_kv", stated,
+                        quant.kv_round(quant.below("kv", kv))))
+    return tuple(out)
 
 
 def serve_readings(cfg: Dict[str, Any], seed: int,
@@ -49,11 +66,9 @@ def serve_readings(cfg: Dict[str, Any], seed: int,
     M = cells.architecture(cfg)
     d = M.dims_of(cfg)
     axes = M.CONTRACT_AXES
-    eps = float(cfg["as_run"]["norm_eps"])
-    theta = float(cfg["as_run"]["rope_base"])
     key = W.seed_key(seed)
     n = len(samples)
-    blk = ROWS_PER_BLOCK
+    blk = M.rows_per_block(d, pad_len)
     n_pad = -(-n // blk) * blk
     toks = np.zeros((n_pad, pad_len), np.int32)
     for r, s in enumerate(samples):
@@ -61,7 +76,7 @@ def serve_readings(cfg: Dict[str, Any], seed: int,
         toks[r, :len(seq)] = seq
     pos = jnp.broadcast_to(jnp.arange(pad_len, dtype=jnp.int32),
                            (blk, pad_len))
-    passes = (REFERENCE,) + (CONTROLS if control else ())
+    passes = passes_of(cfg, control)
 
     @jax.jit
     def embed_all(key, toks):
@@ -71,31 +86,39 @@ def serve_readings(cfg: Dict[str, Any], seed: int,
             for _, q, _ in passes
         )
 
-    @jax.jit
-    def layer_all(key, i, xs):
-        w = M.layer_weights(key, i, d, jnp.bfloat16)
-        out = []
-        for (_, q, kv), x in zip(passes, xs):
-            wq = quantize_leaves(w, q, axes)
-            kw = {} if kv is None else {"kv_fn": kv}
-            xb = x.reshape(n_pad // blk, blk, pad_len, d["hidden"])
-            y = jax.lax.map(
-                lambda b: M.layer(b, wq, pos, eps, theta, **kw), xb
-            )
-            out.append(y.reshape(x.shape))
-        return tuple(out)
+    def layers_of(kind):
+        """The program for every layer of one kind."""
+
+        @jax.jit
+        def layer_all(key, i, xs):
+            w = M.layer_weights(key, i, d, jnp.bfloat16, kind)
+            out = []
+            for (_, q, kv), x in zip(passes, xs):
+                wq = quantize_leaves(w, q, axes)
+                kw = {} if kv is None else {"kv_fn": kv}
+                xb = x.reshape(n_pad // blk, blk, pad_len, d["hidden"])
+                y = jax.lax.map(
+                    lambda b: M.layer(b, wq, pos, d, kind, **kw), xb
+                )
+                out.append(y.reshape(x.shape))
+            return tuple(out)
+
+        return layer_all
 
     @jax.jit
     def head_all(key, hs):
         top = M.top_weights(key, d, jnp.bfloat16)
         return tuple(
-            M.logits(h, quantize_leaves(top, q, axes), eps)
+            M.logits(h, quantize_leaves(top, q, axes), d)
             for (_, q, _), h in zip(passes, hs)
         )
 
     xs = embed_all(key, jnp.asarray(toks))
-    for i in range(d["layers"]):
-        xs = layer_all(key, jnp.int32(i), xs)
+    programs = {}
+    for i, kind in enumerate(M.layer_kinds(d)):
+        if kind not in programs:
+            programs[kind] = layers_of(kind)
+        xs = programs[kind](key, jnp.int32(i), xs)
 
     gap = lp_err = 0.0
     c_gap = [0.0] * (len(passes) - 1)

@@ -59,13 +59,27 @@ def leaf_stat(v_row, v_col, v, lead: int):
     return jnp.sqrt(jnp.mean(acc, axis=axes))
 
 
-def _row_layer(M, w, x_row, eps, theta, rf):
+def one_kind(M, d) -> str:
+    """The kind of every layer.  This comparison stacks nothing, but it
+    lists its numbers leaf by leaf in ONE canonical order
+    (``LAYER_LEAVES``), so it follows architectures whose layers are all
+    of one kind and refuses the others by name."""
+    kinds = sorted(set(M.layer_kinds(d)))
+    if len(kinds) != 1:
+        raise SystemExit(
+            f"the training comparison follows one kind of layer; this "
+            f"architecture has {kinds} (the serve comparison walks them)"
+        )
+    return kinds[0]
+
+
+def _row_layer(M, w, x_row, d, kind, rf):
     """One layer on one row (S, d) -> (S, d)."""
     pos = jnp.arange(x_row.shape[0], dtype=jnp.int32)[None]
-    return M.layer(x_row[None], w, pos, eps, theta, rf)[0]
+    return M.layer(x_row[None], w, pos, d, kind, round_fn=rf)[0]
 
 
-def _head_nll(M, top, x_row, tgt, eps, rf, seq_chunk):
+def _head_nll(M, top, x_row, tgt, d, rf, seq_chunk):
     """Summed next-token loss of one row; the head in sequence chunks,
     each rematerialized (float32 logits of the whole row at this
     vocabulary would be gigabytes)."""
@@ -77,7 +91,7 @@ def _head_nll(M, top, x_row, tgt, eps, rf, seq_chunk):
     @jax.checkpoint
     def chunk(xtk):
         xx, tt, kk = xtk
-        lp = jax.nn.log_softmax(M.logits(xx, top, eps, rf), axis=-1)
+        lp = jax.nn.log_softmax(M.logits(xx, top, d, round_fn=rf), axis=-1)
         nll = -jnp.take_along_axis(lp, tt[:, None], axis=-1)[:, 0]
         return jnp.sum(jnp.where(kk, nll, 0.0))
 
@@ -94,8 +108,7 @@ class Reference:
         # the architecture: the file the configuration's ``reference`` names
         self.arch = M = cells.architecture(cfg)
         self.d = d = M.dims_of(cfg)
-        eps = float(cfg["as_run"]["norm_eps"])
-        theta = float(cfg["as_run"]["rope_base"])
+        kind = one_kind(M, d)
         rf = round_fn if round_fn is not None else (lambda x: x)
         tx = self.tx = make_optimizer(cfg["trainer"]["optimizer"])
         self.key = W.seed_key(seed)
@@ -103,21 +116,21 @@ class Reference:
         chunk = min(512, seq)
 
         self.init_layer = jax.jit(
-            lambda key, i: M.layer_weights(key, i, d, jnp.float32))
+            lambda key, i: M.layer_weights(key, i, d, jnp.float32, kind))
         self.init_top = jax.jit(lambda key: M.top_weights(key, d, jnp.float32))
         self.init_opt = jax.jit(tx.init)
 
         @jax.jit
         def fwd(w, x):
             return jax.lax.map(
-                lambda r: _row_layer(M, w, r, eps, theta, rf), x)
+                lambda r: _row_layer(M, w, r, d, kind, rf), x)
 
         @partial(jax.jit, donate_argnums=(0, 1))
         def bwd(w, st, x, dy):
             def one(carry, xr_dy):
                 xr, dyr = xr_dy
                 _, vjp = jax.vjp(
-                    lambda w_, x_: _row_layer(M, w_, x_, eps, theta, rf), w, xr)
+                    lambda w_, x_: _row_layer(M, w_, x_, d, kind, rf), w, xr)
                 gw, dx = vjp(dyr)
                 return jax.tree.map(jnp.add, carry, gw), dx
 
@@ -137,7 +150,7 @@ class Reference:
             def loss_of(head_part, x):
                 t = {**top, **head_part}
                 rows = jax.lax.map(
-                    lambda xt: _head_nll(M, t, xt[0], xt[1], eps, rf, chunk),
+                    lambda xt: _head_nll(M, t, xt[0], xt[1], d, rf, chunk),
                     (x, tgt))
                 return jnp.sum(rows) / n
 
@@ -159,7 +172,7 @@ class Reference:
 
         @jax.jit
         def layer_numbers(w, st, key, i):
-            w0 = M.layer_weights(key, i, d, jnp.float32)
+            w0 = M.layer_weights(key, i, d, jnp.float32, kind)
             fac = _fac_state(st)
             return (
                 [leaf_stat(fac.v_row[n], fac.v_col[n], fac.v[n], 0)

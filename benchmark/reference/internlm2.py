@@ -10,10 +10,10 @@ untied output head.  No biases.
 
 Departures from the published model, applied here because the program
 under test fixes them in code (the configuration files list them):
-``rope_theta`` and ``rms_norm_eps`` are arguments, and the benchmark
-passes the program's 10000 and 1e-6 where InternLM2 publishes 1e6 and
-1e-5.  InternLM2 stores q, k and v packed in one ``wqkv``; they are
-separate leaves here — a layout, not arithmetic.
+the RoPE base and the norm's epsilon are read from the configuration's
+``as_run`` (``dims_of``), the program's 10000 and 1e-6 where InternLM2
+publishes 1e6 and 1e-5.  InternLM2 stores q, k and v packed in one
+``wqkv``; they are separate leaves here — a layout, not arithmetic.
 
 ``round_fn`` rounds every product's operands (identity for the
 reference itself); the training control passes a float8 rounding.
@@ -21,31 +21,37 @@ reference itself); the training control passes a float8 rounding.
 reference; serving's second control passes an int4 rounding).
 
 This file is also the architecture as the harness sees it
-(``cells.architecture``, named by a configuration's ``reference`` key):
-besides the forward pass it hands on the seeded weights of this shape
-and the program's layout of them (``benchmark/weights.py``) and says
-which axes a weight's quantization scale is constant along.  A second
-architecture is a second file with the same names.
+(``cells.architecture``, named by a configuration's ``reference`` key;
+``benchmark/README.md`` lists the names and who calls each): the sizes
+(``dims_of``), the list of layer kinds (``layer_kinds``: every layer of
+InternLM2 is one kind), the shapes of the seeded leaves
+(``layer_weights``, ``top_weights``; ``benchmark/weights.py`` draws
+them), the program's layout of them (``layer_key``, ``program_layer``,
+``program_top``), which axes a weight's quantization scale is constant
+along (``CONTRACT_AXES``) and how many rows the comparison can put
+through a layer together (``rows_per_block``).  A second architecture is
+a second file with the same names.
 """
 
 from __future__ import annotations
 
+from typing import Any, Dict, List
+
 import jax
 import jax.numpy as jnp
 
-from benchmark.weights import (  # noqa: F401  (the architecture's interface)
-    LAYER_LEAVES,
-    TOP_LEAVES,
-    dims_of,
-    layer_key,
-    layer_weights,
-    program_layer,
-    program_params,
-    program_top,
-    top_weights,
-)
+from benchmark import weights as W
 
 HI = jax.lax.Precision.HIGHEST
+
+KIND = "decoder"
+# canonical order of the leaves: training's comparison lists its numbers
+# in it, and a leaf's seeded values follow from its place in it
+LAYER_LEAVES = (
+    "attn_norm", "wq", "wk", "wv", "wo", "mlp_norm", "w_gate", "w_up",
+    "w_down",
+)
+TOP_LEAVES = ("emb", "final_norm", "head")
 
 # contraction axes of each canonical leaf (weights.py): a weight's
 # quantization scale is per output channel, constant along these.  The
@@ -55,6 +61,104 @@ CONTRACT_AXES = {
     "w_gate": (0,), "w_up": (0,), "w_down": (0,),
     "emb": (0,), "head": (0,),
 }
+
+
+def dims_of(cfg: Dict[str, Any]) -> Dict[str, Any]:
+    """Everything the functions below need, from a configuration file:
+    the published sizes and the two constants the program fixes in code
+    (``as_run``).  No function here reads the configuration again."""
+    heads = int(cfg["num_attention_heads"])
+    hidden = int(cfg["hidden_size"])
+    return {
+        "vocab": int(cfg["vocab_size"]),
+        "hidden": hidden,
+        "layers": int(cfg["num_hidden_layers"]),
+        "heads": heads,
+        "kv_heads": int(cfg["num_key_value_heads"]),
+        "head_dim": hidden // heads,
+        "mlp": int(cfg["intermediate_size"]),
+        "norm_eps": float(cfg["as_run"]["norm_eps"]),
+        "rope_base": float(cfg["as_run"]["rope_base"]),
+    }
+
+
+def layer_kinds(d: Dict[str, Any]) -> List[str]:
+    """One name a layer, in order; a program is compiled per name."""
+    return [KIND] * d["layers"]
+
+
+def _shapes(d: Dict[str, Any]) -> W.Shapes:
+    """(shape, fan_in) of every leaf; fan_in None = a norm scale.
+    Projections are (hidden, heads, head_dim) / (heads, head_dim,
+    hidden), MLP matrices (in, out), embedding (vocab, hidden), head
+    (hidden, vocab)."""
+    h, dh = d["hidden"], d["head_dim"]
+    return {
+        "attn_norm": ((h,), None),
+        "mlp_norm": ((h,), None),
+        "final_norm": ((h,), None),
+        "wq": ((h, d["heads"], dh), h),
+        "wk": ((h, d["kv_heads"], dh), h),
+        "wv": ((h, d["kv_heads"], dh), h),
+        "wo": ((d["heads"], dh, h), d["heads"] * dh),
+        "w_gate": ((h, d["mlp"]), h),
+        "w_up": ((h, d["mlp"]), h),
+        "w_down": ((d["mlp"], h), d["mlp"]),
+        "emb": ((d["vocab"], h), h),
+        "head": ((h, d["vocab"]), h),
+    }
+
+
+def layer_weights(key, i, d, dtype, kind=KIND) -> Dict[str, Any]:
+    """Layer ``i``'s leaves (``i`` may be a traced integer); shapes
+    follow from the kind alone."""
+    shapes = _shapes(d)
+    return W.layer_leaves(key, i, {n: shapes[n] for n in LAYER_LEAVES}, dtype)
+
+
+def top_weights(key, d, dtype) -> Dict[str, Any]:
+    shapes = _shapes(d)
+    return W.top_leaves(key, {n: shapes[n] for n in TOP_LEAVES}, dtype)
+
+
+def layer_key(i: int, d: Dict[str, Any]) -> str:
+    """Where layer ``i`` sits in the program's parameter tree."""
+    return f"DecoderLayer_{i}"
+
+
+def program_layer(w: Dict[str, Any], kind=KIND) -> Dict[str, Any]:
+    """One layer in the parameter layout of ``TransformerLM``."""
+    return {
+        "attn": {
+            "RMSNorm_0": {"scale": w["attn_norm"]},
+            "q": {"kernel": w["wq"]},
+            "k": {"kernel": w["wk"]},
+            "v": {"kernel": w["wv"]},
+            "out": {"kernel": w["wo"]},
+        },
+        "RMSNorm_0": {"scale": w["mlp_norm"]},
+        "gate": {"kernel": w["w_gate"]},
+        "up": {"kernel": w["w_up"]},
+        "down": {"kernel": w["w_down"]},
+    }
+
+
+def program_top(top: Dict[str, Any]) -> Dict[str, Any]:
+    """The leaves outside the layers, in ``TransformerLM``'s layout."""
+    return {
+        "emb": {"embedding": top["emb"]},
+        "RMSNorm_0": {"scale": top["final_norm"]},
+        "lm_head": {"kernel": top["head"]},
+    }
+
+
+def rows_per_block(d: Dict[str, Any], pad_len: int) -> int:
+    """Rows the serve comparison puts through a layer together.  The
+    float32 scores of one row are heads x pad_len^2 x 4 B (340 MB at
+    2304 tokens); two rows fit the chip beside the reference's weights,
+    and a block is never more than two."""
+    per_row = d["heads"] * pad_len * pad_len * 4
+    return max(1, min(2, int(1.0e9 // per_row)))
 
 
 def _id(x):
@@ -90,8 +194,9 @@ def attention(q, k, v):
     return out.reshape(b, s, h, d)
 
 
-def layer(x, w, positions, eps, theta, round_fn=_id, kv_fn=_id):
+def layer(x, w, positions, d, kind=KIND, round_fn=_id, kv_fn=_id):
     r = round_fn
+    eps, theta = d["norm_eps"], d["rope_base"]
     h = r(rms_norm(x, w["attn_norm"], eps))
     q = jnp.einsum("bsd,dhk->bshk", h, r(w["wq"]), precision=HI)
     k = jnp.einsum("bsd,dhk->bshk", h, r(w["wk"]), precision=HI)
@@ -110,13 +215,7 @@ def embed(ids, emb):
     return jnp.take(emb, ids, axis=0)
 
 
-def logits(x, top, eps, round_fn=_id):
-    h = round_fn(rms_norm(x, top["final_norm"], eps))
+def logits(x, top, d, round_fn=_id):
+    h = round_fn(rms_norm(x, top["final_norm"], d["norm_eps"]))
     return jnp.einsum("...d,dv->...v", h, round_fn(top["head"]), precision=HI)
 
-
-def next_token_loss(lg, ids):
-    """Mean over rows of the mean next-token cross-entropy (S-1 targets)."""
-    lp = jax.nn.log_softmax(lg[:, :-1], axis=-1)
-    tok = jnp.take_along_axis(lp, ids[:, 1:, None], axis=-1)[..., 0]
-    return -jnp.mean(jnp.mean(tok, axis=-1))

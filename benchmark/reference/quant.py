@@ -9,15 +9,63 @@ precision below, the step that would tempt a later PR).  Which axes a
 leaf's scale is constant along is the architecture's to say
 (``CONTRACT_AXES`` in its file under ``reference/``).
 
-``kv_int4`` is the second serving control: the configuration keeps keys
+``kv_round`` is the second serving control: the configuration keeps keys
 and values in int8 with a scale per (token, kv head); the control rounds
 them to int4 the same way.
+
+What a configuration rounds to is its ``rounding`` mapping, read here
+and nowhere else: ``{"weights": "int8", "kv": "int8"}`` for serving,
+``{"operands": "bfloat16"}`` for training.  ``BELOW`` is the ladder of
+controls: the nearest precision below each one a configuration can
+state, the step that would tempt a later PR.
 """
 
 from __future__ import annotations
 
+from typing import Any, Callable, Dict, Optional
+
 import jax
 import jax.numpy as jnp
+
+BELOW = {
+    "weights": {"bfloat16": "int8", "int8": "int4"},
+    "kv": {"bfloat16": "int8", "int8": "int4"},
+    "operands": {"bfloat16": "fp8"},
+}
+QMAX = {"int8": 127, "int4": 7}
+
+
+def stated(cfg: Dict[str, Any], what: str) -> Optional[str]:
+    """The precision a configuration states for ``what`` (``weights``,
+    ``kv``, ``operands``); None where it states none."""
+    if "rounding" not in cfg:
+        raise SystemExit(
+            'the configuration states no precision: add "rounding", e.g. '
+            '{"weights": "int8", "kv": "int8"}'
+        )
+    name = cfg["rounding"].get(what)
+    if name is not None and name not in BELOW[what]:
+        raise SystemExit(
+            f"rounding.{what} {name!r}: this yardstick knows "
+            f"{sorted(BELOW[what])}"
+        )
+    return name
+
+
+def below(what: str, name: str) -> str:
+    return BELOW[what][name]
+
+
+def weight_qmax(name: str) -> Optional[int]:
+    """``quantize_leaves``' qmax for weights stated as ``name``; None for
+    bfloat16, which the seeded weights are drawn in."""
+    return QMAX.get(name)
+
+
+def kv_round(name: str) -> Callable:
+    """Keys or values (..., kv heads, head_dim) rounded to ``name``, one
+    scale per (token, kv head): absmax over the head's dimension / qmax."""
+    return lambda x: fake_quant(x, (-1,), QMAX[name])
 
 
 def fake_quant(w, axes, qmax: int):
@@ -39,10 +87,9 @@ def quantize_leaves(weights, qmax, contract_axes):
     return out
 
 
-def kv_int4(x):
-    """Keys or values (..., kv heads, head_dim) rounded to int4, one
-    scale per (token, kv head): absmax over the head's dimension / 7."""
-    return fake_quant(x, (-1,), 7)
+def operand_round(name: str) -> Callable:
+    """The rounding of every product's operands, training's control."""
+    return {"fp8": fp8}[name]
 
 
 def fp8(x):

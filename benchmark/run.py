@@ -48,6 +48,9 @@ def main(argv=None) -> int:
                  dev=dev, t_start=_T_START)
     sys.stdout.flush()
     print(json.dumps(result), flush=True)
+    for name, c in result["compared"].items():
+        print(f"correct.{name} value={c['value']!r} limit={c['limit']!r}",
+              file=sys.stderr, flush=True)
     return 0
 
 

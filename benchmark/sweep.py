@@ -10,11 +10,16 @@ Every rate offers the cell's own trace (the mix's ``schedule_seed``, its
 gaps scaled to the rate), so the knee is read on the generator the cell
 runs.  For each rate it prints the tails, the share of requests inside
 both latency limits (the mix's ``latency_limits_ms``; a failed request
-misses; a mix that has none yet counts every finished request, for the
-first sweep that sets them), and the backlog: requests still waiting
-for a first token at the window's half and at its close.  The knee is
-the highest rate at which >= 90% meet both limits with no backlog
-growing through the window.
+misses; a mix that has none yet counts every finished request), and the
+backlog: requests still waiting for a first token at the window's half
+and at its close.  The knee is the highest rate at which >= 90% meet
+both limits with no backlog growing through the window.
+
+A cell's limits are twice the lowest rate's p90s, so the last line,
+``sweep.against_twice_the_lowest_rate``, gives those limits and each
+rate's share inside them: the first sweep of a new mix, and the sweep
+that finds a knee again after the program got faster, read the knee
+from one call.
 """
 
 from __future__ import annotations
@@ -48,6 +53,12 @@ def main() -> None:
     ttft_ms = float(limits.get("ttft", float("inf")))
     tpot_ms = float(limits.get("tpot", float("inf")))
     log("sweep.latency_limits_ms", {"ttft": ttft_ms, "tpot": tpot_ms})
+    def inside(pairs, sent, ttft_ms, tpot_ms):
+        """Share of the requests sent that finished inside both limits."""
+        return sum(1 for a, b in pairs
+                   if a <= ttft_ms and b <= tpot_ms) / max(1, sent)
+
+    tails = []  # per rate: (rate, its numbers, (ttft, tpot) of each finished one)
     for i, rate in enumerate(float(r) for r in args.rates.split(",")):
         cell.traffic["rate_per_s"] = rate
         with counter.window():
@@ -69,7 +80,7 @@ def main() -> None:
         backlog = {"at_half": waiting(mid), "at_close": waiting(t_end)}
         serving.drain(win["reqs"], 120.0)
         out = serving.reduce_window(win, args.seconds)
-        met = 0
+        pairs = []
         for r in win["reqs"]:
             if r.result is None:
                 continue
@@ -77,8 +88,8 @@ def main() -> None:
             ttft = (r.stream.t_first - t0 - r.due) * 1e3
             tpot = ((r.stream.t_last - r.stream.t_first) * 1e3 / (n - 1)
                     if n > 1 else 0.0)
-            if ttft <= ttft_ms and tpot <= tpot_ms:
-                met += 1
+            pairs.append((ttft, tpot))
+        tails.append((rate, out, pairs))
         log("sweep", {
             "rate_per_s": rate, "sent": out["sent"],
             "succeeded": out["succeeded"], "failed": out["failed"],
@@ -86,12 +97,23 @@ def main() -> None:
             "ttft_p90_ms": out.get("ttft_p90_ms"),
             "tpot_p50_ms": out.get("tpot_p50_ms"),
             "tpot_p90_ms": out.get("tpot_p90_ms"),
-            "met_both_limits": met / max(1, out["sent"]),
+            "met_both_limits": inside(pairs, out["sent"], ttft_ms, tpot_ms),
             "backlog": backlog, "lateness": out["lateness"],
             "programs_lowered": lowered,
             "drain_s": time.perf_counter() - t_end,
         })
     service.close()
+    lowest, base, _ = min(tails, key=lambda t: t[0])
+    lim_ttft = 2.0 * base["ttft_p90_ms"]
+    lim_tpot = 2.0 * base["tpot_p90_ms"]
+    log("sweep.against_twice_the_lowest_rate", {
+        "lowest_rate_per_s": lowest,
+        "latency_limits_ms": {"ttft": lim_ttft, "tpot": lim_tpot},
+        "met_both_limits": {
+            str(rate): inside(pairs, out["sent"], lim_ttft, lim_tpot)
+            for rate, out, pairs in tails
+        },
+    })
 
 
 if __name__ == "__main__":

@@ -49,6 +49,13 @@ def test_closed_loop_cell_runs_and_agrees(rehearse):
                          "--trace", "0", "--seed", "13")
     assert res["correct"] is True and res["attempted"] > 8
     assert "serve_tokens_per_s" in res["rehearsal_metrics"]
+    # every number compared, beside its limit, is the line's last key
+    assert list(res)[-1] == "compared"
+    lim = _limits("batch-offline")
+    assert {k: v["limit"] for k, v in res["compared"].items()} == {
+        **lim, "programs_lowered_in_window": 0, "nothing_to_compare": 0}
+    assert res["compared"]["max_logit_gap"]["value"] == (
+        seen["correct.max_logit_gap"]["value"])
 
 
 @pytest.mark.parametrize("seed", [14, 15])
@@ -194,14 +201,120 @@ def test_training_on_the_mesh_a_configuration_names_agrees():
     assert res["correct"] is True and res["device"]["count"] == 2
 
 
+# An architecture whose layers are not all alike (tests/two_kinds.py):
+# moe_lm at rehearsal width, a dense layer then a routed one, served in
+# float32 on weights stated as bfloat16.  Registered here, never a cell.
+TWO_KINDS = {
+    "reference": "two_kinds",
+    "hidden_size": 256, "num_hidden_layers": 2, "num_attention_heads": 2,
+    "num_key_value_heads": 1, "intermediate_size": 256, "vocab_size": 512,
+    "n_experts": 4, "experts_per_token": 2, "moe_every": 2,
+    "as_run": {"rope_base": 10000.0, "norm_eps": 1e-06},
+    "rounding": {"weights": "bfloat16"},
+    "model": {"name": "moe_lm", "vocab_size": 512, "hidden": 256,
+              "layers": 2, "heads": 2, "kv_heads": 1, "n_experts": 4,
+              "d_ff": 256, "k": 2, "moe_every": 2, "dtype": "float32"},
+    "service": {"batch_sizes": [4], "prompt_buckets": [32, 64],
+                "max_new_buckets": [16], "prefill_chunk": 32,
+                "steps_per_dispatch": 4, "request_timeout_s": 600.0},
+}
+# CPU readings at this width (PR 27, seeds 41-43 and 2**31 + 44): sound
+# max_logit_gap 0.0 on all four / the int8 control 0.011-0.193 / every
+# layer taken for the first kind 2.19-3.50; mean_abs_logprob_err sound
+# 2e-6 - 3e-6 / control 0.021-0.039 / first kind everywhere 0.69-0.92
+TWO_KINDS_LIMITS = {"max_logit_gap": 0.003, "mean_abs_logprob_err": 0.0003}
+
+
+@pytest.fixture(scope="module")
+def two_kinds_served():
+    """One window of the fixture through ``GenerationService`` on the
+    CPU: (configuration, seed, sampled finished requests, pad length)."""
+    import contextlib
+
+    from benchmark import serving
+    from benchmark.harness import configure_jax
+
+    arch = cells._load_py(cells.HERE / "tests" / "two_kinds.py")
+    cells._ARCHITECTURES["two_kinds"] = arch
+    cell = cells.Cell("chat-steady", rehearsal=True)
+    cell.config = cfg = TWO_KINDS
+    configure_jax(cell)
+    seed = 41
+    service = serving.build_service(cell, seed, lambda *a: None)
+    try:
+        assert set(service.variables["params"]) >= {
+            "DecoderLayer_0", "MoELayer_0"}
+        win = serving.open_loop(service, cell, seed, 2.0, cfg["vocab_size"],
+                                lambda name: contextlib.nullcontext())
+        serving.drain(win["reqs"], 120.0)
+        samples = serving.sample_finished(win["reqs"], 6, seed)
+    finally:
+        service.close()
+    assert len(samples) == 6
+    yield cfg, seed, samples, 64 + 16
+    del cells._ARCHITECTURES["two_kinds"]
+
+
+def test_a_model_with_two_kinds_of_layer_agrees_with_its_reference(
+        two_kinds_served):
+    from benchmark.harness import judge
+    from benchmark.reference.check_serve import serve_readings
+
+    arch = cells.architecture(TWO_KINDS)
+    assert arch.layer_kinds(arch.dims_of(TWO_KINDS)) == ["dense", "moe"]
+    readings = serve_readings(*two_kinds_served)
+    assert readings["tokens_compared"] >= 48
+    assert judge(readings, TWO_KINDS_LIMITS) is True
+
+
+def test_the_second_kind_computed_as_the_first_is_not_correct(
+        two_kinds_served, monkeypatch):
+    """A comparison that took every layer for the first kind: the routed
+    layer's place is computed by the dense layer's function."""
+    from benchmark.harness import judge
+    from benchmark.reference.check_serve import serve_readings
+
+    arch = cells.architecture(TWO_KINDS)
+    monkeypatch.setattr(arch, "layer_kinds",
+                        lambda d: ["dense"] * d["layers"])
+    readings = serve_readings(*two_kinds_served)
+    assert judge(readings, TWO_KINDS_LIMITS) is False
+
+
+def test_a_configuration_stated_in_bfloat16_has_an_int8_control_that_fails(
+        two_kinds_served):
+    from benchmark.reference.check_serve import passes_of, serve_readings
+
+    assert [p[:2] for p in passes_of(TWO_KINDS, True)] == [
+        ("", None), ("control", 127)]           # no statement on kv: no control
+    assert [p[:2] for p in passes_of(
+        cells.Cell("chat-steady", rehearsal=True).config, True)] == [
+        ("", 127), ("control", 7), ("control_kv", 127)]
+    readings = serve_readings(*two_kinds_served, control=True)
+    lim = TWO_KINDS_LIMITS
+    assert all(readings[k] <= lim[k] for k in lim)
+    failed = [k for k in lim if readings[f"control.{k}"] > lim[k]]
+    assert failed, "the int8 control has to fail one of the numbers"
+    assert "control_kv.max_logit_gap" not in readings
+
+
+def test_the_training_comparison_refuses_layers_of_several_kinds(
+        two_kinds_served):
+    from benchmark.reference.check_train import one_kind
+
+    arch = cells.architecture(TWO_KINDS)
+    with pytest.raises(SystemExit, match="one kind of layer"):
+        one_kind(arch, arch.dims_of(TWO_KINDS))
+
+
 def test_a_configuration_names_its_architecture():
     cell = cells.Cell("train-4k", rehearsal=True)
     arch = cells.architecture(cell.config)
     assert arch is cells.architecture(cell.config)  # loaded once
-    for name in ("dims_of", "layer_weights", "top_weights", "program_layer",
-                 "program_top", "program_params", "layer_key", "layer",
-                 "embed", "logits", "LAYER_LEAVES", "TOP_LEAVES",
-                 "CONTRACT_AXES"):
+    for name in ("dims_of", "layer_kinds", "layer_weights", "top_weights",
+                 "program_layer", "program_top", "layer_key", "layer",
+                 "embed", "logits", "rows_per_block", "LAYER_LEAVES",
+                 "TOP_LEAVES", "CONTRACT_AXES"):
         assert hasattr(arch, name), name
     with pytest.raises(SystemExit):
         cells.architecture({"reference": "no_such_architecture"})

@@ -184,7 +184,14 @@ def test_benchmark_json_names_units_and_files():
     for c in spec["configs"]:
         cfg = json.loads((cells.ROOT / c["file"]).read_text())
         assert cfg["source"] == c["source"] and len(c["source"]) <= 200
-        assert cfg["hidden_size"] == 2048 and cfg["vocab_size"] == 92544
+        path = cells.ROOT / c["file"]
+        tiny = json.loads((path.parent / "_rehearsal" / path.name).read_text())
+        for f in (cfg, tiny):   # both name an architecture and a precision
+            arch = cells.architecture(f)
+            d = arch.dims_of(f)
+            assert len(arch.layer_kinds(d)) == d["layers"]
+            assert set(f["rounding"]) <= {"weights", "kv", "operands"}
+        assert tiny["rounding"] == cfg["rounding"]
 
 
 def test_decode_step_ms_counts_plain_dispatches_only():
@@ -223,5 +230,41 @@ def test_traced_slice_length_is_the_mixs(mix, seconds, want):
 def test_serve_mixes_capture_one_second():
     # 4 s of a serve cell's 690,000 ops a second took the profiler minutes
     # to stop and the run past its 360 s (PR 24's refusal): the files say 1 s
-    for name in ("chat-steady", "batch-offline"):
+    for name in ("chat-steady", "batch-offline", "long-prefill"):
         assert cells.Cell(name).traffic["trace_slice_s"] == 1.0
+
+
+@pytest.mark.parametrize("what, stated, control", [
+    ("weights", "int8", "int4"), ("weights", "bfloat16", "int8"),
+    ("kv", "int8", "int4"), ("kv", "bfloat16", "int8"),
+    ("operands", "bfloat16", "fp8"),
+])
+def test_a_control_is_the_nearest_precision_below_the_stated_one(
+        what, stated, control):
+    from benchmark.reference import quant
+
+    assert quant.stated({"rounding": {what: stated}}, what) == stated
+    assert quant.below(what, stated) == control
+    assert quant.stated({"rounding": {}}, what) is None
+    with pytest.raises(SystemExit):
+        quant.stated({"rounding": {what: "int3"}}, what)
+    with pytest.raises(SystemExit):
+        quant.stated({}, what)
+
+
+def test_rounding_to_a_named_precision():
+    import jax.numpy as jnp
+
+    from benchmark.reference import quant
+
+    x = jnp.linspace(-1.0, 1.0, 64).reshape(2, 32)
+    assert quant.weight_qmax("int8") == 127 and quant.weight_qmax("int4") == 7
+    assert quant.weight_qmax("bfloat16") is None   # drawn in it already
+    for name, levels in (("int8", 255), ("int4", 15)):
+        q = quant.kv_round(name)(x)
+        assert q.shape == x.shape
+        assert all(len(set(map(float, row))) <= levels for row in q)
+    coarse = float(jnp.abs(quant.kv_round("int4")(x) - x).max())
+    fine = float(jnp.abs(quant.kv_round("int8")(x) - x).max())
+    assert fine < coarse / 8
+    assert quant.operand_round("fp8") is quant.fp8

@@ -1,65 +1,27 @@
 """Seeded weights, made by the benchmark and by nobody else.
 
-One function of (seed, layer, leaf) gives every weight of an InternLM2-
-shaped decoder.  The harness builds the program's parameter tree from it
-in ONE jitted call on the device; the plain reference regenerates the
-same values layer by layer after the program's state is freed.  Neither
-side takes a weight the other has made.
+One function of (seed, layer, leaf) gives every weight of a model.  The
+harness builds the program's parameter tree from it in ONE jitted call
+on the device; the plain reference regenerates the same values layer by
+layer after the program's state is freed.  Neither side takes a weight
+the other has made.
 
-Leaves carry canonical names and the shapes the architecture defines:
-projections as (hidden, heads, head_dim) / (heads, head_dim, hidden),
-MLP matrices as (in, out), embedding (vocab, hidden), head (hidden,
-vocab).  Matrices are N(0, 1/fan_in); norm scales are ones.  Values are
-drawn in float32 and rounded to ``dtype`` (bfloat16 for serving, the
-type the service is handed; float32 for training).
+What the leaves are called, their shapes and where the program keeps
+them is the architecture's to say (its file under ``reference/``); this
+file draws them.  A leaf is given as ``(shape, fan_in)``: a matrix is
+N(0, 1/fan_in), drawn in float32 and rounded to ``dtype`` (bfloat16 for
+serving, the type the service is handed; float32 for training); a leaf
+whose ``fan_in`` is None is a norm scale, all ones.
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
-LAYER_LEAVES = (
-    "attn_norm", "wq", "wk", "wv", "wo", "mlp_norm", "w_gate", "w_up",
-    "w_down",
-)
-TOP_LEAVES = ("emb", "final_norm", "head")
-
-
-def dims_of(cfg: Dict[str, Any]) -> Dict[str, int]:
-    """Canonical sizes from a configuration file's published keys."""
-    heads = int(cfg["num_attention_heads"])
-    hidden = int(cfg["hidden_size"])
-    return {
-        "vocab": int(cfg["vocab_size"]),
-        "hidden": hidden,
-        "layers": int(cfg["num_hidden_layers"]),
-        "heads": heads,
-        "kv_heads": int(cfg["num_key_value_heads"]),
-        "head_dim": hidden // heads,
-        "mlp": int(cfg["intermediate_size"]),
-    }
-
-
-def leaf_shape(name: str, d: Dict[str, int]):
-    """(shape, fan_in) of one canonical leaf; fan_in None = a norm scale."""
-    h, dh = d["hidden"], d["head_dim"]
-    return {
-        "attn_norm": ((h,), None),
-        "mlp_norm": ((h,), None),
-        "final_norm": ((h,), None),
-        "wq": ((h, d["heads"], dh), h),
-        "wk": ((h, d["kv_heads"], dh), h),
-        "wv": ((h, d["kv_heads"], dh), h),
-        "wo": ((d["heads"], dh, h), d["heads"] * dh),
-        "w_gate": ((h, d["mlp"]), h),
-        "w_up": ((h, d["mlp"]), h),
-        "w_down": ((d["mlp"], h), d["mlp"]),
-        "emb": ((d["vocab"], h), h),
-        "head": ((h, d["vocab"]), h),
-    }[name]
+Shapes = Dict[str, Tuple[Tuple[int, ...], Any]]
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -70,73 +32,45 @@ def seed_key(seed: int) -> jax.Array:
     )
 
 
-def _leaf(key, name: str, d: Dict[str, int], dtype) -> jax.Array:
-    shape, fan_in = leaf_shape(name, d)
+def _leaf(key, shape, fan_in, dtype) -> jax.Array:
     if fan_in is None:
         return jnp.ones(shape, jnp.float32)
     w = jax.random.normal(key, shape, jnp.float32) * (float(fan_in) ** -0.5)
     return w.astype(dtype)
 
 
-def layer_weights(key, layer, d: Dict[str, int], dtype) -> Dict[str, Any]:
-    """Layer ``layer``'s leaves (``layer`` may be a traced integer)."""
-    lk = jax.random.fold_in(key, 1000 + layer)
+def _leaves(key, shapes: Shapes, dtype) -> Dict[str, Any]:
     return {
-        n: _leaf(jax.random.fold_in(lk, j), n, d, dtype)
-        for j, n in enumerate(LAYER_LEAVES)
+        n: _leaf(jax.random.fold_in(key, j), shape, fan_in, dtype)
+        for j, (n, (shape, fan_in)) in enumerate(shapes.items())
     }
 
 
-def top_weights(key, d: Dict[str, int], dtype) -> Dict[str, Any]:
-    tk = jax.random.fold_in(key, 1)
-    return {
-        n: _leaf(jax.random.fold_in(tk, j), n, d, dtype)
-        for j, n in enumerate(TOP_LEAVES)
-    }
+def layer_leaves(key, layer, shapes: Shapes, dtype) -> Dict[str, Any]:
+    """Layer ``layer``'s leaves (``layer`` may be a traced integer), in
+    the order ``shapes`` names them: a leaf's values follow from the
+    seed, the layer and its place in that order."""
+    return _leaves(jax.random.fold_in(key, 1000 + layer), shapes, dtype)
 
 
-def program_layer(w: Dict[str, Any]) -> Dict[str, Any]:
-    """One layer in the parameter layout of ``TransformerLM``."""
-    return {
-        "attn": {
-            "RMSNorm_0": {"scale": w["attn_norm"]},
-            "q": {"kernel": w["wq"]},
-            "k": {"kernel": w["wk"]},
-            "v": {"kernel": w["wv"]},
-            "out": {"kernel": w["wo"]},
-        },
-        "RMSNorm_0": {"scale": w["mlp_norm"]},
-        "gate": {"kernel": w["w_gate"]},
-        "up": {"kernel": w["w_up"]},
-        "down": {"kernel": w["w_down"]},
-    }
+def top_leaves(key, shapes: Shapes, dtype) -> Dict[str, Any]:
+    """The leaves outside the layers."""
+    return _leaves(jax.random.fold_in(key, 1), shapes, dtype)
 
 
-def layer_key(i: int) -> str:
-    """Where layer ``i`` sits in the program's parameter tree."""
-    return f"DecoderLayer_{i}"
-
-
-def program_top(top: Dict[str, Any]) -> Dict[str, Any]:
-    """The leaves outside the layers, in ``TransformerLM``'s layout."""
-    return {
-        "emb": {"embedding": top["emb"]},
-        "RMSNorm_0": {"scale": top["final_norm"]},
-        "lm_head": {"kernel": top["head"]},
-    }
-
-
-def program_params(seed: int, d: Dict[str, int], dtype,
+def program_params(arch, seed: int, d: Dict[str, Any], dtype,
                    shardings=None) -> Dict[str, Any]:
-    """The whole parameter tree, on the device, in one jitted call;
-    ``shardings`` (a tree like the result) places each leaf where a
-    program on a mesh keeps it, so that no chip ever holds the whole."""
+    """The whole parameter tree of architecture ``arch`` in the
+    program's layout, on the device, in one jitted call; ``shardings``
+    (a tree like the result) places each leaf where a program on a mesh
+    keeps it, so that no chip ever holds the whole."""
+    kinds = arch.layer_kinds(d)
 
     def build(key):
-        tree = program_top(top_weights(key, d, dtype))
-        for i in range(d["layers"]):
-            tree[layer_key(i)] = program_layer(
-                layer_weights(key, i, d, dtype)
+        tree = arch.program_top(arch.top_weights(key, d, dtype))
+        for i, kind in enumerate(kinds):
+            tree[arch.layer_key(i, d)] = arch.program_layer(
+                arch.layer_weights(key, i, d, dtype, kind), kind
             )
         return tree
 
