@@ -203,6 +203,36 @@ class ProfileBusy(RuntimeError):
     status = "profile_busy"
 
 
+# what a routed expert layer sows a call (models/moe.py RoutedExperts):
+# assignments made, assignments whose expert is held here, experts a
+# token reached, 1 (the call), experts held
+_COUNT_METRICS = (
+    ("assignments", "Token-to-expert assignments routed"),
+    ("assignments_held", "Assignments whose expert this chip holds"),
+    ("experts_touched", "Experts a call's tokens reached, summed over calls"),
+    ("expert_layer_calls", "Expert-layer calls (layers x steps, and chunks)"),
+    ("experts_held", "Experts held, summed over calls"),
+)
+N_COUNTS = len(_COUNT_METRICS)
+
+
+def _sown_counts(upd):
+    """The ``counters`` collection of one model call summed over its
+    layers, (N_COUNTS,) float32; None for a model that sows nothing."""
+    import jax
+
+    leaves = jax.tree_util.tree_leaves(upd.get("counters", {}))
+    return sum(leaves[1:], leaves[0]) if leaves else None
+
+
+def _pack_counts(packed, counts):
+    """The packed token buffer flattened, the counts as its tail: still
+    one buffer, one transfer (``_process_oldest`` splits it)."""
+    import jax.numpy as jnp
+
+    return jnp.concatenate([packed.reshape(-1), counts.astype(packed.dtype)])
+
+
 def _fail_future(fut: Future, err: Exception) -> None:
     """Fail a future idempotently: submit's close-race check and close's
     queue drain can both reach the same future — a bare done()-then-
@@ -949,7 +979,20 @@ class DecodeEngine:
             "issued": 0, "host_ms": 0.0, "hidden_ms": 0.0, "wait_ms": 0.0,
             "inflight_sum": 0, "peak_inflight": 0,
             "rows_attended": 0, "rows_total": 0,
+            "kv_attended": 0, "kv_live": 0,
         }
+        # one entry an attention layer: its window, None where it
+        # reads the whole context (a model that does not say is one
+        # layer of full attention: the share then reads 1)
+        windows = getattr(model, "attention_windows", None)
+        self._attn_windows: Tuple[Optional[int], ...] = (
+            tuple(windows()) if callable(windows) else (None,)
+        )
+        # what the model's layers sowed, summed over every program
+        # read back (_sown_counts' order); a staged chunk's counts wait
+        # here for the next read
+        self._counts = np.zeros((N_COUNTS,), np.float64)  # guarded_by: loop [writes]
+        self._counts_pending: Deque[Any] = deque()  # guarded_by: loop [writes]
         self._t_acct = time.perf_counter()  # guarded_by: loop [writes]
         # per-request latency reservoirs (most recent ~2k requests;
         # warmup submissions excluded): time-to-first-token and the
@@ -1822,7 +1865,28 @@ class DecodeEngine:
             "rows_attended_share": round(
                 p["rows_attended"] / p["rows_total"], 4
             ) if p["rows_total"] else None,
+            # context tokens the live rows held at issue, summed over
+            # attention layers and dispatches, and the part inside each
+            # layer's window (min(context, window))
+            "kv_tokens_attended": p["kv_attended"],
+            "kv_tokens_live": p["kv_live"],
+            "kv_tokens_attended_share": round(
+                p["kv_attended"] / p["kv_live"], 4
+            ) if p["kv_live"] else None,
         }
+        made, held, touched, calls, here = (float(c) for c in self._counts)
+        if calls:
+            # a routed expert layer's own counts, summed over layers,
+            # steps and chunks of every dispatch read back
+            out["moe"] = {
+                "assignments": made,
+                "assignments_held": held,
+                "experts_touched": touched,
+                "expert_layer_calls": calls,
+                "experts_touched_per_call": round(touched / calls, 3),
+                # of the experts held, summed over the same calls
+                "experts_touched_share": round(touched / here, 4),
+            }
         out["latency"] = {
             # "samples" is the WINDOW the percentiles summarize (the
             # deque, capped at its maxlen); "lifetime_samples" is the
@@ -1984,6 +2048,15 @@ class DecodeEngine:
         ctr("mlcomp_engine_attention_rows_total",
             "Slot rows in the carry at issue, summed over dispatches",
             p["rows_total"])
+        ctr("mlcomp_engine_attention_kv_tokens_attended_total",
+            "Context tokens inside each attention layer's window, summed "
+            "over live rows, layers and dispatches", p["kv_attended"])
+        ctr("mlcomp_engine_attention_kv_tokens_live_total",
+            "Context tokens held by live rows, summed over layers and "
+            "dispatches", p["kv_live"])
+        if self._counts[3]:  # a routed expert layer has been called
+            for (name, what), value in zip(_COUNT_METRICS, self._counts):
+                ctr(f"mlcomp_engine_moe_{name}_total", what, float(value))
         gau("mlcomp_engine_pipeline_depth", "Configured pipeline depth",
             self.pipeline_depth)
         gau("mlcomp_engine_pipeline_inflight",
@@ -2415,7 +2488,7 @@ class DecodeEngine:
             if ("dispatch", k) in self._fns and k in self._dispatch_warmed:
                 continue
             out = self._dispatch_fn(k)(self.variables, self._fresh_dstate())
-            np.asarray(out[1][0, 0, 0])  # block until it really ran
+            np.asarray(out[1].ravel()[0])  # block until it really ran
             self._dispatch_warmed.add(k)
             n += 1
         return n
@@ -2464,7 +2537,7 @@ class DecodeEngine:
         )
         # block until it really ran — on the PACKED output, which is
         # replicated in a multi-process gang (the logits are not)
-        np.asarray(out[1][0, 0, 0])
+        np.asarray(out[1].ravel()[0])
         self._fused_warmed.add((c, k))
 
     def _prefill_chunk_fn(self, c: int):
@@ -2480,9 +2553,12 @@ class DecodeEngine:
             def pchunk(variables, cache, chunk, positions, kv_mask):
                 logits, upd = self._apply(
                     {**variables, "cache": cache}, chunk, decode=True,
-                    positions=positions, kv_mask=kv_mask, mutable=["cache"],
+                    positions=positions, kv_mask=kv_mask,
+                    mutable=["cache", "counters"],
                 )
-                return logits[:, -1].astype(jnp.float32), upd["cache"]
+                counts = _sown_counts(upd)
+                out = (logits[:, -1].astype(jnp.float32), upd["cache"])
+                return out if counts is None else out + (counts,)
 
             self._fns[key] = jax.jit(pchunk, donate_argnums=(1,))
         return self._fns[key]
@@ -3082,17 +3158,20 @@ class DecodeEngine:
     def _kv_forward_fn(self, variables, dstate):
         """The model-forward adapter the dispatch cores thread their
         KV carry through: ``(kv, tok, positions, cursors, kv_mask) ->
-        (logits, kv')`` where ``kv`` is the dense cache pytree — or,
-        fused-paged, the page TUPLE (the table is dispatch-invariant
-        and closes over from the carry)."""
+        (logits, kv', counts)`` where ``kv`` is the dense cache pytree
+        — or, fused-paged, the page TUPLE (the table is
+        dispatch-invariant and closes over from the carry) — and
+        ``counts`` is what the model's layers sowed (``_sown_counts``;
+        None for a model that sows nothing, whose program is then what
+        it always was)."""
         if not self._kv_fused():
             def forward(kv, tok, positions, cursors, kv_mask):
                 logits, upd = self._apply(
                     {**variables, "cache": kv}, tok, decode=True,
                     positions=positions, kv_mask=kv_mask,
-                    cache_cursor=cursors, mutable=["cache"],
+                    cache_cursor=cursors, mutable=["cache", "counters"],
                 )
-                return logits, upd["cache"]
+                return logits, upd["cache"], _sown_counts(upd)
 
             return forward
         from mlcomp_tpu.kvpool.attn import PagedKV, paged_kv
@@ -3115,7 +3194,7 @@ class DecodeEngine:
                     positions=positions, kv_mask=kv_mask,
                     cache_cursor=cursors, mutable=["cache"],
                 )
-            return logits, tuple(ctx.pages)
+            return logits, tuple(ctx.pages), None
 
         return forward
 
@@ -3143,8 +3222,11 @@ class DecodeEngine:
                 logits, upd = self._apply(
                     {**variables, "cache": adm_cache}, chunk, decode=True,
                     positions=positions, kv_mask=kv_mask,
-                    mutable=["cache"],
+                    mutable=["cache", "counters"],
                 )
+                counts = _sown_counts(upd)
+                if counts is not None:
+                    packed = packed.at[-N_COUNTS:].add(counts)
                 out = self._constrain_carry(out)
                 packed = self._replicate_out(packed)
                 return (out, packed, logits[:, -1].astype(jnp.float32),
@@ -3223,7 +3305,7 @@ class DecodeEngine:
                 # no valid slot its window is empty, so the attention
                 # neither fetches nor computes its stale buffer (the
                 # KV write at its frozen cursor stays)
-                logits, kv2 = forward(
+                logits, kv2, counts = forward(
                     kv, tok[:, None], positions[:, None], cursors,
                     kv_mask & live[:, None],
                 )
@@ -3235,7 +3317,9 @@ class DecodeEngine:
                     live & ~done_now,
                     remaining,
                 )
-                return carry2, (tok, lp, live)
+                if counts is None:
+                    return carry2, (tok, lp, live)
+                return carry2, (tok, lp, live, counts)
 
             kv0 = (
                 tuple(dstate["pages"]) if fused_kv else dstate["cache"]
@@ -3246,7 +3330,7 @@ class DecodeEngine:
                 dstate["positions"], dstate["active"],
                 dstate["remaining"],
             )
-            carry, (toks, lps, valid) = jax.lax.scan(
+            carry, (toks, lps, valid, *sown) = jax.lax.scan(
                 one_step, carry0, None, length=K
             )
             out = dict(dstate)
@@ -3262,6 +3346,8 @@ class DecodeEngine:
                 lps.astype(jnp.float32),
                 valid.astype(jnp.float32),
             ])
+            if sown:
+                packed = _pack_counts(packed, sown[0].sum(axis=0))
             return out, packed
 
         return dispatch
@@ -3308,7 +3394,7 @@ class DecodeEngine:
             kv0 = (
                 tuple(dstate["pages"]) if fused_kv else dstate["cache"]
             )
-            logits, kv_out = forward(
+            logits, kv_out, _ = forward(
                 kv0, seq, pos, dstate["cursors"],
                 kv_mask & live0[:, None],   # as the scan core's one_step
             )
@@ -3592,12 +3678,14 @@ class DecodeEngine:
                 rid=adm.req.get("rid", 0), fused=False,
                 trace_id=adm.req.get("trace_id"),
             ):
-                logits, adm.cache = self._prefill_chunk_fn(c)(
+                logits, adm.cache, *sown = self._prefill_chunk_fn(c)(
                     self.variables, adm.cache,
                     self._dev(adm.row[:, lo:lo + c]),
                     self._dev(adm.positions[:, lo:lo + c]),
                     adm.kv_mask,
                 )
+                # read with the next dispatch's packed buffer, not here
+                self._counts_pending.extend(sown)
         finally:
             self._busy_since = None
         if decoding:
@@ -4649,8 +4737,16 @@ class DecodeEngine:
         # rows whose window the attention walks in this dispatch, by
         # the host's slot mirror (a row that retires inside an
         # in-flight dispatch still counts until its tokens are read)
-        p["rows_attended"] += sum(1 for sl in self._host if sl is not None)
+        ctx = [sl.position for sl in self._host if sl is not None]
+        p["rows_attended"] += len(ctx)
         p["rows_total"] += len(self._host)
+        # tokens of context the live rows hold, over the layers, and
+        # the part of them a layer's window lets its attention read
+        p["kv_live"] += sum(ctx) * len(self._attn_windows)
+        p["kv_attended"] += sum(
+            c if w is None else min(c, w)
+            for w in self._attn_windows for c in ctx
+        )
         if len(self._inflight) > p["peak_inflight"]:
             p["peak_inflight"] = len(self._inflight)
         # the dispatch's LIFETIME (issue -> outputs read) as an async
@@ -4681,6 +4777,13 @@ class DecodeEngine:
         try:
             _inject_fault("engine.resolve")  # chaos: slow readback
             arr = np.asarray(packed)  # (3, K, slots) f32, 1 transfer
+            if arr.ndim == 1:
+                # a model whose layers sow counts: they ride the tail
+                # of the same buffer (_pack_counts)
+                self._counts += arr[-N_COUNTS:]
+                arr = arr[:-N_COUNTS].reshape(3, -1, len(self._host))
+            while self._counts_pending:
+                self._counts += np.asarray(self._counts_pending.popleft())
         finally:
             self._busy_since = None
             t_done = self._loop_span("resolve", t_open, seq=seq)

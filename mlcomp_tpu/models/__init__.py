@@ -19,7 +19,8 @@ def load_all() -> None:
 
     import importlib
 
-    for mod in ("resnet", "unet", "bert", "transformer", "moe", "vit", "pipeline_lm"):
+    for mod in ("resnet", "unet", "bert", "transformer", "moe", "vit", "pipeline_lm",
+                "mixed_layer_lm"):
         name = f"mlcomp_tpu.models.{mod}"
         try:
             importlib.import_module(name)

@@ -1,0 +1,173 @@
+"""Decoder LM whose layers are not alike: built from per-layer lists.
+
+``transformer_lm`` and ``moe_lm`` build every layer from one set of
+numbers.  Here each layer names its attention kind (``"full"`` or
+``"sliding"``: its own RoPE description, and a window for the sliding
+kind), its number of query heads, and its MLP kind (``"dense"``
+SwiGLU, or ``"sparse"``: dropless top-k routed SwiGLU experts plus a
+shared expert, ``models/moe.py`` ``RoutedExperts``).  Every layer is
+``transformer.SelfAttention`` (a per-head output gate, a head width
+that is a field) followed by its MLP: one attention module, one
+decode-attention kernel family, one cache layout (a whole ``l_buf`` a
+layer; a window layer reads its last ``window`` tokens).
+
+Serving only: the expert layer has no capacity and no auxiliary loss.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Optional, Tuple
+
+import flax.linen as nn
+import jax.numpy as jnp
+
+from mlcomp_tpu.models import MODELS
+from mlcomp_tpu.models.moe import RoutedExperts
+from mlcomp_tpu.models.transformer import (
+    RMSNorm,
+    RopeSpec,
+    SelfAttention,
+    _LMHead,
+    resolve_positions,
+)
+
+
+class MixedLayer(nn.Module):
+    """One decoder layer: attention of its kind, then its MLP."""
+
+    hidden: int
+    heads: int
+    kv_heads: int
+    head_dim: int
+    dtype: jnp.dtype
+    rope: Optional[RopeSpec] = None
+    window: Optional[int] = None
+    head_gate: bool = False
+    kv_quant: bool = False
+    # the dense MLP's width, or None for the routed experts below
+    mlp_dim: Optional[int] = None
+    experts: int = 0
+    experts_per_token: int = 0
+    experts_held: Optional[Tuple[int, int]] = None
+    routed_scale: float = 1.0
+    expert_width: int = 0
+    shared_width: int = 0
+
+    @nn.compact
+    def __call__(self, x, positions, decode=False, kv_mask=None,
+                 cache_cursor=None):
+        x = SelfAttention(
+            self.hidden, self.heads, self.kv_heads, self.dtype,
+            kv_quant=self.kv_quant, head_dim=self.head_dim, rope=self.rope,
+            window=self.window, head_gate=self.head_gate, name="attn",
+        )(x, positions, decode=decode, kv_mask=kv_mask,
+          cache_cursor=cache_cursor)
+        h = RMSNorm(self.dtype)(x)
+        if self.mlp_dim is not None:
+            dense = lambda n, name: nn.Dense(  # noqa: E731
+                n, use_bias=False, dtype=self.dtype, name=name
+            )
+            h = nn.silu(dense(self.mlp_dim, "gate")(h)) * dense(
+                self.mlp_dim, "up"
+            )(h)
+            return x + dense(self.hidden, "down")(h)
+        return x + RoutedExperts(
+            n_experts=self.experts, d_model=self.hidden,
+            d_ff=self.expert_width, k=self.experts_per_token,
+            experts_held=self.experts_held, routed_scale=self.routed_scale,
+            shared_width=self.shared_width, dtype=self.dtype, name="moe",
+        )(h)
+
+
+class MixedLayerLM(nn.Module):
+    vocab_size: int
+    hidden: int
+    head_dim: int
+    kv_heads: int
+    layer_types: Tuple[str, ...]
+    heads_per_layer: Tuple[int, ...]
+    mlp_layer_types: Tuple[str, ...]
+    mlp_dim: int
+    rope_full: Optional[RopeSpec] = None
+    rope_sliding: Optional[RopeSpec] = None
+    window: Optional[int] = None
+    head_gate: bool = False
+    experts: int = 0
+    experts_per_token: int = 0
+    experts_held: Optional[Tuple[int, int]] = None
+    routed_scale: float = 1.0
+    expert_width: int = 0
+    shared_width: int = 0
+    dtype: str = "bfloat16"
+    kv_quant: bool = False
+    # the head's matmul operands (accumulation and logits stay float32):
+    # "bfloat16" reads a bfloat16 head as it is stored
+    head_dtype: str = "float32"
+
+    def attention_windows(self) -> Tuple[Optional[int], ...]:
+        """Each layer's window (None: the whole context), for the
+        engine's count of the context tokens attention reads."""
+        return tuple(
+            self.window if kind == "sliding" else None
+            for kind in self.layer_types
+        )
+
+    @nn.compact
+    def __call__(self, x, train: bool = False, decode: bool = False,
+                 positions=None, kv_mask=None, cache_cursor=None):
+        if train:
+            raise NotImplementedError(
+                "mixed_layer_lm is served, not trained: its expert layer "
+                "has no capacity and no balance loss"
+            )
+        dtype = jnp.dtype(self.dtype)
+        ids = x.astype(jnp.int32)
+        positions = resolve_positions(ids, decode, positions)
+        h = nn.Embed(self.vocab_size, self.hidden, dtype=dtype, name="emb")(ids)
+        for i, (kind, heads, mlp) in enumerate(zip(
+            self.layer_types, self.heads_per_layer, self.mlp_layer_types
+        )):
+            sliding = kind == "sliding"
+            sparse = mlp == "sparse"
+            h = MixedLayer(
+                self.hidden, heads, self.kv_heads, self.head_dim, dtype,
+                rope=self.rope_sliding if sliding else self.rope_full,
+                window=self.window if sliding else None,
+                head_gate=self.head_gate, kv_quant=self.kv_quant,
+                mlp_dim=None if sparse else self.mlp_dim,
+                experts=self.experts,
+                experts_per_token=self.experts_per_token,
+                experts_held=self.experts_held,
+                routed_scale=self.routed_scale,
+                expert_width=self.expert_width,
+                shared_width=self.shared_width,
+                name=f"layer_{i}",
+            )(h, positions, decode, kv_mask, cache_cursor)
+        h = RMSNorm(dtype)(h)
+        return _LMHead(
+            self.vocab_size, self.hidden, compute_dtype=self.head_dtype,
+            name="lm_head",
+        )(h)
+
+
+@MODELS.register("mixed_layer_lm")
+def mixed_layer_lm(**cfg: Any) -> MixedLayerLM:
+    """``MixedLayerLM`` from a configuration's mapping: JSON lists
+    become tuples and the two RoPE mappings ``RopeSpec``s (a flax
+    module's fields are hashed)."""
+    lists = ("layer_types", "heads_per_layer", "mlp_layer_types")
+    n = {len(cfg[k]) for k in lists}
+    if len(n) != 1:
+        raise ValueError(f"{lists} must be one entry a layer, got lengths {n}")
+    for kind, allowed in (("layer_types", ("full", "sliding")),
+                          ("mlp_layer_types", ("dense", "sparse"))):
+        bad = sorted(set(cfg[kind]) - set(allowed))
+        if bad:
+            raise ValueError(f"{kind}: {bad} not among {allowed}")
+    for k in lists:
+        cfg[k] = tuple(cfg[k])
+    if cfg.get("experts_held") is not None:
+        cfg["experts_held"] = tuple(int(v) for v in cfg["experts_held"])
+    for k in ("rope_full", "rope_sliding"):
+        cfg[k] = RopeSpec.of(cfg.get(k))
+    return MixedLayerLM(**cfg)

@@ -21,7 +21,7 @@ capability. TPU-first design choices:
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -191,6 +191,115 @@ class MoEBlock(nn.Module):
             "tec,ecd->td", combine.astype(self.dtype), expert_out
         )
         return out.reshape(b, s, d)
+
+
+class RoutedExperts(nn.Module):
+    """Dropless top-k routing over SwiGLU experts, of which this chip
+    may hold a share (expert parallelism's one-chip half), plus an
+    optional shared SwiGLU expert on every token.
+
+    The float32 router scores all ``n_experts`` by softmax; a token's
+    top ``k`` are renormalised to sum 1 and scaled by ``routed_scale``.
+    ``experts_held = (first, count)`` says which experts' weights are
+    here (None: all).  The assignments whose expert is held are sorted
+    by expert (``group_layout``'s counting sort), run through the
+    grouped matmul against the stacked weights ``experts_gate`` /
+    ``experts_up`` (count, d, f) and ``experts_down`` (count, f, d), and
+    summed back into their tokens by gate weight.  What the experts not
+    held would add is left out: the result is this chip's part, and on
+    one chip nothing stands in for the exchange.  No capacity, no
+    dropped token, one algorithm for every token count.
+
+    Each call sows ``[assignments made, assignments held, experts
+    touched, 1, experts held]`` into the ``counters`` collection (summed where a
+    caller makes it mutable; the decode engine does).
+    """
+
+    n_experts: int
+    d_model: int
+    d_ff: int
+    k: int
+    experts_held: Optional[Tuple[int, int]] = None
+    routed_scale: float = 1.0
+    shared_width: int = 0
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        from mlcomp_tpu.ops.pallas.grouped_matmul import (
+            ROW_TILE,
+            group_layout,
+            grouped_matmul,
+        )
+
+        b, s, d = x.shape
+        t = b * s
+        first, count = self.experts_held or (0, self.n_experts)
+        tokens = x.reshape(t, d)
+        stack = lambda name, shape: self.param(  # noqa: E731
+            name, nn.initializers.normal(0.02), shape, jnp.float32
+        ).astype(self.dtype)
+        w_gate = stack("experts_gate", (count, d, self.d_ff))
+        w_up = stack("experts_up", (count, d, self.d_ff))
+        w_down = stack("experts_down", (count, self.d_ff, d))
+
+        with jax.named_scope("moe.route"):
+            logits = nn.Dense(
+                self.n_experts, use_bias=False, dtype=jnp.float32,
+                name="router",
+            )(tokens.astype(jnp.float32))
+            topv, topi = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), self.k)
+            gates = topv / jnp.sum(topv, axis=-1, keepdims=True)
+            gates = gates * self.routed_scale                     # (T, k)
+            local = (topi - first).reshape(t * self.k)
+            lay = group_layout(
+                local, count, ROW_TILE,
+                source=jnp.arange(t * self.k, dtype=jnp.int32) // self.k,
+            )
+            held = lay.dest < lay.row_source.shape[0]
+            self.sow(
+                "counters", "moe",
+                jnp.stack([
+                    jnp.float32(t * self.k),
+                    jnp.sum(held).astype(jnp.float32),
+                    jnp.sum(lay.sizes > 0).astype(jnp.float32),
+                    jnp.float32(1.0),
+                    jnp.float32(count),
+                ]),
+                reduce_fn=lambda a, c: a + c,
+                init_fn=lambda: jnp.zeros((5,), jnp.float32),
+            )
+
+        with jax.named_scope("moe.experts"):
+            rows = jnp.take(tokens.astype(self.dtype), lay.row_source, axis=0)
+            act = grouped_matmul(
+                rows, w_gate, lay.tile_group, lay.tiles_used, w2=w_up
+            )
+            out = grouped_matmul(
+                act, w_down, lay.tile_group, lay.tiles_used
+            )
+            # each token's sum over its held assignments, by gate weight
+            # (a row no tile wrote is whatever was there: select, never
+            # multiply by zero)
+            picked = jnp.where(
+                held[:, None],
+                jnp.take(out, jnp.where(held, lay.dest, 0), axis=0), 0,
+            ).reshape(t, self.k, d)
+            y = jnp.einsum(
+                "tkd,tk->td", picked.astype(jnp.float32), gates
+            )
+
+        if self.shared_width:
+            with jax.named_scope("moe.shared"):
+                dense = lambda n, name: nn.Dense(  # noqa: E731
+                    n, use_bias=False, dtype=self.dtype, name=name
+                )
+                h = tokens.astype(self.dtype)
+                h = nn.silu(dense(self.shared_width, "shared_gate")(h)) * dense(
+                    self.shared_width, "shared_up"
+                )(h)
+                y = y + dense(d, "shared_down")(h).astype(jnp.float32)
+        return y.astype(self.dtype).reshape(b, s, d)
 
 
 class MoELayer(nn.Module):

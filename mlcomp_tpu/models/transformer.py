@@ -12,11 +12,13 @@ critical params, fused attention via ops.attention, MXU-aligned widths.
 
 from __future__ import annotations
 
-from typing import Optional
+import math
+from typing import NamedTuple, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from mlcomp_tpu.models import MODELS
 from mlcomp_tpu.ops.attention import dot_product_attention
@@ -44,6 +46,69 @@ def apply_rope(x: jax.Array, positions: jax.Array, base: float = 10000.0) -> jax
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
     )
     return out.astype(x.dtype)
+
+
+class RopeSpec(NamedTuple):
+    """A layer's rotary embedding, where it is not :func:`apply_rope`'s
+    default: the base, how many leading dimensions of each head rotate
+    (``rotary_dim``; the rest pass through), and optionally YaRN's
+    frequency blend (``factor`` set): below ``lo`` the published
+    frequencies stay, above ``hi`` they are divided by ``factor``,
+    with a linear ramp between (``lo`` / ``hi`` from ``beta_fast`` /
+    ``beta_slow`` and ``original_max``); cos and sin are both
+    multiplied by ``attention_factor``."""
+
+    base: float = 10000.0
+    rotary_dim: Optional[int] = None
+    factor: Optional[float] = None
+    original_max: int = 8192
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    @classmethod
+    def of(cls, spec) -> Optional["RopeSpec"]:
+        """From a configuration's mapping (None stays None)."""
+        if spec is None or isinstance(spec, cls):
+            return spec
+        return cls(**dict(spec))
+
+
+def rope_inv_freq(spec: RopeSpec, head_dim: int) -> np.ndarray:
+    """The rotary_dim / 2 angular frequencies of ``spec``, float32."""
+    d_r = spec.rotary_dim or head_dim
+    half = d_r // 2
+    j = np.arange(half, dtype=np.float64)
+    freq = float(spec.base) ** (-2.0 * j / d_r)
+    if spec.factor is not None:
+        def dim_of(n_rot):
+            return d_r * math.log(
+                spec.original_max / (n_rot * 2.0 * math.pi)
+            ) / (2.0 * math.log(spec.base))
+
+        lo = max(math.floor(dim_of(spec.beta_fast)), 0)
+        hi = min(math.ceil(dim_of(spec.beta_slow)), d_r - 1)
+        ramp = np.clip((j - lo) / max(hi - lo, 1e-3), 0.0, 1.0)
+        freq = (freq / spec.factor) * ramp + freq * (1.0 - ramp)
+    return freq.astype(np.float32)
+
+
+def apply_rope_spec(x: jax.Array, positions: jax.Array,
+                    spec: RopeSpec) -> jax.Array:
+    """Rotary embeddings by description; x: (B, S, H, D).  Dimension j
+    of the rotating part pairs with j + rotary_dim / 2
+    (:func:`apply_rope`'s convention)."""
+    d = x.shape[-1]
+    d_r = spec.rotary_dim or d
+    half = d_r // 2
+    angles = positions[..., None].astype(jnp.float32) * rope_inv_freq(spec, d)
+    cos = (jnp.cos(angles) * spec.attention_factor)[:, :, None, :]
+    sin = (jnp.sin(angles) * spec.attention_factor)[:, :, None, :]
+    x1, x2 = x[..., :half], x[..., half:d_r]
+    parts = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    if d_r < d:
+        parts.append(x[..., d_r:].astype(jnp.float32))
+    return jnp.concatenate(parts, axis=-1).astype(x.dtype)
 
 
 def resolve_positions(ids: jax.Array, decode: bool, positions):
@@ -141,11 +206,53 @@ class SelfAttention(nn.Module):
     # Param layout changes, so it is an opt-in serving flag; checkpoints
     # convert via fuse_decode_params.
     decode_fused: bool = False
+    # what may differ by layer in a model whose layers are not alike;
+    # the defaults are the module as it always was (same programs,
+    # same parameter paths).  ``head_dim``: a head's width where it is
+    # not hidden // heads.  ``rope``: a :class:`RopeSpec` (None:
+    # :func:`apply_rope` at its default base).  ``window``: query t
+    # attends keys t - window + 1 .. t (None: every earlier key) — a
+    # lower bound on the keys, applied in the forward pass, the cache's
+    # single-token step and its chunk form; the cache keeps whole
+    # buffers and reads their last ``window`` tokens.  ``head_gate``:
+    # each head's output is multiplied by the logistic function of its
+    # own projection ("head_gate/kernel", hidden -> heads) of the
+    # normed layer input.
+    head_dim: Optional[int] = None
+    rope: Optional[RopeSpec] = None
+    window: Optional[int] = None
+    head_gate: bool = False
+
+    def _window_lo(self, lo, stop):
+        """``lo`` raised to the window's lower bound for keys ending
+        (exclusive) at ``stop``; unchanged without a window."""
+        if self.window is None:
+            return lo
+        with jax.named_scope("attn.window"):
+            return jnp.maximum(lo, stop - self.window)
+
+    def _band(self, mask, slots, stops):
+        """``mask`` without the keys below each query's window:
+        ``slots`` are key indices, ``stops`` the queries' exclusive
+        stops, both shaped to broadcast against ``mask``."""
+        if self.window is None:
+            return mask
+        with jax.named_scope("attn.window"):
+            return mask & (slots >= stops - self.window)
+
+    def _refuse_long_fresh(self, s):
+        if self.window is not None and s > self.window \
+                and not self.is_initializing():
+            raise NotImplementedError(
+                f"a fresh prefill of {s} tokens is longer than this "
+                f"layer's window of {self.window}: the flash kernel has "
+                f"no band; prefill in chunks of at most the window"
+            )
 
     @nn.compact
     def __call__(self, x, positions, decode=False, kv_mask=None,
                  cache_cursor=None):
-        d_head = self.hidden // self.heads
+        d_head = self.head_dim or self.hidden // self.heads
         h = RMSNorm(self.dtype)(x)
         if self.decode_fused:
             qkv = nn.DenseGeneral(
@@ -159,16 +266,31 @@ class SelfAttention(nn.Module):
             q = nn.DenseGeneral((self.heads, d_head), use_bias=False, dtype=self.dtype, name="q")(h)
             k = nn.DenseGeneral((self.kv_heads, d_head), use_bias=False, dtype=self.dtype, name="k")(h)
             v = nn.DenseGeneral((self.kv_heads, d_head), use_bias=False, dtype=self.dtype, name="v")(h)
-        q = apply_rope(q, positions)
-        k = apply_rope(k, positions)
+        if self.rope is None:
+            q = apply_rope(q, positions)
+            k = apply_rope(k, positions)
+        else:
+            q = apply_rope_spec(q, positions, self.rope)
+            k = apply_rope_spec(k, positions, self.rope)
+        if self.head_gate:
+            gate = jax.nn.sigmoid(nn.Dense(
+                self.heads, use_bias=False, dtype=self.dtype,
+                name="head_gate",
+            )(h))[..., None]
         if decode:
             attn = self._decode_attention(q, k, v, kv_mask, cache_cursor)
+            if self.head_gate:
+                attn = attn * gate
             return x + nn.DenseGeneral(
                 self.hidden, axis=(-2, -1), use_bias=False, dtype=self.dtype, name="out"
             )(attn)
         # GQA: shared KV heads are broadcast inside the attention op, never
         # materialized rep× in HBM
         attn = None
+        if self.seq_parallel and self.window is not None:
+            raise NotImplementedError(
+                "sequence-parallel attention has no window"
+            )
         if self.seq_parallel:
             from mlcomp_tpu.parallel.mesh import axis_size, current_mesh
             from mlcomp_tpu.parallel.ring import ring_attention_sharded
@@ -196,8 +318,19 @@ class SelfAttention(nn.Module):
             mesh = current_mesh()
             if axis_size(mesh, "sp") > 1:
                 attn = sp_attn[mode](q, k, v, mesh, causal=True)
+        if attn is None and self.window is not None \
+                and q.shape[1] > self.window:
+            # longer than the window: the masked XLA path (flash has no
+            # band)
+            t = jnp.arange(q.shape[1], dtype=jnp.int32)
+            mask = self._band(
+                t[None, :] <= t[:, None], t[None, :], t[:, None] + 1
+            )
+            attn = dot_product_attention(q, k, v, mask=mask[None, None])
         if attn is None:
             attn = dot_product_attention(q, k, v, causal=True)
+        if self.head_gate:
+            attn = attn * gate
         return x + nn.DenseGeneral(
             self.hidden, axis=(-2, -1), use_bias=False, dtype=self.dtype, name="out"
         )(attn)
@@ -263,6 +396,11 @@ class SelfAttention(nn.Module):
                 mask = (
                     slots[None, None, None, :] <= stops[:, None, :, None]
                 )
+            mask = self._band(
+                mask, slots[None, None, None, :],
+                (cur[:, None] + jnp.arange(1, s + 1, dtype=jnp.int32)[None])
+                [:, None, :, None],
+            )
             if kv_mask is not None:
                 mask = mask & kv_mask[:, None, None, :].astype(jnp.bool_)
             return dot_product_attention(q, k_all, v_all, mask=mask)
@@ -276,9 +414,14 @@ class SelfAttention(nn.Module):
         slots = jnp.arange(max_len, dtype=jnp.int32)
         q_slots = i + jnp.arange(s, dtype=jnp.int32)
         mask = (slots[None, :] <= q_slots[:, None])[None, None]  # (1,1,S,max)
+        mask = self._band(
+            mask, slots[None, None, None, :],
+            q_slots[None, None, :, None] + 1,
+        )
         if kv_mask is not None:
             mask = mask & kv_mask[:, None, None, :].astype(jnp.bool_)
         if s > 1:
+            self._refuse_long_fresh(s)
             # prefill fast path: when the cache is still empty, attention
             # over the full buffer under the slot mask equals plain causal
             # attention over just the new K/V — which takes the flash
@@ -314,6 +457,7 @@ class SelfAttention(nn.Module):
         :meth:`_decode_attention` verbatim.  No dense cache variable is
         ever created — the dense view exists only transiently inside
         this layer's attention consumer."""
+        self._refuse_paged_window()
         if cache_cursor is None:
             raise NotImplementedError(
                 "fused paged attention runs only under the engine's "
@@ -346,6 +490,13 @@ class SelfAttention(nn.Module):
             mask = mask & kv_mask[:, None, None, :].astype(jnp.bool_)
         return dot_product_attention(q, k_all, v_all, mask=mask)
 
+    def _refuse_paged_window(self):
+        if self.window is not None:
+            raise NotImplementedError(
+                "the paged attention kernels take no window: serve a "
+                "model with window layers with kv_layout='dense'"
+            )
+
     def _paged_decode_attention_quant(self, ctx, q, k, v, kv_mask,
                                       cache_cursor):
         """Fused paged decode for the int8 KV family: quantize the new
@@ -365,6 +516,7 @@ class SelfAttention(nn.Module):
             quantize_kv,
         )
 
+        self._refuse_paged_window()
         if cache_cursor is None:
             raise NotImplementedError(
                 "fused paged attention runs only under the engine's "
@@ -627,7 +779,7 @@ class SelfAttention(nn.Module):
                 out = decode_attention_chunk(
                     qp, ckq.value, cks.value, cvq.value, cvs.value,
                     kv_start=row_start, kv_stop0=stop0,
-                    scale=1.0 / (dh**0.5),
+                    scale=1.0 / (dh**0.5), window=self.window,
                 )
                 return out[..., :dh]
             k_scale = cks.value.transpose(0, 1, 3, 2)   # (B, Hkv, L, 1)
@@ -644,6 +796,9 @@ class SelfAttention(nn.Module):
             mask = mask & (
                 slots[None, :] >= row_start[:, None]
             )[:, None, None, :]
+            mask = self._band(
+                mask, slots[None, None, None, :], stops[:, None, :, None]
+            )
             return dot_product_attention(q, k_all, v_all, mask=mask)
 
         if cache_cursor is not None:
@@ -692,7 +847,7 @@ class SelfAttention(nn.Module):
                 cvs.value = jnp.where(hit, vs_dense.astype(sdt), cvs.value)
             row_start = _window_start(kv_mask, b)
             if s == 1:
-                return flash(row_start, cur + 1)
+                return flash(self._window_lo(row_start, cur + 1), cur + 1)
             return chunk_attend(row_start, cur + 1)
         if s == 1:
             # single-token step (the serving hot path).  Two trace-time
@@ -756,7 +911,8 @@ class SelfAttention(nn.Module):
         start = _window_start(kv_mask, b)
 
         if s == 1:
-            return flash(start, i + 1)
+            return flash(self._window_lo(start, i + 1), i + 1)
+        self._refuse_long_fresh(s)
 
         def fresh_prefill():
             if kv_mask is None:

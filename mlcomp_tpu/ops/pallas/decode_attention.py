@@ -427,6 +427,7 @@ def _kernel_chunk(
     o_ref,
     acc_ref, m_ref, l_ref,
     *, scale: float, block_kv: int, rep: int, s_q: int,
+    window: Optional[int] = None,
 ):
     """Multi-query flash-decode: S query tokens per row in one pass over
     the int8 cache (the speculative verify / small-chunk shape).
@@ -436,7 +437,9 @@ def _kernel_chunk(
     is read ONCE for all S queries (the whole point: a verify of K+1
     tokens costs one cache sweep, not K+1).  Causality is per sublane
     row: query j's window is [start, stop0 + j) where stop0 is query
-    0's exclusive stop (its own cache slot + 1)."""
+    0's exclusive stop (its own cache slot + 1).  With ``window`` the
+    start is per sublane row too: query j sees its last ``window``
+    keys, [max(start, stop0 + j - window), stop0 + j)."""
     b = pl.program_id(0)
     j = pl.program_id(1)
     nk = pl.num_programs(1)
@@ -450,7 +453,9 @@ def _kernel_chunk(
     lo = start_ref[b]
     stop0 = stop0_ref[b]
     hi_max = stop0 + (s_q - 1)
-    live = (j * block_kv < hi_max) & ((j + 1) * block_kv > lo)
+    # the first key any query of the tile sees
+    lo_min = lo if window is None else jnp.maximum(lo, stop0 - window)
+    live = (j * block_kv < hi_max) & ((j + 1) * block_kv > lo_min)
 
     def mask_fn(shape):
         cols = j * block_kv + jax.lax.broadcasted_iota(jnp.int32, shape, 2)
@@ -463,7 +468,10 @@ def _kernel_chunk(
             jax.lax.broadcasted_iota(jnp.int32, shape, 1) // rep,
             s_q - 1,
         )
-        return (cols >= lo) & (cols < stop0 + qrow)
+        seen = (cols >= lo) & (cols < stop0 + qrow)
+        if window is not None:
+            seen = seen & (cols >= stop0 + qrow - window)
+        return seen
 
     @pl.when(live)
     def _step():
@@ -605,9 +613,13 @@ def decode_attention_chunk(
     kv_stop0: Optional[jax.Array] = None,
     scale: Optional[float] = None,
     interpret: Optional[bool] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Multi-query attention against an int8 KV cache: S chunk tokens
-    per row in ONE sweep of the cache.
+    per row in ONE sweep of the cache.  ``window``: query j attends its
+    last ``window`` keys only, [max(kv_start, kv_stop0 + j - window),
+    kv_stop0 + j), and blocks below every query's window are neither
+    fetched nor computed.
 
     q: (B, S, H, dh) chunk queries whose K/V are ALREADY written to the
     cache at slots [stop0-1+j for j in range(S)]... i.e. query j sits
@@ -645,7 +657,7 @@ def decode_attention_chunk(
             decode_attention_chunk(
                 q[:, o:o + CHUNK_MAX_SQ], k8, ks, v8, vs,
                 kv_start=kv_start, kv_stop0=stop0 + o, scale=scale,
-                interpret=interpret,
+                interpret=interpret, window=window,
             )
             for o in range(0, s_q, CHUNK_MAX_SQ)
         ], axis=1)
@@ -680,7 +692,10 @@ def decode_attention_chunk(
     )
 
     def _clamp(b_, j, start_ref, stop0_ref):
-        lo_b = jnp.minimum(start_ref[b_] // blk, nk - 1)
+        lo = start_ref[b_]
+        if window is not None:
+            lo = jnp.maximum(lo, stop0_ref[b_] - window)
+        lo_b = jnp.minimum(lo // blk, nk - 1)
         hi_b = jnp.maximum(
             (stop0_ref[b_] + (s_q - 1) - 1) // blk, lo_b
         )
@@ -694,7 +709,8 @@ def decode_attention_chunk(
 
     out = pl.pallas_call(
         functools.partial(
-            _kernel_chunk, scale=scale, block_kv=blk, rep=rep, s_q=s_q
+            _kernel_chunk, scale=scale, block_kv=blk, rep=rep, s_q=s_q,
+            window=window,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,

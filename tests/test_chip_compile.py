@@ -189,3 +189,59 @@ def test_page_gather_page128(chip, leaf):
         "bf16_scale": scale,
     }[leaf]
     _compiles_to_a_kernel(_gather_leaf_pallas, pages, table)
+
+
+# the mixed-layer cell (rollout-offline): 48 slots of 256 + 768 + 1
+# tokens, GQA groups of 9 (72 heads) and 6 (48) over 8 KV heads, a
+# 512-token window; 128 experts held, hidden 3072, expert width 1024
+MIXED_B, MIXED_L, MIXED_HKV = 48, 1152, 8
+
+
+@pytest.mark.parametrize("heads", [72, 48], ids=["sliding_72", "full_48"])
+def test_decode_attention_mixed_layer_groups(chip, heads):
+    from mlcomp_tpu.ops.pallas.decode_attention import decode_attention
+
+    _compiles_to_a_kernel(
+        functools.partial(decode_attention, interpret=False),
+        chip((MIXED_B, heads, DH), jnp.bfloat16),
+        *_dense_cache(chip, MIXED_B, MIXED_HKV, MIXED_L),
+    )
+
+
+def test_decode_attention_chunk_window_256_queries(chip):
+    """A 256-token chunk against the cache in a window layer: eight
+    query tiles of 32 x 9 sublane rows, a start per query row."""
+    from mlcomp_tpu.ops.pallas.decode_attention import decode_attention_chunk
+
+    _compiles_to_a_kernel(
+        functools.partial(decode_attention_chunk, interpret=False,
+                          window=512),
+        chip((1, 256, 72, DH), jnp.bfloat16),
+        *_dense_cache(chip, 1, MIXED_HKV, MIXED_L),
+    )
+
+
+@pytest.mark.parametrize("tokens", [48, 256], ids=["decode", "chunk"])
+def test_grouped_matmul_held_experts(chip, tokens):
+    from mlcomp_tpu.ops.pallas.grouped_matmul import (
+        ROW_TILE,
+        grouped_matmul,
+        padded_rows,
+    )
+
+    e, h, f = 128, 3072, 1024
+    rows = padded_rows(tokens * 10, e, ROW_TILE)
+    tiles = (chip((rows // ROW_TILE,), jnp.int32), chip((1,), jnp.int32))
+
+    def experts(x, w_gate, w_up, w_down, tile_group, used):
+        act = grouped_matmul(x, w_gate, tile_group, used, w2=w_up,
+                             interpret=False)
+        return grouped_matmul(act, w_down, tile_group, used, interpret=False)
+
+    text = _compiles_to_a_kernel(
+        experts, chip((rows, h), jnp.bfloat16),
+        chip((e, h, f), jnp.bfloat16), chip((e, h, f), jnp.bfloat16),
+        chip((e, f, h), jnp.bfloat16), *tiles,
+    )
+    # the benchmark's roofline reader matches the op by this name
+    assert "grouped_matmul" in text

@@ -1,0 +1,69 @@
+"""The grouped matmul (interpret mode) against plain ``jnp``: empty
+experts, experts not held, one expert taking every row."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from mlcomp_tpu.ops.pallas.grouped_matmul import (
+    ROW_TILE,
+    group_layout,
+    grouped_matmul,
+    padded_rows,
+)
+
+G, K, N = 4, 128, 256
+
+
+def _weights(seed):
+    k1, k2 = jax.random.split(jax.random.PRNGKey(seed))
+    w = jax.random.normal(k1, (G, K, N), jnp.float32) * K ** -0.5
+    w2 = jax.random.normal(k2, (G, K, N), jnp.float32) * K ** -0.5
+    return w, w2
+
+
+# a value outside [0, G) is an assignment whose expert is not held
+CASES = {
+    "mixed": [0, 3, 3, 7, 1, 0, -1, 3, 5, 0, 3, 3, 3, 1, 4, 0, 3, 3, 3, 3],
+    "empty_experts": [2, 2, 2, 2, 2, 0],
+    "none_held": [4, 5, 6, 7, 9],
+    "one_takes_all": [1] * 37,
+    "all_not_held_but_one": [9, 9, 9, 2, 9],
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+@pytest.mark.parametrize("swiglu", [False, True])
+def test_grouped_matmul_matches_jnp(case, swiglu):
+    group = jnp.asarray(CASES[case], jnp.int32)
+    a = group.shape[0]
+    x = jax.random.normal(jax.random.PRNGKey(a), (a, K), jnp.float32)
+    w, w2 = _weights(3)
+    lay = group_layout(group, G, ROW_TILE)
+    rows = padded_rows(a, G, ROW_TILE)
+    assert lay.row_source.shape == (rows,)
+    held = np.asarray((group >= 0) & (group < G))
+    assert np.array_equal(np.asarray(lay.dest) < rows, held)
+    sizes = np.bincount(np.asarray(group)[held], minlength=G)
+    assert np.array_equal(np.asarray(lay.sizes), sizes)
+    assert int(lay.tiles_used[0]) == sum(-(-s // ROW_TILE) for s in sizes)
+    out = grouped_matmul(
+        jnp.take(x, lay.row_source, axis=0), w, lay.tile_group,
+        lay.tiles_used, w2=w2 if swiglu else None, interpret=True,
+    )
+    dest = np.asarray(lay.dest)
+    # every held assignment's row sits in a tile of its own expert
+    tile_group = np.asarray(lay.tile_group)
+    for i in np.flatnonzero(held):
+        assert tile_group[dest[i] // ROW_TILE] == int(group[i])
+        want = x[i] @ w[int(group[i])]
+        if swiglu:
+            want = jax.nn.silu(want) * (x[i] @ w2[int(group[i])])
+        np.testing.assert_allclose(
+            np.asarray(out[dest[i]]), np.asarray(want), rtol=2e-5, atol=2e-5
+        )
+    # held assignments of one expert keep their arrival order
+    for g in range(G):
+        mine = dest[np.asarray(group) == g]
+        assert np.all(np.diff(mine) == 1)

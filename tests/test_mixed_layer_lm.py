@@ -1,0 +1,353 @@
+"""``mixed_layer_lm`` (layers built from per-layer lists: window and
+full attention with their own head counts and RoPE, a per-head gate,
+dropless routed experts of which a share is held) against the plain
+reference ``benchmark/reference/laguna.py``, at tiny widths on the CPU
+with seeded weights."""
+
+import json
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import cells
+from benchmark import weights as W
+from mlcomp_tpu.models import create_model
+from mlcomp_tpu.models.generation import init_cache
+from mlcomp_tpu.models.moe import RoutedExperts
+from mlcomp_tpu.models.transformer import (
+    RopeSpec,
+    SelfAttention,
+    apply_rope,
+    apply_rope_spec,
+    rope_inv_freq,
+)
+
+ROOT = Path(__file__).resolve().parents[1]
+HI = jax.lax.Precision.HIGHEST
+
+
+def _cfg(name="_rehearsal/laguna-s-2_1-serve.json"):
+    with open(ROOT / "benchmark" / "configs" / name) as f:
+        return json.load(f)
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    """(reference module, dims, model kwargs) at rehearsal width, float32."""
+    cfg = _cfg()
+    arch = cells.architecture(cfg)
+    model = {**cfg["model"], "dtype": "float32", "head_dtype": "float32"}
+    return arch, arch.dims_of(cfg), model
+
+
+def _reference_logits(arch, d, seed, ids):
+    key = W.seed_key(seed)
+    top = arch.top_weights(key, d, jnp.float32)
+    x = arch.embed(jnp.asarray(ids), top["emb"])
+    pos = jnp.broadcast_to(jnp.arange(ids.shape[1])[None], ids.shape)
+    for i, kind in enumerate(arch.layer_kinds(d)):
+        x = arch.layer(x, arch.layer_weights(key, i, d, jnp.float32, kind),
+                       pos, d, kind)
+    return np.asarray(arch.logits(x, top, d))
+
+
+def test_the_model_is_assembled_from_the_lists(tiny):
+    arch, d, kw = tiny
+    assert arch.layer_kinds(d) == ["dense_full", "sparse_sliding",
+                                   "sparse_full"]
+    model = create_model(dict(kw))
+    assert model.attention_windows() == (None, 16, None)
+    params = W.program_params(arch, 7, d, jnp.float32)
+    abstract = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
+    W.check_layout(params, abstract)
+    assert set(params["layer_1"]["moe"]) == {
+        "router", "experts_gate", "experts_up", "experts_down",
+        "shared_gate", "shared_up", "shared_down"}
+    assert params["layer_1"]["moe"]["experts_gate"].shape == (4, 256, 128)
+    assert params["layer_1"]["moe"]["router"]["kernel"].shape == (256, 8)
+    assert params["layer_1"]["attn"]["q"]["kernel"].shape == (256, 3, 64)
+    assert params["layer_0"]["attn"]["head_gate"]["kernel"].shape == (256, 2)
+    with pytest.raises(ValueError, match="one entry a layer"):
+        create_model({**kw, "heads_per_layer": [2, 3]})
+    with pytest.raises(ValueError, match="not among"):
+        create_model({**kw, "layer_types": ["full", "ring", "full"]})
+
+
+def test_full_forward_past_the_window_agrees_with_the_reference(tiny):
+    arch, d, kw = tiny
+    model = create_model({**kw, "kv_quant": False})
+    params = W.program_params(arch, 7, d, jnp.float32)
+    ids = np.asarray(jax.random.randint(jax.random.PRNGKey(0), (2, 40), 1, 512))
+    with jax.default_matmul_precision("highest"):
+        got, sown = model.apply({"params": params}, jnp.asarray(ids),
+                                mutable=["counters"])
+    np.testing.assert_allclose(
+        np.asarray(got), _reference_logits(arch, d, 7, ids), atol=2e-4)
+    counts = jax.tree_util.tree_leaves(sown["counters"])
+    assert len(counts) == 2            # one vector a sparse layer
+    for c in counts:                   # made, held, touched, calls, held here
+        assert c[0] == 2 * 40 * 2 and 0 < c[1] < c[0]
+        assert 1 <= c[2] <= 4 and c[3] == 1 and c[4] == 4
+
+
+@pytest.mark.parametrize("kv_quant", [False, True])
+def test_prefill_then_decode_through_the_cache_agrees_with_the_reference(
+        tiny, kv_quant):
+    """The engine's contract: a LEFT-padded prompt prefilled in chunks
+    (the first fresh, the second against the cache), then single-token
+    steps at a per-row cursor, contexts running past the window of 16;
+    logits against the reference's full forward, no cache."""
+    arch, d, kw = tiny
+    model = create_model({**kw, "kv_quant": kv_quant})
+    params = W.program_params(arch, 7, d, jnp.float32)
+    n_prompt, bucket, chunk, n_new, l_buf = 20, 32, 16, 30, 65
+    ids = np.asarray(jax.random.randint(
+        jax.random.PRNGKey(0), (1, n_prompt + n_new), 1, 512))
+    pad = bucket - n_prompt
+    row = np.zeros((1, bucket), np.int32)
+    row[0, pad:] = ids[0, :n_prompt]
+    positions = np.maximum(np.arange(bucket) - pad, 0)[None].astype(np.int32)
+    kv_mask = jnp.asarray((np.arange(l_buf) >= pad)[None])
+    cache = init_cache(model, 1, l_buf)
+    out = []
+    with jax.default_matmul_precision("highest"):
+        for lo in range(0, bucket, chunk):
+            lg, upd = model.apply(
+                {"params": params, "cache": cache},
+                jnp.asarray(row[:, lo:lo + chunk]), decode=True,
+                positions=jnp.asarray(positions[:, lo:lo + chunk]),
+                kv_mask=kv_mask, mutable=["cache"])
+            cache = upd["cache"]
+            out.append(np.asarray(lg))
+        out = [np.concatenate(out, 1)[:, pad:]]
+        for t in range(n_prompt, n_prompt + n_new):
+            lg, upd = model.apply(
+                {"params": params, "cache": cache},
+                jnp.asarray(ids[:, t:t + 1]), decode=True,
+                positions=jnp.full((1, 1), t, jnp.int32), kv_mask=kv_mask,
+                cache_cursor=jnp.array([bucket + t - n_prompt], jnp.int32),
+                mutable=["cache"])
+            cache = upd["cache"]
+            out.append(np.asarray(lg))
+    err = np.abs(np.concatenate(out, 1)
+                 - _reference_logits(arch, d, 7, ids)).max(-1)[0]
+    assert not np.isnan(err).any()
+    if not kv_quant:
+        assert err.max() < 2e-4
+    else:
+        # int8 keys and values: every position off by a little, and a
+        # top-2-of-8 routing flip on a near-tie now and then by a lot
+        assert np.median(err) < 0.15 and (err < 0.3).mean() > 0.8
+
+
+def test_a_window_layers_fresh_prefill_longer_than_its_window_is_refused(tiny):
+    arch, d, kw = tiny
+    model = create_model(dict(kw))
+    params = W.program_params(arch, 7, d, jnp.float32)
+    cache = init_cache(model, 1, 64)
+    with pytest.raises(NotImplementedError, match="longer than this"):
+        model.apply(
+            {"params": params, "cache": cache}, jnp.ones((1, 32), jnp.int32),
+            decode=True, mutable=["cache"],
+            positions=jnp.arange(32, dtype=jnp.int32)[None])
+
+
+def test_the_two_halves_and_the_shared_expert_once_are_the_whole_layer(tiny):
+    """Guide section 4's share test: what the chip holding experts 0-3
+    computes, plus what the chip holding 4-7 computes, with the shared
+    expert (which both compute alike) counted once, is the uncut
+    reference layer's MLP."""
+    arch, d, _ = tiny
+    h, f = d["hidden"], d["expert_width"]
+    uncut = {**d, "held": (0, d["experts"])}
+    w = arch.layer_weights(W.seed_key(3), 1, uncut, jnp.float32,
+                           "sparse_sliding")
+    assert w["experts_gate"].shape == (8, h, f)
+    u = jax.random.normal(jax.random.PRNGKey(1), (2, 24, h), jnp.float32)
+    shared = arch.swiglu(u, w["shared_gate"], w["shared_up"], w["shared_down"])
+    whole = arch.routed(u, w, uncut) + shared
+
+    def half(first):
+        layer = RoutedExperts(
+            n_experts=8, d_model=h, d_ff=f, k=d["top_k"],
+            experts_held=(first, 4), routed_scale=d["routed_scale"],
+            shared_width=d["shared_width"], dtype=jnp.float32)
+        params = {
+            "router": {"kernel": w["router"]},
+            **{f"experts_{n}": w[f"experts_{n}"][first:first + 4]
+               for n in ("gate", "up", "down")},
+            **{f"shared_{n}": {"kernel": w[f"shared_{n}"]}
+               for n in ("gate", "up", "down")},
+        }
+        with jax.default_matmul_precision("highest"):
+            return layer.apply({"params": params}, u)
+
+    np.testing.assert_allclose(
+        np.asarray(half(0) + half(4) - shared), np.asarray(whole), atol=2e-5)
+    # and a half alone is the reference's half
+    np.testing.assert_allclose(
+        np.asarray(half(4)),
+        np.asarray(arch.routed(u, {**w, **{
+            k: w[k][4:] for k in ("experts_gate", "experts_up",
+                                  "experts_down")}}, {**d, "held": (4, 4)})
+                   + shared), atol=2e-5)
+
+
+def test_yarn_and_partial_rotary_angles_are_the_references():
+    cfg = _cfg("laguna-s-2_1-serve.json")
+    arch = cells.architecture(cfg)
+    d = arch.dims_of(cfg)
+    model = cfg["model"]
+    full = RopeSpec.of(model["rope_full"])
+    d_r, inv = arch.rope_angles(d["rope"]["full"], 128)
+    assert d_r == 64 == full.rotary_dim
+    np.testing.assert_allclose(rope_inv_freq(full, 128), np.asarray(inv),
+                               rtol=1e-6)
+    # the published numbers: the ramp runs from dimension 9 to 18
+    plain = 500000.0 ** (-2.0 * np.arange(32) / 64)
+    got = rope_inv_freq(full, 128)
+    np.testing.assert_allclose(got[:10], plain[:10], rtol=1e-6)
+    np.testing.assert_allclose(got[18:], plain[18:] / 128.0, rtol=1e-6)
+    assert np.all(got[10:18] < plain[10:18]) and np.all(
+        got[10:18] > plain[10:18] / 128.0)
+    sliding = RopeSpec.of(model["rope_sliding"])
+    _, inv = arch.rope_angles(d["rope"]["sliding"], 128)
+    np.testing.assert_allclose(rope_inv_freq(sliding, 128), np.asarray(inv),
+                               rtol=1e-6)
+    # the rotation itself: 64 dimensions turn, 64 pass through
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 6, 3, 128), jnp.float32)
+    pos = jnp.asarray([[0, 1, 2, 3, 4, 5], [700, 701, 702, 703, 704, 9000]])
+    np.testing.assert_allclose(
+        np.asarray(apply_rope_spec(x, pos, full)),
+        np.asarray(arch.rope(x, pos, d["rope"]["full"])), atol=1e-5)
+    assert np.array_equal(np.asarray(apply_rope_spec(x, pos, full))[..., 64:],
+                          np.asarray(x)[..., 64:])
+    # a description of apply_rope's own default is apply_rope
+    np.testing.assert_allclose(
+        np.asarray(apply_rope_spec(x, pos, RopeSpec())),
+        np.asarray(apply_rope(x, pos)), atol=1e-6)
+
+
+def _masked_softmax_attention(q, k, v, lo, hi, scale):
+    """q (H, dh), k/v (Hkv, L, dh) float32; keys [lo, hi)."""
+    rep = q.shape[0] // k.shape[0]
+    out = []
+    for h in range(q.shape[0]):
+        s = k[h // rep] @ q[h] * scale
+        s = np.where((np.arange(len(s)) >= lo) & (np.arange(len(s)) < hi),
+                     s, -np.inf)
+        p = np.exp(s - s.max())
+        out.append((p / p.sum()) @ v[h // rep])
+    return np.stack(out)
+
+
+@pytest.fixture(scope="module")
+def kv8():
+    from mlcomp_tpu.ops.pallas.decode_attention import quantize_kv
+
+    b, hkv, l_buf, dh = 2, 2, 256, 128
+    ks = jax.random.split(jax.random.PRNGKey(5), 2)
+    k = jax.random.normal(ks[0], (b, l_buf, hkv, dh), jnp.float32)
+    v = jax.random.normal(ks[1], (b, l_buf, hkv, dh), jnp.float32)
+    (k8, ksc), (v8, vsc) = quantize_kv(k), quantize_kv(v)
+    lay = lambda x: x.transpose(0, 2, 1, 3)                   # noqa: E731
+    sc = lambda s: s.transpose(0, 2, 1)[:, :, None].astype(jnp.bfloat16)  # noqa
+    deq = lambda x8, s: np.asarray(                            # noqa: E731
+        lay(x8).astype(jnp.float32)
+        * sc(s).astype(jnp.float32).transpose(0, 1, 3, 2))
+    return (lay(k8), sc(ksc), lay(v8), sc(vsc)), deq(k8, ksc), deq(v8, vsc)
+
+
+def test_the_decode_kernels_window_is_a_raised_start(kv8):
+    """Single-token step: the window is ``kv_start`` raised to stop -
+    window, against a masked softmax over the dequantized cache."""
+    from mlcomp_tpu.ops.pallas.decode_attention import decode_attention
+
+    bufs, k, v = kv8
+    heads, dh, window = 6, 128, 48
+    q = jax.random.normal(jax.random.PRNGKey(6), (2, heads, dh), jnp.float32)
+    first = jnp.asarray([3, 140], jnp.int32)
+    stop = jnp.asarray([200, 170], jnp.int32)
+    start = jnp.maximum(first, stop - window)
+    got = np.asarray(decode_attention(
+        q, *bufs, kv_start=start, kv_stop=stop, interpret=True))
+    for r in range(2):
+        want = _masked_softmax_attention(
+            np.asarray(q[r]), k[r], v[r], int(start[r]), int(stop[r]),
+            dh ** -0.5)
+        np.testing.assert_allclose(got[r], want, atol=2e-2)
+    assert int(start[0]) == 152 and int(start[1]) == 140  # one of each
+
+
+@pytest.mark.parametrize("s_q", [8, 40])
+def test_the_chunk_kernels_window_is_a_start_per_query(kv8, s_q):
+    """Chunk against the cache: query j sees [max(start, stop0 + j -
+    window), stop0 + j), a lower bound of its own per sublane row; 40
+    queries take two query tiles."""
+    from mlcomp_tpu.ops.pallas.decode_attention import decode_attention_chunk
+
+    bufs, k, v = kv8
+    heads, dh, window = 6, 128, 48
+    q = jax.random.normal(jax.random.PRNGKey(7), (2, s_q, heads, dh),
+                          jnp.float32)
+    first = jnp.asarray([3, 150], jnp.int32)
+    stop0 = jnp.asarray([180, 160], jnp.int32)
+    got = np.asarray(decode_attention_chunk(
+        q, *bufs, kv_start=first, kv_stop0=stop0, window=window,
+        interpret=True))
+    whole = np.asarray(decode_attention_chunk(
+        q, *bufs, kv_start=first, kv_stop0=stop0, interpret=True))
+    differs = False
+    for r in range(2):
+        for j in range(s_q):
+            hi = int(stop0[r]) + j
+            lo = max(int(first[r]), hi - window)
+            want = _masked_softmax_attention(
+                np.asarray(q[r, j]), k[r], v[r], lo, hi, dh ** -0.5)
+            np.testing.assert_allclose(got[r, j], want, atol=2e-2)
+            if lo > int(first[r]):
+                differs |= bool(np.abs(got[r, j] - whole[r, j]).max() > 1e-3)
+    assert differs  # the window took keys away from the long row
+
+
+INTERNLM2_REHEARSAL = dict(hidden=256, heads=2, kv_heads=1)
+
+
+@pytest.mark.parametrize("kv_quant", [False, True])
+def test_self_attention_with_default_fields_is_the_module_it_was(kv_quant):
+    """InternLM2's rehearsal size.  The new fields at their defaults
+    leave the parameter paths where they were, and the same attention
+    described through the fields (head width, RoPE base, a window no
+    context reaches, no gate) is bit-identical to the defaults: the
+    general paths compute what the fixed ones did."""
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 24, 256), jnp.float32)
+    pos = jnp.broadcast_to(jnp.arange(24)[None], (2, 24))
+    plain = SelfAttention(dtype=jnp.float32, kv_quant=kv_quant,
+                          **INTERNLM2_REHEARSAL)
+    params = plain.init(jax.random.PRNGKey(0), x, pos)["params"]
+    assert set(params) == {"RMSNorm_0", "q", "k", "v", "out"}
+    assert params["q"]["kernel"].shape == (256, 2, 128)
+    described = SelfAttention(
+        dtype=jnp.float32, kv_quant=kv_quant, head_dim=128,
+        rope=RopeSpec(base=10000.0), window=4096, **INTERNLM2_REHEARSAL)
+    a = plain.apply({"params": params}, x, pos)
+    b = described.apply({"params": params}, x, pos)
+    assert np.array_equal(np.asarray(a), np.asarray(b))
+    # through the cache: prefill 16, then a chunk of 4, then single steps
+    def served(module):
+        cache = module.init(jax.random.PRNGKey(0), jnp.zeros((2, 40, 256)),
+                            jnp.zeros((2, 40), jnp.int32), decode=True)["cache"]
+        out = []
+        for lo, hi in [(0, 16), (16, 20)] + [(t, t + 1) for t in range(20, 24)]:
+            y, upd = module.apply(
+                {"params": params, "cache": cache}, x[:, lo:hi], pos[:, lo:hi],
+                decode=True, mutable=["cache"])
+            cache = upd["cache"]
+            out.append(np.asarray(y))
+        return np.concatenate(out, 1)
+
+    assert np.array_equal(served(plain), served(described))

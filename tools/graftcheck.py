@@ -154,6 +154,14 @@ CONDITIONAL_METRICS = {
     "mlcomp_engine_handoffs_exported_total",
     "mlcomp_engine_kv_pages_exported_total",
     "mlcomp_engine_handoff_bytes_exported_total",
+    # models with a routed expert layer only (RoutedExperts sows the
+    # counts; the tier-1 obs_check daemon serves a dense transformer_lm,
+    # whose engine emits none of them)
+    "mlcomp_engine_moe_assignments_total",
+    "mlcomp_engine_moe_assignments_held_total",
+    "mlcomp_engine_moe_experts_touched_total",
+    "mlcomp_engine_moe_expert_layer_calls_total",
+    "mlcomp_engine_moe_experts_held_total",
 }
 
 MUTATOR_METHODS = {
