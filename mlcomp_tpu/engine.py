@@ -621,7 +621,10 @@ class DecodeEngine:
         # without the scratch slot a dead row would overwrite its own
         # last real K/V — harmless today (retired rows are never read
         # before slot reuse) but a corruption trap for any future
-        # reader; spec verify widens the span by K.
+        # reader; spec verify widens the span by K.  (The int8 cache's
+        # single-token step no longer writes a row without a request:
+        # decode_attention appends for the rows it walks.  The bf16
+        # cache and the verify still do, so the slot stays.)
         self.l_buf = self.prompt_buckets[-1] + self.max_new_cap + (
             self.spec_k or 0
         ) + 1
@@ -974,11 +977,12 @@ class DecodeEngine:
         # host_ms spent while at least one dispatch was in flight;
         # inflight_sum/issued is the mean in-flight depth at issue
         # (occupancy); rows_attended/rows_total is the share of the
-        # carry's rows that held a request when a dispatch went out
+        # carry's rows that held a request when a dispatch went out;
+        # kv_rows_written is those rows times the dispatch's steps
         self._pstats = {  # guarded_by: loop [writes]
             "issued": 0, "host_ms": 0.0, "hidden_ms": 0.0, "wait_ms": 0.0,
             "inflight_sum": 0, "peak_inflight": 0,
-            "rows_attended": 0, "rows_total": 0,
+            "rows_attended": 0, "rows_total": 0, "kv_rows_written": 0,
             "kv_attended": 0, "kv_live": 0,
         }
         # one entry an attention layer: its window, None where it
@@ -1865,6 +1869,13 @@ class DecodeEngine:
             "rows_attended_share": round(
                 p["rows_attended"] / p["rows_total"], 4
             ) if p["rows_total"] else None,
+            # row writes of a token's K and V a layer: the attended
+            # rows times the steps of their dispatch.  The int8 cache's
+            # decode attention appends where it attends, so a row
+            # without a request is not written; over rows_total x
+            # steps it is the share of a write loop over every row
+            # that is left
+            "kv_rows_written": p["kv_rows_written"],
             # context tokens the live rows held at issue, summed over
             # attention layers and dispatches, and the part inside each
             # layer's window (min(context, window))
@@ -2048,6 +2059,10 @@ class DecodeEngine:
         ctr("mlcomp_engine_attention_rows_total",
             "Slot rows in the carry at issue, summed over dispatches",
             p["rows_total"])
+        ctr("mlcomp_engine_attention_kv_rows_written_total",
+            "Rows whose new token a step's attention appended to the KV "
+            "cache in each layer: rows holding a request at issue x the "
+            "dispatch's steps", p["kv_rows_written"])
         ctr("mlcomp_engine_attention_kv_tokens_attended_total",
             "Context tokens inside each attention layer's window, summed "
             "over live rows, layers and dispatches", p["kv_attended"])
@@ -4740,6 +4755,7 @@ class DecodeEngine:
         ctx = [sl.position for sl in self._host if sl is not None]
         p["rows_attended"] += len(ctx)
         p["rows_total"] += len(self._host)
+        p["kv_rows_written"] += len(ctx) * self._steps_hi()
         # tokens of context the live rows hold, over the layers, and
         # the part of them a layer's window lets its attention read
         p["kv_live"] += sum(ctx) * len(self._attn_windows)

@@ -24,15 +24,6 @@ from mlcomp_tpu.models import MODELS
 from mlcomp_tpu.ops.attention import dot_product_attention
 
 
-# trace-time layout knobs for the int8 KV cache's single-token update
-# (see the comment at their use site).  tools/exp_kv_write_ab.py
-# measures all four combinations in ONE process (1.2B b8_kv8_int8,
-# marginal timing).  The defaults below came from a pre-round
-# attachment; on this chip the choice is not measured.
-_KV_UPDATE_RESHAPE = True
-_KV_SCALE_WRITE = "where"
-
-
 def apply_rope(x: jax.Array, positions: jax.Array, base: float = 10000.0) -> jax.Array:
     """Rotary embeddings; x: (B, S, H, D), positions: (B, S)."""
     d = x.shape[-1]
@@ -160,9 +151,15 @@ def _window_start(kv_mask, b):
 def _row_cursor_dus(buf, upd, cur, seq_axis):
     """Write ``upd[r]`` into ``buf`` at row r's cursor slot(s) —
     per-row ``dynamic_update_slice`` in a ``fori_loop``, NOT a batched
-    scatter: the round-5 engine profile showed XLA materializing
-    full-buffer copies for the scatter lowering (~4.5 ms/step at
-    1.2B/B=8) where row-wise DUS aliases the loop carry in place.
+    scatter (a scatter lowering copies the whole buffer; row-wise DUS
+    aliases the loop carry in place).  Two paths still write this way:
+    the bfloat16 cache's per-row-cursor step and the int8 cache's
+    multi-token verify (s > 1); no benchmark cell runs either.  The
+    int8 cache's single-token step used to, and the chip's verdict on
+    that is the ledger's PR 28 and PR 29 lines: a trip costs ~1 us
+    whatever the row holds, 2,304 of them a step at 48 slots x 24
+    layers, a quarter of the step; ``decode_attention`` now appends
+    the token itself (its ``append``).
     ``seq_axis`` is the cache's slot axis (1 for the bf16 (B, L, H, dh)
     layout, 2 for the KV-major quant (B, Hkv, L, dh) layout).  DUS
     CLAMPS at the buffer edge (the engine allocates a scratch slot so
@@ -720,18 +717,27 @@ class SelfAttention(nn.Module):
         kq, ks_ = quantize_kv(kp)
         vq, vs_ = quantize_kv(vp)
 
-        def flash(kv_start, kv_stop):
+        def flash(kv_start, kv_stop, append=False):
             """Single-token flash-decode against the updated buffers,
             mesh-dispatched (a bare pallas_call would not partition
             itself under SPMD) — shared by the global-cursor and
-            per-row-cursor (engine) paths.  The softmax scale uses the
-            TRUE head dim (q was zero-padded to a lane multiple)."""
+            per-row-cursor (engine) paths.  With ``append`` the token
+            is not in the buffers yet: the kernel writes it at each
+            row's ``kv_stop - 1``, in place, on its way through the
+            row's last granule, and the cache variables take what it
+            returns.  The softmax scale uses the TRUE head dim (q was
+            zero-padded to a lane multiple)."""
             from mlcomp_tpu.ops.quant import pallas_mesh
 
             qp = (
                 jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, dhp - dh)))
                 if dhp != dh else q
             )
+            caches = (ckq, cks, cvq, cvs)
+            kw = dict(kv_start=kv_start, kv_stop=kv_stop,
+                      scale=1.0 / (dh**0.5))
+            if append:
+                kw["append"] = (kq[:, 0], ks_[:, 0], vq[:, 0], vs_[:, 0])
             mesh = pallas_mesh()
             if mesh is not None:
                 from mlcomp_tpu.ops.pallas.decode_attention import (
@@ -739,16 +745,16 @@ class SelfAttention(nn.Module):
                 )
 
                 out = sharded_decode_attention(
-                    qp[:, 0], ckq.value, cks.value, cvq.value, cvs.value,
-                    mesh, kv_start=kv_start, kv_stop=kv_stop,
-                    scale=1.0 / (dh**0.5),
+                    qp[:, 0], *(c.value for c in caches), mesh, **kw
                 )
             else:
                 out = decode_attention(
-                    qp[:, 0], ckq.value, cks.value, cvq.value, cvs.value,
-                    kv_start=kv_start, kv_stop=kv_stop,
-                    scale=1.0 / (dh**0.5),
+                    qp[:, 0], *(c.value for c in caches), **kw
                 )
+            if append:
+                out, *written = out
+                for c, value in zip(caches, written):
+                    c.value = value
             return out[..., :dh][:, None]
 
         def chunk_attend(row_start, stop0):
@@ -803,93 +809,66 @@ class SelfAttention(nn.Module):
 
         if cache_cursor is not None:
             # per-row cursors (engine contract, see _decode_attention):
-            # scatter each row's K/V at its own slot(s), window per row.
-            # s > 1 (round 5) is the engine's speculative verify — the
-            # multi-query kernel takes per-row stop0 directly.
+            # each row's K/V go to its own slot(s), window per row.
             cur = jnp.asarray(cache_cursor).astype(jnp.int32)
+            row_start = _window_start(kv_mask, b)
+            if s == 1:
+                # the step every engine dispatch takes: the kernel
+                # appends the token where it attends it, for the rows
+                # that hold a window and for no other
+                return flash(
+                    self._window_lo(row_start, cur + 1), cur + 1,
+                    append=True,
+                )
+            # s > 1 (round 5) is the engine's speculative verify — the
+            # multi-query kernel takes per-row stop0 directly.  Per-row
+            # DUS, not scatter (_row_cursor_dus; the scatter lowering
+            # copied the full int8 buffers every step)
             sdt = cks.value.dtype
-            # per-row DUS, not scatter (_row_cursor_dus; the scatter
-            # lowering copied the full int8 buffers every step)
             kqt = kq.transpose(0, 2, 1, 3)          # (B, Hkv, s, dhp)
             vqt = vq.transpose(0, 2, 1, 3)
             ckq.value = _row_cursor_dus(ckq.value, kqt, cur, 2)
             cvq.value = _row_cursor_dus(cvq.value, vqt, cur, 2)
-            if s == 1:
-                # scale caches are lane-minor: a one-lane DUS is a
-                # relayout copy of the row (r4 A/B), so the masked
-                # full-buffer select stays the write of choice here
-                hit = (
-                    jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, l_buf), 3)
-                    == cur[:, None, None, None]
-                )
-                cks.value = jnp.where(
-                    hit, ks_.reshape(b, hkv, 1, 1).astype(sdt), cks.value
-                )
-                cvs.value = jnp.where(
-                    hit, vs_.reshape(b, hkv, 1, 1).astype(sdt), cvs.value
-                )
-            else:
-                # s scale slots per row via the same masked select:
-                # gather each slot's scale from its position relative
-                # to the row's cursor (dense over L — s is tiny and
-                # the select is one fused full-buffer pass)
-                sl = jnp.arange(l_buf, dtype=jnp.int32)
-                rel = sl[None, :] - cur[:, None]        # (B, L)
-                hit = ((rel >= 0) & (rel < s))[:, None, None, :]
-                relc = jnp.clip(rel, 0, s - 1)
-                ks_dense = jnp.take_along_axis(
-                    ks_.transpose(0, 2, 1), relc[:, None, :], axis=2
-                )[:, :, None, :]                        # (B, Hkv, 1, L)
-                vs_dense = jnp.take_along_axis(
-                    vs_.transpose(0, 2, 1), relc[:, None, :], axis=2
-                )[:, :, None, :]
-                cks.value = jnp.where(hit, ks_dense.astype(sdt), cks.value)
-                cvs.value = jnp.where(hit, vs_dense.astype(sdt), cvs.value)
-            row_start = _window_start(kv_mask, b)
-            if s == 1:
-                return flash(self._window_lo(row_start, cur + 1), cur + 1)
+            # s scale slots per row via a masked select: gather each
+            # slot's scale from its position relative to the row's
+            # cursor (dense over L — s is tiny and the select is one
+            # fused full-buffer pass; the scale caches are lane-minor,
+            # so a one-lane DUS is a relayout copy of the row)
+            sl = jnp.arange(l_buf, dtype=jnp.int32)
+            rel = sl[None, :] - cur[:, None]        # (B, L)
+            hit = ((rel >= 0) & (rel < s))[:, None, None, :]
+            relc = jnp.clip(rel, 0, s - 1)
+            ks_dense = jnp.take_along_axis(
+                ks_.transpose(0, 2, 1), relc[:, None, :], axis=2
+            )[:, :, None, :]                        # (B, Hkv, 1, L)
+            vs_dense = jnp.take_along_axis(
+                vs_.transpose(0, 2, 1), relc[:, None, :], axis=2
+            )[:, :, None, :]
+            cks.value = jnp.where(hit, ks_dense.astype(sdt), cks.value)
+            cvs.value = jnp.where(hit, vs_dense.astype(sdt), cvs.value)
             return chunk_attend(row_start, cur + 1)
         if s == 1:
-            # single-token step (the serving hot path).  Two trace-time
-            # knobs below: tools/exp_kv_write_ab.py measures all four
-            # combinations in ONE process (not measured on this chip):
-            # reshape vs transpose for the (B,1,H,*)->(B,H,1,*) update
-            # layout, and masked-row where vs one-slot DUS for the f32
-            # scale caches.
-            if _KV_UPDATE_RESHAPE:
-                kq_u, vq_u = (
-                    kq.reshape(b, hkv, 1, dhp), vq.reshape(b, hkv, 1, dhp)
-                )
-            else:
-                kq_u = kq.transpose(0, 2, 1, 3)
-                vq_u = vq.transpose(0, 2, 1, 3)
+            # single-token step under ONE cursor (bare ``generate``;
+            # no cell runs it): every row writes the same slot, so one
+            # update-slice a tensor, and a masked select for the
+            # lane-minor scale caches
             ckq.value = jax.lax.dynamic_update_slice(
-                ckq.value, kq_u, (0, 0, i, 0)
+                ckq.value, kq.reshape(b, hkv, 1, dhp), (0, 0, i, 0)
             )
             cvq.value = jax.lax.dynamic_update_slice(
-                cvq.value, vq_u, (0, 0, i, 0)
+                cvq.value, vq.reshape(b, hkv, 1, dhp), (0, 0, i, 0)
             )
             sdt = cks.value.dtype
-            if _KV_SCALE_WRITE == "where":
-                hit = (
-                    jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, l_buf), 3)
-                    == i
-                )
-                cks.value = jnp.where(
-                    hit, ks_.reshape(b, hkv, 1, 1).astype(sdt), cks.value
-                )
-                cvs.value = jnp.where(
-                    hit, vs_.reshape(b, hkv, 1, 1).astype(sdt), cvs.value
-                )
-            else:
-                cks.value = jax.lax.dynamic_update_slice(
-                    cks.value, ks_.reshape(b, hkv, 1, 1).astype(sdt),
-                    (0, 0, 0, i)
-                )
-                cvs.value = jax.lax.dynamic_update_slice(
-                    cvs.value, vs_.reshape(b, hkv, 1, 1).astype(sdt),
-                    (0, 0, 0, i)
-                )
+            hit = (
+                jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, l_buf), 3)
+                == i
+            )
+            cks.value = jnp.where(
+                hit, ks_.reshape(b, hkv, 1, 1).astype(sdt), cks.value
+            )
+            cvs.value = jnp.where(
+                hit, vs_.reshape(b, hkv, 1, 1).astype(sdt), cvs.value
+            )
         else:
             sdt = cks.value.dtype
             ckq.value = jax.lax.dynamic_update_slice(

@@ -40,7 +40,13 @@ a jnp ``k8 * ks`` prefix materializes the bf16 copy in HBM every step
   (``auto_block_kv`` tokens each) through a double buffer — granule i+1, or the
   next live row's first, lands while granule i computes.  A row whose
   window is empty (the engine hands one to every slot that holds no
-  request) starts no copy and computes nothing.  The multi-query chunk
+  request) starts no copy and computes nothing.  The engine's step
+  also WRITES through it (``append``, PR 29): the row's new token is
+  patched into its last granule in VMEM, attended there, and the tile
+  that holds it copied back in place, so the cache takes one device
+  operation a layer whose cost follows the live rows, where a loop of
+  update-slices over every slot row was a quarter of the step (ledger,
+  PR 28 against PR 29).  The multi-query chunk
   kernel and the paged twins still sweep a (B, L/BLK) BlockSpec grid
   of fat blocks (``KV_BLOCK_BUDGET``): blocks outside a row's window
   are clamped in the index maps to the nearest live block of that row,
@@ -188,13 +194,15 @@ def _flash_finalize(o_ref, acc_ref, l_ref, row=0):
     )
 
 
+# int8 rows of one packed sublane tile: the unit the append writes back
+APPEND_TILE = 32
+
+
 def _kernel(
     start_ref, stop_ref,  # scalar prefetch: (B,) int32 each
     q_ref, k_hbm, ks_hbm, v_hbm, vs_hbm,
-    o_ref,
-    k_buf, ks_buf, v_buf, vs_buf, sem, slot_ref,
-    acc_ref, m_ref, l_ref,
-    *, scale: float, granule: int,
+    *refs,
+    scale: float, granule: int, append: bool,
 ):
     """One grid step a BLOCK OF ROWS, whose queries and outputs sit in
     VMEM; K, V and their scales stay in HBM.  Each row walks the
@@ -203,7 +211,23 @@ def _kernel(
     starts the first granule of the next row that has one, so a
     one-granule row does not expose its fetch either.  A row whose
     window is empty starts no copy, computes nothing and costs one
-    trip of a scalar loop."""
+    trip of a scalar loop.
+
+    With ``append`` the row's new token (``hi - 1``, the window's last
+    column, always in the last granule) is not in the cache yet: the
+    last trip patches it into the landed granule in VMEM, attends the
+    patched granule, and copies the aligned tile that holds the column
+    back to HBM (:data:`APPEND_TILE` int8 rows a head for K and V, one
+    lane group of the scales) while the flash update runs.  The cache
+    operands are the outputs' aliases, so that is the whole write."""
+    if append:
+        (kn_ref, ksn_ref, vn_ref, vsn_ref,
+         o_ref, k_out, ks_out, v_out, vs_out,
+         k_buf, ks_buf, v_buf, vs_buf, sem, slot_ref,
+         acc_ref, m_ref, l_ref, wsem) = refs
+    else:
+        (o_ref, k_buf, ks_buf, v_buf, vs_buf, sem, slot_ref,
+         acc_ref, m_ref, l_ref) = refs
     nb, _, l_buf, _ = k_hbm.shape
     rows = q_ref.shape[0]
     row0 = pl.program_id(0) * rows
@@ -244,6 +268,51 @@ def _kernel(
             for cp in copies(r, span(r)[2], slot):
                 cp.start()
 
+    def tile_of(c):
+        return pl.multiple_of(c // APPEND_TILE * APPEND_TILE, APPEND_TILE)
+
+    def patch(j, c, slot):
+        """The new token into column ``c`` of the landed granule.  K
+        and V: a select over the one int8 tile that holds the row, in
+        int32 (a one-row int8 store would split a packed sublane); the
+        scales: a select over the slot's (Hkv, granule) block."""
+        rows32 = pl.ds(tile_of(c), APPEND_TILE)
+        for new_ref, buf in ((kn_ref, k_buf), (vn_ref, v_buf)):
+            tile = buf[slot, :, rows32, :].astype(jnp.int32)
+            hit = tile_of(c) + jax.lax.broadcasted_iota(
+                jnp.int32, tile.shape, 1
+            ) == c
+            buf[slot, :, rows32, :] = jnp.where(
+                hit, new_ref[j][:, None, :], tile
+            ).astype(buf.dtype)
+        for new_ref, buf in ((ksn_ref, ks_buf), (vsn_ref, vs_buf)):
+            block = buf[slot].astype(jnp.float32)
+            hit = jax.lax.broadcasted_iota(jnp.int32, block.shape, 1) == c
+            buf[slot] = jnp.where(
+                hit, new_ref[j][:, :1], block
+            ).astype(buf.dtype)
+
+    def write_backs(r, g, c, slot):
+        """Copies VMEM -> HBM of the tile and the lane group that hold
+        column ``c`` of granule ``g``; built alike to start and to wait
+        for them, on one semaphore."""
+        t0 = tile_of(c)
+        l0 = pl.multiple_of(c // LANES * LANES, LANES)
+        at = g * granule
+        return [
+            pltpu.make_async_copy(src, dst, wsem.at[0])
+            for src, dst in (
+                (k_buf.at[slot, :, pl.ds(t0, APPEND_TILE), :],
+                 k_out.at[r, :, pl.ds(at + t0, APPEND_TILE), :]),
+                (v_buf.at[slot, :, pl.ds(t0, APPEND_TILE), :],
+                 v_out.at[r, :, pl.ds(at + t0, APPEND_TILE), :]),
+                (ks_buf.at[slot, :, pl.ds(l0, LANES)],
+                 ks_out.at[r, :, pl.ds(at + l0, LANES)]),
+                (vs_buf.at[slot, :, pl.ds(l0, LANES)],
+                 vs_out.at[r, :, pl.ds(at + l0, LANES)]),
+            )
+        ]
+
     @pl.when(row0 == 0)
     def _prologue():
         slot_ref[0] = 0
@@ -268,18 +337,27 @@ def _kernel(
             def trip(i, carry):
                 slot = jax.lax.rem(slot0 + i, 2)
                 g = g0 + i
+                last = i + 1 == n
+                c = hi - 1 - g * granule   # the new token's column
 
                 @pl.when(i + 1 < n)
                 def _next_granule():
                     for cp in copies(r, g + 1, 1 - slot):
                         cp.start()
 
-                @pl.when(i + 1 == n)
+                @pl.when(last)
                 def _next_row():
                     start_first(next_row(r + 1), 1 - slot)
 
                 for cp in copies(r, g, slot):
                     cp.wait()
+
+                if append:
+                    @pl.when(last)
+                    def _append():
+                        patch(j, c, slot)
+                        for cp in write_backs(r, g, c, slot):
+                            cp.start()
 
                 def mask_fn(shape):
                     cols = g * granule + jax.lax.broadcasted_iota(
@@ -294,6 +372,15 @@ def _kernel(
                     vs_buf[slot][:, None, :],
                     mask_fn, scale, acc_ref, m_ref, l_ref,
                 )
+
+                if append:
+                    # the tile has left this slot before the next
+                    # row's first trip starts a fetch into it
+                    @pl.when(last)
+                    def _written():
+                        for cp in write_backs(r, g, c, slot):
+                            cp.wait()
+
                 return carry
 
             jax.lax.fori_loop(0, n, trip, 0)
@@ -316,7 +403,8 @@ def decode_attention(
     scale: Optional[float] = None,
     block_kv: Optional[int] = None,
     interpret: Optional[bool] = None,
-) -> jax.Array:
+    append: Optional[Tuple[jax.Array, ...]] = None,
+):
     """Single-token attention against an int8 KV cache.
 
     q: (B, H, dh) current-token queries; k8/v8: (B, Hkv, L, dh) int8;
@@ -331,6 +419,15 @@ def decode_attention(
     empty (start >= stop) costs nothing and returns zeros.  L and dh
     must be lane multiples (the cache allocator rounds L up; dh pads).
     Returns (B, H, dh) in q.dtype.
+
+    ``append`` = (kq, ks_new, vq, vs_new), the current token's
+    :func:`quantize_kv` ((B, Hkv, dh) int8 and (B, Hkv) scales): the
+    token is NOT in the cache yet, and the kernel writes it at each
+    row's ``kv_stop - 1`` (clamped to the buffer, as a
+    ``dynamic_update_slice`` clamps) before attending it.  The four
+    cache operands are updated in place (``input_output_aliases``) and
+    returned: ``(out, k8, ks, v8, vs)``.  A row with an empty window
+    is not written.
     """
     b, h, dh = q.shape
     _, h_kv, l_buf, _ = k8.shape
@@ -384,27 +481,68 @@ def decode_attention(
     rows = max(d for d in range(1, min(b, ROWS_PER_STEP) + 1) if b % d == 0)
     row = pl.BlockSpec((rows, h_kv, gp, dh), lambda i, *_: (i, 0, 0, 0))
     hbm = pl.BlockSpec(memory_space=pl.ANY)
+    # the scales as (B, Hkv, L): a (Hkv, granule) slice is whole
+    # tiles, where Mosaic pads a (.., 1, L) bf16 memref to two rows
+    # and refuses the one-row slice; the 3-D view is also XLA's own
+    # layout for the (B, Hkv, 1, L) cache, so the reshape is free
+    operands = [qg, k8, ks.reshape(b, h_kv, l_buf), v8,
+                vs.reshape(b, h_kv, l_buf)]
+    in_specs = [row, hbm, hbm, hbm, hbm]
+    out_specs = row
+    out_shape = jax.ShapeDtypeStruct((b, h_kv, gp, dh), q.dtype)
+    scratch = [
+        # two granule slots: one computes while the other lands
+        pltpu.VMEM((2, h_kv, granule, dh), k8.dtype),
+        pltpu.VMEM((2, h_kv, granule), ks.dtype),
+        pltpu.VMEM((2, h_kv, granule, dh), v8.dtype),
+        pltpu.VMEM((2, h_kv, granule), vs.dtype),
+        pltpu.SemaphoreType.DMA((2,)),
+        pltpu.SMEM((1,), jnp.int32),  # slot of the next first granule
+        pltpu.VMEM((h_kv, gp, dh), jnp.float32),
+        pltpu.VMEM((h_kv, gp, LANES), jnp.float32),
+        pltpu.VMEM((h_kv, gp, LANES), jnp.float32),
+    ]
+    aliases = {}
+    if append is not None:
+        kq, ks_new, vq, vs_new = append
+        if kq.shape != (b, h_kv, dh) or ks_new.shape != (b, h_kv):
+            raise ValueError(
+                f"append must be (B, Hkv, dh) = {(b, h_kv, dh)} values and "
+                f"(B, Hkv) scales; got {kq.shape}, {ks_new.shape}"
+            )
+        # the new token rides in whole 32-bit tiles: int32 values (a
+        # head a sublane), and each scale, rounded to the cache's
+        # dtype as a plain write would round it, across one lane group
+        new_kv = pl.BlockSpec((rows, h_kv, dh), lambda i, *_: (i, 0, 0))
+        new_sc = pl.BlockSpec((rows, h_kv, LANES), lambda i, *_: (i, 0, 0))
+
+        def lanes(x, like):
+            x = x.astype(like.dtype).astype(jnp.float32)
+            return jnp.broadcast_to(x[..., None], (b, h_kv, LANES))
+
+        operands += [kq.astype(jnp.int32), lanes(ks_new, ks),
+                     vq.astype(jnp.int32), lanes(vs_new, vs)]
+        in_specs += [new_kv, new_sc, new_kv, new_sc]
+        out_specs = [row, hbm, hbm, hbm, hbm]
+        out_shape = [out_shape] + [
+            jax.ShapeDtypeStruct(x.shape, x.dtype) for x in operands[1:5]
+        ]
+        scratch.append(pltpu.SemaphoreType.DMA((1,)))   # the write-backs
+        # operand 0 and 1 are the prefetched windows
+        aliases = {3 + i: 1 + i for i in range(4)}
     out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, granule=granule),
+        functools.partial(
+            _kernel, scale=scale, granule=granule, append=append is not None
+        ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b // rows,),
-            in_specs=[row, hbm, hbm, hbm, hbm],
-            out_specs=row,
-            scratch_shapes=[
-                # two granule slots: one computes while the other lands
-                pltpu.VMEM((2, h_kv, granule, dh), k8.dtype),
-                pltpu.VMEM((2, h_kv, granule), ks.dtype),
-                pltpu.VMEM((2, h_kv, granule, dh), v8.dtype),
-                pltpu.VMEM((2, h_kv, granule), vs.dtype),
-                pltpu.SemaphoreType.DMA((2,)),
-                pltpu.SMEM((1,), jnp.int32),  # slot of the next first granule
-                pltpu.VMEM((h_kv, gp, dh), jnp.float32),
-                pltpu.VMEM((h_kv, gp, LANES), jnp.float32),
-                pltpu.VMEM((h_kv, gp, LANES), jnp.float32),
-            ],
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct((b, h_kv, gp, dh), q.dtype),
+        out_shape=out_shape,
+        input_output_aliases=aliases,
         # the slot parity and the prefetched first granule carry from
         # one grid step to the next: the steps run in order
         compiler_params=pltpu.CompilerParams(
@@ -412,13 +550,12 @@ def decode_attention(
         ),
         interpret=interpret,
         name="decode_attention",
-        # the scales as (B, Hkv, L): a (Hkv, granule) slice is whole
-        # tiles, where Mosaic pads a (.., 1, L) bf16 memref to two rows
-        # and refuses the one-row slice; the 3-D view is also XLA's own
-        # layout for the (B, Hkv, 1, L) cache, so the reshape is free
-    )(start, stop, qg, k8, ks.reshape(b, h_kv, l_buf), v8,
-      vs.reshape(b, h_kv, l_buf))
-    return out[:, :, :rep].reshape(b, h, dh)
+    )(start, stop, *operands)
+    if append is None:
+        return out[:, :, :rep].reshape(b, h, dh)
+    out, k8, ks3, v8, vs3 = out
+    return (out[:, :, :rep].reshape(b, h, dh), k8, ks3.reshape(ks.shape),
+            v8, vs3.reshape(vs.shape))
 
 
 def _kernel_chunk(
@@ -1435,7 +1572,8 @@ def sharded_decode_attention(
     kv_start: Optional[jax.Array] = None,
     kv_stop: Optional[jax.Array] = None,
     scale: Optional[float] = None,
-) -> jax.Array:
+    append: Optional[Tuple[jax.Array, ...]] = None,
+):
     """:func:`decode_attention` under a device mesh: a shard_map island
     with heads over ``tp`` and batch over the data axes.
 
@@ -1444,6 +1582,8 @@ def sharded_decode_attention(
     query heads next to their shared KV head), so no cross-device math
     happens at all: the wrapper only pins a layout that matches the
     tp-sharded q/k/v projections feeding it (serve --mesh --kv-quant).
+    ``append`` (the new token, :func:`decode_attention`) shards like
+    the caches it lands in, and the four caches come back with them.
     """
     import jax as _jax
     from jax.sharding import PartitionSpec as P
@@ -1469,12 +1609,26 @@ def sharded_decode_attention(
         else jnp.broadcast_to(kv_stop, (b,)).astype(jnp.int32)
     )
     kv_spec = P(rows_ax, head_ax, None, None)
+    q_spec = P(rows_ax, head_ax, None)
+    in_specs = (q_spec, kv_spec, kv_spec, kv_spec, kv_spec, P(rows_ax),
+                P(rows_ax))
+    if append is None:
+        def call(q, k8, ks, v8, vs, start, stop):
+            return decode_attention(q, k8, ks, v8, vs, start, stop, scale)
+
+        out_specs = q_spec
+        append = ()
+    else:
+        def call(q, k8, ks, v8, vs, start, stop, *new):
+            return decode_attention(
+                q, k8, ks, v8, vs, start, stop, scale, append=new
+            )
+
+        sc_spec = P(rows_ax, head_ax)
+        in_specs += (q_spec, sc_spec, q_spec, sc_spec)
+        out_specs = (q_spec, kv_spec, kv_spec, kv_spec, kv_spec)
     fn = _jax.shard_map(
-        functools.partial(decode_attention, scale=scale),
-        mesh=mesh,
-        in_specs=(P(rows_ax, head_ax, None), kv_spec, kv_spec, kv_spec,
-                  kv_spec, P(rows_ax), P(rows_ax)),
-        out_specs=P(rows_ax, head_ax, None),
+        call, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
     )
-    return fn(q, k8, ks, v8, vs, start, stop)
+    return fn(q, k8, ks, v8, vs, start, stop, *append)

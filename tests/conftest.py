@@ -36,6 +36,33 @@ def tmp_db(tmp_path):
 ENGINE_FNS_POOL: dict = {}
 
 
+def loop_write_kv(caches, new, cur):
+    """The int8 cache's single-token write up to PR 28, kept as the
+    reference the kernel's ``append`` is held to: K and V row by row
+    through ``_row_cursor_dus`` (EVERY row, at its cursor), each scale
+    cache rewritten by a masked select.  ``caches`` = (k8, ks, v8, vs),
+    ``new`` = (kq, ks_new, vq, vs_new) as ``decode_attention`` takes
+    them."""
+    import jax
+    import jax.numpy as jnp
+
+    from mlcomp_tpu.models.transformer import _row_cursor_dus
+
+    k8, ks, v8, vs = caches
+    kq, ks_new, vq, vs_new = new
+    b, h_kv, _, l_buf = ks.shape
+    hit = (
+        jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, l_buf), 3)
+        == cur[:, None, None, None]
+    )
+    return (
+        _row_cursor_dus(k8, kq[:, :, None, :], cur, 2),
+        jnp.where(hit, ks_new.reshape(b, h_kv, 1, 1).astype(ks.dtype), ks),
+        _row_cursor_dus(v8, vq[:, :, None, :], cur, 2),
+        jnp.where(hit, vs_new.reshape(b, h_kv, 1, 1).astype(vs.dtype), vs),
+    )
+
+
 def share_engine_fns(eng, key):
     pool = ENGINE_FNS_POOL.setdefault(key, {})
     eng._fns.update(pool)

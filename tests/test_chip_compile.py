@@ -110,6 +110,48 @@ def test_decode_attention_dense_int8_kv(chip, b, h_kv, l_buf):
     assert "decode_attention" in text
 
 
+# + Laguna-S-2.1's serve cell (72 query heads a full layer over 8 KV
+# heads, 48 slots of 1152)
+@pytest.mark.parametrize("b,h,h_kv,l_buf", [
+    (B, H, H, L), (48, H, 8, 2560), (48, 72, 8, 1152),
+], ids=["smoke_1p2b", "cell_internlm2_1p8b", "cell_laguna_full_layer"])
+def test_decode_attention_appends_in_place(chip, b, h, h_kv, l_buf):
+    """The append form inside a K-step scan whose carry holds the
+    caches, as the engine's dispatch program holds them: Mosaic takes
+    the kernel (a select over one int8 tile, copies VMEM -> HBM at a
+    dynamic tile offset), and XLA aliases all four caches through the
+    call: no copy, slice or update-slice of a cache buffer is left."""
+    import re
+
+    from mlcomp_tpu.ops.pallas.decode_attention import decode_attention
+
+    def steps(q, k8, ks, v8, vs, start, cur, kq, ks_new, vq, vs_new):
+        def step(carry, _):
+            q, caches, cur = carry
+            out, *caches = decode_attention(
+                q, *caches, kv_start=start, kv_stop=cur + 1,
+                append=(kq, ks_new, vq, vs_new), interpret=False,
+            )
+            return ((out + q).astype(q.dtype), tuple(caches), cur + 1), None
+
+        (q, caches, _), _ = jax.lax.scan(
+            step, (q, (k8, ks, v8, vs), cur), None, length=4
+        )
+        return q, caches
+
+    new_kv, new_scale = chip((b, h_kv, DH), jnp.int8), chip((b, h_kv), jnp.float32)
+    text = jax.jit(steps, donate_argnums=(1, 2, 3, 4)).lower(
+        chip((b, h, DH), jnp.bfloat16), *_dense_cache(chip, b, h_kv, l_buf),
+        new_kv, new_scale, new_kv, new_scale,
+    ).compile().as_text()
+    assert "tpu_custom_call" in text and "decode_attention" in text
+    cache_shaped = re.compile(
+        rf"= (s8\[{b},{h_kv},{l_buf},{DH}\]|bf16\[{b},{h_kv},(1,)?{l_buf}\])"
+        r"\S* (copy|copy-start|dynamic-update-slice|dynamic-slice)\("
+    )
+    assert not [ln for ln in text.splitlines() if cache_shaped.search(ln)]
+
+
 def test_decode_attention_chunk_sq5(chip):
     from mlcomp_tpu.ops.pallas.decode_attention import decode_attention_chunk
 

@@ -103,3 +103,134 @@ def test_the_counts_ride_the_tail_of_the_packed_buffer():
     finally:
         eng.close()
     assert packed.shape == (3 * 2 * 2 + N_COUNTS,)
+
+
+# ---- the int8 cache's single-token step appends inside the kernel ----
+
+DENSE = {"name": "transformer_lm", "vocab_size": 64, "hidden": 64,
+         "layers": 2, "heads": 2, "mlp_dim": 128, "dtype": "float32",
+         "kv_quant": True}
+
+
+def _loop_write_then_attend(real):
+    """``decode_attention`` with the write it replaced: a call that
+    carries ``append`` goes through ``conftest.loop_write_kv`` and then
+    attends with the plain kernel."""
+    from conftest import loop_write_kv
+
+    def old(q, *caches, kv_stop=None, append=None, **kw):
+        if append is not None:
+            caches = loop_write_kv(caches, append, kv_stop - 1)
+        out = real(q, *caches, kv_stop=kv_stop, **kw)
+        return out if append is None else (out, *caches)
+
+    return old
+
+
+def _cache_row_writes(jaxpr):
+    """(update-slices on a 4-D int8 buffer, ``decode_attention`` kernel
+    calls) in a program, through its scans, loops and calls; a kernel's
+    own body is not searched."""
+    writes = kernels = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            kernels += eqn.params["name"] == "decode_attention"
+            continue
+        aval = eqn.outvars[0].aval if eqn.outvars else None
+        if (eqn.primitive.name == "dynamic_update_slice"
+                and aval.dtype == jnp.int8 and aval.ndim == 4):
+            writes += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            w, k = _cache_row_writes(sub)
+            writes, kernels = writes + w, kernels + k
+    return writes, kernels
+
+
+def _serve_three(model, params):
+    """A run that admits A and B, decodes across several K = 4
+    dispatches, retires B and gives its slot to C while A decodes."""
+    import queue
+
+    eng = DecodeEngine(model, {"params": params}, slots=2,
+                       prompt_buckets=(16,), max_new_cap=24,
+                       steps_per_dispatch=4, prefill_chunk=8)
+    try:
+        program = jax.make_jaxpr(eng._dispatch_fn())(
+            eng.variables, eng._dstate)
+        stream: "queue.Queue" = queue.Queue()
+        fa = eng.submit([3, 14, 15, 9, 2, 7, 7, 30, 2, 1], 22,
+                        logprobs=True, stream=stream)
+        stream.get(timeout=300)                      # A is decoding
+        rb = eng.submit([7, 3, 44, 5, 6], 3, logprobs=True).result(
+            timeout=300)                             # B retires
+        rc = eng.submit([11, 12, 13], 9, logprobs=True).result(timeout=300)
+        ra = fa.result(timeout=300)
+        slots_used = eng.stats()["slots"]
+    finally:
+        eng.close()
+    assert slots_used == 2           # so C took the slot B left
+    return [(r["ids"], r["logprobs"]) for r in (ra, rb, rc)], program
+
+
+def test_the_kernels_append_equals_the_row_loop_it_replaced(monkeypatch):
+    """Tokens and logprobs of a run whose single-token steps append
+    inside ``decode_attention`` equal, bit for bit, a recording of the
+    same run made through the old write; and the dispatch program
+    holds no update-slice on an int8 cache buffer any more, where the
+    recording's holds one a tensor a layer inside its row loop."""
+    import mlcomp_tpu.ops.pallas.decode_attention as da
+
+    model, params = _build(DENSE)
+    got, program = _serve_three(model, params)
+    monkeypatch.setattr(
+        da, "decode_attention", _loop_write_then_attend(da.decode_attention)
+    )
+    recorded, old_program = _serve_three(model, params)
+    assert got == recorded
+    assert [len(ids) for ids, _ in got] == [22, 3, 9]
+    layers = DENSE["layers"]
+    assert _cache_row_writes(program.jaxpr) == (0, layers)
+    assert _cache_row_writes(old_program.jaxpr) == (2 * layers, layers)
+
+
+def test_kv_rows_written_is_live_rows_times_steps(monkeypatch):
+    """One request alone on three slots, 2 x K tokens (a step a token,
+    the first included): each of the two dispatches writes one row's
+    token in each of its K steps, by the host's mirror and by the
+    windows the kernels were handed."""
+    import mlcomp_tpu.ops.pallas.decode_attention as da
+
+    seen = []
+    real = da.decode_attention
+
+    def spy(q, k8, ks, v8, vs, kv_start=None, kv_stop=None, **kw):
+        assert kw.get("append") is not None
+        jax.debug.callback(
+            lambda a, b: seen.append(int((np.asarray(a) < np.asarray(b)).sum())),
+            kv_start, kv_stop, ordered=True,
+        )
+        return real(q, k8, ks, v8, vs, kv_start=kv_start, kv_stop=kv_stop,
+                    **kw)
+
+    monkeypatch.setattr(da, "decode_attention", spy)
+    model, params = _build(DENSE)
+    k = 4
+    eng = DecodeEngine(model, {"params": params}, slots=3,
+                       prompt_buckets=(16,), max_new_cap=16,
+                       steps_per_dispatch=k, pipeline_depth=1)
+    try:
+        out = eng.submit([5, 6, 7, 8], 2 * k).result(timeout=300)
+        st = eng.stats()
+        text = eng.metrics.render()
+    finally:
+        eng.close()
+    assert len(out["ids"]) == 2 * k
+    att, issued = st["attention"], st["pipeline"]["issued"]
+    assert issued == 2
+    assert att["rows_total"] == 3 * issued
+    assert att["kv_rows_written"] == att["rows_attended"] * k == 1 * k * issued
+    # what the device saw: one kernel call a layer a step, one live row
+    assert sum(seen) == att["kv_rows_written"] * DENSE["layers"]
+    assert set(seen) == {1}
+    assert (f"mlcomp_engine_attention_kv_rows_written_total "
+            f"{att['kv_rows_written']}") in text

@@ -207,6 +207,132 @@ def test_decode_kernel_walks_ragged_windows(h, h_kv, case):
         np.testing.assert_array_equal(out[~empty], filled[~empty])
 
 
+def _append_case(h, h_kv, dh, l_buf, sdt, cursors, starts, seed=1):
+    """Operands of one append call, and what the write it replaced
+    leaves behind (``conftest.loop_write_kv``)."""
+    from conftest import loop_write_kv
+
+    b = len(cursors)
+    dhp = max(dh, 128)
+    rng = np.random.default_rng(seed)
+
+    def normal(*shape):
+        x = jnp.asarray(rng.normal(size=shape), jnp.float32)
+        # the model zero-pads a small head dim to a lane multiple
+        return x.at[..., dh:].set(0.0) if dhp != dh else x
+
+    q = normal(b, h, dhp).astype(jnp.bfloat16)
+    k8, ks = quantize_kv(normal(b, h_kv, l_buf, dhp))
+    v8, vs = quantize_kv(normal(b, h_kv, l_buf, dhp))
+    ks, vs = (x.astype(sdt)[:, :, None, :] for x in (ks, vs))
+    caches = (k8, ks, v8, vs)
+    new = (*quantize_kv(normal(b, h_kv, dhp)),
+           *quantize_kv(normal(b, h_kv, dhp)))
+    cur = jnp.asarray(cursors, jnp.int32)
+    windows = dict(
+        kv_start=jnp.asarray(starts, jnp.int32), kv_stop=cur + 1,
+        scale=1.0 / dh**0.5,
+    )
+    return q, caches, new, windows, loop_write_kv(caches, new, cur)
+
+
+# (cursors, window starts) against an L-slot buffer walked in granules
+# of G: the new token goes to the cursor and the window ends behind it
+_APPEND = {
+    # the first slot, both sides of an int8 tile's edge and of a
+    # granule's, and the buffer's last slots
+    "cursors": lambda G, L: (
+        [0, 31, 32, G - 1, G, L - 2, L - 1], [0] * 7),
+    # a raised kv_start: the window's first granule is not the buffer's
+    "window": lambda G, L: (
+        [G + 5, 2 * G - 1, L - 2, 40], [G + 1, G + 3, L - 100, 40]),
+    # rows 1, 3 and 4 hold no window: start past the cursor, or at L as
+    # the engine hands a slot without a request
+    "empty_rows": lambda G, L: (
+        [77, 90, G, 5, L - 1, G + 7], [0, 91, 3, L, L, G]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_APPEND))
+@pytest.mark.parametrize("sdt", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16_scales", "f32_scales"])
+@pytest.mark.parametrize("h,h_kv,dh", [(16, 8, 128), (4, 2, 64)],
+                         ids=["hkv8_dh128", "padded_dh64"])
+def test_decode_kernel_appends_in_place(h, h_kv, dh, sdt, case):
+    """``append``: the kernel writes the row's new token at its cursor
+    and attends it.  Against the write it replaced (a row loop of
+    update-slices, a select over the scale caches, then the plain
+    kernel): the output and all four buffers bit-equal; a row with an
+    empty window returns zeros and keeps every byte it had."""
+    from mlcomp_tpu.ops.pallas.decode_attention import auto_block_kv
+
+    l_buf, block_kv = 1280, 640
+    granule = min(block_kv, auto_block_kv(l_buf, h_kv, 128))
+    assert granule == 640
+    cursors, starts = _APPEND[case](granule, l_buf)
+    q, caches, new, windows, want = _append_case(
+        h, h_kv, dh, l_buf, sdt, cursors, starts
+    )
+    out, *got = decode_attention(
+        q, *caches, block_kv=block_kv, append=new, **windows
+    )
+    ref = decode_attention(q, *want, block_kv=block_kv, **windows)
+    bits = lambda x: np.asarray(x.astype(jnp.float32))
+    np.testing.assert_array_equal(bits(out), bits(ref))
+    live = np.asarray(windows["kv_start"] < windows["kv_stop"])
+    assert live.all() == (case != "empty_rows")
+    assert (bits(out)[~live] == 0.0).all()
+    for new_buf, old_write, old_buf in zip(got, want, caches):
+        assert new_buf.shape == old_buf.shape
+        assert new_buf.dtype == old_buf.dtype
+        np.testing.assert_array_equal(
+            bits(new_buf)[live], bits(old_write)[live]
+        )
+        np.testing.assert_array_equal(
+            bits(new_buf)[~live], bits(old_buf)[~live]
+        )
+
+
+def test_decode_kernel_append_rejects_bad_shapes():
+    q, caches, new, windows, _ = _append_case(
+        4, 2, 128, 256, jnp.bfloat16, [3, 4], [0, 0]
+    )
+    with pytest.raises(ValueError, match="append"):
+        decode_attention(
+            q, *caches, append=(new[0][:, :1], *new[1:]), **windows
+        )
+
+
+@pytest.mark.parametrize("shape", [(2, 1, 2), (1, 2, 2), (4, 1, 1)],
+                         ids=["dp2_tp2", "fsdp2_tp2", "dp4"])
+def test_sharded_decode_attention_appends(shape):
+    """Under a mesh the append rides the shard_map island: rows over
+    the data axis, KV heads over ``tp``, each device writing its own
+    shard of the caches; bit-equal to the single-device call."""
+    from jax.sharding import Mesh
+    from mlcomp_tpu.ops.pallas.decode_attention import (
+        sharded_decode_attention,
+    )
+
+    mesh = Mesh(
+        np.asarray(jax.devices()[:4]).reshape(shape), ("dp", "fsdp", "tp")
+    )
+    q, caches, new, windows, _ = _append_case(
+        8, 4, 128, 256, jnp.bfloat16, [0, 40, 128, 254], [0, 41, 100, 0]
+    )
+    want = decode_attention(q, *caches, append=new, **windows)
+    got = jax.jit(lambda *a: sharded_decode_attention(
+        *a[:5], mesh, append=a[5:], **windows
+    ))(q, *caches, *new)
+    plain = sharded_decode_attention(
+        q, *want[1:], mesh, **windows
+    )
+    bits = lambda x: np.asarray(x.astype(jnp.float32))
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(bits(g), bits(w))
+    np.testing.assert_array_equal(bits(plain), bits(want[0]))
+
+
 def test_chunk_kernel_tiles_wide_chunks():
     """Chunks wider than one kernel tile no longer raise (the pre-
     ISSUE-13 NotImplementedError): they run as query-TILED sweeps —

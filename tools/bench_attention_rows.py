@@ -1,7 +1,9 @@
 """Run one benchmark cell and print, beside its result line, the
 engine's ``stats()["attention"]`` block at every ``stats()`` call the
 harness makes (one before the timed window, one after the drain), so
-``rows_attended_share`` of the window is the difference of the two:
+``rows_attended_share`` of the window is the difference of the two, and
+``kv_rows_written`` over ``rows_total`` x K is the share of the slot
+rows a step's kernels wrote a token into:
 
     python tools/bench_attention_rows.py --workload chat-steady \\
         --seed 7 --seconds 50 --trace 0

@@ -4,13 +4,25 @@ like the two cells' traffic, plus the 1.2B smoke's full buffer (B 8,
 Hkv 16, L 2304).  Marginal timing: one jitted ``fori_loop`` a variant
 with a traced trip count, run at two counts; us a call is the slope.
 
-    python tools/exp_decattn.py [--parent DIR] [--out FILE]
+    python tools/exp_decattn.py [--parent DIR] [--out FILE] [--append]
 
 ``--parent`` names a checkout whose ``decode_attention`` is timed
 beside this tree's, on the windows that tree's engine would hand it
 (a retired row keeps its stale window) and on this tree's (empty).
+
+``--append`` runs the KV-append cases instead of the granule sweep (the
+table that chose PR 29's form): a layer's write of one token a row
+plus its attention, at 5 / 10 / 48 live rows of 48 and contexts of
+~240 / ~700 tokens, three ways: ``attend`` (the plain kernel, nothing
+written), ``loop_write`` (``_row_cursor_dus`` for K and V over every
+row, a select over each scale cache, then the plain kernel: the write
+up to PR 28) and ``append`` (the kernel's ``append``).  The caches ride
+the loop's carry, donated, as they ride the engine's K-step scan; each
+case first checks on this device that ``append`` leaves the bytes
+``loop_write`` leaves in every live row and returns its output.
 """
 import argparse
+import functools
 import importlib.util
 import json
 import statistics
@@ -76,16 +88,137 @@ def looped(fn, start, stop, **kw):
     return jax.jit(run)
 
 
-def us_a_call(fn, q, operands):
-    float(fn(q, operands, 2)[0, 0, 0])           # compile
+def slope_us(call):
+    """us an iteration of ``call(n)``, which runs n and waits: the
+    slope between two trip counts."""
+    call(2)                                      # compile
     t = {n: [] for n in (N_LO, N_HI)}
     for _ in range(REPEATS):
         for n in t:
             t0 = time.perf_counter()
-            float(fn(q, operands, n)[0, 0, 0])
+            call(n)
             t[n].append(time.perf_counter() - t0)
     lo, hi = (statistics.median(t[n]) for n in (N_LO, N_HI))
     return (hi - lo) / (N_HI - N_LO) * 1e6
+
+
+def us_a_call(fn, q, operands):
+    return slope_us(lambda n: float(fn(q, operands, n)[0, 0, 0]))
+
+
+def layer_step(variant, q, caches, new, start, cur):
+    """One layer's write of a token a row and its attention."""
+    from mlcomp_tpu.models.transformer import _row_cursor_dus
+
+    k8, ks, v8, vs = caches
+    kq, ks_new, vq, vs_new = new
+    kw = dict(kv_start=start, kv_stop=cur + 1)
+    if variant == "append":
+        out, *caches = decode_attention(q, *caches, append=new, **kw)
+        return out, tuple(caches)
+    if variant == "loop_write":
+        b, h_kv, l_buf = ks.shape[0], ks.shape[1], ks.shape[3]
+        hit = (
+            jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, l_buf), 3)
+            == cur[:, None, None, None]
+        )
+        caches = (
+            _row_cursor_dus(k8, kq[:, :, None, :], cur, 2),
+            jnp.where(hit, ks_new.reshape(b, h_kv, 1, 1).astype(ks.dtype), ks),
+            _row_cursor_dus(v8, vq[:, :, None, :], cur, 2),
+            jnp.where(hit, vs_new.reshape(b, h_kv, 1, 1).astype(vs.dtype), vs),
+        )
+    return decode_attention(q, *caches, **kw), caches
+
+
+def looped_layer(variant, new, start, cur):
+    """``(q, caches, n)`` -> (q, caches) after n layer steps, the
+    caches the loop's carry and donated."""
+    def run(q, caches, n):
+        def body(i, carry):
+            q, caches = carry
+            o, caches = layer_step(variant, q, caches, new, start, cur)
+            return (o * 1e-3 + q * 0.5).astype(q.dtype), caches
+
+        return jax.lax.fori_loop(0, n, body, (q, caches))
+
+    return jax.jit(run, donate_argnums=(1,))
+
+
+def us_a_layer(fn, q, caches):
+    """(us a layer step, the caches as the last call left them): each
+    call donates the caches and takes the next call's from its result."""
+    held = [caches]
+
+    def call(n):
+        out, held[0] = fn(q, held[0], n)
+        float(out[0, 0, 0])
+
+    return slope_us(call), held[0]
+
+
+def append_cases(args, dev):
+    """The table behind PR 29's choice of form (module docstring)."""
+    b, h, hkv, l_buf, dh = 48, 16, 8, 2560, 128
+    if args.tiny:
+        b, h, hkv = 12, 4, 2
+    key = jax.random.PRNGKey(29)
+
+    kv = [jax.random.randint(jax.random.fold_in(key, i),
+                             (b, hkv, l_buf, dh), -127, 127, jnp.int8)
+          for i in (0, 1)]
+    sc = [(jax.random.uniform(jax.random.fold_in(key, i),
+                              (b, hkv, 1, l_buf)) * 0.01
+           ).astype(jnp.bfloat16) for i in (2, 3)]
+    caches = (kv[0], sc[0], kv[1], sc[1])
+    q = jax.random.normal(jax.random.fold_in(key, 9), (b, h, dh),
+                          jnp.bfloat16)
+    new = (
+        jax.random.randint(jax.random.fold_in(key, 4), (b, hkv, dh),
+                           -127, 127, jnp.int8),
+        jax.random.uniform(jax.random.fold_in(key, 5), (b, hkv)) * 0.01,
+        jax.random.randint(jax.random.fold_in(key, 6), (b, hkv, dh),
+                           -127, 127, jnp.int8),
+        jax.random.uniform(jax.random.fold_in(key, 7), (b, hkv)) * 0.01,
+    )
+    results = []
+    rng = np.random.default_rng(29)
+    for context in (240, 700):
+        for live_rows in ((5, 10, b) if not args.tiny else (3, b)):
+            live = np.zeros(b, bool)
+            live[rng.choice(b, live_rows, replace=False)] = True
+            bucket = next(x for x in BUCKETS if x >= context - 40)
+            # a prompt of context - 40 tokens left-padded to its
+            # bucket, 40 + row tokens decoded behind it
+            cur = np.where(live, bucket + 39 + np.arange(b) % 7, 0)
+            start = np.where(live, bucket - (context - 40), l_buf)
+            start, cur = (jnp.asarray(x, jnp.int32) for x in (start, cur))
+            wrote = {
+                v: jax.jit(functools.partial(layer_step, v))(
+                    q, caches, new, start, cur
+                ) for v in ("loop_write", "append")
+            }
+            same = all(
+                bool(jnp.array_equal(x[live], y[live]))
+                for x, y in zip(jax.tree.leaves(wrote["append"]),
+                                jax.tree.leaves(wrote["loop_write"]))
+            )
+            del wrote
+            print(f"context {context} live {live_rows}: append == "
+                  f"loop_write in live rows: {same}", flush=True)
+            for variant in ("attend", "loop_write", "append"):
+                us, caches = us_a_layer(
+                    looped_layer(variant, new, start, cur), q, caches
+                )
+                results.append({
+                    "context": context, "live_rows": live_rows,
+                    "variant": variant, "us_a_layer_call": us,
+                    "bit_equal": same,
+                })
+                print(f"context {context:4d} live {live_rows:2d} "
+                      f"{variant:10s} {us:8.2f} us a layer call", flush=True)
+    with open(args.out, "w") as f:
+        json.dump({"device": dev.device_kind, "results": results}, f, indent=1)
 
 
 def main():
@@ -94,6 +227,8 @@ def main():
     ap.add_argument("--out", default="chiprun_out/decattn_sweep.json")
     ap.add_argument("--tiny", action="store_true",
                     help="a CPU rehearsal of the control flow: no timing")
+    ap.add_argument("--append", action="store_true",
+                    help="the KV-append cases instead of the granule sweep")
     args = ap.parse_args()
     if args.tiny:
         global N_LO, N_HI, REPEATS
@@ -110,6 +245,10 @@ def main():
 
     dev = jax.devices()[0]
     print("device", dev.platform, dev.device_kind, flush=True)
+    if args.append:
+        if args.out == ap.get_default("out"):
+            args.out = "chiprun_out/decattn_append.json"
+        return append_cases(args, dev)
     results = []
     geometries = [
         ("cell", 48, 16, 8, 2560, ("steady", "steady_long", "offline", "full"),

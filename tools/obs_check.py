@@ -89,6 +89,7 @@ DOCUMENTED_SERVE_METRICS = [
     "mlcomp_engine_pipeline_overlap_efficiency",
     "mlcomp_engine_attention_rows_attended_total",
     "mlcomp_engine_attention_rows_total",
+    "mlcomp_engine_attention_kv_rows_written_total",
     "mlcomp_engine_attention_kv_tokens_attended_total",
     "mlcomp_engine_attention_kv_tokens_live_total",
     "mlcomp_engine_dispatch_k",
