@@ -413,8 +413,6 @@ def _cmd_serve(args: argparse.Namespace) -> int:
         engine_fused_admission=(
             False if args.engine_staged_admission else None
         ),
-        spec_k=args.spec_k,
-        engine_spec_k=args.engine_spec_k,
         prefix_cache=args.prefix_cache,
         prefix_cache_bytes=args.prefix_cache_bytes,
         flight_recorder_events=args.flight_recorder_events,
@@ -551,7 +549,7 @@ def _cmd_fleet(args: argparse.Namespace) -> int:
 
         def strip_flags(argv, flags):
             """Drop ``--flag value`` pairs the prefill daemons reject
-            (decode-pool / spec tuning passed via --serve-arg sizes
+            (decode-pool tuning passed via --serve-arg sizes
             the DECODE half; a prefill_only engine refuses them at
             construction, which would crash-loop the whole set)."""
             out, skip = [], False
@@ -565,7 +563,7 @@ def _cmd_fleet(args: argparse.Namespace) -> int:
                 out.append(a)
             return out
 
-        decode_only = ("--kv-pages", "--max-slots", "--engine-spec-k")
+        decode_only = ("--kv-pages", "--max-slots")
         managers = []
         for set_name, target, prange, base_argv, extra in (
             ("prefill", n_prefill, (lo, mid),
@@ -874,8 +872,8 @@ def main(argv=None) -> int:
         " batches servable.  --quantize kernel and --kv-quant compose"
         " with tp/dp meshes (shard_map kernel islands); fsdp does not."
         " The continuous engine's dispatch pipeline (depth 2) and the"
-        " paged KV layout compose with the mesh too; speculative"
-        " dispatch and --prefix-cache remain single-chip",
+        " paged KV layout compose with the mesh too; --prefix-cache"
+        " remains single-chip",
     )
     sv.add_argument(
         "--distributed", action="store_true",
@@ -901,31 +899,13 @@ def main(argv=None) -> int:
     )
     sv.add_argument(
         "--batcher", default="auto",
-        choices=("auto", "continuous", "window", "speculative"),
+        choices=("auto", "continuous", "window"),
         help="'continuous' (the default, mesh or not): fixed decode"
         " slots, requests join a running decode at a dispatch"
         " boundary, finished rows free their slot, tokens stream"
         " (POST /generate with \"stream\": true -> SSE).  'window':"
         " the request-granularity batcher (one generate per arrival"
-        " window — offline batch generation).  'speculative': B=1"
-        " latency mode — each request runs the device-resident"
-        " n-gram speculative loop (greedy-only, single-chip; see"
-        " --spec-k)",
-    )
-    sv.add_argument(
-        "--spec-k", type=int, default=8,
-        help="speculative batcher: draft tokens per verify forward —"
-        " accepted drafts are nearly free on weight-bound B=1 decode",
-    )
-    sv.add_argument(
-        "--engine-spec-k", type=int, default=None,
-        help="continuous batcher: BATCHED speculative decoding — every"
-        " dispatch drafts + verifies K tokens per slot in one"
-        " per-row-cursor forward (greedy-only fleet; single-chip)."
-        " Replaces the K-step scan dispatch, so --steps-per-dispatch"
-        " is ignored (the engine warns if you set both); with"
-        " --quantize kernel keep slots*(K+1) <= 64 or the verify falls"
-        " off the fat-block decode GEMV layout",
+        " window — offline batch generation)",
     )
     sv.add_argument(
         "--steps-per-dispatch", type=_steps_per_dispatch, default=None,
@@ -936,8 +916,7 @@ def main(argv=None) -> int:
         " live queue-depth/occupancy signals over a warmed 1/2/4/8"
         " ladder (shallow queues small K for TTFT, deep queues large K"
         " for amortization; tokens are bit-identical under any K"
-        " schedule).  An integer PINS K — the bisect override.  Dead"
-        " under --engine-spec-k (speculation replaces the K-step scan)",
+        " schedule).  An integer PINS K — the bisect override",
     )
     sv.add_argument(
         "--engine-pipeline-depth", type=int, default=None,
@@ -1027,8 +1006,8 @@ def main(argv=None) -> int:
         help="continuous batcher: bound on the engine flight recorder's"
         " event ring (GET /trace exports it as Perfetto-loadable Chrome"
         " trace JSON; GET /metrics is always on).  0 disables recording"
-        " — measured overhead is <1%% of dispatch wall (bench.py's"
-        " recorder A/B), so the default stays on",
+        " (its overhead is a dict append per event; not measured on the"
+        " chip)",
     )
     sv.add_argument(
         "--request-timeout", type=float, default=600.0,

@@ -506,8 +506,7 @@ class Store:
         with self._tx() as c:
             # one executemany, not a Python loop of executes: the big
             # dispatch tick flips ~10k rows at once (a grid unblocking)
-            # and per-statement Python overhead was most of its 104 ms
-            # (bench.py scheduler line, r3)
+            # and per-statement Python overhead was most of its wall
             if expect is None:
                 cur = c.executemany(
                     "UPDATE tasks SET status=? WHERE dag_id=? AND name=?",

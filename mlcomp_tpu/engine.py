@@ -38,9 +38,7 @@ dispatch + ONE sync per K tokens instead of per token.  A row that
 hits EOS or its budget mid-dispatch stops emitting on device (its
 later inner steps are masked); joins still happen at dispatch
 boundaries, so K bounds the extra join latency at K-1 steps.  K=1
-recovers the round-4 per-token behavior exactly.  ``bench.py``'s
-engine section measures the per-dispatch overhead and the K
-amortization with the in-process A/B methodology (SURVEY §6).
+recovers the round-4 per-token behavior exactly.
 Since the adaptive-K PR the serve default is
 ``steps_per_dispatch="adaptive"``: a hysteretic ladder controller
 (``dispatch_control.py``) re-picks K at every boundary from the live
@@ -72,7 +70,7 @@ prefill chunk as a LONE dispatch at a drained pipeline boundary, so
 each chunk gapped the decode stream by a full host dispatch + the
 chunk's compute.  Now an admission's chunk rides the SAME jitted
 program as the boundary's K decode steps — one combined donated
-dispatch (one per (chunk width, spec on/off), ``_fused_dispatch_fn``)
+dispatch (one per chunk width, ``_fused_dispatch_fn``)
 runs the decode scan over all active slots AND one ``(1, c)`` chunk
 against the admission's carried cache, sharing one weights argument so
 parameters stream from HBM once per dispatch instead of twice.  The
@@ -108,9 +106,9 @@ islands — the named follow-up), and a multi-host gang serves through
 ``serve --distributed``: process 0 owns the HTTP front door and
 submit queue and broadcasts per-boundary admission/retire/K decisions
 over a TCP side channel (``parallel/distributed.BoundaryChannel``) so
-every process executes the identical dispatch sequence.  Speculative
-dispatch and the host prefix cache remain single-chip (rejected with
-messages naming the follow-up).
+every process executes the identical dispatch sequence.  The host
+prefix cache remains single-chip (rejected with a message naming the
+follow-up).
 
 Resilience layer (this PR): failure behavior is defined, not
 emergent.  Every request may carry a deadline and a cancel handle
@@ -128,8 +126,8 @@ progress-gated restart on a fresh device carry.  Prefix-cache faults
 are contained to a cache-bypass (degraded mode), never a failed
 request.  The fault points live in utils/faults.py;
 tools/chaoscheck.py drives a live daemon through each and asserts
-recovery invariants, and bench.py's resilience A/B gates the
-per-boundary maintenance under 1% of dispatch wall.
+recovery invariants; the per-boundary maintenance cost is not
+measured on the chip.
 
 No upstream analog: the reference framework has no serving path at all.
 """
@@ -342,7 +340,6 @@ class DecodeEngine:
         steps_per_dispatch: "Optional[int | str]" = None,
         prefill_chunk: int = 256,
         mesh=None,
-        spec_k: Optional[int] = None,
         prefix_cache=None,
         pipeline_depth: Optional[int] = None,
         flight_recorder_events: Optional[int] = 32768,
@@ -372,11 +369,6 @@ class DecodeEngine:
         # batched-forward shape the BERT/scoring fast path shares.
         self.prefill_only = bool(prefill_only)
         if self.prefill_only:
-            if spec_k is not None:
-                raise ValueError(
-                    "prefill_only engines run no decode dispatch; "
-                    "drop spec_k"
-                )
             if dist is not None:
                 raise ValueError(
                     "prefill_only does not compose with distributed "
@@ -411,9 +403,10 @@ class DecodeEngine:
         self.max_new_cap = int(max_new_cap)
         self.pad_id = int(pad_id)
         self.quant_kernel = bool(quant_kernel)
-        # steps_per_dispatch: an int PINS K (the bisect mode and the
-        # bench's fixed arms); "adaptive" runs the load-to-K ladder
-        # controller (dispatch_control.AdaptiveKController) — shallow
+        # steps_per_dispatch: an int PINS K (the bisect mode, and what
+        # the benchmark's configurations set); "adaptive" runs the
+        # load-to-K ladder controller
+        # (dispatch_control.AdaptiveKController) — shallow
         # queues pick small K (TTFT), deep queues large K (dispatch
         # amortization), hysteresis keeps the precompiled ladder warm.
         # Tokens are bit-identical under ANY K schedule by
@@ -424,9 +417,7 @@ class DecodeEngine:
         # K under mid-stream admission — and the scan body at K is
         # the K=1 body iterated), so adaptivity moves time, never
         # tokens.
-        # None = resolve by mode: 4 for the K-step scan dispatch, 1 for
-        # a speculative engine (whose dispatch verifies spec_k+1
-        # positions in ONE forward and never reads this knob).
+        # None resolves to 4.
         from mlcomp_tpu.dispatch_control import (
             DEFAULT_LADDER,
             AdaptiveKController,
@@ -441,24 +432,6 @@ class DecodeEngine:
                 "steps_per_dispatch must be an int, None, or "
                 f"'adaptive'; got {steps_per_dispatch!r}"
             )
-        if adaptive and spec_k is not None:
-            # a speculative dispatch verifies spec_k+1 positions in
-            # one forward and never runs the K-step scan — same
-            # dead-knob contract as a pinned K != 1 (which warns
-            # below); say so HERE, because the fallback to K=1 would
-            # otherwise dodge that warning and drop adaptivity (and
-            # any k_ladder) with zero feedback
-            warnings.warn(
-                f"spec_k={spec_k} engines ignore "
-                "steps_per_dispatch='adaptive' (a speculative dispatch "
-                "drafts and verifies spec_k+1 positions in one forward "
-                "— there is no K-step scan to adapt); drop the knob "
-                "or spec_k",
-                stacklevel=2,
-            )
-            adaptive = False
-            steps_per_dispatch = None
-            k_ladder = None  # covered by the warning above
         self._k_controller = None
         if adaptive:
             ladder = tuple(
@@ -473,24 +446,13 @@ class DecodeEngine:
                 "'adaptive' (got a pinned/default steps_per_dispatch)"
             )
         if steps_per_dispatch is None:
-            steps_per_dispatch = 1 if spec_k is not None else 4
+            steps_per_dispatch = 4
         self.steps_per_dispatch = int(steps_per_dispatch)
         if not adaptive:
             self.k_ladder = (self.steps_per_dispatch,)
         self.adaptive_k = adaptive
         if self.steps_per_dispatch < 1:
             raise ValueError("steps_per_dispatch must be >= 1")
-        if spec_k is not None and self.steps_per_dispatch != 1:
-            # ADVICE r5: the CLI default (4) made the dead knob silent —
-            # a user tuning --steps-per-dispatch with --engine-spec-k
-            # got no feedback that speculation replaces the K-step scan
-            warnings.warn(
-                f"spec_k={spec_k} engines ignore steps_per_dispatch "
-                f"(got {self.steps_per_dispatch}): a speculative "
-                "dispatch drafts and verifies spec_k+1 positions in one "
-                "forward; drop steps_per_dispatch (or pass 1)",
-                stacklevel=2,
-            )
         self.prefill_chunk = int(prefill_chunk)
         if self.prefill_chunk < 1:
             raise ValueError("prefill_chunk must be >= 1")
@@ -535,52 +497,10 @@ class DecodeEngine:
             raise ValueError(
                 f"pipeline_depth must be >= 1, got {pipeline_depth}"
             )
-        # speculative dispatch (round 5, opt-in): each dispatch samples
-        # tok0 per row, drafts spec_k continuations by DEVICE-side
-        # n-gram prompt-lookup over a device-carried ids buffer (tok0
-        # only exists on device — host drafting would cost a sync), and
-        # verifies all rows' K+1 positions in ONE per-row-cursor
-        # chunked forward (the s>1 cache_cursor contract,
-        # models/transformer.py; int8 caches ride the multi-query
-        # flash kernel).  Greedy-only: submit rejects sampling knobs.
-        # Tuning note (int8 weights): the verify's GEMMs run
-        # slots*(spec_k+1) rows — keep that <= ops/pallas/quant_matmul
-        # _GEMV_ROWS (64) or the kernels fall off the swept fat-block
-        # decode layout onto prefill blocks (measured ~2x per-call at
-        # these shapes); e.g. 8 slots pair with spec_k <= 7.
-        self.spec_k = None if spec_k is None else int(spec_k)
-        if self.spec_k is not None:
-            if self.spec_k < 1:
-                raise ValueError(f"spec_k must be >= 1, got {spec_k}")
-            if mesh is not None:
-                raise ValueError(
-                    "speculative dispatch is single-chip for now (the "
-                    "multi-query verify kernel has no sharded wrapper; "
-                    "a sharded drafter is the sharded-serving PR's "
-                    "named follow-up); drop spec_k or the mesh"
-                )
-            if self.quant_kernel:
-                # r5 verdict weak #3: the fat-block cliff lived only in
-                # the tuning note above — slots=16, spec_k=7 silently
-                # fell onto 512x512 prefill blocks at ~2x per-call cost
-                from mlcomp_tpu.ops.pallas.quant_matmul import _GEMV_ROWS
-
-                verify_rows = self.slots * (self.spec_k + 1)
-                if verify_rows > _GEMV_ROWS:
-                    warnings.warn(
-                        f"slots*(spec_k+1) = {self.slots}*"
-                        f"{self.spec_k + 1} = {verify_rows} exceeds the "
-                        f"int8 kernel's fat-block decode boundary "
-                        f"(_GEMV_ROWS = {_GEMV_ROWS}): the speculative "
-                        "verify's GEMMs fall onto prefill blocks at a "
-                        "measured ~2x per-call cost — shrink slots or "
-                        "spec_k so their product stays within budget",
-                        stacklevel=2,
-                    )
         # host-RAM prefix KV cache (mlcomp_tpu/cache): lookup on
         # admission, capture on prefill completion.  Host->device row
         # inserts would fight XLA's cache sharding under SPMD, so the
-        # cache is single-chip like the speculative paths.
+        # cache is single-chip.
         self.prefix_cache = prefix_cache
         if prefix_cache is not None and mesh is not None:
             raise ValueError(
@@ -621,13 +541,11 @@ class DecodeEngine:
         # without the scratch slot a dead row would overwrite its own
         # last real K/V — harmless today (retired rows are never read
         # before slot reuse) but a corruption trap for any future
-        # reader; spec verify widens the span by K.  (The int8 cache's
-        # single-token step no longer writes a row without a request:
-        # decode_attention appends for the rows it walks.  The bf16
-        # cache and the verify still do, so the slot stays.)
-        self.l_buf = self.prompt_buckets[-1] + self.max_new_cap + (
-            self.spec_k or 0
-        ) + 1
+        # reader.  (The int8 cache's single-token step no longer writes
+        # a row without a request: decode_attention appends for the
+        # rows it walks.  The bf16 cache still does, so the slot
+        # stays.)
+        self.l_buf = self.prompt_buckets[-1] + self.max_new_cap + 1
         self.vocab = int(getattr(model, "vocab_size"))
         self._jax, self._jnp = jax, jnp
 
@@ -864,10 +782,6 @@ class DecodeEngine:
                 variables = dequantize_params(variables, jnp.bfloat16)
         self.variables = jax.tree.map(jnp.asarray, variables)
 
-        if self.spec_k is not None:
-            # device-carried token history per slot (left-aligned real
-            # ids, no bucket pads): the n-gram draft's source
-            self.t_ids = self.prompt_buckets[-1] + self.max_new_cap
         self._seed = int(seed)
         # the jitted-program pool — built before the first carry (the
         # sharded fresh-dstate initializer is itself a pooled program)
@@ -926,12 +840,6 @@ class DecodeEngine:
             # (0 forever on pinned-K engines)
             "dispatch_k_changes": 0,
         }
-        if self.spec_k is not None:
-            # spec-honesty denominator: live row-forwards across spec
-            # dispatches — emitted_tokens / spec_rows is the measured
-            # acceptance (tokens per row per verify forward); <= 1.0
-            # means speculation is a pure loss on this traffic
-            self._stats["spec_rows"] = 0
         if self._pool is not None:
             # elastic-slot + device-registry accounting (paged only),
             # plus the lazy decode-page allocator's ledger: pages
@@ -956,12 +864,6 @@ class DecodeEngine:
             self._stats["handoffs_exported"] = 0
             self._stats["kv_pages_exported"] = 0
             self._stats["handoff_bytes_exported"] = 0
-        self._spec_warned = False
-        # sticky spec-honesty verdict: flips True (and stays) when
-        # measured acceptance is <= 1.0 past the 64-row window — the
-        # bit behind /healthz's spec_ineffective and the
-        # mlcomp_engine_spec_ineffective gauge
-        self._spec_ineffective = False
         self._fatblock_scale_warned = False
         # issued-but-unprocessed dispatches, oldest first: (packed
         # device buffer, host issue time, dispatch seq — the flight
@@ -1011,9 +913,9 @@ class DecodeEngine:
         self._lat_tok_n = 0  # guarded_by: loop [writes]
         # flight recorder: an always-on bounded ring of dispatch /
         # admission / prefix-cache / request-lifecycle events, exported
-        # on demand (serve's GET /trace).  0/None disables (the bench
-        # A/B arm); overhead is a dict append per event — gated <1% of
-        # dispatch wall by bench.py's recorder A/B
+        # on demand (serve's GET /trace).  0/None disables; overhead is
+        # a dict append per event (its share of dispatch wall is not
+        # measured on the chip)
         self.recorder: Tracer = (
             Tracer(max_events=int(flight_recorder_events))
             if flight_recorder_events else null_tracer()
@@ -1061,10 +963,10 @@ class DecodeEngine:
         self._last_attr: Optional[Dict[str, Any]] = None
         # HBM-roofline accounting for the device-time attribution: one
         # decode forward streams the full weight tree plus its KV
-        # working set — K forwards per scan dispatch, one per spec
-        # verify.  DENSE: the whole allocated buffer (XLA attends the
-        # masked buffer; the Pallas kernels clamp at the cursor, so
-        # the count is conservative for them).  PAGED: the LIVE pages
+        # working set — K forwards per scan dispatch.  DENSE: the whole
+        # allocated buffer (XLA attends the masked buffer; the Pallas
+        # kernels clamp at the cursor, so the count is conservative
+        # for them).  PAGED: the LIVE pages
         # only, read at roofline time — a forward reads exactly the
         # mapped pages through the table, so charging the full pool
         # would overstate bytes and flatter roofline_utilization on
@@ -1200,9 +1102,6 @@ class DecodeEngine:
                     "positions", "active", "remaining", "eos", "t",
                     "k", "p", "rp", "rng", "rseed"):
             sh[key] = rep
-        if self.spec_k is not None:  # unreachable under a mesh; shaped
-            sh["ids"] = rep          # anyway so the trees always match
-            sh["ids_len"] = rep
         return sh
 
     def _fresh_dstate(self) -> Dict[str, Any]:
@@ -1278,12 +1177,9 @@ class DecodeEngine:
             # into dispatches, when neighbours joined, or pipeline
             # depth.  This is what makes emitted tokens bit-identical
             # under any adaptive-K schedule; the greedy path never
-            # reads it, and the spec dispatch carries it untouched.
+            # reads it.
             "rseed": jnp.zeros((ns,), jnp.int32),
         }
-        if self.spec_k is not None:
-            dstate["ids"] = jnp.zeros((ns, self.t_ids), jnp.int32)
-            dstate["ids_len"] = jnp.zeros((ns,), jnp.int32)
         return dstate
 
     # ------------------------------------------------------------- public
@@ -1319,13 +1215,6 @@ class DecodeEngine:
             raise NotCoordinator(
                 "this process is a follower in a distributed serve "
                 "gang; submit to the coordinator (process 0)"
-            )
-        if self.spec_k is not None and (
-            float(temperature) != 0.0 or float(repetition_penalty) != 1.0
-        ):
-            raise ValueError(
-                "a speculative engine (spec_k set) is greedy-only: "
-                "temperature must be 0 and repetition_penalty 1"
             )
         if self.prefill_only and stream is not None:
             raise ValueError(
@@ -1807,27 +1696,6 @@ class DecodeEngine:
             out["live_slots"] = len(self._host)
             out["max_slots"] = self.max_slots
             out["kv_pool"] = self._pool_stats()
-        if self.spec_k is not None:
-            rows = self._stats["spec_rows"]
-            acc = self._stats["emitted_tokens"] / rows if rows else None
-            out["spec"] = {
-                "spec_k": self.spec_k,
-                # measured tokens per row per verify forward; a plain
-                # decode step emits exactly 1, so net_gain <= 0 means
-                # every verify forward paid its K+1-wide cost for
-                # nothing — the knob is hurting (the engine warns once)
-                "acceptance_tokens_per_row": (
-                    round(acc, 3) if acc is not None else None
-                ),
-                "spec_net_gain": (
-                    round(acc - 1.0, 3) if acc is not None else None
-                ),
-                # persistent operator flag: measured acceptance fell
-                # to <= 1 token/row/forward past the 64-row warning
-                # window — speculation is burning fat-block rows for
-                # nothing (sticky until restart; /healthz surfaces it)
-                "spec_ineffective": self._spec_ineffective,
-            }
         out["watchdog"] = {
             "dispatch_stall_timeout_s": self.dispatch_stall_timeout,
             "stalls": self._stats["watchdog_stalls"],
@@ -1990,17 +1858,6 @@ class DecodeEngine:
         ctr("mlcomp_engine_admissions_overlapped_total",
             "Completed admissions with at least one fused chunk",
             st["admissions_overlapped"])
-        if self.spec_k is not None and st.get("spec_rows"):
-            gau("mlcomp_engine_spec_net_gain",
-                "Accepted tokens per row per verify forward minus 1 "
-                "(<= 0: speculation is a measured net loss)",
-                st["emitted_tokens"] / st["spec_rows"] - 1.0)
-        if self.spec_k is not None:
-            gau("mlcomp_engine_spec_ineffective",
-                "1 once measured acceptance fell to <= 1 token/row/"
-                "forward past the 64-row window (sticky): speculation "
-                "is burning fat-block rows for nothing",
-                1 if self._spec_ineffective else 0)
         gau("mlcomp_engine_dispatch_k",
             "Decode steps per dispatch currently in effect (the "
             "adaptive controller's pick, or the pinned K)",
@@ -2588,11 +2445,10 @@ class DecodeEngine:
         a sampled token, so f32 rounding of a huge eos is harmless)."""
         if "insert" not in self._fns:
             jax, jnp = self._jax, self._jnp
-            spec = self.spec_k is not None
             layout = self._layout
 
             def insert(dstate, row_cache, row_logits, row_presence, packed,
-                       *extra):
+                       *table_rows):
                 slot = packed[0].astype(jnp.int32)
                 out = dict(dstate)
                 if layout is not None:
@@ -2605,14 +2461,12 @@ class DecodeEngine:
                     # mirroring the dense insert keeping the engine's
                     # cache_index scalars (decode reads per-row cursors,
                     # never the global index).
-                    trow, wsel = extra[0], extra[1]
-                    ids_row = extra[2:]
+                    trow, wsel = table_rows
                     out["pages"] = layout.insert_rows(
                         dstate["pages"], wsel, row_cache
                     )
                     out["table"] = dstate["table"].at[slot].set(trow)
                 else:
-                    ids_row = extra
                     out["cache"] = jax.tree.map(
                         lambda ec, rc: ec if rc.ndim == 0
                         else ec.at[slot].set(rc[0]),
@@ -2634,11 +2488,6 @@ class DecodeEngine:
                     out[key] = dstate[key].at[slot].set(
                         packed[i + 1].astype(dt)
                     )
-                if spec:  # token history seeds the n-gram draft
-                    out["ids"] = dstate["ids"].at[slot].set(ids_row[0][0])
-                    out["ids_len"] = dstate["ids_len"].at[slot].set(
-                        packed[11].astype(jnp.int32)
-                    )
                 out["active"] = dstate["active"].at[slot].set(True)
                 return self._constrain_carry(out)
 
@@ -2651,7 +2500,7 @@ class DecodeEngine:
         """Retire ONE row on device (deadline/cancel): the device
         normally retires rows itself at EOS/budget, but a host-initiated
         retirement must clear ``active`` (and zero the budget) or the
-        dead row keeps burning verify/scan lanes until its slot is
+        dead row keeps burning scan lanes until its slot is
         reused.  Composes onto the latest carry even with dispatches in
         flight — JAX sequences it after them on the device stream."""
         if "deactivate" not in self._fns:
@@ -2740,7 +2589,7 @@ class DecodeEngine:
         # issue advances by the current one
         lookahead = sum(
             steps for _, _, _, steps in self._inflight
-        ) + self._steps_hi() + 1
+        ) + self.steps_per_dispatch + 1
         grew = False
         for i, sl in enumerate(self._host):
             if sl is None or sl.span_end is None:
@@ -2814,23 +2663,11 @@ class DecodeEngine:
         tokens start at the left-pad boundary, decode writes run to the
         budget plus the scratch slot (a retired row's frozen cursor
         still receives each dispatch's write one past its last real
-        slot; spec verify widens the span by K).  Every page the span
+        slot).  Every page the span
         touches must be privately backed — pages fully inside the pad
         prefix (or past the span) map NULL and cost nothing."""
         start_pad = s_bucket - n_ids
-        span_end = s_bucket + int(n_new) + (
-            self.spec_k + 1 if self.spec_k is not None else 1
-        )
-        return start_pad, span_end
-
-    def _steps_hi(self) -> int:
-        """Upper bound on cache slots one dispatch advances a row: the
-        K-step scan writes K tokens, a spec dispatch writes K+1 verify
-        positions — the lazy allocator's lookahead unit."""
-        return (
-            self.spec_k + 1 if self.spec_k is not None
-            else self.steps_per_dispatch
-        )
+        return start_pad, s_bucket + int(n_new) + 1
 
     def _pages_worst(self, req: Dict[str, Any]) -> int:
         """Worst-case pages a request can occupy (prefix sharing only
@@ -2849,7 +2686,7 @@ class DecodeEngine:
         content plus one dispatch of decode lookahead — everything
         past it allocates lazily as the cursor approaches
         (``_lazy_extend_tick``)."""
-        return min(span_end, s_bucket + self._steps_hi() + 1)
+        return min(span_end, s_bucket + self.steps_per_dispatch + 1)
 
     def _pages_initial(self, req: Dict[str, Any]) -> int:
         """Pages a request needs AT ADMISSION under lazy decode
@@ -2869,24 +2706,21 @@ class DecodeEngine:
         )
 
     def _check_scale_fatblock(self, ns2: int) -> None:
-        """Re-derive the int8 fat-block cliff at SCALE time: the
-        constructor's ``slots*(spec_k+1) > _GEMV_ROWS`` warning prices
-        the row count it was built with, but elastic slots change the
-        live row count at scale-up — warn (once) when a grow step
-        pushes the decode GEMMs off the swept fat-block layout."""
+        """The int8 fat-block cliff at SCALE time: elastic slots
+        change the live row count at scale-up — warn (once) when a grow
+        step pushes the decode GEMMs off the swept fat-block layout."""
         if not self.quant_kernel or self._fatblock_scale_warned:
             return
         from mlcomp_tpu.ops.pallas.quant_matmul import _GEMV_ROWS
 
-        rows = ns2 * (self.spec_k + 1) if self.spec_k is not None else ns2
-        if rows > _GEMV_ROWS:
+        if ns2 > _GEMV_ROWS:
             self._fatblock_scale_warned = True
             warnings.warn(
-                f"elastic scale-up to {ns2} slots puts "
-                f"{rows} rows through the int8 kernels, past the "
+                f"elastic scale-up to {ns2} slots puts as many rows "
+                f"through the int8 kernels, past the "
                 f"fat-block decode boundary (_GEMV_ROWS = {_GEMV_ROWS}): "
                 "dispatches at this width fall onto prefill blocks at a "
-                "measured ~2x per-call cost — cap max_slots (or spec_k) "
+                "measured ~2x per-call cost — cap max_slots "
                 "to keep the row count within budget",
                 stacklevel=2,
             )
@@ -2910,9 +2744,6 @@ class DecodeEngine:
                 "remaining": 0, "eos": -1, "t": 0.0, "k": self.vocab,
                 "p": 1.0, "rp": 1.0, "rseed": 0, "table": GRAVE_PAGE,
             }
-            if self.spec_k is not None:
-                fills["ids"] = 0
-                fills["ids_len"] = 0
 
             def resize(sub):
                 out = {}
@@ -2944,7 +2775,7 @@ class DecodeEngine:
             self._check_scale_fatblock(ns2)
         keys = self._PER_SLOT_KEYS + (
             ("table",) if self._pool is not None else ()
-        ) + (("ids", "ids_len") if self.spec_k is not None else ())
+        )
         self._busy_since = time.perf_counter()
         try:
             with self.recorder.span(
@@ -3098,18 +2929,13 @@ class DecodeEngine:
 
     def _dispatch_core(self, k: int):
         """The raw ``(variables, dstate) -> (dstate', packed)`` dispatch
-        body — K-step scan, or speculative verify when ``spec_k`` is
-        set — shared by the plain jitted dispatch AND the fused
-        prefill+decode program family: the fused trace embeds this SAME
-        function, so decode math, scan order, and the RNG stream are
+        body, the K-step scan, shared by the plain jitted dispatch AND
+        the fused prefill+decode program family: the fused trace embeds
+        this SAME function, so decode math, scan order, and the RNG stream are
         identical across the two paths by construction."""
         key = ("dispatch_core", k)
         if key not in self._fns:
-            self._fns[key] = (
-                self._build_spec_dispatch_core()
-                if self.spec_k is not None
-                else self._build_scan_dispatch_core(k)
-            )
+            self._fns[key] = self._build_scan_dispatch_core(k)
         return self._fns[key]
 
     def _carry_core(self, k: int):
@@ -3221,8 +3047,8 @@ class DecodeEngine:
         from HBM once per dispatch instead of once for decode plus once
         for a staged chunk, and the chunk costs no extra host dispatch
         at a drained boundary.  One program per distinct chunk width
-        per dispatch family (scan K — one per ladder rung on adaptive
-        engines — or spec verify) — the same compile budget shape as
+        per scan K (one per ladder rung on adaptive engines) — the
+        same compile budget shape as
         the staged ``_prefill_chunk_fn``."""
         if k is None:
             k = self.steps_per_dispatch
@@ -3363,109 +3189,6 @@ class DecodeEngine:
             ])
             if sown:
                 packed = _pack_counts(packed, sown[0].sum(axis=0))
-            return out, packed
-
-        return dispatch
-
-    def _build_spec_dispatch_core(self):
-        """SPECULATIVE dispatch (spec_k set): one per-row-cursor chunked
-        verify instead of a K-step scan.  Per dispatch each live row
-        samples tok0 (greedy — enforced at submit), drafts ``spec_k``
-        continuations by bigram prompt-lookup over its device-carried
-        token history, scores all K+1 positions in ONE forward (int8
-        caches ride the multi-query flash kernel), and advances by the
-        accepted prefix + 1 — up to K+1 tokens per dispatch for the
-        cost of ~one step (B=1's measured verify ratio: ~1.06-1.09 at
-        1.2B).  Rejected cache slots sit beyond the new cursor: masked
-        now, overwritten by the next verify.  Packed output is
-        (3, K+1, slots) — the host loop is shape-agnostic."""
-        jax, jnp = self._jax, self._jnp
-        from mlcomp_tpu.models.speculative import ngram_propose
-
-        K = self.spec_k
-        fused_kv = self._kv_fused()
-
-        def dispatch(variables, dstate):
-            rows = jnp.arange(dstate["active"].shape[0])
-            kv_start = dstate["kv_start"]
-            live0 = dstate["active"]
-            slots_iota = jnp.arange(self.l_buf, dtype=jnp.int32)
-            kv_mask = slots_iota[None, :] >= kv_start[:, None]
-
-            tok0 = jnp.argmax(
-                dstate["last_logits"], axis=-1
-            ).astype(jnp.int32)
-            tok0 = jnp.where(live0, tok0, jnp.int32(self.pad_id))
-            prop = jax.vmap(
-                lambda ids_r, cur_r, t0: ngram_propose(
-                    ids_r, cur_r, t0, K, self.pad_id
-                )
-            )(dstate["ids"], dstate["ids_len"], tok0)     # (slots, K)
-            seq = jnp.concatenate([tok0[:, None], prop], axis=1)
-            pos = dstate["positions"][:, None] + jnp.arange(
-                K + 1, dtype=jnp.int32
-            )[None]
-            forward = self._kv_forward_fn(variables, dstate)
-            kv0 = (
-                tuple(dstate["pages"]) if fused_kv else dstate["cache"]
-            )
-            logits, kv_out, _ = forward(
-                kv0, seq, pos, dstate["cursors"],
-                kv_mask & live0[:, None],   # as the scan core's one_step
-            )
-            lg = logits.astype(jnp.float32)               # (slots, K+1, V)
-            greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
-            ok = (prop == greedy[:, :K]).astype(jnp.int32)
-            accepted = jnp.sum(jnp.cumprod(ok, axis=1), axis=1)
-            e = jnp.minimum(accepted + 1, dstate["remaining"])
-            j_iota = jnp.arange(K + 1, dtype=jnp.int32)[None]
-            eos_hit = (seq == dstate["eos"][:, None]) & (j_iota < e[:, None])
-            any_eos = jnp.any(eos_hit, axis=1)
-            first = jnp.argmax(eos_hit, axis=1).astype(jnp.int32)
-            e = jnp.where(any_eos, jnp.minimum(e, first + 1), e)
-            e = jnp.where(live0, e, 0)
-
-            # logprobs of emitted tokens: token j scores against the
-            # logits BEFORE it (last_logits for j=0, verify row j-1 on)
-            prevl = jnp.concatenate(
-                [dstate["last_logits"][:, None], lg[:, :K]], axis=1
-            )
-            lp = jnp.take_along_axis(
-                jax.nn.log_softmax(prevl, axis=-1), seq[..., None], axis=-1
-            )[..., 0]
-
-            valid = j_iota < e[:, None]                   # (slots, K+1)
-            # invalid lanes route OUT of range and drop (ADVICE r5):
-            # clipping parked them at t_ids-1, where a valid lane could
-            # target the same index — a duplicate-index scatter whose
-            # winner is implementation-defined.  mode="drop" also sheds
-            # a valid lane that would land past the history buffer (a
-            # max-bucket prompt running its full budget) instead of
-            # clobbering the last slot, and removes the read-back
-            # gather the old where-select needed.
-            write_idx = jnp.where(
-                valid, dstate["ids_len"][:, None] + j_iota,
-                jnp.int32(self.t_ids)
-            )
-            out = dict(dstate)
-            if fused_kv:
-                out["pages"] = list(kv_out)
-            else:
-                out["cache"] = kv_out
-            out["ids"] = dstate["ids"].at[rows[:, None], write_idx].set(
-                seq, mode="drop"
-            )
-            out["ids_len"] = dstate["ids_len"] + e
-            out["cursors"] = dstate["cursors"] + e
-            out["positions"] = dstate["positions"] + e
-            out["remaining"] = dstate["remaining"] - e
-            out["active"] = live0 & ~any_eos & (out["remaining"] > 0)
-            out["last_logits"] = lg[rows, jnp.maximum(e - 1, 0)]
-            packed = jnp.stack([
-                seq.T.astype(jnp.float32),
-                lp.T.astype(jnp.float32),
-                valid.T.astype(jnp.float32),
-            ])
             return out, packed
 
         return dispatch
@@ -3675,7 +3398,7 @@ class DecodeEngine:
     def _run_admission_chunk(self) -> None:  # graftcheck: runs-on(loop)
         """Run ONE STAGED prefill chunk — its own dispatch at a drained
         boundary, the pre-fused behavior (``fused_admission=False``,
-        admissions with no decode fleet to ride, and the bench/tools
+        admissions with no decode fleet to ride, and the tools'
         entry point) — and complete the admission after its last chunk.
         The fused path advances chunks inside ``_issue_dispatch``
         instead, so decode never waits on this call."""
@@ -3815,12 +3538,9 @@ class DecodeEngine:
 
     def _family_name(self, fused_chunk: Optional[int] = None) -> str:
         """The dispatch-program family a capture attributes to: the
-        K-step scan or the spec verify, with the fused prefill+decode
-        width as a suffix when an admission chunk rode the dispatch."""
-        base = (
-            f"spec_verify_k{self.spec_k}" if self.spec_k is not None
-            else f"decode_scan_k{self.steps_per_dispatch}"
-        )
+        K-step scan, with the fused prefill+decode width as a suffix
+        when an admission chunk rode the dispatch."""
+        base = f"decode_scan_k{self.steps_per_dispatch}"
         if fused_chunk is not None:
             return f"{base}+prefill_c{fused_chunk}"
         return base
@@ -4120,12 +3840,6 @@ class DecodeEngine:
 
     # -------------------------------------------------- bytes accounting
 
-    @property
-    def _forwards(self) -> int:
-        """Model forwards one dispatch runs — K for the scan dispatch
-        (the CURRENT K: adaptive engines re-price the roofline as the
-        controller moves), 1 for a spec verify."""
-        return 1 if self.spec_k is not None else self.steps_per_dispatch
 
     def _kv_live_bytes(self) -> int:
         """Paged: bytes of the live page MAPPINGS — the KV working set
@@ -4153,22 +3867,21 @@ class DecodeEngine:
             self._kv_live_bytes() if self._pool is not None
             else self._kv_dense_bytes
         )
-        return self._forwards * (self._w_bytes + kv)
+        return self.steps_per_dispatch * (self._w_bytes + kv)
 
     def _roofline_ms(self) -> float:
         return self._roofline_bytes() / (self._hbm_gbps * 1e9) * 1e3
 
     def _kv_bytes_moved_per_dispatch(self) -> int:
         """Estimated KV bytes one dispatch moves through HBM — the
-        cost model behind ``mlcomp_engine_kv_bytes_moved_per_dispatch``
-        and bench's fused-vs-gather A/B.  Dense: K forwards read the
-        buffer.  Paged FUSED: K forwards read the live pages (the
+        cost model behind ``mlcomp_engine_kv_bytes_moved_per_dispatch``.
+        Dense: K forwards read the buffer.  Paged FUSED: K forwards read the live pages (the
         whole point of the fused path — per-token appends are noise).
         Paged LAX sandwich: the gather reads the live pages and writes
         the dense view, the core reads it K times, the scatter reads
         it back and rewrites the pages — the round trip the fused path
         deletes."""
-        fw = self._forwards
+        fw = self.steps_per_dispatch
         if self._pool is None:
             return fw * self._kv_dense_bytes
         live = self._kv_live_bytes()
@@ -4265,13 +3978,8 @@ class DecodeEngine:
             # collides live streams; warmup rows are greedy and never
             # read it.
             req.get("rid", 0) % (1 << 23),
-            len(req["ids"]),  # ids_len (spec mode; ignored otherwise)
         ], np.float32)
         extra = ()
-        if self.spec_k is not None:
-            ids_np = np.zeros((1, self.t_ids), np.int32)
-            ids_np[0, : len(req["ids"])] = req["ids"]
-            extra = (self._dev(ids_np),)
         prow = None
         if self._pool is not None:
             # PAGED: compose the slot's table row host-side — NULL for
@@ -4317,7 +4025,7 @@ class DecodeEngine:
                     alloc_end=alloc_end,
                 )
             wsel = np.where(pmask, prow, GRAVE_PAGE).astype(np.int32)
-            extra = (self._dev(prow), self._dev(wsel)) + extra
+            extra = (self._dev(prow), self._dev(wsel))
         try:
             with self.recorder.span(
                 "insert", track="engine.loop", slot=slot,
@@ -4550,13 +4258,8 @@ class DecodeEngine:
             # rid: sampled tokens must not depend on which replica
             # admitted the prompt
             int(meta.get("rseed", 0)) % (1 << 23),
-            len(ids),
         ], np.float32)
         extra = (self._dev(prow), self._dev(wsel))
-        if self.spec_k is not None:
-            ids_np = np.zeros((1, self.t_ids), np.int32)
-            ids_np[0, : len(ids)] = ids
-            extra = extra + (self._dev(ids_np),)
         n_pages = p_n - p0
         try:
             with self.recorder.span(
@@ -4745,7 +4448,7 @@ class DecodeEngine:
         # actually advance, not by the current knob
         t_issued = time.perf_counter()
         self._account(t_issued)  # before _inflight grows: see _account
-        self._inflight.append((packed, t_issued, seq, self._steps_hi()))
+        self._inflight.append((packed, t_issued, seq, self.steps_per_dispatch))
         p = self._pstats
         p["issued"] += 1
         p["inflight_sum"] += len(self._inflight)
@@ -4755,7 +4458,7 @@ class DecodeEngine:
         ctx = [sl.position for sl in self._host if sl is not None]
         p["rows_attended"] += len(ctx)
         p["rows_total"] += len(self._host)
-        p["kv_rows_written"] += len(ctx) * self._steps_hi()
+        p["kv_rows_written"] += len(ctx) * self.steps_per_dispatch
         # tokens of context the live rows hold, over the layers, and
         # the part of them a layer's window lets its attention read
         p["kv_live"] += sum(ctx) * len(self._attn_windows)
@@ -4818,17 +4521,9 @@ class DecodeEngine:
         valid = arr[2] > 0.5
         n_tokens = int(valid.sum())
         self._stats["dispatches"] += 1
-        # "steps" counts device FORWARDS (a spec dispatch is ONE verify
-        # forward however many packed rows it returns); emitted_tokens /
-        # steps is then the live tokens-per-forward (acceptance) rate
-        self._stats["steps"] += 1 if self.spec_k else toks.shape[0]
+        # "steps" counts device FORWARDS: the K of the scan
+        self._stats["steps"] += toks.shape[0]
         self._stats["emitted_tokens"] += n_tokens
-        if self.spec_k is not None:
-            # spec honesty: a live row emits >= 1 token per verify
-            # forward, so rows-with-any-valid is the per-forward live
-            # row count — emitted/spec_rows is the measured acceptance
-            self._stats["spec_rows"] += int(valid.any(axis=0).sum())
-            self._maybe_warn_spec_loss()
         for kk in range(toks.shape[0]):
             self.step_count += 1
             for i, sl in enumerate(self._host):
@@ -4855,39 +4550,10 @@ class DecodeEngine:
                     self._release_slot_pages(i)
         return self._loop_span("unpack", t_done, seq=seq, tokens=n_tokens)
 
-    def _maybe_warn_spec_loss(self) -> None:
-        """One-time operator warning when MEASURED acceptance makes
-        speculation a pure loss (acceptance_tokens_per_row
-        1.0 and a marginal estimate BELOW the vanilla engine line —
-        the knob silently cost throughput).  1.0 tokens/row/forward
-        means every draft was rejected: each K+1-wide verify emitted
-        exactly what a plain decode step would, while paying more for
-        it.  ``spec_net_gain`` in stats()//healthz tracks it live."""
-        if self._spec_warned or self._stats["spec_rows"] < 64:
-            return
-        acc = self._stats["emitted_tokens"] / self._stats["spec_rows"]
-        if acc <= 1.0 + 1e-6:
-            self._spec_warned = True
-            # persistent flag (sticky until restart): operators — and
-            # the autoscaler, later — read it from /healthz and the
-            # mlcomp_engine_spec_ineffective gauge instead of hoping
-            # someone saw the one-shot warning below
-            self._spec_ineffective = True
-            warnings.warn(
-                f"speculative decoding (spec_k={self.spec_k}) is a "
-                f"measured net LOSS on this traffic: acceptance "
-                f"{acc:.2f} tokens/row/forward over "
-                f"{self._stats['spec_rows']} row-forwards — every "
-                "verify forward emits no more than a plain decode step "
-                "while paying the K+1-wide cost; drop --engine-spec-k "
-                "(spec_net_gain in stats() / /healthz tracks this live)",
-                stacklevel=2,
-            )
-
     def _run_dispatch(self) -> None:  # graftcheck: runs-on(loop)
         # the synchronous compose (= pipeline depth 1): issue, then
         # resolve everything in flight.  Kept as the one-call entry
-        # point for the bench/tools that drive the engine by hand.
+        # point for the tests and tools that drive the engine by hand.
         self._issue_dispatch()
         while self._inflight:
             self._process_oldest()
@@ -4997,8 +4663,7 @@ class DecodeEngine:
         DEVICE (the engine's own retirement path only fires at EOS/
         budget) and its slot freed for the next admission.  Fault-free
         cost is one queue poll + an O(slots + pending) scan per
-        boundary — gated <1% of dispatch wall by bench.py's resilience
-        A/B.
+        boundary (not measured on the chip).
 
         Returns ``(new, ctrls, retired)``: the requests/ctrl items
         pumped this boundary and the ``(rid, status)`` retirements it

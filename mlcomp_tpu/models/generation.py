@@ -196,8 +196,7 @@ def sample_token_rowwise_keyed(
 
 
 def prep_decode_variables(model, variables, quant_kernel, weights_dtype):
-    """Decode-loop weight prep shared by ``generate`` and
-    ``speculative_generate``: int8 entry-dequant or kernel-fold (with the
+    """Decode-loop weight prep for ``generate``: int8 entry-dequant or kernel-fold (with the
     optimization barrier that pins ONE materialized copy outside the
     token loop), optional bf16 pre-cast, and the apply wrapper that
     routes quantized leaves through the Pallas interception (with norm

@@ -152,11 +152,10 @@ def _row_cursor_dus(buf, upd, cur, seq_axis):
     """Write ``upd[r]`` into ``buf`` at row r's cursor slot(s) —
     per-row ``dynamic_update_slice`` in a ``fori_loop``, NOT a batched
     scatter (a scatter lowering copies the whole buffer; row-wise DUS
-    aliases the loop carry in place).  Two paths still write this way:
-    the bfloat16 cache's per-row-cursor step and the int8 cache's
-    multi-token verify (s > 1); no benchmark cell runs either.  The
-    int8 cache's single-token step used to, and the chip's verdict on
-    that is the ledger's PR 28 and PR 29 lines: a trip costs ~1 us
+    aliases the loop carry in place).  One path still writes this way:
+    the bfloat16 cache's per-row-cursor step, which no benchmark cell
+    runs.  The int8 cache's single-token step used to, and the chip's
+    verdict on that is the ledger's PR 28 and PR 29 lines: a trip costs ~1 us
     whatever the row holds, 2,304 of them a step at 48 slots x 24
     layers, a quarter of the step; ``decode_attention`` now appends
     the token itself (its ``append``).
@@ -346,14 +345,20 @@ class SelfAttention(nn.Module):
         (False = left-padding in a ragged prompt batch).
 
         ``cache_cursor`` (B,) int32 switches to PER-ROW write offsets:
-        each row writes its K/V starting at its own slot and query j
-        attends slots <= cursor + j — the contract the
-        continuous-batching engine (mlcomp_tpu/engine.py) drives, where
-        every row is at a different decode depth (s == 1 is the plain
-        decode step; s > 1 is the engine's speculative verify chunk).
-        The module's scalar ``cache_index`` is neither read nor
-        advanced then (the engine owns the cursors).
+        each row writes its one token's K/V at its own slot and attends
+        slots <= its cursor — the contract the continuous-batching
+        engine (mlcomp_tpu/engine.py) drives, where every row is at a
+        different decode depth.  It is a single-token contract (s == 1;
+        anything wider is a ValueError: chunks run under the one
+        ``cache_index``).  The module's scalar ``cache_index`` is
+        neither read nor advanced then (the engine owns the cursors).
         """
+        if cache_cursor is not None and q.shape[1] != 1:
+            raise ValueError(
+                "cache_cursor (per-row cursors) is the single-token "
+                f"decode step's contract; got a chunk of {q.shape[1]} "
+                "tokens (chunked prefill runs under the one cache_index)"
+            )
         if self.kv_quant:
             return self._decode_attention_quant(
                 q, k, v, kv_mask, cache_cursor
@@ -375,10 +380,9 @@ class SelfAttention(nn.Module):
             "cache", "cache_index", lambda: jnp.zeros((), jnp.int32)
         )
         if cache_cursor is not None:
-            # per-row write offsets; s > 1 (round 5) is the engine's
-            # SPECULATIVE verify: row b's query j writes slot cur_b + j
-            # and attends slots <= cur_b + j (per-row causal chunk).
-            # Writes via _row_cursor_dus (per-row DUS, not scatter).
+            # per-row write offsets (s == 1): row b writes slot cur_b
+            # and attends slots <= cur_b.  Writes via _row_cursor_dus
+            # (per-row DUS, not scatter).
             cur = jnp.asarray(cache_cursor).astype(jnp.int32)
             cached_k.value = _row_cursor_dus(cached_k.value, k, cur, 1)
             cached_v.value = _row_cursor_dus(cached_v.value, v, cur, 1)
@@ -386,17 +390,10 @@ class SelfAttention(nn.Module):
             v_all = cached_v.value
             max_len = k_all.shape[1]
             slots = jnp.arange(max_len, dtype=jnp.int32)
-            if s == 1:
-                mask = (slots[None, :] <= cur[:, None])[:, None, None]
-            else:  # (B, 1, S, L): per-row, per-query causal stops
-                stops = cur[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
-                mask = (
-                    slots[None, None, None, :] <= stops[:, None, :, None]
-                )
+            mask = (slots[None, :] <= cur[:, None])[:, None, None]
             mask = self._band(
                 mask, slots[None, None, None, :],
-                (cur[:, None] + jnp.arange(1, s + 1, dtype=jnp.int32)[None])
-                [:, None, :, None],
+                (cur + 1)[:, None, None, None],
             )
             if kv_mask is not None:
                 mask = mask & kv_mask[:, None, None, :].astype(jnp.bool_)
@@ -461,28 +458,19 @@ class SelfAttention(nn.Module):
                 "per-row-cursor decode dispatch (admission prefills "
                 "carry a dense (1, l_buf) cache)"
             )
-        b, s, h_kv, dh = k.shape
+        b = k.shape[0]
         prefix = "/".join(self.path)
         k_i = ctx.index_of(prefix, "cached_key")
         v_i = ctx.index_of(prefix, "cached_value")
         cur = jnp.asarray(cache_cursor).astype(jnp.int32)
-        rows = jnp.repeat(jnp.arange(b, dtype=jnp.int32), s)
-        pos = (
-            cur[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
-        ).reshape(-1)
-        ctx.append_rows(k_i, rows, pos, k.reshape(b * s, h_kv, dh))
-        ctx.append_rows(v_i, rows, pos, v.reshape(b * s, h_kv, dh))
+        rows = jnp.arange(b, dtype=jnp.int32)
+        ctx.append_rows(k_i, rows, cur, k[:, 0])
+        ctx.append_rows(v_i, rows, cur, v[:, 0])
         k_all = ctx.gather_dense(k_i)          # (B, L, Hkv, dh)
         v_all = ctx.gather_dense(v_i)
         max_len = k_all.shape[1]
         slots = jnp.arange(max_len, dtype=jnp.int32)
-        if s == 1:
-            mask = (slots[None, :] <= cur[:, None])[:, None, None]
-        else:  # (B, 1, S, L): per-row, per-query causal stops
-            stops = cur[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
-            mask = (
-                slots[None, None, None, :] <= stops[:, None, :, None]
-            )
+        mask = (slots[None, :] <= cur[:, None])[:, None, None]
         if kv_mask is not None:
             mask = mask & kv_mask[:, None, None, :].astype(jnp.bool_)
         return dot_product_attention(q, k_all, v_all, mask=mask)
@@ -505,11 +493,8 @@ class SelfAttention(nn.Module):
         to the dense engine: the kernels share ``_flash_block_update``
         and the block partition; the gather is pure data movement."""
         from mlcomp_tpu.ops.pallas.decode_attention import (
-            chunk_uses_kernels,
             decode_attention,
-            decode_attention_chunk,
             paged_decode_attention,
-            paged_decode_attention_chunk,
             quantize_kv,
         )
 
@@ -520,7 +505,7 @@ class SelfAttention(nn.Module):
                 "per-row-cursor decode dispatch (admission prefills "
                 "carry a dense (1, l_buf) cache)"
             )
-        b, s, hkv, dh = k.shape
+        b, _, hkv, dh = k.shape
         dhp = -(-dh // 128) * 128
         prefix = "/".join(self.path)
         kq_i = ctx.index_of(prefix, "cached_key_q")
@@ -533,22 +518,15 @@ class SelfAttention(nn.Module):
             vp = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, dhp - dh)))
         else:
             kp, vp = k, v
-        kq, ks_ = quantize_kv(kp)              # (B, S, Hkv, dhp) / (B, S, Hkv)
+        kq, ks_ = quantize_kv(kp)              # (B, 1, Hkv, dhp) / (B, 1, Hkv)
         vq, vs_ = quantize_kv(vp)
         cur = jnp.asarray(cache_cursor).astype(jnp.int32)
-        rows = jnp.repeat(jnp.arange(b, dtype=jnp.int32), s)
-        pos = (
-            cur[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
-        ).reshape(-1)
+        rows = jnp.arange(b, dtype=jnp.int32)
         sdt = ctx.spec(ks_i).dtype
-        ctx.append_rows(kq_i, rows, pos, kq.reshape(b * s, hkv, dhp))
-        ctx.append_rows(vq_i, rows, pos, vq.reshape(b * s, hkv, dhp))
-        ctx.append_rows(
-            ks_i, rows, pos, ks_.reshape(b * s, hkv, 1).astype(sdt)
-        )
-        ctx.append_rows(
-            vs_i, rows, pos, vs_.reshape(b * s, hkv, 1).astype(sdt)
-        )
+        ctx.append_rows(kq_i, rows, cur, kq[:, 0])
+        ctx.append_rows(vq_i, rows, cur, vq[:, 0])
+        ctx.append_rows(ks_i, rows, cur, ks_[:, 0, :, None].astype(sdt))
+        ctx.append_rows(vs_i, rows, cur, vs_[:, 0, :, None].astype(sdt))
 
         row_start = _window_start(kv_mask, b)
         qp = (
@@ -556,53 +534,15 @@ class SelfAttention(nn.Module):
             if dhp != dh else q
         )
         scale = 1.0 / (dh**0.5)
-        if not chunk_uses_kernels(s):
-            # wider than one multi-query kernel tile, off-TPU: the
-            # same XLA dequant fallback the dense path takes, on
-            # gathered bytes — degrade like dense does, never crash.
-            # On TPU (wide_chunk_mode "pallas") wide chunks fall
-            # through to the TILED kernel routes below instead: pages
-            # stream through the table (or a gather feeds the dense
-            # kernels), closing the per-layer barrier-gather +
-            # full-buffer dequant round trip overlapped admissions
-            # used to pay here.  chunk_uses_kernels is the SHARED
-            # predicate chunk_attention_route (the bench's bytes
-            # model) consults — routing cannot drift from the model.
-            k8 = ctx.gather_dense(kq_i)
-            ks4 = ctx.gather_dense(ks_i)
-            v8 = ctx.gather_dense(vq_i)
-            vs4 = ctx.gather_dense(vs_i)
-            l_buf = ctx.spec(kq_i).seq_len
-            k_scale = ks4.transpose(0, 1, 3, 2)      # (B, Hkv, L, 1)
-            v_scale = vs4.transpose(0, 1, 3, 2)
-            k_all = (
-                k8.astype(jnp.float32) * k_scale
-            ).astype(k.dtype).transpose(0, 2, 1, 3)[..., :dh]
-            v_all = (
-                v8.astype(jnp.float32) * v_scale
-            ).astype(v.dtype).transpose(0, 2, 1, 3)[..., :dh]
-            sl = jnp.arange(l_buf, dtype=jnp.int32)
-            stops = (cur + 1)[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
-            mask = sl[None, None, None, :] < stops[:, None, :, None]
-            mask = mask & (
-                sl[None, :] >= row_start[:, None]
-            )[:, None, None, :]
-            return dot_product_attention(q, k_all, v_all, mask=mask)
         if ctx.use_pallas_kernels(kq_i, hkv, dhp):
             tbl = ctx.kernel_table(kq_i)
             pages = (ctx.pages[kq_i], ctx.pages[ks_i],
                      ctx.pages[vq_i], ctx.pages[vs_i])
-            if s == 1:
-                out = paged_decode_attention(
-                    qp[:, 0], *pages, tbl, kv_start=row_start,
-                    kv_stop=cur + 1, scale=scale,
-                )
-                return out[..., :dh][:, None]
-            out = paged_decode_attention_chunk(
-                qp, *pages, tbl, kv_start=row_start, kv_stop0=cur + 1,
-                scale=scale,
+            out = paged_decode_attention(
+                qp[:, 0], *pages, tbl, kv_start=row_start,
+                kv_stop=cur + 1, scale=scale,
             )
-            return out[..., :dh]
+            return out[..., :dh][:, None]
         # gather fallback (geometry cannot keep the dense block
         # partition): per-layer lax reads feeding the DENSE kernels —
         # same bytes, same math, still no carried dense view
@@ -610,17 +550,11 @@ class SelfAttention(nn.Module):
         ks4 = ctx.gather_dense(ks_i)
         v8 = ctx.gather_dense(vq_i)
         vs4 = ctx.gather_dense(vs_i)
-        if s == 1:
-            out = decode_attention(
-                qp[:, 0], k8, ks4, v8, vs4, kv_start=row_start,
-                kv_stop=cur + 1, scale=scale,
-            )
-            return out[..., :dh][:, None]
-        out = decode_attention_chunk(
-            qp, k8, ks4, v8, vs4, kv_start=row_start, kv_stop0=cur + 1,
-            scale=scale,
+        out = decode_attention(
+            qp[:, 0], k8, ks4, v8, vs4, kv_start=row_start,
+            kv_stop=cur + 1, scale=scale,
         )
-        return out[..., :dh]
+        return out[..., :dh][:, None]
 
     def _decode_attention_quant(self, q, k, v, kv_mask, cache_cursor=None):
         """int8 KV-cache decode (``kv_quant=True``).
@@ -640,12 +574,13 @@ class SelfAttention(nn.Module):
         ``kv_start = argmax(kv_mask)`` is exact).  Prefill attends the
         fresh bf16 K/V directly — ragged batches stay on the flash
         kernel via ``kv_start`` windows instead of dropping to a dense
-        mask like the bf16 cache path.  Chunked decode (i > 0, s > 1)
-        at verify widths (s <= CHUNK_MAX_SQ, single-chip) runs the
-        multi-query flash kernel (``decode_attention_chunk`` — one
-        int8 cache sweep for all s queries; the speculative verify
-        path); wider chunks and mesh serving dequantize the buffer in
-        XLA — correct, bandwidth-amortized at prefill widths.
+        mask like the bf16 cache path.  Chunked decode (i > 0, s > 1:
+        chunked prefill under the one ``cache_index``) runs the
+        multi-query flash kernel where ``chunk_uses_kernels`` says so
+        (``decode_attention_chunk`` — one int8 cache sweep for all s
+        queries); other widths off the TPU and mesh serving dequantize
+        the buffer in XLA — correct, bandwidth-amortized at prefill
+        widths.
         """
         from mlcomp_tpu.kvpool.attn import current_paged_kv
         from mlcomp_tpu.ops.pallas.decode_attention import (
@@ -683,8 +618,9 @@ class SelfAttention(nn.Module):
         # r4 A/B), so its bytes are pure per-token overhead; bf16
         # halves them.  Quantization still computes the scale in f32
         # (exact division), only the stored dequant multiplier rounds —
-        # a ~0.2% relative perturbation on top of int8's ~0.8% step,
-        # gated by the bench_quality perplexity line.
+        # a ~0.2% relative perturbation on top of int8's ~0.8% step
+        # (the benchmark's ``correct`` check holds the outputs to the
+        # plain reference).
         cks = self.variable(
             "cache", "cached_key_scale", zeros((b, hkv, 1, lpad), jnp.bfloat16)
         )
@@ -762,21 +698,17 @@ class SelfAttention(nn.Module):
             per-row per-query causal stops [row_start, stop0 + j):
             the multi-query flash kernel when eligible (ONE int8 cache
             sweep for all s queries), the XLA dequant path otherwise
-            (wide prefill chunks, mesh serving).  Shared by the
-            global-index chunked path and the per-row-cursor verify —
-            the two differ only in the stop vector."""
+            (wide prefill chunks off the TPU, mesh serving)."""
             from mlcomp_tpu.ops.pallas.decode_attention import (
                 chunk_uses_kernels,
                 decode_attention_chunk,
             )
             from mlcomp_tpu.ops.quant import pallas_mesh
 
-            # verify widths always ride the kernel; WIDE chunks
-            # (admission prefill) ride the query-TILED kernel sweeps
-            # when wide_chunk_mode says so (TPU default) instead of
-            # round-tripping a full bf16 copy of the cache per layer.
-            # chunk_uses_kernels is the SHARED predicate behind the
-            # bench's chunk_attention_route bytes model.
+            # chunks up to CHUNK_MAX_SQ always ride the kernel; WIDE
+            # chunks (admission prefill) ride the query-TILED kernel
+            # sweeps when wide_chunk_mode says so (TPU default) instead
+            # of round-tripping a full bf16 copy of the cache per layer.
             if chunk_uses_kernels(s, mesh=pallas_mesh() is not None):
                 qp = (
                     jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, dhp - dh)))
@@ -812,41 +744,13 @@ class SelfAttention(nn.Module):
             # each row's K/V go to its own slot(s), window per row.
             cur = jnp.asarray(cache_cursor).astype(jnp.int32)
             row_start = _window_start(kv_mask, b)
-            if s == 1:
-                # the step every engine dispatch takes: the kernel
-                # appends the token where it attends it, for the rows
-                # that hold a window and for no other
-                return flash(
-                    self._window_lo(row_start, cur + 1), cur + 1,
-                    append=True,
-                )
-            # s > 1 (round 5) is the engine's speculative verify — the
-            # multi-query kernel takes per-row stop0 directly.  Per-row
-            # DUS, not scatter (_row_cursor_dus; the scatter lowering
-            # copied the full int8 buffers every step)
-            sdt = cks.value.dtype
-            kqt = kq.transpose(0, 2, 1, 3)          # (B, Hkv, s, dhp)
-            vqt = vq.transpose(0, 2, 1, 3)
-            ckq.value = _row_cursor_dus(ckq.value, kqt, cur, 2)
-            cvq.value = _row_cursor_dus(cvq.value, vqt, cur, 2)
-            # s scale slots per row via a masked select: gather each
-            # slot's scale from its position relative to the row's
-            # cursor (dense over L — s is tiny and the select is one
-            # fused full-buffer pass; the scale caches are lane-minor,
-            # so a one-lane DUS is a relayout copy of the row)
-            sl = jnp.arange(l_buf, dtype=jnp.int32)
-            rel = sl[None, :] - cur[:, None]        # (B, L)
-            hit = ((rel >= 0) & (rel < s))[:, None, None, :]
-            relc = jnp.clip(rel, 0, s - 1)
-            ks_dense = jnp.take_along_axis(
-                ks_.transpose(0, 2, 1), relc[:, None, :], axis=2
-            )[:, :, None, :]                        # (B, Hkv, 1, L)
-            vs_dense = jnp.take_along_axis(
-                vs_.transpose(0, 2, 1), relc[:, None, :], axis=2
-            )[:, :, None, :]
-            cks.value = jnp.where(hit, ks_dense.astype(sdt), cks.value)
-            cvs.value = jnp.where(hit, vs_dense.astype(sdt), cvs.value)
-            return chunk_attend(row_start, cur + 1)
+            # the step every engine dispatch takes: the kernel appends
+            # the token where it attends it, for the rows that hold a
+            # window and for no other
+            return flash(
+                self._window_lo(row_start, cur + 1), cur + 1,
+                append=True,
+            )
         if s == 1:
             # single-token step under ONE cursor (bare ``generate``;
             # no cell runs it): every row writes the same slot, so one

@@ -63,9 +63,8 @@ DEFAULT_SLOS: Dict[str, Dict[str, Any]] = {
         "kind": "ratio",
         "bad": "mlcomp_serving_requests_rejected_total",
         # accepted requests live in the ENGINE counter on the
-        # continuous batcher and the SERVICE counter on window/
-        # speculative ones (each daemon publishes exactly one of the
-        # two) — sum both so a lone 429 on a window daemon is a ratio,
+        # continuous batcher and the SERVICE counter on the window
+        # one (each daemon publishes exactly one of the two) — sum both so a lone 429 on a window daemon is a ratio,
         # not a guaranteed 1.0 breach
         "total": ["mlcomp_serving_requests_rejected_total",
                   "mlcomp_engine_requests_total",

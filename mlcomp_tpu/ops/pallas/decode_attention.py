@@ -47,7 +47,7 @@ a jnp ``k8 * ks`` prefix materializes the bf16 copy in HBM every step
   operation a layer whose cost follows the live rows, where a loop of
   update-slices over every slot row was a quarter of the step (ledger,
   PR 28 against PR 29).  The multi-query chunk
-  kernel and the paged twins still sweep a (B, L/BLK) BlockSpec grid
+  kernel and the paged kernel still sweep a (B, L/BLK) BlockSpec grid
   of fat blocks (``KV_BLOCK_BUDGET``): blocks outside a row's window
   are clamped in the index maps to the nearest live block of that row,
   so the pipeline elides the copy, and their compute is
@@ -147,8 +147,8 @@ def _flash_block_update(
 ):
     """ONE online-softmax block update — the arithmetic core every
     kernel in this family (dense single-token, dense multi-query, and
-    their PAGED twins) shares.  Factoring it is what makes the paged
-    kernels bit-identical to the dense ones BY CONSTRUCTION: same ops,
+    the PAGED single-token one) shares.  Factoring it is what makes the
+    paged kernel bit-identical to the dense one BY CONSTRUCTION: same ops,
     same shapes, same accumulation order — only where the K/V block's
     bytes came from differs (BlockSpec copy vs table-driven page DMA).
 
@@ -567,12 +567,12 @@ def _kernel_chunk(
     window: Optional[int] = None,
 ):
     """Multi-query flash-decode: S query tokens per row in one pass over
-    the int8 cache (the speculative verify / small-chunk shape).
+    the int8 cache (the chunked-prefill shape).
 
     Query tokens ride the SUBLANE axis next to their GQA group —
     row r = j * rep + g is query j, group head g — so the cache block
-    is read ONCE for all S queries (the whole point: a verify of K+1
-    tokens costs one cache sweep, not K+1).  Causality is per sublane
+    is read ONCE for all S queries (the whole point: a chunk of S
+    tokens costs one cache sweep, not S).  Causality is per sublane
     row: query j's window is [start, stop0 + j) where stop0 is query
     0's exclusive stop (its own cache slot + 1).  With ``window`` the
     start is per sublane row too: query j sees its last ``window``
@@ -638,14 +638,11 @@ CHUNK_MAX_SQ = 32
 def wide_chunk_mode() -> str:
     """``MLCOMP_TPU_WIDE_CHUNK``: how chunk attention WIDER than the
     multi-query kernel tile (S > CHUNK_MAX_SQ — admission prefill
-    chunks, spec_k >= 32) runs against an int8 KV cache.
+    chunks) runs against an int8 KV cache.
 
     - ``pallas``: query-TILED flash-kernel sweeps — ceil(S/32) passes
       over the live window, dequant in VMEM, no full-buffer bf16
-      materialization.  On the paged path the tiles stream pages
-      through the table (``paged_decode_attention_chunk``), so an
-      overlapped admission's chunk stops paying the per-layer
-      barrier-gather + dense-dequant round trip;
+      materialization;
     - ``xla``: the dequantize-the-whole-buffer XLA path (the PR-5
       reference — bandwidth-amortized at prefill widths, but it
       round-trips a full bf16 copy of the cache through HBM per layer
@@ -670,74 +667,13 @@ def wide_chunk_mode() -> str:
 
 
 def chunk_uses_kernels(s_q: int, mesh: bool = False) -> bool:
-    """Kernel-vs-XLA half of the chunk routing — the SHARED predicate
-    the transformer's int8 chunk-attention branches and
-    :func:`chunk_attention_route` both consult, so the bench's
-    route-aware acceptance can never drift from the real data path:
-    verify widths always ride the kernels; wider chunks do when
-    :func:`wide_chunk_mode` says so; mesh-sharded serving never does
-    (the kernels are single-chip)."""
+    """Kernel-vs-XLA routing of the transformer's int8 chunk
+    attention: chunks up to ``CHUNK_MAX_SQ`` always ride the kernels;
+    wider chunks do when :func:`wide_chunk_mode` says so; mesh-sharded
+    serving never does (the kernels are single-chip)."""
     if mesh:
         return False
     return s_q <= CHUNK_MAX_SQ or wide_chunk_mode() == "pallas"
-
-
-def chunk_attention_route(s_q: int, l_buf: int, h_kv: int, dh: int,
-                          page_tokens: Optional[int] = None,
-                          mesh: bool = False) -> str:
-    """The data path an ``s_q``-wide int8-KV chunk attention takes —
-    the single source of truth behind the transformer's routing and
-    bench's route-aware bytes model.  Returns one of:
-
-    - ``kernel``        dense flash kernel(s), query-tiled past 32
-    - ``kernel_paged``  paged flash kernel(s): pages stream through
-                        the table, no dense view (eligible geometry)
-    - ``kernel_gather`` per-layer page gather feeding the DENSE
-                        kernels (paged, ineligible geometry)
-    - ``xla_dequant``   full-buffer dequantize in XLA (wide chunks
-                        off-TPU, and any mesh-sharded serving)
-    - ``gather_xla_dequant``  the same, on a gathered dense view
-                        (paged + wide + off-TPU)
-    """
-    paged = page_tokens is not None
-    if not chunk_uses_kernels(s_q, mesh=mesh):
-        return "gather_xla_dequant" if paged else "xla_dequant"
-    if not paged:
-        return "kernel"
-    if paged_block_kv(l_buf, h_kv, dh, page_tokens) is not None:
-        return "kernel_paged"
-    return "kernel_gather"
-
-
-def chunk_attention_bytes(s_q: int, l_buf: int, h_kv: int, dh: int,
-                          route: str, window: Optional[int] = None,
-                          scale_bytes: int = 2) -> int:
-    """Modeled HBM bytes ONE layer's chunk attention moves for the
-    K/V operands under ``route`` — the admission-side cost model the
-    bench's route-aware arm reports (weights/activations are
-    route-invariant and excluded).  ``window`` is the live span the
-    kernels actually sweep (kernel routes read only it; the XLA
-    routes touch the whole buffer)."""
-    win = l_buf if window is None else int(window)
-    q8 = 2 * h_kv * dh            # K+V int8 bytes per slot
-    sc = 2 * scale_bytes          # K+V scale bytes per slot
-    if route in ("kernel", "kernel_paged"):
-        tiles = max(1, -(-s_q // CHUNK_MAX_SQ))
-        return tiles * win * (q8 + sc)
-    if route == "kernel_gather":
-        # per-layer gather materializes the dense int8 view (read
-        # pages + write view), then the tiled kernels sweep it
-        tiles = max(1, -(-s_q // CHUNK_MAX_SQ))
-        return l_buf * 2 * (q8 + sc) + tiles * win * (q8 + sc)
-    bf16 = 2 * h_kv * dh * 2      # K+V bf16 dequant copy per slot
-    base = l_buf * (q8 + sc)      # read the quant buffers once
-    base += l_buf * 2 * bf16      # write the bf16 copy + read it back
-    if route == "gather_xla_dequant":
-        base += l_buf * 2 * (q8 + sc)   # the gather round trip first
-        return base
-    if route == "xla_dequant":
-        return base
-    raise ValueError(f"unknown chunk-attention route {route!r}")
 
 
 def decode_attention_chunk(
@@ -761,9 +697,9 @@ def decode_attention_chunk(
     q: (B, S, H, dh) chunk queries whose K/V are ALREADY written to the
     cache at slots [stop0-1+j for j in range(S)]... i.e. query j sits
     at cache slot ``kv_stop0 - 1 + j`` and attends [kv_start,
-    kv_stop0 + j).  The speculative verify and small chunked-decode
-    shape (models/speculative.py; transformer._decode_attention_quant
-    routes here for S <= CHUNK_MAX_SQ).  The single-token kernel is the
+    kv_stop0 + j).  The chunked-prefill shape
+    (transformer._decode_attention_quant routes here where
+    ``chunk_uses_kernels``).  The single-token kernel is the
     S == 1 special case (kv_stop0 == its kv_stop).
 
     Layout and masking follow :func:`decode_attention`; the only new
@@ -878,7 +814,7 @@ def decode_attention_chunk(
 
 # ---------------------------------------------------------------- paged
 #
-# The PAGED twins of the two kernels above (mlcomp_tpu/kvpool): K/V
+# The PAGED twin of the single-token kernel (mlcomp_tpu/kvpool): K/V
 # live in (num_pages, Hkv, T, dh) page arrays addressed through a
 # per-slot page table, and the kernels read them THROUGH the table —
 # the table rides the scalar prefetch, and each grid step DMAs its
@@ -949,38 +885,6 @@ def paged_fetch_mode() -> str:
     if mode == "auto":
         mode = "double" if on_tpu() else "rolled"
     return mode
-
-
-def paged_fetch_cost_model(l_buf: int, h_kv: int, dh: int,
-                           page_tokens: int,
-                           window: Optional[int] = None,
-                           itemsize: int = 1,
-                           scale_bytes: int = 2) -> dict:
-    """Analytic per-row cost model for the two fetch modes (the
-    CPU-container stand-in for a real-TPU profile, next to the
-    engine's ``kv_bytes_moved_per_dispatch``): bytes are identical —
-    what differs is how many block-fetches sit on the critical path.
-    ``rolled`` serializes every live block's DMA before its compute
-    (exposed_block_fetches = live blocks); ``double`` exposes only the
-    first live block's fetch and overlaps the rest behind
-    ``_flash_block_update`` (exposed = 1).  Real-TPU tuning of the
-    overlap is the documented follow-up (this container is CPU-only).
-    """
-    blk = paged_block_kv(l_buf, h_kv, dh, page_tokens)
-    if blk is None:
-        return {"eligible": False}
-    win = l_buf if window is None else int(window)
-    live_blocks = max(1, -(-win // blk))
-    block_bytes = 2 * h_kv * blk * (dh * itemsize + scale_bytes)
-    return {
-        "eligible": True,
-        "block_kv": blk,
-        "pages_per_block": blk // page_tokens,
-        "live_blocks": live_blocks,
-        "block_fetch_bytes": block_bytes,
-        "fetch_bytes_per_row": block_bytes * live_blocks,
-        "exposed_block_fetches": {"rolled": live_blocks, "double": 1},
-    }
 
 
 def _fetch_block_pages(
@@ -1266,7 +1170,7 @@ def _paged_call(
     kernel, q, kq_pages, ks_pages, vq_pages, vs_pages, table,
     start, stop, interpret: bool, fetch: Optional[str] = None,
 ):
-    """Shared pallas_call plumbing for the two paged kernels: grid
+    """The paged kernel's pallas_call plumbing: grid
     (B, nk) over dense-sized blocks, table prefetched as the third
     scalar, page arrays pinned in HBM (ANY), block scratch + online
     state in VMEM.  ``fetch`` picks the page-DMA schedule (default:
@@ -1334,7 +1238,7 @@ def _paged_call(
         ),
         out_shape=jax.ShapeDtypeStruct((b, h_kv, sp, dh), q.dtype),
         interpret=interpret,
-        # decode_attention_paged_kernel[_chunk] in device traces
+        # decode_attention_paged_kernel in device traces
         name="decode_attention" + kernel.func.__name__,
     )(start, stop, table, q, kq_pages, ks_pages, vq_pages, vs_pages)
 
@@ -1416,150 +1320,6 @@ def paged_decode_attention(
         table.astype(jnp.int32), start, stop, interpret, fetch=fetch,
     )
     return out[:, :, :rep].reshape(b, h, dh)
-
-
-def _paged_kernel_chunk(
-    start_ref, stop0_ref, tbl_ref,  # scalar prefetch
-    q_ref, kq_hbm, ks_hbm, vq_hbm, vs_hbm,
-    o_ref,
-    *scratch,
-    scale: float, block_kv: int, page_tokens: int,
-    pages_per_block: int, null_page: int, rep: int, s_q: int,
-    fetch: str,
-):
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    nk = pl.num_programs(1)
-    acc_ref, m_ref, l_ref = scratch[-3:]
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-
-    lo = start_ref[b]
-    stop0 = stop0_ref[b]
-    hi_max = stop0 + (s_q - 1)
-
-    def live_fn(jb):
-        return (jb * block_kv < hi_max) & ((jb + 1) * block_kv > lo)
-
-    def mask_fn(shape):
-        cols = j * block_kv + jax.lax.broadcasted_iota(jnp.int32, shape, 2)
-        qrow = jnp.minimum(
-            jax.lax.broadcasted_iota(jnp.int32, shape, 1) // rep,
-            s_q - 1,
-        )
-        return (cols >= lo) & (cols < stop0 + qrow)
-
-    def compute(bufs):
-        k_buf, ks_buf, v_buf, vs_buf, _sem = bufs
-        q = q_ref[0]                               # (Hkv, Sp, dh)
-        _flash_block_update(
-            q, k_buf[:].astype(q.dtype), ks_buf[:][:, None, :],
-            v_buf[:].astype(q.dtype), vs_buf[:][:, None, :],
-            mask_fn, scale, acc_ref, m_ref, l_ref,
-        )
-
-    if fetch == "double":
-        bufs0, bufs1 = scratch[0:5], scratch[5:10]
-        _db_fetch_step(
-            tbl_ref, b, j, nk, lo, hi_max, live_fn, compute,
-            bufs0, bufs1, kq_hbm, ks_hbm, vq_hbm, vs_hbm,
-            page_tokens=page_tokens, pages_per_block=pages_per_block,
-            null_page=null_page,
-        )
-    else:
-        bufs = scratch[0:5]
-
-        @pl.when(live_fn(j))
-        def _step():
-            _fetch_block_pages(
-                tbl_ref, b, j, lo, hi_max, bufs[4],
-                kq_hbm, ks_hbm, vq_hbm, vs_hbm,
-                bufs[0], bufs[1], bufs[2], bufs[3],
-                page_tokens=page_tokens,
-                pages_per_block=pages_per_block, null_page=null_page,
-            )
-            compute(bufs)
-
-    @pl.when(j == nk - 1)
-    def _finalize():
-        _flash_finalize(o_ref, acc_ref, l_ref)
-
-
-def paged_decode_attention_chunk(
-    q: jax.Array,
-    kq_pages: jax.Array,
-    ks_pages: jax.Array,
-    vq_pages: jax.Array,
-    vs_pages: jax.Array,
-    table: jax.Array,
-    kv_start: Optional[jax.Array] = None,
-    kv_stop0: Optional[jax.Array] = None,
-    scale: Optional[float] = None,
-    interpret: Optional[bool] = None,
-    fetch: Optional[str] = None,
-) -> jax.Array:
-    """:func:`decode_attention_chunk` through a page table: S chunk
-    queries per row, ONE table-driven sweep of the paged cache (the
-    speculative-verify shape).  q (B, S, H, dh); pages/table as
-    :func:`paged_decode_attention`; per-row causal stops
-    [kv_start, kv_stop0 + j) like the dense chunk kernel."""
-    b, s_q, h, dh_q = q.shape
-    h_kv, T, dh = _check_paged_operands(
-        h, kq_pages, ks_pages, vq_pages, vs_pages, table
-    )
-    if dh_q != dh:
-        raise ValueError(f"q head dim {dh_q} != page head dim {dh}")
-    if s_q > CHUNK_MAX_SQ:
-        # query-tiled wide chunk, paged flavor: each tile streams the
-        # live window's pages through the table once (see the dense
-        # twin above for the exactness argument)
-        l_buf_w = table.shape[1] * T
-        stop0 = (
-            jnp.full((b,), l_buf_w - s_q + 1, jnp.int32)
-            if kv_stop0 is None
-            else jnp.broadcast_to(kv_stop0, (b,)).astype(jnp.int32)
-        )
-        return jnp.concatenate([
-            paged_decode_attention_chunk(
-                q[:, o:o + CHUNK_MAX_SQ], kq_pages, ks_pages, vq_pages,
-                vs_pages, table, kv_start=kv_start, kv_stop0=stop0 + o,
-                scale=scale, interpret=interpret, fetch=fetch,
-            )
-            for o in range(0, s_q, CHUNK_MAX_SQ)
-        ], axis=1)
-    if interpret is None:
-        interpret = interpret_default()
-    l_buf = table.shape[1] * T
-    scale = scale if scale is not None else 1.0 / (dh**0.5)
-
-    rep = h // h_kv
-    rows = s_q * rep
-    sp = max(SUBLANES, -(-rows // SUBLANES) * SUBLANES)
-    qg = q.reshape(b, s_q, h_kv, rep, dh).transpose(0, 2, 1, 3, 4)
-    qg = qg.reshape(b, h_kv, rows, dh)
-    if sp != rows:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, sp - rows), (0, 0)))
-
-    start = (
-        jnp.zeros((b,), jnp.int32) if kv_start is None
-        else kv_start.astype(jnp.int32)
-    )
-    stop0 = (
-        jnp.full((b,), l_buf - s_q + 1, jnp.int32) if kv_stop0 is None
-        else jnp.broadcast_to(kv_stop0, (b,)).astype(jnp.int32)
-    )
-    out = _paged_call(
-        functools.partial(_paged_kernel_chunk, scale=scale, rep=rep,
-                          s_q=s_q),
-        qg, kq_pages, ks_pages, vq_pages, vs_pages,
-        table.astype(jnp.int32), start, stop0, interpret, fetch=fetch,
-    )
-    out = out[:, :, :rows].reshape(b, h_kv, s_q, rep, dh)
-    return out.transpose(0, 2, 1, 3, 4).reshape(b, s_q, h, dh)
 
 
 def sharded_decode_attention(

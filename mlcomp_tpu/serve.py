@@ -19,7 +19,8 @@ replacing it still needs to SERVE the model they trained.  This daemon
   never blocks a later arrival — see mlcomp_tpu/engine.py.  The
   round-3 WINDOW batcher (requests within a small window decode
   together through one ``generate`` scan; zero per-token dispatches)
-  remains available as ``batcher="window"`` and is the mesh default;
+  remains available as ``batcher="window"`` (continuous is the
+  default, mesh or not);
 - **weight residency**: weights load once, optionally int8-quantized
   with the Pallas kernel consuming them directly (``--quantize kernel``,
   the measured B=1 win) or pre-cast to bf16;
@@ -207,8 +208,6 @@ class GenerationService:
         batcher: str = "auto",
         steps_per_dispatch: "Optional[int | str]" = None,
         prefill_chunk: int = 256,
-        spec_k: int = 8,
-        engine_spec_k: Optional[int] = None,
         prefix_cache: bool = False,
         prefix_cache_bytes: int = 1 << 31,
         engine_pipeline_depth: Optional[int] = None,
@@ -331,9 +330,7 @@ class GenerationService:
                 self.knobs["quant_kernel"] = True
         self.variables = variables
         self._rng = jax.random.PRNGKey(seed)  # guarded_by: batcher [writes]
-        # window keys are (b, s, n_new) int triples; the speculative
-        # batcher uses ("spec", s, n_new) — the two never coexist in
-        # one service (stats() sorts the keys, which would mix types)
+        # window keys are (b, s, n_new) int triples
         self._fns: Dict[Tuple[Any, ...], Any] = {}
         self._queue: "queue.Queue" = queue.Queue()
         self._deferred: List[Dict[str, Any]] = []  # guarded_by: batcher [writes]
@@ -385,11 +382,6 @@ class GenerationService:
                     "are named follow-ups); drop --mesh/--distributed "
                     "or phase"
                 )
-        if self.phase == "prefill" and engine_spec_k is not None:
-            raise ValueError(
-                "a prefill replica runs no decode dispatch; drop "
-                "engine_spec_k"
-            )
         if self.phase == "decode" and kv_layout != "paged":
             raise ValueError(
                 "phase='decode' needs kv_layout='paged': handoff "
@@ -461,65 +453,14 @@ class GenerationService:
         # round-3 request-granularity batcher: one generate() per
         # arrival window — zero per-token dispatches, the right tool
         # for offline batch generation.
-        # "speculative" (round 5) = B=1 latency mode: each request runs
-        # the device-resident speculative loop (n-gram prompt-lookup
-        # draft + K+1-wide verify, models/speculative.py) — the right
-        # tool for a single interactive stream on repetitive text;
-        # greedy-only, single-chip, one request per program.
         if batcher == "auto":
             batcher = "continuous"
-        if batcher not in ("continuous", "window", "speculative"):
+        if batcher not in ("continuous", "window"):
             raise ValueError(
-                f"batcher: expected 'auto'/'continuous'/'window'/"
-                f"'speculative', got {batcher!r}"
+                f"batcher: expected 'auto'/'continuous'/'window', "
+                f"got {batcher!r}"
             )
         self.batcher = batcher
-        self.spec_k = int(spec_k)
-        if batcher == "speculative":
-            if self.spec_k < 1:
-                raise ValueError(f"spec_k must be >= 1, got {spec_k}")
-            if mesh is not None:
-                raise ValueError(
-                    "the speculative batcher is single-chip (B=1 latency "
-                    "mode); use the continuous batcher under a mesh"
-                )
-            if self.defaults["temperature"] != 0.0:
-                raise ValueError(
-                    "the speculative batcher is greedy-only; set the "
-                    "service default temperature to 0"
-                )
-            if self.defaults["repetition_penalty"] != 1.0:
-                # reject at construction like temperature: otherwise
-                # every defaults-only request fails at submit blaming
-                # a knob the client never passed
-                raise ValueError(
-                    "repetition_penalty is not supported by the "
-                    "speculative batcher; drop the service default"
-                )
-            # one request per program — B=1 by design (throughput cases
-            # want the continuous engine); requests never co-batch
-            self.batch_sizes = (1,)
-            self._stats["spec_tokens"] = 0
-            self._stats["spec_forwards"] = 0
-        if engine_spec_k is not None:
-            # BATCHED speculative decoding (round 5, opt-in): the
-            # continuous engine's dispatch becomes a per-row-cursor
-            # verify — up to K+1 tokens per row per dispatch for ~one
-            # step's cost.  Greedy-only fleet: validate the defaults
-            # here so a misconfigured service fails at construction,
-            # not on every defaults-only request.
-            if batcher != "continuous":
-                raise ValueError(
-                    "engine_spec_k needs the continuous batcher"
-                )
-            if self.defaults["temperature"] != 0.0 or (
-                self.defaults["repetition_penalty"] != 1.0
-            ):
-                raise ValueError(
-                    "engine_spec_k engines are greedy-only: service "
-                    "defaults must keep temperature 0 and "
-                    "repetition_penalty 1"
-                )
         if engine_pipeline_depth is not None and (
             int(engine_pipeline_depth) > 1 and batcher != "continuous"
         ):
@@ -563,10 +504,8 @@ class GenerationService:
             # loop picks K per boundary from the live queue-depth /
             # occupancy signals (shallow queues small K for TTFT, deep
             # queues large K for dispatch amortization).  An explicit
-            # --engine-steps-per-dispatch PINS K (the bisect override);
-            # spec engines never read the knob (the verify replaces
-            # the scan).
-            if steps_per_dispatch is None and engine_spec_k is None:
+            # --engine-steps-per-dispatch PINS K (the bisect override).
+            if steps_per_dispatch is None:
                 steps_per_dispatch = "adaptive"
             self.engine = DecodeEngine(
                 model, self.variables,
@@ -579,7 +518,6 @@ class GenerationService:
                 steps_per_dispatch=steps_per_dispatch,
                 prefill_chunk=prefill_chunk,
                 mesh=mesh,
-                spec_k=engine_spec_k,
                 prefix_cache=self.prefix_cache,
                 pipeline_depth=engine_pipeline_depth,
                 fused_admission=engine_fused_admission,
@@ -725,25 +663,6 @@ class GenerationService:
         # request errors, not batcher crashes
         _bucket(len(ids), self.prompt_buckets, "prompt length")
         nb = _bucket(n_new, self.max_new_buckets, "max_new_tokens")
-        if self.batcher == "speculative":
-            # the device-resident speculative loop is greedy-only and
-            # emits no per-token host boundaries to stream or score at
-            if t != 0.0:
-                raise ValueError(
-                    "the speculative batcher is greedy-only "
-                    "(temperature 0); use the continuous batcher for "
-                    "sampling"
-                )
-            if rp != 1.0:
-                raise ValueError(
-                    "repetition_penalty is not supported by the "
-                    "speculative batcher"
-                )
-            if logprobs:
-                raise ValueError(
-                    "logprobs are not supported by the speculative "
-                    "batcher"
-                )
         if self.engine is not None:
             self._admission_check(ids, n_new)
             # per-request deadlines may only TIGHTEN the operator's
@@ -772,7 +691,7 @@ class GenerationService:
             )
         self._stats["requests"] += 1
         fut: Future = Future()
-        # window/speculative requests carry a trace id too — no
+        # window requests carry a trace id too — no
         # flight recorder to thread it through, but the response echo
         # keeps the cross-daemon contract uniform
         tid = trace_id if trace_id is not None else make_trace_id()
@@ -1048,20 +967,6 @@ class GenerationService:
                     + self.engine.warm_dispatch_fns()
                     + self.engine.warm_fused_fns()
                     + self.engine.warm_export_fns())
-        if self.batcher == "speculative":
-            import jax.numpy as jnp
-
-            n = 0
-            for s in self.prompt_buckets:
-                for nb in self.max_new_buckets:
-                    row, mask = left_pad_row([1], s, self.pad_id)
-                    out, _ = self._get_spec_fn(s, nb)(
-                        self.variables, jnp.asarray(row[None]),
-                        jnp.asarray(mask[None]), jnp.int32(-1),
-                    )
-                    int(out[0, -1])
-                    n += 1
-            return n
         n = 0
         s = self.prompt_buckets[-1]
         # smallest + largest SERVABLE batch (1 may not be a bucket
@@ -1104,7 +1009,7 @@ class GenerationService:
             "compiled": sorted(self._fns),
             "quantize": self.quant_mode,
             "batcher": self.batcher,
-            # window/speculative batchers have no watchdog: a live
+            # the window batcher has no watchdog: a live
             # batcher thread is the whole health story
             "healthy": True,
             "rejected": dict(self._rejects),
@@ -1133,12 +1038,6 @@ class GenerationService:
             # payload and the report server's /api/serving proxy read
             # them without digging through the engine section
             out["latency"] = eng.get("latency")
-            if "spec" in eng:
-                # the spec-honesty block rides at the top level too:
-                # operators watching /healthz see spec_net_gain (<= 0:
-                # the --engine-spec-k knob is a measured loss) without
-                # digging through the engine section
-                out["spec"] = eng["spec"]
             if "kv_pool" in eng:
                 # paged-KV occupancy at the top level: /healthz readers
                 # (and the report proxy) see pages free/used and the
@@ -1181,8 +1080,8 @@ class GenerationService:
 
     def _collect_metrics(self) -> None:
         """Scrape-time collector for the service-level counters (the
-        engine registers its own; window/speculative batchers have
-        only these)."""
+        engine registers its own; the window batcher has only
+        these)."""
         m = self.metrics
         st = self._stats
         m.gauge(
@@ -1199,7 +1098,7 @@ class GenerationService:
             rej.set_total(n, reason=reason)
         m.counter(
             "mlcomp_service_batches_total",
-            "Batches run (window/speculative batchers)",
+            "Batches run (window batcher)",
         ).set_total(st["batches"])
         m.counter(
             "mlcomp_service_batched_rows_total",
@@ -1210,7 +1109,7 @@ class GenerationService:
             # queue depth (submit() skips the service-level counter)
             m.counter(
                 "mlcomp_service_requests_total",
-                "Requests submitted (window/speculative batchers)",
+                "Requests submitted (window batcher)",
             ).set_total(st["requests"])
             m.gauge(
                 "mlcomp_service_queue_depth",
@@ -1429,10 +1328,7 @@ class GenerationService:
                 if not batch:
                     continue
                 try:
-                    if self.batcher == "speculative":
-                        self._run_spec(batch[0])  # batch_sizes == (1,)
-                    else:
-                        self._run_batch(batch)
+                    self._run_batch(batch)
                 except Exception as e:  # surface to the waiting requests
                     for item in batch:
                         if not item["future"].done():
@@ -1451,55 +1347,6 @@ class GenerationService:
                 except queue.Empty:
                     break
                 _fail_future(item["future"], err)
-
-    def _get_spec_fn(self, s_bucket: int, n_bucket: int):
-        import jax
-
-        from mlcomp_tpu.models.speculative import speculative_generate
-
-        key = ("spec", s_bucket, n_bucket)
-        if key not in self._fns:
-            def run(variables, prompt, mask, eos):
-                # eos rides TRACED (-1 = none: no vocab id matches), so
-                # one program per (prompt, new) bucket serves every
-                # request; the budget is the bucket (static shape), the
-                # host trims to the request's n_new like _run_batch
-                return speculative_generate(
-                    self.model, variables, prompt, n_bucket,
-                    prompt_mask=mask, spec_k=self.spec_k, eos_id=eos,
-                    pad_id=self.pad_id,
-                    quant_kernel=bool(self.knobs.get("quant_kernel")),
-                    with_stats=True,
-                )
-
-            self._fns[key] = jax.jit(run)
-        return self._fns[key]
-
-    def _run_spec(self, item: Dict[str, Any]) -> None:  # graftcheck: runs-on(batcher)
-        """One request through the device-resident speculative loop
-        (speculative batcher): prefill + ngram-draft + K+1-wide verify
-        entirely on device — a single dispatch per request."""
-        import jax.numpy as jnp
-
-        t0 = time.perf_counter()
-        s_bucket = _bucket(len(item["ids"]), self.prompt_buckets, "prompt")
-        row, mask = left_pad_row(item["ids"], s_bucket, self.pad_id)
-        fn = self._get_spec_fn(s_bucket, item["bucket_new"])
-        out, stats = fn(
-            self.variables, jnp.asarray(row[None]), jnp.asarray(mask[None]),
-            jnp.int32(item.get("eos_id", -1)),
-        )
-        gen = _trim_generated(np.asarray(out)[0], s_bucket, item)
-        self._stats["batches"] += 1
-        self._stats["batched_rows"] += 1
-        self._stats["spec_tokens"] += int(stats["emitted"])
-        self._stats["spec_forwards"] += int(stats["steps"])
-        item["future"].set_result({
-            "ids": gen,
-            "latency_ms": round((time.perf_counter() - t0) * 1e3, 2),
-            "batched_with": 1,
-            "trace_id": item.get("trace_id"),
-        })
 
     def _run_batch(self, batch: List[Dict[str, Any]]) -> None:  # graftcheck: runs-on(batcher)
         import jax

@@ -1,8 +1,7 @@
 """Where JAX's persistent compilation cache lives.
 
 A 1.2B train step or serve dispatch compiles for tens of seconds, and
-every process (``cli dag``, each ``serve`` daemon, ``bench.py``, task
-children) used to pay that from cold.  The cache directory is part of
+every process (``cli dag``, each ``serve`` daemon, task children) used to pay that from cold.  The cache directory is part of
 the cache key, so it must never move: no mkdtemp, pid or timestamp.
 
 ``JAX_COMPILATION_CACHE_DIR`` set from outside wins and nothing else is
@@ -10,8 +9,8 @@ set in code (JAX reads the variable itself).  Unset, the cache sits at
 one fixed directory inside the checkout, exported through the same
 variable so child processes land in the same place.
 
-Called from process entry points only (``cli.main``, ``scheduler.child``,
-``bench.py``) — never at import of the package.
+Called from process entry points only (``cli.main``,
+``scheduler.child``) — never at import of the package.
 """
 
 from __future__ import annotations

@@ -206,20 +206,6 @@ def test_paged_decode_attention_page128(chip, fetch):
     )
 
 
-@pytest.mark.parametrize("fetch", ["double", "rolled"])
-def test_paged_decode_attention_chunk_page128(chip, fetch):
-    from mlcomp_tpu.ops.pallas.decode_attention import (
-        paged_decode_attention_chunk,
-    )
-
-    _compiles_to_a_kernel(
-        functools.partial(
-            paged_decode_attention_chunk, interpret=False, fetch=fetch
-        ),
-        chip((B, 5, H, DH), jnp.bfloat16), *_paged_pool(chip),
-    )
-
-
 @pytest.mark.parametrize("leaf", ["int8_kv", "bf16_kv", "bf16_scale"])
 def test_page_gather_page128(chip, leaf):
     from mlcomp_tpu.kvpool.layout import _gather_leaf_pallas

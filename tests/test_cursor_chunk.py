@@ -1,10 +1,11 @@
-"""Per-row-cursor multi-token decode (``cache_cursor`` with s > 1):
-the engine's speculative-verify contract in models/transformer.py.
+"""The per-row-cursor contract of models/transformer.py
+(``cache_cursor``): one token a row, each row at its own depth.
 
-A chunked forward at per-row cursors must produce, position by
-position, the same logits as feeding the same tokens one step at a
-time through the s == 1 cursor path — for both cache modes (bf16 and
-int8 KV; the latter routes the multi-query flash kernel)."""
+Rows at different depths stepped together through the per-row-cursor
+path must produce the logits and the cache each row produces alone
+through the one-cursor (``cache_index``) path — for both cache modes
+(bf16 and int8 KV) and both head layouts — and a chunk (s > 1) under a
+cursor is refused: chunks run under the one ``cache_index``."""
 
 import jax
 import jax.numpy as jnp
@@ -13,100 +14,101 @@ import pytest
 
 from mlcomp_tpu.models import create_model
 from mlcomp_tpu.models.generation import init_cache
+from mlcomp_tpu.train.state import init_model
+
+L_BUF = 32
+DEPTHS = (6, 4)     # prompt tokens a row holds before the steps
+STEPS = 3
 
 
-def _setup(kv_quant, heads=2, kv_heads=None):
+def _setup(kv_quant, kv_heads=None):
     model = create_model({
         "name": "transformer_lm", "vocab_size": 64, "hidden": 64,
-        "layers": 2, "heads": heads, "mlp_dim": 128, "dtype": "float32",
+        "layers": 2, "heads": 2, "mlp_dim": 128, "dtype": "float32",
         "kv_quant": kv_quant,
         **({"kv_heads": kv_heads} if kv_heads else {}),
     })
     rs = np.random.RandomState(3)
-    prompts = jnp.asarray(rs.randint(1, 64, (2, 6)))
-    params, _ = init_model_params(model, prompts)
-    return model, params, prompts
+    prompts = [jnp.asarray(rs.randint(1, 64, (1, n))) for n in DEPTHS]
+    toks = jnp.asarray(rs.randint(1, 64, (len(DEPTHS), STEPS)))
+    params, _ = init_model(
+        model, {"x": prompts[0]}, jax.random.PRNGKey(0)
+    )
+    return model, params, prompts, toks
 
 
-def init_model_params(model, prompts):
-    from mlcomp_tpu.train.state import init_model
-
-    return init_model(model, {"x": prompts}, jax.random.PRNGKey(0))
+def _prefill(model, params, prompt):
+    n = prompt.shape[1]
+    _, upd = model.apply(
+        {"params": params, "cache": init_cache(model, 1, L_BUF)}, prompt,
+        decode=True, positions=jnp.arange(n, dtype=jnp.int32)[None],
+        mutable=["cache"],
+    )
+    return upd["cache"]
 
 
 @pytest.mark.parametrize("kv_quant", [False, True])
 @pytest.mark.parametrize("kv_heads", [None, 1])
-def test_cursor_chunk_matches_stepwise(kv_quant, kv_heads):
-    model, params, prompts = _setup(kv_quant, kv_heads=kv_heads)
-    b, s0 = prompts.shape
-    l_buf = 32
-    s_chunk = 3
+def test_cursor_step_matches_one_cursor_path(kv_quant, kv_heads):
+    model, params, prompts, toks = _setup(kv_quant, kv_heads)
+    alone = [_prefill(model, params, p) for p in prompts]
 
-    def prefill(cache):
-        pos = jnp.broadcast_to(jnp.arange(s0, dtype=jnp.int32)[None], (b, s0))
-        logits, upd = model.apply(
-            {"params": params, "cache": cache}, prompts, decode=True,
-            positions=pos, mutable=["cache"],
-        )
-        return logits, upd["cache"]
-
-    # rows sit at DIFFERENT depths: advance row 1 by two extra steps
-    # through the s=1 cursor path so cursors diverge
-    rs = np.random.RandomState(9)
-    extra = jnp.asarray(rs.randint(1, 64, (b, 1)))
-    chunk_toks = jnp.asarray(rs.randint(1, 64, (b, s_chunk)))
-
-    def advance_row1(cache, cursors, positions):
-        # row 0's write lands at its own cursor too, but we only CARE
-        # about row 1; keep both rows' tokens identical so row 0's
-        # state stays deterministic across both pipelines
-        for _ in range(2):
-            _, upd = model.apply(
-                {"params": params, "cache": cache}, extra, decode=True,
-                positions=positions[:, None], cache_cursor=cursors,
+    # each row alone, one token at a time under the one cache_index
+    ref_logits, ref_caches = [], []
+    for r, cache in enumerate(alone):
+        row = []
+        for j in range(STEPS):
+            lg, upd = model.apply(
+                {"params": params, "cache": cache}, toks[r:r + 1, j:j + 1],
+                decode=True,
+                positions=jnp.full((1, 1), DEPTHS[r] + j, jnp.int32),
                 mutable=["cache"],
             )
+            row.append(lg[0, 0])
             cache = upd["cache"]
-            cursors = cursors + 1
-            positions = positions + 1
-        return cache, cursors, positions
+        ref_logits.append(jnp.stack(row))
+        ref_caches.append(cache)
 
-    _, cache0 = prefill(init_cache(model, b, l_buf))
-    cursors = jnp.full((b,), s0, jnp.int32)
-    positions = jnp.full((b,), s0, jnp.int32)
-    cache0, cursors, positions = advance_row1(cache0, cursors, positions)
-
-    # pipeline A: one s=3 chunked forward at per-row cursors
-    pos_chunk = positions[:, None] + jnp.arange(s_chunk, dtype=jnp.int32)
-    logits_chunk, updA = model.apply(
-        {"params": params, "cache": cache0}, chunk_toks, decode=True,
-        positions=pos_chunk, cache_cursor=cursors, mutable=["cache"],
+    # the rows together, each at its own cursor
+    cache = jax.tree.map(
+        lambda a, b: a if a.ndim == 0 else jnp.concatenate([a, b]), *alone
     )
-
-    # pipeline B: the same tokens one s=1 step at a time
-    cacheB, curB, posB = cache0, cursors, positions
-    step_logits = []
-    for j in range(s_chunk):
+    cursors = jnp.asarray(DEPTHS, jnp.int32)
+    got = []
+    for j in range(STEPS):
         lg, upd = model.apply(
-            {"params": params, "cache": cacheB}, chunk_toks[:, j:j + 1],
-            decode=True, positions=posB[:, None], cache_cursor=curB,
-            mutable=["cache"],
+            {"params": params, "cache": cache}, toks[:, j:j + 1],
+            decode=True, positions=(cursors + j)[:, None],
+            cache_cursor=cursors + j, mutable=["cache"],
         )
-        step_logits.append(lg[:, 0])
-        cacheB, curB, posB = upd["cache"], curB + 1, posB + 1
-    ref = jnp.stack(step_logits, axis=1)
+        got.append(lg[:, 0])
+        cache = upd["cache"]
+    got = jnp.stack(got, axis=1)
 
-    np.testing.assert_allclose(
-        np.asarray(logits_chunk), np.asarray(ref),
-        atol=3e-2 if kv_quant else 1e-4, rtol=1e-3,
-    )
-    # the caches agree afterwards too (same slots written)
-    for a_leaf, b_leaf in zip(
-        jax.tree.leaves(updA["cache"]), jax.tree.leaves(cacheB)
-    ):
-        if a_leaf.ndim == 0:
-            continue  # cache_index: unused under cursors
+    for r in range(len(DEPTHS)):
         np.testing.assert_allclose(
-            np.asarray(a_leaf, np.float32), np.asarray(b_leaf, np.float32),
-            atol=1e-5,
+            np.asarray(got[r]), np.asarray(ref_logits[r]),
+            atol=1e-4, rtol=1e-4,
+        )
+        # the same slots hold the same K and V afterwards
+        for together, own in zip(
+            jax.tree.leaves(cache), jax.tree.leaves(ref_caches[r])
+        ):
+            if together.ndim == 0:
+                continue  # cache_index: unused under cursors
+            np.testing.assert_allclose(
+                np.asarray(together[r], np.float32),
+                np.asarray(own[0], np.float32), atol=1e-5,
+            )
+
+
+def test_cursor_with_a_chunk_raises():
+    model, params, prompts, toks = _setup(kv_quant=True)
+    cache = _prefill(model, params, prompts[0])
+    with pytest.raises(ValueError, match="single-token"):
+        model.apply(
+            {"params": params, "cache": cache}, toks[:1, :2], decode=True,
+            positions=DEPTHS[0] + jnp.arange(2, dtype=jnp.int32)[None],
+            cache_cursor=jnp.asarray(DEPTHS[:1], jnp.int32),
+            mutable=["cache"],
         )

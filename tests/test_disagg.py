@@ -362,7 +362,6 @@ def test_prefill_only_constructor_contract():
     kw = dict(slots=1, prompt_buckets=(16,), max_new_cap=12,
               prefill_chunk=4)
     for bad in (
-        {"spec_k": 2},
         {"kv_layout": "paged"},
         {"kv_pages": 8},
         {"max_slots": 2},

@@ -315,6 +315,35 @@ def test_engine_slot_churn_keeps_outputs_exact():
         eng.close()
 
 
+def test_engine_buffer_edge_rows_stay_exact():
+    """A max-bucket prompt running its FULL budget sits exactly at the
+    buffer edge — where a retired row's frozen-cursor write would
+    clamp onto its last real K/V without the engine's scratch slot
+    (round-5 DUS semantics).  Outputs must stay exact while other rows
+    keep decoding past the retirement."""
+    model, params = _model_and_params()
+    eng = DecodeEngine(model, {"params": params}, slots=2,
+                       prompt_buckets=(16,), max_new_cap=8)
+    try:
+        rs = np.random.RandomState(11)
+        full = rs.randint(1, 64, 16).tolist()   # fills the top bucket
+        short = rs.randint(1, 64, 5).tolist()
+        fa = eng.submit(full, 8)                # retires at the edge
+        fb = eng.submit(short, 8)
+        assert fa.result(timeout=300)["ids"] == _reference(
+            model, params, full, 8
+        )
+        assert fb.result(timeout=300)["ids"] == _reference(
+            model, params, short, 8
+        )
+        # a second wave reuses the freed slots (insert overwrites any
+        # scratch-slot leftovers)
+        again = eng.submit(full, 8).result(timeout=300)
+        assert again["ids"] == _reference(model, params, full, 8)
+    finally:
+        eng.close()
+
+
 def test_engine_k_step_dispatch_matches_and_bounds_join():
     """K>1 amortizes host dispatch: greedy outputs stay EXACTLY equal to
     bare generate (the inner lax.scan replicates the per-token math),

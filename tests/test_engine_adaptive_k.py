@@ -294,17 +294,15 @@ def _gather_dense_np(pages, table, null_page):
     return out
 
 
-def test_double_buffered_fetch_bit_exact_single_and_chunk():
+def test_double_buffered_fetch_bit_exact():
     """Interpret-mode unit (tentpole 2): the double-buffered page
     fetch is bit-exact vs the rolled fetch AND vs the lax reference
-    (page gather feeding the dense kernel) for both paged kernels,
-    windows clipping blocks on both sides and NULL pages in range."""
+    (page gather feeding the dense kernel), windows clipping blocks on
+    both sides and NULL pages in range."""
     from mlcomp_tpu.kvpool.allocator import NULL_PAGE
     from mlcomp_tpu.ops.pallas.decode_attention import (
         decode_attention,
-        decode_attention_chunk,
         paged_decode_attention,
-        paged_decode_attention_chunk,
     )
 
     rng = np.random.default_rng(0)
@@ -343,37 +341,15 @@ def test_double_buffered_fetch_bit_exact_single_and_chunk():
     )
     assert (np.asarray(o_lax) == np.asarray(o_db)).all()
 
-    # multi-query (chunk) kernels: same three-way equality
-    S = 4
-    qc = rng.standard_normal((B, S, 2 * HKV, DH)).astype(np.float32)
-    stop0 = np.array([397, 327], np.int32)
-    oc_roll = paged_decode_attention_chunk(
-        jnp.asarray(qc), *pages, jt, kv_start=jnp.asarray(start),
-        kv_stop0=jnp.asarray(stop0), interpret=True, fetch="rolled",
-    )
-    oc_db = paged_decode_attention_chunk(
-        jnp.asarray(qc), *pages, jt, kv_start=jnp.asarray(start),
-        kv_stop0=jnp.asarray(stop0), interpret=True, fetch="double",
-    )
-    assert (np.asarray(oc_roll) == np.asarray(oc_db)).all()
-    oc_lax = decode_attention_chunk(
-        jnp.asarray(qc), jnp.asarray(k8), jnp.asarray(ks2),
-        jnp.asarray(v8), jnp.asarray(vs2), kv_start=jnp.asarray(start),
-        kv_stop0=jnp.asarray(stop0), interpret=True,
-    )
-    assert (np.asarray(oc_lax) == np.asarray(oc_db)).all()
-
 
 def test_wide_chunk_query_tiling_matches_untiled_reference():
     """Tentpole 3: a chunk wider than CHUNK_MAX_SQ runs as query-tiled
-    kernel sweeps; each tile's rows must be bit-identical to the
-    per-query single-token kernel at the matching causal stop, dense
-    and paged alike."""
+    kernel sweeps; each tile's rows must match the per-query
+    single-token kernel at the matching causal stop."""
     from mlcomp_tpu.ops.pallas.decode_attention import (
         CHUNK_MAX_SQ,
         decode_attention,
         decode_attention_chunk,
-        paged_decode_attention_chunk,
     )
 
     rng = np.random.default_rng(1)
@@ -428,24 +404,15 @@ def test_wide_chunk_query_tiling_matches_untiled_reference():
         kv_stop0=jnp.asarray(stop0 + CHUNK_MAX_SQ), interpret=True,
     )
     assert (np.asarray(tile2) == wide[:, CHUNK_MAX_SQ:]).all()
-    # paged tiled == dense tiled (both fetch modes)
-    pages = tuple(jnp.asarray(a) for a in (kq, ks, vq, vs))
-    for fetch in ("rolled", "double"):
-        pw = paged_decode_attention_chunk(
-            jnp.asarray(qc), *pages, jnp.asarray(table),
-            kv_start=jnp.asarray(start), kv_stop0=jnp.asarray(stop0),
-            interpret=True, fetch=fetch,
-        )
-        assert (np.asarray(pw) == wide).all(), fetch
 
 
-def test_paged_fetch_mode_env_and_cost_model():
+def test_paged_fetch_mode_env(monkeypatch):
     import mlcomp_tpu.ops.pallas.decode_attention as da
 
     assert da.paged_fetch_mode() in ("double", "rolled")
-    cm = da.paged_fetch_cost_model(512, 2, 128, 128, window=400)
-    assert cm["eligible"]
-    assert cm["exposed_block_fetches"]["double"] == 1
-    assert cm["exposed_block_fetches"]["rolled"] == cm["live_blocks"]
-    bad = da.paged_fetch_cost_model(512 + 128, 2, 128, 96)
-    assert bad == {"eligible": False}
+    for mode in ("double", "rolled"):
+        monkeypatch.setenv("MLCOMP_TPU_PAGED_FETCH", mode)
+        assert da.paged_fetch_mode() == mode
+    monkeypatch.setenv("MLCOMP_TPU_PAGED_FETCH", "both")
+    with pytest.raises(ValueError, match="MLCOMP_TPU_PAGED_FETCH"):
+        da.paged_fetch_mode()

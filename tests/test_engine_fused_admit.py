@@ -52,8 +52,7 @@ def _reference(model, params, ids, n_new, bucket=16, **kw):
 IDS_A = [3, 14, 15, 9, 2]
 IDS_B = [7, 3, 44, 5, 6]
 
-# compiled-program cache across same-config engines (the bench.py
-# sharing idiom): fused/staged/pipeline-depth are host-side knobs, so
+# compiled-program cache across same-config engines: fused/staged/pipeline-depth are host-side knobs, so
 # every engine a workload key builds runs the identical program set —
 # compile once per key instead of once per engine
 _FNS: dict = {}

@@ -1,8 +1,8 @@
 """Paged device KV (engine ``kv_layout="paged"``, mlcomp_tpu/kvpool).
 
 The acceptance contract: paged outputs are BIT-IDENTICAL to the dense
-layout — across cache families (f32 + kv8), pipeline depths, the
-speculative dispatch, mid-stream admissions, and the device
+layout — across cache families (f32 + kv8), pipeline depths,
+mid-stream admissions, and the device
 prefix-registry COW path — while admission is gated by free pages,
 the slot count scales elastically, and nothing leaks a page."""
 
@@ -52,8 +52,7 @@ def _engine(layout, kv_quant=False, fns_key=None, **kw):
     kw.setdefault("slots", 2)
     kw.setdefault("prompt_buckets", (16,))
     kw.setdefault("max_new_cap", 12)
-    if kw.get("spec_k") is None:
-        kw.setdefault("steps_per_dispatch", 2)
+    kw.setdefault("steps_per_dispatch", 2)
     kw.setdefault("prefill_chunk", 4)
     if layout == "paged":
         kw["kv_layout"] = "paged"
@@ -71,23 +70,20 @@ def _close(eng):
     eng.close()
 
 
-def _overlapped(layout, kv_quant=False, depth=2, spec_k=None):
+def _overlapped(layout, kv_quant=False, depth=2):
     """A decodes while B's multi-chunk admission lands mid-stream —
     the same workload shape the fused-admission matrix certifies."""
     model, params = _model_and_params(kv_quant)
-    kw = {}
-    if spec_k is not None:
-        kw = {"spec_k": spec_k, "steps_per_dispatch": 1}
-    # the dispatch family closes over spec_k AND the paged data path
-    # (fused vs lax sandwich) — keep each in its own compiled pool
+    # the dispatch family closes over the paged data path (fused vs
+    # lax sandwich) — keep each in its own compiled pool
     attn = os.environ.get("MLCOMP_TPU_PAGED_ATTN", "auto")
-    eng = _engine(layout, kv_quant, fns_key=("mtx", spec_k, attn),
-                  pipeline_depth=depth, **kw)
+    eng = _engine(layout, kv_quant, fns_key=("mtx", attn),
+                  pipeline_depth=depth)
     try:
         qa: "queue.Queue" = queue.Queue()
-        fa = eng.submit(IDS_A, 10, logprobs=spec_k is None, stream=qa)
+        fa = eng.submit(IDS_A, 10, logprobs=True, stream=qa)
         qa.get(timeout=300)                   # A is decoding
-        fb = eng.submit(IDS_B, 6, logprobs=spec_k is None)
+        fb = eng.submit(IDS_B, 6, logprobs=True)
         ra, rb = fa.result(timeout=300), fb.result(timeout=300)
         st = eng.stats()
     finally:
@@ -113,15 +109,6 @@ def test_paged_bit_identical_to_dense(kv_quant, depth):
     assert st["kv_pool"]["pages_total"] > 0
     assert st["kv_pages_lazy_allocated"] > 0
     assert st["kv_decode_page_failures"] == 0
-
-
-def test_paged_bit_identical_spec_dispatch():
-    """The speculative verify (draft + K+1-wide forward) runs fused
-    too: the multi-query PAGED kernel sweeps the table-mapped pages
-    once for all K+1 positions."""
-    dense, _ = _overlapped("dense", spec_k=3)
-    paged, _ = _overlapped("paged", spec_k=3)
-    assert paged == dense
 
 
 def test_fused_matches_lax_reference(monkeypatch):

@@ -120,12 +120,15 @@ def test_depth2_bit_identical_to_depth1(kv_quant):
 
 
 def test_pipeline_join_bound_depth2():
-    """A join under depth 2 pays at most the in-flight dispatch, one
-    fused prefill+decode dispatch per run chunk (during which the
-    decode fleet keeps advancing — the fused-admission contract), the
-    insert drain, and its own first dispatch: first token within
-    step_at_submit + 2 + n_chunks + (depth-1) steps at K=1 (one chunk
-    here)."""
+    """A join under depth 2 pays at most one fused prefill+decode
+    dispatch per run chunk (during which the decode fleet keeps
+    advancing — the fused-admission contract), the insert's boundary,
+    its own first dispatch and the depth-1 dispatches issued ahead of
+    that one's read: between B's ``admit`` and its ``first_token`` on
+    the loop's own record, at most 2 + n_chunks + (depth-1) dispatches
+    open at K=1 (one chunk here).  Counted on the flight recorder, not
+    against ``eng.step_count`` read from this thread: how late a loaded
+    machine lets this thread submit B moves no event of that span."""
     model, params = _model_and_params()
     eng = _share(
         DecodeEngine(model, {"params": params}, slots=2,
@@ -137,15 +140,18 @@ def test_pipeline_join_bound_depth2():
         qa: "queue.Queue" = queue.Queue()
         eng.submit([3, 14, 15, 9, 2], 16, stream=qa)
         qa.get(timeout=300)                    # A is decoding
-        step_at_submit = eng.step_count
-        qb: "queue.Queue" = queue.Queue()
-        eng.submit([7, 3, 44], 2, stream=qb)
-        first_b = qb.get(timeout=300)
-        assert first_b["step"] <= step_at_submit + 4, (
-            first_b, step_at_submit
-        )
+        fb = eng.submit([7, 3, 44], 2)
+        assert len(fb.result(timeout=300)["ids"]) == 2
     finally:
         _close(eng)
+    events = eng.recorder.export()["traceEvents"]
+    life = {e["name"]: e["ts"] for e in events
+            if e.get("cat") == "req" and e["id"] == "2" and e["ph"] == "n"}
+    opened = [e for e in events
+              if e.get("cat") == "disp" and e["name"] == "dispatch"
+              and e["ph"] == "b"
+              and life["admit"] <= e["ts"] <= life["first_token"]]
+    assert 1 <= len(opened) <= 4, (life, opened)
 
 
 def test_close_with_dispatch_in_flight_fails_pending_exactly_once():

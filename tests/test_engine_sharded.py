@@ -125,7 +125,7 @@ def test_sharded_depth2_bit_identical_to_depth1_and_paged_to_dense():
 def test_mesh_defaults_pipelined_and_remaining_rejections_name_followup():
     """Engine(..., mesh=...) no longer rejects pipeline_depth=2 or
     kv_layout='paged'; the default depth under a mesh is 2; the
-    REMAINING incompatibilities (spec, prefix cache, forced pallas
+    REMAINING incompatibilities (prefix cache, forced pallas
     knobs) are rejected with messages naming the follow-up."""
     model, params = _model_and_params()
     kw = dict(slots=2, prompt_buckets=(16,), max_new_cap=8)
@@ -144,9 +144,6 @@ def test_mesh_defaults_pipelined_and_remaining_rejections_name_followup():
         assert eng.pipeline_depth == 2  # explicit depth accepted
     finally:
         eng.close()
-    with pytest.raises(ValueError, match="follow-up"):
-        DecodeEngine(model, {"params": params}, mesh=FakeMesh(),
-                     spec_k=2, **kw)
     with pytest.raises(ValueError, match="follow-up"):
         import os
 

@@ -349,10 +349,9 @@ def test_build_slot_row_alloc_end_defers_decode_pages():
     assert pool.private_pages_needed(10, 21) == 4
 
 
-@pytest.mark.parametrize("chunk", [False, True])
-def test_paged_kernel_bit_exact_vs_dense(chunk):
-    """The paged Pallas kernels (interpret mode) against the dense
-    kernels on the same cache bytes scattered into pages through a
+def test_paged_kernel_bit_exact_vs_dense():
+    """The paged Pallas kernel (interpret mode) against the dense
+    kernel on the same cache bytes scattered into pages through a
     permuted table: BIT-exact, with NULL pages outside the windows
     skipped and unmapped pages poisoned with NaN scale bytes (a
     skipped page's garbage must never reach the accumulator — the
@@ -363,10 +362,8 @@ def test_paged_kernel_bit_exact_vs_dense(chunk):
     agrees to float rounding when handed a thinner one."""
     from mlcomp_tpu.ops.pallas.decode_attention import (
         decode_attention,
-        decode_attention_chunk,
         paged_block_kv,
         paged_decode_attention,
-        paged_decode_attention_chunk,
         quantize_kv,
     )
 
@@ -405,102 +402,35 @@ def test_paged_kernel_bit_exact_vs_dense(chunk):
             vsp[pid] = vs4n[b, :, :, p * T:(p + 1) * T]
     pages = (jnp.asarray(kqp), jnp.asarray(ksp).astype(jnp.bfloat16),
              jnp.asarray(vqp), jnp.asarray(vsp).astype(jnp.bfloat16))
-    if chunk:
-        S = 3
-        q = jnp.asarray(rng.randn(B, S, H, DH).astype(np.float32))
-        stop0 = jnp.asarray(np.array([hi for _, hi in lo_hi], np.int32))
-        dense = decode_attention_chunk(
-            q, k8, ks4, v8, vs4, kv_start=start, kv_stop0=stop0
-        )
-        paged = paged_decode_attention_chunk(
-            q, *pages, jnp.asarray(table), kv_start=start,
-            kv_stop0=stop0,
-        )
-    else:
-        q = jnp.asarray(rng.randn(B, H, DH).astype(np.float32))
-        stop = jnp.asarray(np.array([hi for _, hi in lo_hi], np.int32))
-        dense = decode_attention(
-            q, k8, ks4, v8, vs4, kv_start=start, kv_stop=stop
-        )
-        np.testing.assert_allclose(
-            np.asarray(decode_attention(
-                q, k8, ks4, v8, vs4, kv_start=start, kv_stop=stop,
-                block_kv=256,
-            )),
-            np.asarray(dense), atol=1e-5,
-        )
-        # NULL out every page fully outside the window: the kernel
-        # must skip them (no DMA) and still match
-        tbl2 = table.copy()
-        for b, (lo, hi) in enumerate(lo_hi):
-            for p in range(MP):
-                if (p + 1) * T <= lo or p * T >= hi:
-                    tbl2[b, p] = NULL_PAGE
-        paged_null = paged_decode_attention(
-            q, *pages, jnp.asarray(tbl2), kv_start=start, kv_stop=stop
-        )
-        np.testing.assert_array_equal(
-            np.asarray(dense), np.asarray(paged_null)
-        )
-        paged = paged_decode_attention(
-            q, *pages, jnp.asarray(table), kv_start=start, kv_stop=stop
-        )
+    q = jnp.asarray(rng.randn(B, H, DH).astype(np.float32))
+    stop = jnp.asarray(np.array([hi for _, hi in lo_hi], np.int32))
+    dense = decode_attention(
+        q, k8, ks4, v8, vs4, kv_start=start, kv_stop=stop
+    )
+    np.testing.assert_allclose(
+        np.asarray(decode_attention(
+            q, k8, ks4, v8, vs4, kv_start=start, kv_stop=stop,
+            block_kv=256,
+        )),
+        np.asarray(dense), atol=1e-5,
+    )
+    # NULL out every page fully outside the window: the kernel
+    # must skip them (no DMA) and still match
+    tbl2 = table.copy()
+    for b, (lo, hi) in enumerate(lo_hi):
+        for p in range(MP):
+            if (p + 1) * T <= lo or p * T >= hi:
+                tbl2[b, p] = NULL_PAGE
+    paged_null = paged_decode_attention(
+        q, *pages, jnp.asarray(tbl2), kv_start=start, kv_stop=stop
+    )
+    np.testing.assert_array_equal(
+        np.asarray(dense), np.asarray(paged_null)
+    )
+    paged = paged_decode_attention(
+        q, *pages, jnp.asarray(table), kv_start=start, kv_stop=stop
+    )
     np.testing.assert_array_equal(np.asarray(dense), np.asarray(paged))
-
-
-def test_paged_wide_chunk_fallback_matches_dense():
-    """Chunk widths past CHUNK_MAX_SQ (spec_k >= 32) take the XLA
-    dequant fallback on BOTH paths — dense reads its buffer, fused
-    reads a table gather of identical bytes — and must stay bit-equal
-    (the fallback is a hand-mirrored copy of chunk_attend's dense
-    branch; this test is what keeps the two from drifting)."""
-    from mlcomp_tpu.kvpool import PagedKV, paged_kv
-    from mlcomp_tpu.ops.pallas.decode_attention import CHUNK_MAX_SQ
-
-    model, init_cache = _cache_family(True)
-    slots, l_buf, T = 2, 48, 4
-    s = CHUNK_MAX_SQ + 1
-    rng = np.random.RandomState(5)
-    from mlcomp_tpu.train.state import init_model
-
-    prompt = jnp.asarray(rng.randint(1, 64, (1, 8)))
-    params, _ = init_model(model, {"x": prompt}, jax.random.PRNGKey(2))
-    cache = init_cache(model, slots, l_buf)
-    cache_abs = jax.eval_shape(lambda: init_cache(model, 1, l_buf))
-    lay = PagedLayout(cache_abs, l_buf, T)
-    lay.num_pages = RESERVED_PAGES + slots * lay.max_pages
-    table = np.full((slots, lay.max_pages), GRAVE_PAGE, np.int32)
-    nxt = RESERVED_PAGES
-    for s_ in range(slots):
-        for p in range(lay.max_pages):
-            table[s_, p] = nxt
-            nxt += 1
-    table = jnp.asarray(table)
-    pages = lay.scatter(lay.fresh_pages(), table, cache)
-
-    tok = jnp.asarray(rng.randint(1, 64, (slots, s)))
-    cur = jnp.asarray(np.array([2, 5], np.int32))
-    pos = cur[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
-    kv_mask = jnp.ones((slots, l_buf), bool)
-
-    def dense_step(cache_in):
-        return model.apply(
-            {"params": params, "cache": cache_in}, tok, decode=True,
-            positions=pos, kv_mask=kv_mask, cache_cursor=cur,
-            mutable=["cache"],
-        )[0]
-
-    def fused_step(pages_in):
-        ctx = PagedKV(lay, pages_in, table, impl="auto")
-        with paged_kv(ctx):
-            logits, _ = model.apply(
-                {"params": params}, tok, decode=True, positions=pos,
-                kv_mask=kv_mask, cache_cursor=cur, mutable=["cache"],
-            )
-        return logits
-    d = jax.jit(dense_step)(cache)
-    f = jax.jit(fused_step)(pages)
-    np.testing.assert_array_equal(np.asarray(d), np.asarray(f))
 
 
 def test_insert_rows_routes_shared_to_grave():

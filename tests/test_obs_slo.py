@@ -73,7 +73,7 @@ def test_disabled_slo_stays_disabled_through_the_engine():
 
 
 def test_reject_rate_uses_the_service_counter_on_window_batchers():
-    # window/speculative daemons count accepted requests in
+    # window-batcher daemons count accepted requests in
     # mlcomp_service_requests_total (the engine family doesn't exist
     # there): one 429 among many successes must be a RATIO, not a
     # denominator-free guaranteed 1.0 breach

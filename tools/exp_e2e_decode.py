@@ -1,6 +1,6 @@
 """End-to-end check of round-4 decode work: b8_kv8_int8 (fused layout +
-auto blocks) vs its roofline, plus b8_kv8 for reference.  Same marginal
-protocol as bench.py's decode line, fewer variants."""
+auto blocks) vs its roofline, plus b8_kv8 for reference, by the
+marginal protocol (the wall of N more steps)."""
 import os
 import statistics
 import time

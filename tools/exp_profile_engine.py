@@ -1,5 +1,4 @@
-"""Profile ONE continuous-engine K-step dispatch (1.2B all-int8, the
-bench_engine config) and aggregate in-scan per-op device durations —
+"""Profile ONE continuous-engine K-step dispatch (1.2B all-int8) and aggregate in-scan per-op device durations —
 attributing the engine's ~9.0 ms marginal step vs the generate scan's
 3.67 (round-5 finding: the host unpack loop measured FREE, so the gap
 is device-side; this names the ops).  Same xplane methodology as

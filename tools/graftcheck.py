@@ -138,10 +138,7 @@ LOCK_FILES = (
 # they are exempt from the "docs ⊆ obs_check enforced list" direction
 # (and only from that direction).  Keep each entry justified.
 CONDITIONAL_METRICS = {
-    # spec engines only (obs_check's daemon has no --engine-spec-k)
-    "mlcomp_engine_spec_net_gain",
-    "mlcomp_engine_spec_ineffective",
-    # window/speculative batchers only (the daemon runs continuous)
+    # window batcher only (the daemon runs continuous)
     "mlcomp_service_requests_total",
     "mlcomp_service_queue_depth",
     # sharded engines only (the tier-1 obs_check daemon is mesh-less)
@@ -310,9 +307,6 @@ def python_files(root: str, subdirs: Sequence[str]) -> List[str]:
     rels: List[str] = []
     for sub in subdirs:
         base = os.path.join(root, sub)
-        if os.path.isfile(base) and base.endswith(".py"):
-            rels.append(sub)
-            continue
         for dirpath, dirnames, filenames in os.walk(base):
             dirnames[:] = [d for d in dirnames if d != "__pycache__"]
             for fn in sorted(filenames):
@@ -1030,7 +1024,7 @@ def check_locks(mods: Dict[str, ModuleInfo]) -> List[Finding]:
 # ------------------------------------------------------------ drift pass
 
 
-ENV_KEY_RE = re.compile(r"^(MLCOMP_\w+|BENCH_TIER)$")
+ENV_KEY_RE = re.compile(r"^MLCOMP_\w+$")
 
 
 def collect_env_vars(mods: Dict[str, ModuleInfo]
@@ -1046,9 +1040,7 @@ def collect_env_vars(mods: Dict[str, ModuleInfo]
         if rel == "tools/graftcheck.py":
             continue  # this tool's own rule strings are not env reads
         for node in ast.walk(mi.tree):
-            # os.environ.get("X", ...) / os.getenv("X") — plus any
-            # helper taking the env NAME as its first argument (the
-            # bench's _block_on("MLCOMP_BENCH_SKIP_...") idiom)
+            # os.environ.get("X", ...) / os.getenv("X")
             if isinstance(node, ast.Call):
                 if node.args and isinstance(
                     node.args[0], ast.Constant
@@ -1255,12 +1247,12 @@ def collect_cli_flags(mods: Dict[str, ModuleInfo]) -> Set[str]:
 def check_drift(root: str,
                 mods: Optional[Dict[str, ModuleInfo]] = None
                 ) -> List[Finding]:
-    """``mods`` (rel -> ModuleInfo for mlcomp_tpu/bench.py/tools) lets
+    """``mods`` (rel -> ModuleInfo for mlcomp_tpu/ and tools/) lets
     run_passes share its parse; standalone calls re-parse."""
     findings: List[Finding] = []
     if mods is None:
         mods = load_modules(root, python_files(
-            root, ("mlcomp_tpu", "bench.py", "tools")
+            root, ("mlcomp_tpu", "tools")
         ))
     code = {
         rel: mi for rel, mi in mods.items()
@@ -1281,12 +1273,8 @@ def check_drift(root: str,
     serving_md = read("docs/serving.md")
     obs_md = read("docs/observability.md")
 
-    # ---- env vars: code set vs the serving.md table.  The driver
-    # entry (__graft_entry__.py) reads bench-style skip envs for its
-    # dryrun blocks — part of the env contract, scanned here only
-    # (its donation/trace story is the dryruns' own)
-    entry_mods = load_modules(root, ["__graft_entry__.py"])
-    env_code = collect_env_vars({**code, **tools_mods, **entry_mods})
+    # ---- env vars: code set vs the serving.md table
+    env_code = collect_env_vars({**code, **tools_mods})
     env_docs = parse_env_table(serving_md)
     if "## Environment variables" not in serving_md:
         findings.append(Finding(
@@ -1306,7 +1294,7 @@ def check_drift(root: str,
         findings.append(Finding(
             "env-drift", "docs/serving.md", 1,
             f"env var {name} is documented but never read or set in "
-            "mlcomp_tpu/, tools/, or bench.py — stale row",
+            "mlcomp_tpu/ or tools/ — stale row",
         ))
 
     # ---- metrics: collectors vs docs catalog vs obs_check list
@@ -1451,9 +1439,7 @@ def run_passes(root: str = REPO,
                rules: Optional[Set[str]] = None) -> List[Finding]:
     rules = rules or set(ALL_RULES)
     findings: List[Finding] = []
-    code_rels = python_files(
-        root, ("mlcomp_tpu", "bench.py")
-    ) + python_files(root, ("tools",))
+    code_rels = python_files(root, ("mlcomp_tpu", "tools"))
     mods = load_modules(root, code_rels)
 
     if {"use-after-donate", "donation-vector"} & rules:
@@ -1531,8 +1517,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         return 0
     if args.list_env or args.list_metrics or args.list_faults:
         code = load_modules(args.root, python_files(
-            args.root, ("mlcomp_tpu", "bench.py", "tools")
-        ) + ["__graft_entry__.py"])
+            args.root, ("mlcomp_tpu", "tools")
+        ))
         if args.list_env:
             for name, sites in sorted(collect_env_vars(code).items()):
                 rel, line, kind = sites[0]
