@@ -926,11 +926,12 @@ def main(argv=None) -> int:
         " host's per-dispatch overhead hides behind device compute."
         " 1 = the old synchronous loop (the debug/bisect mode:"
         " outputs are bit-identical, only slower).  Admissions ride"
-        " the in-flight dispatches (fused prefill+decode); only the"
-        " final insert drains the pipeline, so joins cost one insert"
-        " at any depth.  Composes with --mesh: SPMD dispatches chain"
-        " the donated sharded carry on the device stream exactly like"
-        " single-chip (depth 2 is the default there too)",
+        " the in-flight dispatches (fused prefill+decode) and the"
+        " final insert is enqueued behind the last of them, so a join"
+        " never drains the pipeline.  Composes with --mesh: SPMD"
+        " dispatches chain the donated sharded carry on the device"
+        " stream exactly like single-chip (depth 2 is the default"
+        " there too)",
     )
     sv.add_argument(
         "--engine-staged-admission", action="store_true",

@@ -56,12 +56,12 @@ read back, so the host's dispatch+unpack work for N runs concurrent
 with the device executing N+1 (JAX's async dispatch sequences the
 donated carry chain on the device stream; the host never blocks to
 issue).  Depth 1 is exactly the old synchronous loop (the debug/bisect
-mode).  Only the admission's final INSERT drains the pipeline (it
-picks a slot from the host view and composes onto the donated carry,
-so both must be fresh — see the fused-admission paragraph below);
-FINISH boundaries need no drain: the device retires rows itself, so an
-extra in-flight dispatch on a finished row emits nothing — the host
-just learns of the finish one boundary later.
+mode).  Nothing in the steady state drains the pipeline: an
+admission's final INSERT chains on the donated carry like a dispatch
+does (see the fused-admission paragraph below), and FINISH boundaries
+need no drain either: the device retires rows itself, so an extra
+in-flight dispatch on a finished row emits nothing — the host just
+learns of the finish one boundary later.
 
 Fused prefill+decode dispatch (the staged path's
 ``admission_stall_ms.chunked_max`` was barely better than the
@@ -74,10 +74,19 @@ dispatch (one per chunk width, ``_fused_dispatch_fn``)
 runs the decode scan over all active slots AND one ``(1, c)`` chunk
 against the admission's carried cache, sharing one weights argument so
 parameters stream from HBM once per dispatch instead of twice.  The
-pipeline no longer drains for admissions: chunks compose on the
-admission's own fresh cache, and only the final insert-at-slot (and
-prefix-cache capture) still needs a resolved carry and a fresh host
-slot view — the one-chunk stall bound collapses to a one-insert bound.
+pipeline does not drain for admissions: chunks compose on the
+admission's own fresh cache, and the final insert-at-slot (and
+prefix-cache capture) is ENQUEUED behind the last fused dispatch — its
+operands are that dispatch's outputs, so the device runs fused
+dispatch -> insert -> next dispatch back to back, and the host sets up
+the next boundary (and the next admission) meanwhile.  The slot comes
+from the host view, which only under-reports free slots; a row is
+inactive in every dispatch issued before its insert, and
+``_Slot.since_seq`` keeps tokens a cancelled predecessor still has in
+flight from being booked to it.  What an admission costs the rows
+decoding is the chunk's and the insert's device time, no host wait
+(``admission_stall_ms`` is the host's enqueue time).  The import and
+export completions of a disaggregated handoff still drain first.
 Decode rows are bit-identical to the staged path by construction: the
 fused trace embeds the SAME dispatch body (same scan order, same RNG
 stream — chunks consume no RNG), and ``fused_admission=False`` forces
@@ -256,11 +265,18 @@ def _set_result(fut: Future, result) -> None:
 class _Slot:
     __slots__ = (
         "req", "cursor", "position", "start", "remaining", "emitted",
-        "t_first", "span_end", "alloc_upto",
+        "t_first", "span_end", "alloc_upto", "since_seq",
     )
 
-    def __init__(self, req, cursor, position, start, remaining):
+    def __init__(self, req, cursor, position, start, remaining,
+                 since_seq=0):
         self.req = req
+        # the first dispatch whose tokens are this row's: the insert
+        # is enqueued BEHIND whatever is in flight, and a dispatch
+        # issued before it may still carry tokens of the slot's
+        # previous holder (one retired by cancel/deadline after that
+        # dispatch went out) — _process_oldest skips those
+        self.since_seq = since_seq
         self.cursor = cursor          # next cache slot this row writes
         self.position = position      # next RoPE position (real tokens)
         self.start = start            # first valid cache slot (pads before)
@@ -880,10 +896,14 @@ class DecodeEngine:
         # inflight_sum/issued is the mean in-flight depth at issue
         # (occupancy); rows_attended/rows_total is the share of the
         # carry's rows that held a request when a dispatch went out;
-        # kv_rows_written is those rows times the dispatch's steps
+        # kv_rows_written is those rows times the dispatch's steps;
+        # inserts_behind_dispatch is the admissions whose insert was
+        # enqueued with a dispatch still unresolved (over prefills:
+        # how often a completion found the pipeline running)
         self._pstats = {  # guarded_by: loop [writes]
             "issued": 0, "host_ms": 0.0, "hidden_ms": 0.0, "wait_ms": 0.0,
             "inflight_sum": 0, "peak_inflight": 0,
+            "inserts_behind_dispatch": 0,
             "rows_attended": 0, "rows_total": 0, "kv_rows_written": 0,
             "kv_attended": 0, "kv_live": 0,
         }
@@ -1713,6 +1733,10 @@ class DecodeEngine:
             # synchronous, pipeline_depth = fully overlapped
             "occupancy": round(p["inflight_sum"] / p["issued"], 3)
             if p["issued"] else None,
+            # completed admissions whose insert was enqueued behind an
+            # unresolved dispatch (of stats()["prefills"]): ~all of
+            # them where rows decode, 0 on an idle engine
+            "inserts_behind_dispatch": p["inserts_behind_dispatch"],
             # the loop thread's ms per dispatch outside the blocked
             # fetch and the idle poll (the spans' boundary minus
             # resolve and idle_wait), the part of it spent while a
@@ -1909,6 +1933,10 @@ class DecodeEngine:
             p["hidden_ms"])
         ctr("mlcomp_engine_pipeline_wait_ms_total",
             "Host ms blocked on dispatch outputs", p["wait_ms"])
+        ctr("mlcomp_engine_inserts_behind_dispatch_total",
+            "Completed admissions whose insert was enqueued behind an "
+            "unresolved dispatch (subset of prefills)",
+            p["inserts_behind_dispatch"])
         ctr("mlcomp_engine_attention_rows_attended_total",
             "Slot rows holding a request at issue, summed over "
             "dispatches (the decode attention walks only these)",
@@ -1935,6 +1963,10 @@ class DecodeEngine:
             "Dispatches currently in flight", len(self._inflight))
         gau("mlcomp_engine_pipeline_peak_inflight",
             "Peak in-flight dispatch depth", p["peak_inflight"])
+        gau("mlcomp_engine_pipeline_occupancy",
+            "Mean in-flight depth right after an issue since start "
+            "(1 = synchronous, the configured depth = fully overlapped)",
+            p["inflight_sum"] / p["issued"] if p["issued"] else 0.0)
         gau("mlcomp_engine_pipeline_overlap_efficiency",
             "hidden_ms / host_ms since start",
             p["hidden_ms"] / p["host_ms"] if p["host_ms"] > 0 else 0.0)
@@ -3203,9 +3235,8 @@ class DecodeEngine:
         disaggregated handoff) skips the whole prefill core: its KV
         already exists as page payloads, so the admission is born
         complete (``next_chunk == n_chunks``) and the loop's
-        completion boundary — drained pipeline, fresh slot view, the
-        same one-insert stall bound — writes the pages and inserts
-        the slot."""
+        completion boundary — which drains the pipeline for an import
+        — writes the pages and inserts the slot."""
         from mlcomp_tpu.serve import left_pad_row
 
         jnp = self._jnp
@@ -3510,8 +3541,8 @@ class DecodeEngine:
 
     def _admission_complete_span(self, adm: _Admission):
         """The ``admission_complete`` span: the admission's last
-        boundary (final drain + insert/export/import) — the one stall
-        the fused path keeps."""
+        boundary — the insert's enqueue (child ``insert``), or for an
+        import / export the drain (``join_drain``) and the transfer."""
         return self.recorder.span(
             "admission_complete", track="engine.loop",
             rid=adm.req.get("rid", 0), chunks=adm.chunks_run,
@@ -3897,15 +3928,24 @@ class DecodeEngine:
         return (fw + 2) * dense + 2 * live
 
     def _complete_admission(self) -> None:  # graftcheck: runs-on(loop)
-        """Final admission boundary — the ONE synchronous stall the
-        fused path keeps: queue the prefix-cache capture, insert the
-        prefilled row at a free slot.  The caller has already drained
-        the pipeline (the insert picks a slot from the host view, so
-        it must be fresh, and the donated carry must be resolved) —
-        the drain stays OUT of this method so a decode-dispatch
-        failure during it is engine-scoped, not blamed on the joiner.
-        The admission's final logits are the last REAL token's
-        (left-padding puts the prompt tail at the bucket end)."""
+        """Final admission boundary: queue the prefix-cache capture
+        and insert the prefilled row at a free slot (or export it /
+        import its pages).  The plain insert is ENQUEUED, not waited
+        for: its operands (the carry, ``adm.cache``, ``adm.last_logits``)
+        are the outputs of whatever is still in flight, so the device
+        runs it behind the admission's last fused dispatch and ahead of
+        the next one while the loop goes on — nothing here reads a
+        dispatch back.  The slot comes from the host view, which only
+        UNDER-reports free slots (the host learns of a finish a
+        boundary late); the row is inactive in every dispatch issued
+        before the insert, and ``_Slot.since_seq`` keeps a predecessor's
+        tokens in those dispatches from being booked to it.  Only the
+        import and the export are called on a drained pipeline (the
+        caller's choice); a drain never happens IN this method, so a
+        decode-dispatch failure stays engine-scoped, not blamed on the
+        joiner.  The admission's final logits are the last REAL
+        token's (left-padding puts the prompt tail at the bucket
+        end)."""
         adm = self._adm
         jnp = self._jnp
         req = adm.req
@@ -3935,6 +3975,8 @@ class DecodeEngine:
         self._hist_stall.observe(adm.stall_ms)
         if adm.fused_chunks:
             self._stats["admissions_overlapped"] += 1
+        if self._inflight:
+            self._pstats["inserts_behind_dispatch"] += 1
         self._stats["prefills"] += 1
         self._adm = None
 
@@ -4060,6 +4102,7 @@ class DecodeEngine:
             position=len(req["ids"]),
             start=s_bucket - len(req["ids"]),
             remaining=req["n_new"],
+            since_seq=self._inflight[-1][2] + 1 if self._inflight else 0,
         )
         if self._pool is not None:
             # lazy-allocation bookkeeping: the committed row covers
@@ -4527,7 +4570,7 @@ class DecodeEngine:
         for kk in range(toks.shape[0]):
             self.step_count += 1
             for i, sl in enumerate(self._host):
-                if sl is None or not valid[kk, i]:
+                if sl is None or not valid[kk, i] or seq < sl.since_seq:
                     continue
                 tok, lp = int(toks[kk, i]), float(lps[kk, i])
                 if sl.t_first is None:
@@ -4988,14 +5031,19 @@ class DecodeEngine:
                     self._fail_admission(e)
         adm = self._adm
         if adm is not None and adm.next_chunk >= adm.n_chunks:
-            # all chunks issued (the last may still be in
-            # flight inside a fused dispatch): drain at LOOP
-            # level — a dispatch failure here is the FLEET's
-            # error, never the joiner's — then the one
-            # remaining synchronous boundary, whose insert/
-            # export/import faults are admission-scoped
+            # all chunks issued, the last possibly still in flight
+            # inside the fused dispatch above: the insert is enqueued
+            # behind it (see _complete_admission) and the loop goes on
+            # to keep pipeline_depth - 1 in flight.  A staged last
+            # chunk left the pipeline empty already.  Only an import
+            # (born complete, dispatches may be in flight) and an
+            # export (fetches to the host anyway) drain first, at LOOP
+            # level: a dispatch failure there is the FLEET's error,
+            # never the joiner's.  Insert / export / import faults are
+            # admission-scoped.
             with self._admission_complete_span(adm):
-                self._drain_inflight()
+                if adm.handoff is not None or self.prefill_only:
+                    self._drain_inflight()
                 try:
                     self._complete_admission()
                 except Exception as e:
@@ -5018,12 +5066,13 @@ class DecodeEngine:
             try:
                 # one admission in flight at a time, one CHUNK of it
                 # per boundary.  FUSED (default): the chunk rides the
-                # boundary's decode dispatch — the pipeline never
-                # drains for an admission, chunks compose on the
-                # admission's own fresh cache, and only the final
-                # insert needs a drained pipeline (fresh host slot
-                # view + resolved carry): the one-chunk stall bound is
-                # now one-insert.  STAGED (fused_admission=False, and
+                # boundary's decode dispatch, chunks compose on the
+                # admission's own fresh cache, and the final insert is
+                # enqueued behind the last fused dispatch — the
+                # pipeline never drains for an admission, and the host
+                # prepares the next boundary (maintenance, the next
+                # admission's start, the issue) while the device still
+                # runs this one.  STAGED (fused_admission=False, and
                 # any admission with no decode fleet to ride): the old
                 # behavior — drain at the join, every chunk its own
                 # dispatch, synchronous boundaries.

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from mlcomp_tpu.engine import DecodeEngine, _fail_future
+from mlcomp_tpu.engine import _POISON, DecodeEngine, _fail_future
 from mlcomp_tpu.models import create_model
 from mlcomp_tpu.models.generation import generate
 from mlcomp_tpu.serve import GenerationService
@@ -606,3 +606,332 @@ def test_host_counter_agrees_with_the_spans(depth, fused):
     assert pl["resolve_wait_ms_per_dispatch"] == pytest.approx(
         waited / 1e3 / issues, rel=0.01, abs=0.01
     )
+
+
+# ------------------------------- the insert enqueued behind a dispatch
+
+_LONG = [3, 14, 15, 9, 2]
+_JOINERS = {
+    # 16 bucket, prefill_chunk 16: one chunk an admission
+    "one": [[7, 3, 44], [5, 6, 7, 8, 9], [11, 12], [21, 3, 5, 8]],
+    # 32 bucket: two chunks an admission
+    "several": [list(range(1, 21)), list(range(30, 48)),
+                list(range(5, 26)), list(range(40, 59))],
+}
+_JOINER_NEW = [6, 5, 7, 4]
+_BEHIND = [(d, lay, p) for d in (1, 2) for lay in ("dense", "paged")
+           for p in ("one", "several")]
+
+
+def _behind_engine(depth, layout):
+    """Three slots, K = 2, parked: the loop thread has exited on a
+    fresh engine, and the caller drives it from its own thread."""
+    model, params = _model_and_params()
+    # max_slots: the paged pool would otherwise grow past three slots
+    # behind the queue, and the hand drive runs no elastic tick
+    kw = ({"kv_layout": "paged", "max_slots": 3} if layout == "paged"
+          else {})
+    eng = _share(
+        DecodeEngine(model, {"params": params}, slots=3,
+                     prompt_buckets=(16, 32), max_new_cap=48,
+                     steps_per_dispatch=2, pipeline_depth=depth,
+                     prefill_chunk=16, **kw),
+        ("behind", layout),
+    )
+    eng._stop.set()
+    eng._queue.put(_POISON)
+    eng._thread.join(timeout=60)
+    assert not eng._thread.is_alive()
+    if eng._watchdog is not None:
+        eng._watchdog.join(timeout=60)
+    eng._stop.clear()                          # submit() works again
+    return eng
+
+
+def _submit_all(eng, prompt):
+    """A long decoder first, then four joiners: with three slots two
+    join while it decodes and two wait for a slot, and every joiner's
+    chunks find the long row to ride."""
+    streams, futs = [], []
+    work = [(_LONG, 44)] + list(zip(_JOINERS[prompt], _JOINER_NEW))
+    for ids, n_new in work:
+        q: "queue.Queue" = queue.Queue()
+        futs.append(eng.submit(ids, n_new, logprobs=True, stream=q))
+        streams.append(q)
+    return streams, futs
+
+
+def _drain_streams_raw(q):
+    got = []
+    while True:
+        item = q.get(timeout=5)
+        if item is None:
+            return got
+        got.append(item)
+
+
+def _drain_streams(streams):
+    return [[(i["token"], i["logprob"], i["step"])
+             for i in _drain_streams_raw(q)] for q in streams]
+
+
+def _run_loop_until_done(eng, futs):
+    """``_loop_body`` on this thread until every future has resolved;
+    the recorder's events back."""
+    def done(_f):
+        if all(f.done() for f in futs):
+            eng._exit_loop.set()
+
+    for f in futs:
+        f.add_done_callback(done)
+    eng._loop_body()
+    assert eng._broken is None, eng._broken
+    assert all(f.done() for f in futs)
+    eng._inflight.clear()        # a last dispatch nobody waits for
+    return eng.recorder.export()["traceEvents"]
+
+
+def _loop_drive(eng, prompt, before=None):
+    """Every request queued, then the engine's OWN ``_loop_body`` on
+    this thread until the last future resolves: the first boundary
+    pumps the whole queue, so the schedule is the loop's alone and the
+    same in every run.  Returns per-request stream records, the
+    futures, the recorder's events and ``stats()``."""
+    streams, futs = _submit_all(eng, prompt)
+    if before is not None:
+        before(eng)
+    events = _run_loop_until_done(eng, futs)
+    return _drain_streams(streams), futs, events, eng.stats()
+
+
+@functools.lru_cache(maxsize=None)
+def _behind_run(depth, layout, prompt):
+    eng = _behind_engine(depth, layout)
+    try:
+        records, futs, events, st = _loop_drive(eng, prompt)
+        results = [f.result(timeout=0) for f in futs]
+    finally:
+        _close(eng)
+    return records, results, events, st
+
+
+def _dispatches_before_insert(events):
+    """rid -> how many dispatches the loop had issued when it enqueued
+    that request's insert."""
+    issues = sorted(e["ts"] for e in events
+                    if e["ph"] == "X" and e["name"] == "issue")
+    return {e["args"]["rid"]: sum(t < e["ts"] for t in issues)
+            for e in events if e["ph"] == "X" and e["name"] == "insert"}
+
+
+def _sync_drive(layout, prompt, schedule):
+    """The synchronous drive: staged chunks and ``_run_dispatch`` by
+    hand on a parked depth-1 engine, each request admitted after as
+    many dispatches as ``schedule`` says."""
+    eng = _behind_engine(1, layout)
+    try:
+        streams, futs = _submit_all(eng, prompt)
+        eng._pump_queue()
+        n_disp = 0
+        while eng._pending:
+            req = eng._pending.popleft()
+            while n_disp < schedule[req["rid"]]:
+                eng._run_dispatch()
+                n_disp += 1
+            eng._start_admission(req)
+            while eng._adm is not None:
+                eng._run_admission_chunk()
+        while any(s is not None for s in eng._host):
+            eng._run_dispatch()
+        results = [f.result(timeout=0) for f in futs]
+        return _drain_streams(streams), results
+    finally:
+        _close(eng)
+
+
+@pytest.mark.parametrize("depth,layout,prompt", _BEHIND)
+def test_insert_behind_dispatch_equals_the_synchronous_drive(
+        depth, layout, prompt):
+    """Tokens, log-probabilities and step numbers of every request,
+    with its insert enqueued behind the fused dispatch that carried
+    its last chunk, are those of the synchronous hand drive that
+    admits each request (staged, on a drained engine) after the same
+    number of dispatches."""
+    records, results, events, _st = _behind_run(depth, layout, prompt)
+    schedule = _dispatches_before_insert(events)
+    assert sorted(schedule) == [1, 2, 3, 4, 5]
+    assert schedule[1] == 0                    # onto the idle engine
+    assert all(schedule[r] > 0 for r in (2, 3, 4, 5))
+    want_records, want_results = _sync_drive(layout, prompt, schedule)
+    for got, want in zip(records, want_records):
+        assert got == want
+    for got, want in zip(results, want_results):
+        assert got["ids"] == want["ids"]
+        assert got["logprobs"] == want["logprobs"]
+    # a row's first token comes out of the dispatch after its insert
+    for rid, rec in enumerate(records, start=1):
+        assert rec[0][2] == 2 * schedule[rid] + 1
+        assert [s for _, _, s in rec] == list(
+            range(rec[0][2], rec[0][2] + len(rec))
+        )
+
+
+@pytest.mark.parametrize("depth,layout,prompt", _BEHIND)
+def test_fused_completion_does_not_drain(depth, layout, prompt):
+    """Under an ``admission_complete`` whose chunks rode dispatches
+    lies an ``insert`` and no ``join_drain``; at depth 2 the dispatch
+    issued next opens with the fused one still unresolved (the
+    recorder's ``dispatch`` span carries the in-flight depth at its
+    issue)."""
+    _records, _results, events, _st = _behind_run(depth, layout, prompt)
+    _roots, nodes = _loop_forest(events)
+    done = [n for n in nodes if n[0]["name"] == "admission_complete"]
+    assert len(done) == 5
+    opened = sorted(
+        (e["ts"], e["args"]["inflight"]) for e in events
+        if e.get("cat") == "disp" and e["name"] == "dispatch"
+        and e["ph"] == "b"
+    )
+    n_chunks = {"one": 1, "several": 2}[prompt]
+    for node in done:
+        e = node[0]
+        below = [n[0]["name"] for n in _under(node)][1:]
+        assert "insert" in [k[0]["name"] for k in node[1]]
+        if e["args"]["rid"] == 1:
+            assert e["args"]["fused_chunks"] == 0   # nothing to ride
+            continue
+        # the long row decodes throughout: every joiner chunk rode
+        assert e["args"]["fused_chunks"] == e["args"]["chunks"] == n_chunks
+        assert "join_drain" not in below
+        nxt = [d for ts, d in opened if ts >= e["ts"] + e["dur"]]
+        if depth == 2 and nxt:
+            assert nxt[0] >= 2, (e, nxt[0])
+
+
+@pytest.mark.parametrize("depth,layout,prompt", _BEHIND)
+def test_inserts_behind_dispatch_counter(depth, layout, prompt):
+    """``inserts_behind_dispatch`` counts the admissions completed with
+    a dispatch unresolved: every joiner, and not the admission onto
+    the idle engine.  Occupancy after an issue reaches the depth."""
+    _records, _results, _events, st = _behind_run(depth, layout, prompt)
+    assert st["prefills"] == 5
+    assert st["pipeline"]["inserts_behind_dispatch"] == 4
+    assert st["admissions_overlapped"] == 4
+    if depth == 2:
+        assert st["pipeline"]["occupancy"] > 1.8
+    else:
+        assert st["pipeline"]["occupancy"] == 1.0
+
+
+def _fail_second_insert(eng):
+    real = eng._insert_fn
+    calls = {"n": 0}
+
+    def insert_fn():
+        fn = real()
+
+        def call(*a, **kw):
+            calls["n"] += 1
+            if calls["n"] == 2:
+                raise RuntimeError("insert fault")
+            return fn(*a, **kw)
+
+        return call
+
+    eng._insert_fn = insert_fn
+
+
+@pytest.mark.parametrize("layout", ["dense", "paged"])
+@pytest.mark.parametrize("fault", ["fused_prefill", "insert"])
+def test_behind_dispatch_faults_fail_only_the_joiner(fault, layout):
+    """A fault while preparing the joiner's fused chunk, or in the host
+    half of its insert (the ``jit_insert`` call, with the fused
+    dispatch still in flight), fails that joiner alone: every other
+    request's tokens and log-probabilities are the fault-free run's,
+    the engine stays healthy and no admission or page is left over."""
+    from mlcomp_tpu.utils import faults
+
+    _rec, clean, _ev, _st = _behind_run(2, layout, "one")
+    eng = _behind_engine(2, layout)
+    try:
+        if fault == "fused_prefill":
+            # the long row is admitted staged: the first fused prep is
+            # the first joiner's
+            faults.arm("engine.fused_prefill", flavor="raise", times=1)
+            arm, err = None, faults.FaultInjected
+        else:
+            arm, err = _fail_second_insert, RuntimeError
+        _records, futs, _events, st = _loop_drive(eng, "one", before=arm)
+        with pytest.raises(err):
+            futs[1].result(timeout=0)
+        for i in (0, 2, 3, 4):
+            got = futs[i].result(timeout=0)
+            assert got["ids"] == clean[i]["ids"]
+            assert got["logprobs"] == clean[i]["logprobs"]
+        # (the loop ran on this thread: _loop_drive saw _broken None)
+        assert eng._adm is None and eng._unhealthy_reason is None
+        assert st["prefills"] == 4
+        assert st["pipeline"]["inserts_behind_dispatch"] == 3
+        if layout == "paged":
+            # all that is still held is the registry's pin of each
+            # INSERTED prompt's page: the failed joiner left none
+            pool = st["kv_pool"]
+            assert pool["pages_used"] == pool["pages_reclaimable"] == 4
+            assert pool["outstanding_page_leases"] == 0
+    finally:
+        faults.disarm_all()
+        _close(eng)
+
+
+def test_tokens_a_cancelled_row_left_in_flight_are_not_its_successors():
+    """A row cancelled with a dispatch in flight still has tokens in
+    that dispatch; the request inserted into its slot at the same
+    boundary, behind that dispatch, is booked none of them: its tokens
+    are bare generate's, and its first step is the dispatch after its
+    insert."""
+    from mlcomp_tpu.engine import RequestCancelled
+
+    model, params = _model_and_params()
+    eng = _behind_engine(2, "dense")
+
+    class CancelAfterTwo(queue.Queue):
+        rid = None
+
+        def put(self, item, *a, **kw):   # runs on the loop's thread
+            super().put(item, *a, **kw)
+            if item is not None and self.qsize() == 2:
+                assert eng.cancel(self.rid)
+
+    try:
+        doomed = CancelAfterTwo()
+        last: "queue.Queue" = queue.Queue()
+        futs = [
+            eng.submit(_LONG, 44),
+            eng.submit([7, 3, 44], 40, stream=doomed),
+            eng.submit([5, 6, 7, 8, 9], 40),
+            eng.submit([11, 12], 6, logprobs=True, stream=last),
+        ]
+        doomed.rid = futs[1].rid
+        events = _run_loop_until_done(eng, futs)
+    finally:
+        _close(eng)
+    with pytest.raises(RequestCancelled):
+        futs[1].result(timeout=0)
+    # the successor went in while the cancelled row's last dispatch was
+    # unresolved: two in flight when its insert was enqueued
+    retire = next(e["ts"] for e in events if e["name"] == "cancel")
+    insert = next(e for e in events if e["ph"] == "X"
+                  and e["name"] == "insert" and e["args"]["rid"] == 4)
+    resolves = sorted(e["ts"] for e in events
+                      if e["ph"] == "X" and e["name"] == "resolve")
+    issues = sorted(e["ts"] for e in events
+                    if e["ph"] == "X" and e["name"] == "issue")
+    assert retire < insert["ts"]
+    assert not any(retire < t < insert["ts"] for t in resolves)
+    assert sum(t < insert["ts"] for t in issues) - sum(
+        t < insert["ts"] for t in resolves) == 2
+    got = futs[3].result(timeout=0)
+    assert got["ids"] == _reference(model, params, [11, 12], 6)
+    steps = [item["step"] for item in _drain_streams_raw(last)]
+    first = 2 * sum(t < insert["ts"] for t in issues) + 1
+    assert steps == list(range(first, first + 6))

@@ -3,7 +3,10 @@ engine's ``stats()["attention"]`` block at every ``stats()`` call the
 harness makes (one before the timed window, one after the drain), so
 ``rows_attended_share`` of the window is the difference of the two, and
 ``kv_rows_written`` over ``rows_total`` x K is the share of the slot
-rows a step's kernels wrote a token into:
+rows a step's kernels wrote a token into.  A second line carries the
+``pipeline`` block and ``prefills``: ``inserts_behind_dispatch`` over
+``prefills`` is the share of admissions whose insert found a dispatch
+in flight, ``occupancy`` the mean in-flight depth after an issue:
 
     python tools/bench_attention_rows.py --workload chat-steady \\
         --seed 7 --seconds 50 --trace 0
@@ -21,8 +24,12 @@ _stats = GenerationService.stats
 
 def stats(self):
     out = _stats(self)
-    print("attention_rows " + json.dumps(out["engine"]["attention"]),
+    eng = out["engine"]
+    print("attention_rows " + json.dumps(eng["attention"]),
           file=sys.stderr, flush=True)
+    print("pipeline " + json.dumps(
+        dict(eng["pipeline"], prefills=eng["prefills"])
+    ), file=sys.stderr, flush=True)
     return out
 
 

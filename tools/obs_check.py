@@ -74,6 +74,7 @@ DOCUMENTED_SERVE_METRICS = [
     "mlcomp_engine_prefill_chunks_total",
     "mlcomp_engine_fused_prefill_chunks_total",
     "mlcomp_engine_admissions_overlapped_total",
+    "mlcomp_engine_inserts_behind_dispatch_total",
     "mlcomp_engine_admission_stall_ms",
     "mlcomp_engine_latency_samples_total",
     "mlcomp_engine_slots",
@@ -82,6 +83,7 @@ DOCUMENTED_SERVE_METRICS = [
     "mlcomp_engine_pipeline_depth",
     "mlcomp_engine_pipeline_inflight",
     "mlcomp_engine_pipeline_peak_inflight",
+    "mlcomp_engine_pipeline_occupancy",
     "mlcomp_engine_pipeline_issued_total",
     "mlcomp_engine_pipeline_host_ms_total",
     "mlcomp_engine_pipeline_hidden_ms_total",
