@@ -219,8 +219,16 @@ _COUNT_METRICS = (
     ("experts_touched", "Experts a call's tokens reached, summed over calls"),
     ("expert_layer_calls", "Expert-layer calls (layers x steps, and chunks)"),
     ("experts_held", "Experts held, summed over calls"),
+    # the first four again, over the chunk calls alone (more than one
+    # token a row: prefill chunks); sum less chunk is the single-token
+    # class, the decode steps
+    ("chunk_assignments", "Assignments routed by chunk calls"),
+    ("chunk_assignments_held", "Chunk calls' assignments held here"),
+    ("chunk_experts_touched", "Experts reached, summed over chunk calls"),
+    ("chunk_expert_layer_calls", "Expert-layer calls that were chunks"),
 )
 N_COUNTS = len(_COUNT_METRICS)
+_CLASS_COUNTS = tuple(name for name, _ in _COUNT_METRICS[:4])
 
 
 def _sown_counts(upd):
@@ -906,6 +914,7 @@ class DecodeEngine:
             "inserts_behind_dispatch": 0,
             "rows_attended": 0, "rows_total": 0, "kv_rows_written": 0,
             "kv_attended": 0, "kv_live": 0,
+            "kv_attended_window": 0, "kv_live_window": 0,
         }
         # one entry an attention layer: its window, None where it
         # reads the whole context (a model that does not say is one
@@ -1776,8 +1785,22 @@ class DecodeEngine:
             "kv_tokens_attended_share": round(
                 p["kv_attended"] / p["kv_live"], 4
             ) if p["kv_live"] else None,
+            # the same two sums split by layer kind: the layers with a
+            # window, and the rest (whole-context layers)
+            "by_kind": {
+                "window": {
+                    "kv_tokens_attended": p["kv_attended_window"],
+                    "kv_tokens_live": p["kv_live_window"],
+                },
+                "full": {
+                    "kv_tokens_attended":
+                        p["kv_attended"] - p["kv_attended_window"],
+                    "kv_tokens_live": p["kv_live"] - p["kv_live_window"],
+                },
+            },
         }
-        made, held, touched, calls, here = (float(c) for c in self._counts)
+        counts = [float(c) for c in self._counts]
+        made, held, touched, calls, here = counts[:5]
         if calls:
             # a routed expert layer's own counts, summed over layers,
             # steps and chunks of every dispatch read back
@@ -1789,6 +1812,15 @@ class DecodeEngine:
                 "experts_touched_per_call": round(touched / calls, 3),
                 # of the experts held, summed over the same calls
                 "experts_touched_share": round(touched / here, 4),
+                # the four counts by call class: chunk calls (prefill)
+                # and single-token calls (decode steps)
+                "by_class": {
+                    "chunk": dict(zip(_CLASS_COUNTS, counts[5:9])),
+                    "single_token": {
+                        k: a - c for k, a, c
+                        in zip(_CLASS_COUNTS, counts[:4], counts[5:9])
+                    },
+                },
             }
         out["latency"] = {
             # "samples" is the WINDOW the percentiles summarize (the
@@ -1954,6 +1986,12 @@ class DecodeEngine:
         ctr("mlcomp_engine_attention_kv_tokens_live_total",
             "Context tokens held by live rows, summed over layers and "
             "dispatches", p["kv_live"])
+        ctr("mlcomp_engine_attention_kv_tokens_attended_window_total",
+            "The part of kv_tokens_attended on layers with a window",
+            p["kv_attended_window"])
+        ctr("mlcomp_engine_attention_kv_tokens_live_window_total",
+            "The part of kv_tokens_live on layers with a window",
+            p["kv_live_window"])
         if self._counts[3]:  # a routed expert layer has been called
             for (name, what), value in zip(_COUNT_METRICS, self._counts):
                 ctr(f"mlcomp_engine_moe_{name}_total", what, float(value))
@@ -4504,11 +4542,14 @@ class DecodeEngine:
         p["kv_rows_written"] += len(ctx) * self.steps_per_dispatch
         # tokens of context the live rows hold, over the layers, and
         # the part of them a layer's window lets its attention read
-        p["kv_live"] += sum(ctx) * len(self._attn_windows)
-        p["kv_attended"] += sum(
-            c if w is None else min(c, w)
-            for w in self._attn_windows for c in ctx
-        )
+        windowed = [w for w in self._attn_windows if w is not None]
+        live_w = sum(ctx) * len(windowed)
+        seen_w = sum(min(c, w) for w in windowed for c in ctx)
+        full = sum(ctx) * (len(self._attn_windows) - len(windowed))
+        p["kv_live"] += full + live_w
+        p["kv_attended"] += full + seen_w
+        p["kv_live_window"] += live_w
+        p["kv_attended_window"] += seen_w
         if len(self._inflight) > p["peak_inflight"]:
             p["peak_inflight"] = len(self._inflight)
         # the dispatch's LIFETIME (issue -> outputs read) as an async

@@ -4,12 +4,19 @@
 numbers.  Here each layer names its attention kind (``"full"`` or
 ``"sliding"``: its own RoPE description, and a window for the sliding
 kind), its number of query heads, and its MLP kind (``"dense"``
-SwiGLU, or ``"sparse"``: dropless top-k routed SwiGLU experts plus a
+SwiGLU, or ``"sparse"``: dropless top-k routed experts plus a
 shared expert, ``models/moe.py`` ``RoutedExperts``).  Every layer is
 ``transformer.SelfAttention`` (a per-head output gate, a head width
 that is a field) followed by its MLP: one attention module, one
 decode-attention kernel family, one cache layout (a whole ``l_buf`` a
 layer; a window layer reads its last ``window`` tokens).
+
+What a model may also say: a RoPE description whose ``rotary_dim`` is
+0 rotates nothing (a layer without positional embedding);
+``early_router`` feeds a sparse layer's router the attention's own
+normed input, the experts still the post-attention normed state;
+``expert_gate`` names the experts' gate activation (``"silu"`` or
+``"relu"``).
 
 Serving only: the expert layer has no capacity and no auxiliary loss.
 """
@@ -52,6 +59,9 @@ class MixedLayer(nn.Module):
     routed_scale: float = 1.0
     expert_width: int = 0
     shared_width: int = 0
+    # the router scores the attention's normed input, not the experts'
+    early_router: bool = False
+    expert_gate: str = "silu"
 
     @nn.compact
     def __call__(self, x, positions, decode=False, kv_mask=None,
@@ -59,9 +69,11 @@ class MixedLayer(nn.Module):
         x = SelfAttention(
             self.hidden, self.heads, self.kv_heads, self.dtype,
             kv_quant=self.kv_quant, head_dim=self.head_dim, rope=self.rope,
-            window=self.window, head_gate=self.head_gate, name="attn",
+            window=self.window, head_gate=self.head_gate,
+            return_normed=self.early_router, name="attn",
         )(x, positions, decode=decode, kv_mask=kv_mask,
           cache_cursor=cache_cursor)
+        x, pre = x if self.early_router else (x, None)
         h = RMSNorm(self.dtype)(x)
         if self.mlp_dim is not None:
             dense = lambda n, name: nn.Dense(  # noqa: E731
@@ -75,8 +87,9 @@ class MixedLayer(nn.Module):
             n_experts=self.experts, d_model=self.hidden,
             d_ff=self.expert_width, k=self.experts_per_token,
             experts_held=self.experts_held, routed_scale=self.routed_scale,
-            shared_width=self.shared_width, dtype=self.dtype, name="moe",
-        )(h)
+            shared_width=self.shared_width, dtype=self.dtype,
+            gate=self.expert_gate, name="moe",
+        )(h, router_input=pre)
 
 
 class MixedLayerLM(nn.Module):
@@ -87,7 +100,8 @@ class MixedLayerLM(nn.Module):
     layer_types: Tuple[str, ...]
     heads_per_layer: Tuple[int, ...]
     mlp_layer_types: Tuple[str, ...]
-    mlp_dim: int
+    # the dense layers' width (a model without a dense layer says none)
+    mlp_dim: int = 0
     rope_full: Optional[RopeSpec] = None
     rope_sliding: Optional[RopeSpec] = None
     window: Optional[int] = None
@@ -98,6 +112,8 @@ class MixedLayerLM(nn.Module):
     routed_scale: float = 1.0
     expert_width: int = 0
     shared_width: int = 0
+    early_router: bool = False
+    expert_gate: str = "silu"
     dtype: str = "bfloat16"
     kv_quant: bool = False
     # the head's matmul operands (accumulation and logits stay float32):
@@ -141,6 +157,8 @@ class MixedLayerLM(nn.Module):
                 routed_scale=self.routed_scale,
                 expert_width=self.expert_width,
                 shared_width=self.shared_width,
+                early_router=self.early_router,
+                expert_gate=self.expert_gate,
                 name=f"layer_{i}",
             )(h, positions, decode, kv_mask, cache_cursor)
         h = RMSNorm(dtype)(h)
@@ -164,6 +182,18 @@ def mixed_layer_lm(**cfg: Any) -> MixedLayerLM:
         bad = sorted(set(cfg[kind]) - set(allowed))
         if bad:
             raise ValueError(f"{kind}: {bad} not among {allowed}")
+    if cfg.get("early_router") and "dense" in cfg["mlp_layer_types"]:
+        raise ValueError(
+            "early_router: a dense MLP has no router to move before the "
+            f"attention; mlp_layer_types {list(cfg['mlp_layer_types'])}"
+        )
+    from mlcomp_tpu.ops.pallas.grouped_matmul import GATES
+
+    if cfg.get("expert_gate", "silu") not in GATES:
+        raise ValueError(
+            f"expert_gate {cfg['expert_gate']!r}: the grouped matmul's "
+            f"gates are {sorted(GATES)}"
+        )
     for k in lists:
         cfg[k] = tuple(cfg[k])
     if cfg.get("experts_held") is not None:

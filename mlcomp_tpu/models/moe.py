@@ -194,9 +194,10 @@ class MoEBlock(nn.Module):
 
 
 class RoutedExperts(nn.Module):
-    """Dropless top-k routing over SwiGLU experts, of which this chip
-    may hold a share (expert parallelism's one-chip half), plus an
-    optional shared SwiGLU expert on every token.
+    """Dropless top-k routing over gated experts (``gate``: ``"silu"``,
+    SwiGLU, or ``"relu"``, ReGLU), of which this chip may hold a share
+    (expert parallelism's one-chip half), plus an optional shared
+    expert of the same form on every token.
 
     The float32 router scores all ``n_experts`` by softmax; a token's
     top ``k`` are renormalised to sum 1 and scaled by ``routed_scale``.
@@ -210,9 +211,18 @@ class RoutedExperts(nn.Module):
     one chip nothing stands in for the exchange.  No capacity, no
     dropped token, one algorithm for every token count.
 
+    ``router_input`` (same shape as ``x``) is what the router scores
+    where that is not ``x``: a layer whose router sits before its
+    attention hands the attention's normed input here and the
+    post-attention normed state as ``x``.
+
     Each call sows ``[assignments made, assignments held, experts
-    touched, 1, experts held]`` into the ``counters`` collection (summed where a
-    caller makes it mutable; the decode engine does).
+    touched, 1, experts held]`` into the ``counters`` collection, and
+    after them the first four again if the call is a chunk (more than
+    one token a row) and zeros if it is a single-token step: the sums
+    stay what they were, and sum minus chunk is the single-token class
+    (summed where a caller makes the collection mutable; the decode
+    engine does).
     """
 
     n_experts: int
@@ -223,10 +233,12 @@ class RoutedExperts(nn.Module):
     routed_scale: float = 1.0
     shared_width: int = 0
     dtype: jnp.dtype = jnp.bfloat16
+    gate: str = "silu"
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, router_input=None):
         from mlcomp_tpu.ops.pallas.grouped_matmul import (
+            GATES,
             ROW_TILE,
             group_layout,
             grouped_matmul,
@@ -244,10 +256,12 @@ class RoutedExperts(nn.Module):
         w_down = stack("experts_down", (count, self.d_ff, d))
 
         with jax.named_scope("moe.route"):
+            scored = tokens if router_input is None \
+                else router_input.reshape(t, d)
             logits = nn.Dense(
                 self.n_experts, use_bias=False, dtype=jnp.float32,
                 name="router",
-            )(tokens.astype(jnp.float32))
+            )(scored.astype(jnp.float32))
             topv, topi = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), self.k)
             gates = topv / jnp.sum(topv, axis=-1, keepdims=True)
             gates = gates * self.routed_scale                     # (T, k)
@@ -257,23 +271,25 @@ class RoutedExperts(nn.Module):
                 source=jnp.arange(t * self.k, dtype=jnp.int32) // self.k,
             )
             held = lay.dest < lay.row_source.shape[0]
+            counts = jnp.stack([
+                jnp.float32(t * self.k),
+                jnp.sum(held).astype(jnp.float32),
+                jnp.sum(lay.sizes > 0).astype(jnp.float32),
+                jnp.float32(1.0),
+                jnp.float32(count),
+            ])
+            as_chunk = counts[:4] if s > 1 else jnp.zeros((4,), jnp.float32)
             self.sow(
-                "counters", "moe",
-                jnp.stack([
-                    jnp.float32(t * self.k),
-                    jnp.sum(held).astype(jnp.float32),
-                    jnp.sum(lay.sizes > 0).astype(jnp.float32),
-                    jnp.float32(1.0),
-                    jnp.float32(count),
-                ]),
+                "counters", "moe", jnp.concatenate([counts, as_chunk]),
                 reduce_fn=lambda a, c: a + c,
-                init_fn=lambda: jnp.zeros((5,), jnp.float32),
+                init_fn=lambda: jnp.zeros((9,), jnp.float32),
             )
 
         with jax.named_scope("moe.experts"):
             rows = jnp.take(tokens.astype(self.dtype), lay.row_source, axis=0)
             act = grouped_matmul(
-                rows, w_gate, lay.tile_group, lay.tiles_used, w2=w_up
+                rows, w_gate, lay.tile_group, lay.tiles_used, w2=w_up,
+                gate=self.gate,
             )
             out = grouped_matmul(
                 act, w_down, lay.tile_group, lay.tiles_used
@@ -295,9 +311,9 @@ class RoutedExperts(nn.Module):
                     n, use_bias=False, dtype=self.dtype, name=name
                 )
                 h = tokens.astype(self.dtype)
-                h = nn.silu(dense(self.shared_width, "shared_gate")(h)) * dense(
-                    self.shared_width, "shared_up"
-                )(h)
+                h = GATES[self.gate](
+                    dense(self.shared_width, "shared_gate")(h)
+                ) * dense(self.shared_width, "shared_up")(h)
                 y = y + dense(d, "shared_down")(h).astype(jnp.float32)
         return y.astype(self.dtype).reshape(b, s, d)
 

@@ -42,7 +42,8 @@ def apply_rope(x: jax.Array, positions: jax.Array, base: float = 10000.0) -> jax
 class RopeSpec(NamedTuple):
     """A layer's rotary embedding, where it is not :func:`apply_rope`'s
     default: the base, how many leading dimensions of each head rotate
-    (``rotary_dim``; the rest pass through), and optionally YaRN's
+    (``rotary_dim``; the rest pass through; 0: nothing rotates, a layer
+    without positional embedding), and optionally YaRN's
     frequency blend (``factor`` set): below ``lo`` the published
     frequencies stay, above ``hi`` they are divided by ``factor``,
     with a linear ramp between (``lo`` / ``hi`` from ``beta_fast`` /
@@ -88,7 +89,9 @@ def apply_rope_spec(x: jax.Array, positions: jax.Array,
                     spec: RopeSpec) -> jax.Array:
     """Rotary embeddings by description; x: (B, S, H, D).  Dimension j
     of the rotating part pairs with j + rotary_dim / 2
-    (:func:`apply_rope`'s convention)."""
+    (:func:`apply_rope`'s convention); ``rotary_dim`` 0 rotates nothing."""
+    if spec.rotary_dim == 0:
+        return x
     d = x.shape[-1]
     d_r = spec.rotary_dim or d
     half = d_r // 2
@@ -206,18 +209,22 @@ class SelfAttention(nn.Module):
     # the defaults are the module as it always was (same programs,
     # same parameter paths).  ``head_dim``: a head's width where it is
     # not hidden // heads.  ``rope``: a :class:`RopeSpec` (None:
-    # :func:`apply_rope` at its default base).  ``window``: query t
+    # :func:`apply_rope` at its default base; ``rotary_dim`` 0: q and k
+    # are not rotated at all).  ``window``: query t
     # attends keys t - window + 1 .. t (None: every earlier key) — a
     # lower bound on the keys, applied in the forward pass, the cache's
     # single-token step and its chunk form; the cache keeps whole
     # buffers and reads their last ``window`` tokens.  ``head_gate``:
     # each head's output is multiplied by the logistic function of its
     # own projection ("head_gate/kernel", hidden -> heads) of the
-    # normed layer input.
+    # normed layer input.  ``return_normed``: the call returns
+    # ``(output, normed input)``, for a layer whose router reads what
+    # the attention reads (the norm's parameter stays here).
     head_dim: Optional[int] = None
     rope: Optional[RopeSpec] = None
     window: Optional[int] = None
     head_gate: bool = False
+    return_normed: bool = False
 
     def _window_lo(self, lo, stop):
         """``lo`` raised to the window's lower bound for keys ending
@@ -248,8 +255,12 @@ class SelfAttention(nn.Module):
     @nn.compact
     def __call__(self, x, positions, decode=False, kv_mask=None,
                  cache_cursor=None):
-        d_head = self.head_dim or self.hidden // self.heads
         h = RMSNorm(self.dtype)(x)
+        out = self._attend(x, h, positions, decode, kv_mask, cache_cursor)
+        return (out, h) if self.return_normed else out
+
+    def _attend(self, x, h, positions, decode, kv_mask, cache_cursor):
+        d_head = self.head_dim or self.hidden // self.heads
         if self.decode_fused:
             qkv = nn.DenseGeneral(
                 (self.heads + 2 * self.kv_heads, d_head),
@@ -262,12 +273,13 @@ class SelfAttention(nn.Module):
             q = nn.DenseGeneral((self.heads, d_head), use_bias=False, dtype=self.dtype, name="q")(h)
             k = nn.DenseGeneral((self.kv_heads, d_head), use_bias=False, dtype=self.dtype, name="k")(h)
             v = nn.DenseGeneral((self.kv_heads, d_head), use_bias=False, dtype=self.dtype, name="v")(h)
-        if self.rope is None:
-            q = apply_rope(q, positions)
-            k = apply_rope(k, positions)
-        else:
-            q = apply_rope_spec(q, positions, self.rope)
-            k = apply_rope_spec(k, positions, self.rope)
+        with jax.named_scope("attn.rope"):
+            if self.rope is None:
+                q = apply_rope(q, positions)
+                k = apply_rope(k, positions)
+            else:
+                q = apply_rope_spec(q, positions, self.rope)
+                k = apply_rope_spec(k, positions, self.rope)
         if self.head_gate:
             gate = jax.nn.sigmoid(nn.Dense(
                 self.heads, use_bias=False, dtype=self.dtype,

@@ -633,6 +633,14 @@ def _kernel_chunk(
 # kernels at all is wide_chunk_mode() — the XLA dequant path remains
 # the reference and the non-TPU default.
 CHUNK_MAX_SQ = 32
+# query tiles a wide chunk runs as one kernel call EACH, written out in
+# the program; a chunk of more tiles runs them as ONE kernel call inside
+# a loop over the tiles.  A call written out is a kernel the compiler
+# builds again (~0.09 s each for a described v5e: a 2,048-token chunk
+# is 64 of them a layer, 512 a program of eight layers); a loop trip
+# costs a few microseconds of control a tile.  Eight is the 256-token
+# chunk: the widest that ran before chunks of thousands did
+CHUNK_UNROLLED_TILES = 8
 
 
 def wide_chunk_mode() -> str:
@@ -726,14 +734,30 @@ def decode_attention_chunk(
             jnp.full((b,), l_buf - s_q + 1, jnp.int32) if kv_stop0 is None
             else jnp.broadcast_to(kv_stop0, (b,)).astype(jnp.int32)
         )
-        return jnp.concatenate([
-            decode_attention_chunk(
-                q[:, o:o + CHUNK_MAX_SQ], k8, ks, v8, vs,
-                kv_start=kv_start, kv_stop0=stop0 + o, scale=scale,
-                interpret=interpret, window=window,
+
+        def tile(q_tile, o):
+            return decode_attention_chunk(
+                q_tile, k8, ks, v8, vs, kv_start=kv_start,
+                kv_stop0=stop0 + o, scale=scale, interpret=interpret,
+                window=window,
             )
-            for o in range(0, s_q, CHUNK_MAX_SQ)
-        ], axis=1)
+
+        n_tiles, rest = divmod(s_q, CHUNK_MAX_SQ)
+        if n_tiles <= CHUNK_UNROLLED_TILES:
+            return jnp.concatenate([
+                tile(q[:, o:o + CHUNK_MAX_SQ], o)
+                for o in range(0, s_q, CHUNK_MAX_SQ)
+            ], axis=1)
+        whole = n_tiles * CHUNK_MAX_SQ
+        tiles = q[:, :whole].reshape(b, n_tiles, CHUNK_MAX_SQ, h, dh)
+        out = jax.lax.map(
+            lambda xs: tile(*xs),
+            (tiles.transpose(1, 0, 2, 3, 4),
+             jnp.arange(n_tiles, dtype=jnp.int32) * CHUNK_MAX_SQ),
+        ).transpose(1, 0, 2, 3, 4).reshape(b, whole, h, dh)
+        if rest:
+            out = jnp.concatenate([out, tile(q[:, whole:], whole)], axis=1)
+        return out
     if l_buf % LANES or dh % LANES:
         raise NotImplementedError(
             f"cache length {l_buf} and head dim {dh} must be multiples of "

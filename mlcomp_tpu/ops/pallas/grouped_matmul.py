@@ -17,8 +17,9 @@ fetch the last used rows' block (the same) and compute nothing; the
 rows they would write are never read back.
 
 One kernel serves the plain product ``x @ w[g]`` and, with a second
-stack ``w2``, the SwiGLU front half ``silu(x @ w[g]) * (x @ w2[g])``
-(one read of the rows, one write of the product).  The contraction is
+stack ``w2``, a gated unit's front half ``act(x @ w[g]) * (x @ w2[g])``
+(one read of the rows, one write of the product), ``act`` one of
+:data:`GATES` (SiLU: SwiGLU; ReLU: ReGLU).  The contraction is
 whole in one block (3072 or 1024 wide at the served widths), so there
 is no accumulator and no K loop; the grid is (output blocks, row
 tiles) with the row tiles innermost.
@@ -46,6 +47,8 @@ ROW_TILE = 16
 # 2.84 ms a layer call at 48 tokens where (3072, 256) / (1024, 1024)
 # took 3.05 and (3072, 128) / (1024, 512) 3.08
 WEIGHT_BLOCK_BYTES = 3 * 1024 * 1024
+# the gate's activation in the fused front half, by name
+GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
 class GroupLayout(NamedTuple):
@@ -100,16 +103,16 @@ def group_layout(group: jax.Array, groups: int, tm: int,
                        tiles_used.reshape(1), sizes)
 
 
-def _kernel(tg_ref, used_ref, x_ref, *refs, swiglu: bool):
+def _kernel(tg_ref, used_ref, x_ref, *refs, gate: Optional[str]):
     o_ref = refs[-1]
 
     @pl.when(pl.program_id(1) < used_ref[0])
     def _tile():
         x = x_ref[...]
         y = jnp.dot(x, refs[0][0], preferred_element_type=jnp.float32)
-        if swiglu:
+        if gate is not None:
             up = jnp.dot(x, refs[1][0], preferred_element_type=jnp.float32)
-            y = jax.nn.silu(y) * up
+            y = GATES[gate](y) * up
         o_ref[...] = y.astype(o_ref.dtype)
 
 
@@ -136,14 +139,18 @@ def grouped_matmul(
     w2: Optional[jax.Array] = None,
     block_n: Optional[int] = None,
     interpret: Optional[bool] = None,
+    gate: str = "silu",
 ) -> jax.Array:
     """``x`` (rows, K) in :func:`group_layout`'s order, ``w`` (G, K, N):
-    row r of tile t times ``w[tile_group[t]]``; with ``w2`` the SwiGLU
-    front half.  Rows of tiles at or past ``tiles_used`` come back
-    unwritten.  Returns (rows, N) in ``x.dtype``."""
+    row r of tile t times ``w[tile_group[t]]``; with ``w2`` the gated
+    front half, ``gate`` naming the activation (:data:`GATES`).  Rows of
+    tiles at or past ``tiles_used`` come back unwritten.  Returns
+    (rows, N) in ``x.dtype``."""
     rows, k = x.shape
     g, k_w, n = w.shape
     n_tiles = tile_group.shape[0]
+    if gate not in GATES:
+        raise ValueError(f"gate {gate!r} not among {sorted(GATES)}")
     if k_w != k or rows % n_tiles or (w2 is not None and w2.shape != w.shape):
         raise ValueError(
             f"x {x.shape}, w {w.shape}, w2 "
@@ -170,7 +177,7 @@ def grouped_matmul(
 
     weights = [w] if w2 is None else [w, w2]
     return pl.pallas_call(
-        functools.partial(_kernel, swiglu=w2 is not None),
+        functools.partial(_kernel, gate=None if w2 is None else gate),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(n // tn, n_tiles),

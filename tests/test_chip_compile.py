@@ -112,9 +112,12 @@ def test_decode_attention_dense_int8_kv(chip, b, h_kv, l_buf):
 
 # + Laguna-S-2.1's serve cell (72 query heads a full layer over 8 KV
 # heads, 48 slots of 1152)
+# + SmallThinker-21BA3B's (28 query heads over 4 KV heads, groups of 7,
+# 32 slots of 12,288 + 512 + 1 tokens rounded to 13,056)
 @pytest.mark.parametrize("b,h,h_kv,l_buf", [
-    (B, H, H, L), (48, H, 8, 2560), (48, 72, 8, 1152),
-], ids=["smoke_1p2b", "cell_internlm2_1p8b", "cell_laguna_full_layer"])
+    (B, H, H, L), (48, H, 8, 2560), (48, 72, 8, 1152), (32, 28, 4, 13056),
+], ids=["smoke_1p2b", "cell_internlm2_1p8b", "cell_laguna_full_layer",
+        "cell_smallthinker"])
 def test_decode_attention_appends_in_place(chip, b, h, h_kv, l_buf):
     """The append form inside a K-step scan whose carry holds the
     caches, as the engine's dispatch program holds them: Mosaic takes
@@ -249,21 +252,39 @@ def test_decode_attention_chunk_window_256_queries(chip):
     )
 
 
-@pytest.mark.parametrize("tokens", [48, 256], ids=["decode", "chunk"])
-def test_grouped_matmul_held_experts(chip, tokens):
+def test_decode_attention_chunk_window_2048_queries_12k_cache(chip):
+    """SmallThinker's admission chunk: 2,048 queries of 28 heads (7 a
+    KV head) against one slot's 13,056-token cache, window 4,096."""
+    from mlcomp_tpu.ops.pallas.decode_attention import decode_attention_chunk
+
+    _compiles_to_a_kernel(
+        functools.partial(decode_attention_chunk, interpret=False,
+                          window=4096),
+        chip((1, 2048, 28, DH), jnp.bfloat16),
+        *_dense_cache(chip, 1, 4, 13056),
+    )
+
+
+# Laguna-S-2.1's share (128 experts held of 256, top 10, SwiGLU) and
+# SmallThinker's whole layer (64 experts, top 6, ReGLU), a decode step
+# and an admission chunk each
+@pytest.mark.parametrize("tokens,e,h,f,k,gate", [
+    (48, 128, 3072, 1024, 10, "silu"), (256, 128, 3072, 1024, 10, "silu"),
+    (32, 64, 2560, 768, 6, "relu"), (2048, 64, 2560, 768, 6, "relu"),
+], ids=["decode", "chunk", "smallthinker_decode", "smallthinker_chunk"])
+def test_grouped_matmul_held_experts(chip, tokens, e, h, f, k, gate):
     from mlcomp_tpu.ops.pallas.grouped_matmul import (
         ROW_TILE,
         grouped_matmul,
         padded_rows,
     )
 
-    e, h, f = 128, 3072, 1024
-    rows = padded_rows(tokens * 10, e, ROW_TILE)
+    rows = padded_rows(tokens * k, e, ROW_TILE)
     tiles = (chip((rows // ROW_TILE,), jnp.int32), chip((1,), jnp.int32))
 
     def experts(x, w_gate, w_up, w_down, tile_group, used):
         act = grouped_matmul(x, w_gate, tile_group, used, w2=w_up,
-                             interpret=False)
+                             interpret=False, gate=gate)
         return grouped_matmul(act, w_down, tile_group, used, interpret=False)
 
     text = _compiles_to_a_kernel(

@@ -105,6 +105,72 @@ def test_the_counts_ride_the_tail_of_the_packed_buffer():
     assert packed.shape == (3 * 2 * 2 + N_COUNTS,)
 
 
+# a router before the attention, ReLU experts, a first layer that rotates
+# nothing and reads every key, two window layers
+EARLY = {
+    "name": "mixed_layer_lm", "vocab_size": 64, "hidden": 128, "head_dim": 64,
+    "kv_heads": 1, "layer_types": ["full", "sliding", "sliding"],
+    "heads_per_layer": [2, 2, 2], "mlp_layer_types": ["sparse"] * 3,
+    "rope_full": {"rotary_dim": 0}, "rope_sliding": {"base": 1500000.0},
+    "window": 8, "experts": 8, "experts_per_token": 2, "expert_width": 128,
+    "early_router": True, "expert_gate": "relu", "dtype": "float32",
+}
+
+
+@pytest.mark.parametrize("kv_quant", [False, True])
+def test_the_counts_by_layer_kind_and_by_call_class_are_the_hand_counts(
+        kv_quant):
+    """One request alone on three slots: 12 prompt tokens in a bucket of
+    16 are two chunks of 8, then three dispatches of K = 2 steps hold
+    12, 14 and 16 tokens of context at their issue."""
+    model, params = _build({**EARLY, "kv_quant": kv_quant})
+    k, layers, top_k, slots = 2, 3, 2, 3
+    eng = DecodeEngine(model, {"params": params}, slots=slots,
+                       prompt_buckets=(16,), max_new_cap=16,
+                       steps_per_dispatch=k, prefill_chunk=8,
+                       pipeline_depth=1)
+    try:
+        out = eng.submit(list(range(1, 13)), 3 * k).result(timeout=300)
+        st = eng.stats()
+        text = eng.metrics.render()
+    finally:
+        eng.close()
+    assert len(out["ids"]) == 3 * k and st["pipeline"]["issued"] == 3
+    held = 12 + 14 + 16
+    att = st["attention"]
+    assert att["by_kind"] == {
+        "full": {"kv_tokens_attended": held, "kv_tokens_live": held},
+        "window": {"kv_tokens_attended": 2 * 3 * 8,   # min(context, 8)
+                   "kv_tokens_live": 2 * held},
+    }
+    assert att["kv_tokens_live"] == 3 * held
+    assert att["kv_tokens_attended"] == held + 48
+    moe = st["moe"]
+    chunk_calls, step_calls = 2 * layers, 3 * k * layers
+    assert moe["by_class"] == {
+        "chunk": {"assignments": 8 * top_k * chunk_calls,
+                  "assignments_held": 8 * top_k * chunk_calls,
+                  "experts_touched": moe["by_class"]["chunk"]["experts_touched"],
+                  "expert_layer_calls": chunk_calls},
+        # a step routes every slot's token, the two empty rows' too
+        "single_token": {
+            "assignments": slots * top_k * step_calls,
+            "assignments_held": slots * top_k * step_calls,
+            "experts_touched":
+                moe["by_class"]["single_token"]["experts_touched"],
+            "expert_layer_calls": step_calls},
+    }
+    for key in ("assignments", "experts_touched", "expert_layer_calls"):
+        assert moe[key] == sum(c[key] for c in moe["by_class"].values())
+    assert chunk_calls <= moe["by_class"]["chunk"]["experts_touched"] \
+        <= 8 * chunk_calls
+    assert "mlcomp_engine_attention_kv_tokens_attended_window_total 48" in text
+    assert f"mlcomp_engine_attention_kv_tokens_live_window_total {2 * held}" \
+        in text
+    assert f"mlcomp_engine_moe_chunk_expert_layer_calls_total {chunk_calls}" \
+        in text
+
+
 # ---- the int8 cache's single-token step appends inside the kernel ----
 
 DENSE = {"name": "transformer_lm", "vocab_size": 64, "hidden": 64,

@@ -240,17 +240,18 @@ def test_fused_prefill_fault_fails_only_the_admission():
     the decode fleet's tokens stay bit-identical to a fault-free run,
     the engine stays healthy, and the next admission succeeds."""
     model, params = _model_and_params()
-    ref_a = _reference(model, params, IDS_A, 10)
+    # A decodes for 20 dispatches, so that B joins it on a loaded host too
+    ref_a = _reference(model, params, IDS_A, 40)
     ref_b = _reference(model, params, IDS_B, 6)
     eng = _share_fns(
         DecodeEngine(model, {"params": params}, slots=2,
-                     prompt_buckets=(16,), max_new_cap=12,
+                     prompt_buckets=(16,), max_new_cap=48,
                      steps_per_dispatch=2, prefill_chunk=4),
-        ("workload", False),
+        ("fault", False),   # a buffer of its own length: programs of its own
     )
     try:
         qa: "queue.Queue" = queue.Queue()
-        fa = eng.submit(IDS_A, 10, stream=qa)
+        fa = eng.submit(IDS_A, 40, stream=qa)
         qa.get(timeout=300)               # A is decoding
         faults.arm("engine.fused_prefill", flavor="raise", times=1)
         fb = eng.submit(IDS_B, 6)

@@ -67,3 +67,31 @@ def test_grouped_matmul_matches_jnp(case, swiglu):
     for g in range(G):
         mine = dest[np.asarray(group) == g]
         assert np.all(np.diff(mine) == 1)
+
+
+@pytest.mark.parametrize("case", ["mixed", "empty_experts", "one_takes_all"])
+def test_the_relu_gate_matches_jnp(case):
+    """ReGLU's front half, ``relu(x @ w[g]) * (x @ w2[g])``, through the
+    one kernel; a gate it has no name for is refused."""
+    group = jnp.asarray(CASES[case], jnp.int32)
+    a = group.shape[0]
+    x = jax.random.normal(jax.random.PRNGKey(a), (a, K), jnp.float32)
+    w, w2 = _weights(5)
+    lay = group_layout(group, G, ROW_TILE)
+    rows = jnp.take(x, lay.row_source, axis=0)
+    out = grouped_matmul(rows, w, lay.tile_group, lay.tiles_used, w2=w2,
+                         interpret=True, gate="relu")
+    silu = grouped_matmul(rows, w, lay.tile_group, lay.tiles_used, w2=w2,
+                          interpret=True)
+    dest = np.asarray(lay.dest)
+    held = np.flatnonzero(np.asarray((group >= 0) & (group < G)))
+    for i in held:
+        g = int(group[i])
+        want = jnp.maximum(x[i] @ w[g], 0.0) * (x[i] @ w2[g])
+        np.testing.assert_allclose(
+            np.asarray(out[dest[i]]), np.asarray(want), rtol=2e-5, atol=2e-5)
+    # and it is not the SiLU gate under another name
+    assert np.abs(np.asarray(out - silu)[dest[held]]).max() > 1e-2
+    with pytest.raises(ValueError, match="gate 'gelu'"):
+        grouped_matmul(rows, w, lay.tile_group, lay.tiles_used, w2=w2,
+                       interpret=True, gate="gelu")

@@ -366,6 +366,39 @@ def test_chunk_kernel_tiles_wide_chunks():
     assert (np.asarray(tail) == np.asarray(wide)[:, CHUNK_MAX_SQ:]).all()
 
 
+@pytest.mark.parametrize("window", [None, 48])
+def test_a_chunk_of_many_tiles_loops_over_the_one_kernel(window):
+    """More query tiles than are written out run as one kernel call in
+    a loop over the tiles (plus a remainder tile): every tile is bit-
+    identical to the kernel called on it with the position-offset
+    stop, with and without a window."""
+    from mlcomp_tpu.ops.pallas.decode_attention import (
+        CHUNK_MAX_SQ,
+        CHUNK_UNROLLED_TILES,
+        decode_attention_chunk,
+    )
+
+    b, h, dh, l_buf = 1, 4, 128, 512
+    n_tiles = CHUNK_UNROLLED_TILES + 2
+    s = n_tiles * CHUNK_MAX_SQ + 5
+    rng = np.random.default_rng(1)
+    q = jnp.asarray(rng.standard_normal((b, s, h, dh)), jnp.float32)
+    k8 = jnp.asarray(rng.integers(-127, 128, (b, 2, l_buf, dh)), jnp.int8)
+    sc = jnp.asarray(rng.random((b, 2, 1, l_buf)), jnp.float32)
+    start = jnp.asarray([7], jnp.int32)
+    stop0 = jnp.asarray([l_buf - s + 1], jnp.int32)
+    kw = dict(kv_start=start, window=window, interpret=True)
+    wide = np.asarray(jax.jit(lambda q: decode_attention_chunk(
+        q, k8, sc, k8, sc, kv_stop0=stop0, **kw))(q))
+    assert wide.shape == (b, s, h, dh)
+    for o in (0, CHUNK_MAX_SQ, (n_tiles - 1) * CHUNK_MAX_SQ,
+              n_tiles * CHUNK_MAX_SQ):
+        one = decode_attention_chunk(
+            q[:, o:o + CHUNK_MAX_SQ], k8, sc, k8, sc, kv_stop0=stop0 + o,
+            **kw)
+        assert (np.asarray(one) == wide[:, o:o + CHUNK_MAX_SQ]).all()
+
+
 def test_decode_kernel_rejects_bad_scale_shape():
     q = jnp.zeros((1, 4, 128))
     k8 = jnp.zeros((1, 4, 128, 128), jnp.int8)

@@ -351,3 +351,157 @@ def test_self_attention_with_default_fields_is_the_module_it_was(kv_quant):
         return np.concatenate(out, 1)
 
     assert np.array_equal(served(plain), served(described))
+
+
+# ---- what SmallThinker-21BA3B adds: layers that rotate nothing, a
+# router fed the attention's own normed input, a ReLU gate ----
+
+def _smallthinker():
+    cfg = _cfg("_rehearsal/smallthinker-21ba3b-serve.json")
+    arch = cells.architecture(cfg)
+    model = {**cfg["model"], "dtype": "float32", "head_dtype": "float32"}
+    return arch, arch.dims_of(cfg), model
+
+
+@pytest.mark.parametrize("asked,refusal", [
+    ({"early_router": True,
+      "mlp_layer_types": ["dense", "sparse"], "mlp_dim": 256},
+     "early_router: a dense MLP has no router"),
+    ({"expert_gate": "gelu"}, "expert_gate 'gelu'"),
+], ids=["early_router_on_a_dense_layer", "a_gate_the_kernel_lacks"])
+def test_what_the_model_cannot_be_is_refused_at_create_model(asked, refusal):
+    _, _, kw = _smallthinker()
+    with pytest.raises(ValueError, match=refusal):
+        create_model({**kw, **asked})
+
+
+def test_smallthinkers_tree_keeps_the_attention_norm_where_it_was():
+    arch, d, kw = _smallthinker()
+    assert arch.layer_kinds(d) == ["full", "sliding"]
+    model = create_model(dict(kw))
+    assert model.attention_windows() == (None, 16)
+    params = W.program_params(arch, 7, d, jnp.float32)
+    abstract = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
+    W.check_layout(params, abstract)
+    assert set(params["layer_0"]) == {"attn", "RMSNorm_0", "moe"}
+    assert set(params["layer_0"]["attn"]) == {"RMSNorm_0", "q", "k", "v", "out"}
+    assert set(params["layer_0"]["moe"]) == {
+        "router", "experts_gate", "experts_up", "experts_down"}
+
+
+def test_only_the_layers_that_rotate_carry_a_rope_scope():
+    """The global layer (``rotary_dim`` 0) has no op under ``attn.rope``;
+    the window layer has; both route under ``moe.route``."""
+    import re
+
+    _, _, kw = _smallthinker()
+    model = create_model({**kw, "kv_quant": False})
+    abstract = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
+    text = jax.jit(lambda p, x: model.apply({"params": p}, x)).lower(
+        abstract, jax.ShapeDtypeStruct((1, 8), jnp.int32)
+    ).as_text(debug_info=True)
+    assert set(re.findall(r"(layer_\d+)/attn/[\w./]*attn\.rope/", text)) == {
+        "layer_1"}
+    assert set(re.findall(r"(layer_\d+)/moe/moe\.route/", text)) == {
+        "layer_0", "layer_1"}
+
+
+def test_the_published_gate_is_the_one_routed_experts_computes():
+    """Top k by logit, then the softmax over those k (the published
+    form, ``reference/smallthinker.py``) against the softmax over all,
+    its top k, renormalised (``RoutedExperts``): the same experts at
+    the same weights."""
+    arch, d, _ = _smallthinker()
+    r_in = jax.random.normal(jax.random.PRNGKey(4), (3, 50, 256), jnp.float32)
+    router = jax.random.normal(jax.random.PRNGKey(5), (256, 8), jnp.float32)
+    published = np.asarray(arch.route(r_in, router, d))
+    logit = jnp.einsum("bsd,de->bse", r_in, router, precision=HI)
+    top, idx = jax.lax.top_k(jax.nn.softmax(logit, axis=-1), d["top_k"])
+    gates = top / jnp.sum(top, axis=-1, keepdims=True)
+    computed = np.asarray(jnp.sum(
+        jax.nn.one_hot(idx, 8, dtype=jnp.float32) * gates[..., None], -2))
+    assert ((published > 0) == (computed > 0)).all()
+    assert ((published > 0).sum(-1) == d["top_k"]).all()
+    np.testing.assert_allclose(computed, published, atol=1e-6)
+
+
+def _wrong_model(arch, d, name):
+    """The reference with one of SmallThinker's three departures from
+    the other served models undone."""
+    if name == "router_after_attention":
+        return d, {"router_input": lambda h, u: u}
+    if name == "silu_gate":
+        return d, {"relu": jax.nn.silu}
+    if name == "global_layer_rotates":
+        return {**d, "rotates": {"sliding": True, "full": True}}, {}
+    return d, {}
+
+
+@pytest.mark.parametrize("reference", [
+    "as_published", "router_after_attention", "silu_gate",
+    "global_layer_rotates"])
+def test_the_full_forward_is_smallthinkers_and_no_other_models(
+        reference, monkeypatch):
+    arch, d, kw = _smallthinker()
+    model = create_model({**kw, "kv_quant": False})
+    params = W.program_params(arch, 7, d, jnp.float32)
+    ids = np.asarray(jax.random.randint(jax.random.PRNGKey(0), (2, 40), 1, 512))
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(model.apply({"params": params}, jnp.asarray(ids)))
+    d, patches = _wrong_model(arch, d, reference)
+    for name, fn in patches.items():
+        monkeypatch.setattr(arch, name, fn)
+    err = np.abs(got - _reference_logits(arch, d, 7, ids)).max()
+    if reference == "as_published":
+        assert err < 2e-4
+    else:
+        assert err > 0.05
+
+
+# the (path, shape) list of the parameter tree, hashed, and the programs
+# the engine builds while one request is admitted alone and a second
+# joins it: both as the commit before SmallThinker built them
+AS_IT_WAS = {
+    "internlm2-1_8b-serve": (21, "e68fcc57a2bb16bc", 32),
+    "laguna-s-2_1-serve": (41, "9b07763613eed0be", 16),
+}
+
+
+@pytest.mark.parametrize("name", sorted(AS_IT_WAS))
+def test_the_other_served_models_build_what_they_built(name):
+    import hashlib
+
+    from mlcomp_tpu.serve import GenerationService
+
+    cfg = _cfg(f"_rehearsal/{name}.json")
+    model = create_model(dict(cfg["model"]))
+    abstract = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
+    leaves = sorted(
+        (jax.tree_util.keystr(p), tuple(x.shape))
+        for p, x in jax.tree_util.tree_leaves_with_path(abstract))
+    digest = hashlib.sha1(repr(leaves).encode()).hexdigest()[:16]
+    n_leaves, was, chunk = AS_IT_WAS[name]
+    assert (len(leaves), digest) == (n_leaves, was)
+
+    params = jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.bfloat16), abstract)
+    service = GenerationService(
+        model, {"params": params}, seed=1, metrics_history_interval=None,
+        batcher="continuous",
+        **{k: tuple(v) if isinstance(v, list) else v
+           for k, v in {**cfg["service"], "max_new_buckets": [128]}.items()})
+    try:
+        # the second is sent right behind the first, which decodes for 32
+        # dispatches: it joins a row that decodes however loaded the host
+        first = service.submit(list(range(1, 20)), 128, temperature=0.0)
+        service.submit(list(range(1, 9)), 8, temperature=0.0).result(
+            timeout=600)
+        first.result(timeout=600)
+        programs = set(service.engine._fns)
+    finally:
+        service.close()
+    assert programs == {
+        ("dispatch", 4), ("dispatch_core", 4), ("fused_dispatch", chunk, 4),
+        ("prefill_chunk", chunk), "insert", "prefill_init"}

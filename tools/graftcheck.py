@@ -159,6 +159,10 @@ CONDITIONAL_METRICS = {
     "mlcomp_engine_moe_experts_touched_total",
     "mlcomp_engine_moe_expert_layer_calls_total",
     "mlcomp_engine_moe_experts_held_total",
+    "mlcomp_engine_moe_chunk_assignments_total",
+    "mlcomp_engine_moe_chunk_assignments_held_total",
+    "mlcomp_engine_moe_chunk_experts_touched_total",
+    "mlcomp_engine_moe_chunk_expert_layer_calls_total",
 }
 
 MUTATOR_METHODS = {
