@@ -914,7 +914,7 @@ class DecodeEngine:
             "inserts_behind_dispatch": 0,
             "rows_attended": 0, "rows_total": 0, "kv_rows_written": 0,
             "kv_attended": 0, "kv_live": 0,
-            "kv_attended_window": 0, "kv_live_window": 0,
+            "kv_attended_window": 0, "kv_live_window": 0, "kv_fetched": 0,
         }
         # one entry an attention layer: its window, None where it
         # reads the whole context (a model that does not say is one
@@ -923,6 +923,29 @@ class DecodeEngine:
         self._attn_windows: Tuple[Optional[int], ...] = (
             tuple(windows()) if callable(windows) else (None,)
         )
+        # (buffer length, granule) of the dense int8 cache that the
+        # single-token decode_attention walks: what kv_tokens_fetched
+        # is counted from.  None where no such walk runs (a bfloat16
+        # cache; the paged layout, whose kernels move whole pages)
+        self._kv_walk: Optional[Tuple[int, int]] = None
+        self._attn_window_layers = {   # window -> layers that have it
+            w: self._attn_windows.count(w) for w in set(self._attn_windows)
+        }
+        if self.kv_layout == "dense":
+            from mlcomp_tpu.cache.kv_store import _leaf_name
+            from mlcomp_tpu.models.generation import init_cache
+            from mlcomp_tpu.ops.pallas.decode_attention import auto_block_kv
+
+            leaves = jax.tree_util.tree_leaves_with_path(jax.eval_shape(
+                lambda: init_cache(self.model, 1, self.l_buf)
+            ))
+            shape = next((leaf.shape for path, leaf in leaves
+                          if _leaf_name(path) == "cached_key_q"), None)
+            if shape is not None:
+                # under a mesh each device walks its own KV heads
+                tp = mesh.shape.get("tp", 1) if mesh is not None else 1
+                self._kv_walk = (shape[2], auto_block_kv(
+                    shape[2], max(1, shape[1] // tp), shape[3]))
         # what the model's layers sowed, summed over every program
         # read back (_sown_counts' order); a staged chunk's counts wait
         # here for the next read
@@ -1785,6 +1808,16 @@ class DecodeEngine:
             "kv_tokens_attended_share": round(
                 p["kv_attended"] / p["kv_live"], 4
             ) if p["kv_live"] else None,
+            # tokens the dense int8 cache's decode attention moved from
+            # HBM to read them: a window's first and last granule
+            # rounded out to the walk's lane blocks and rungs
+            # (decode_attention.kv_tokens_fetched over the windows of a
+            # dispatch's first step), and attended over fetched; 0 and
+            # None where no such walk runs
+            "kv_tokens_fetched": p["kv_fetched"],
+            "kv_fetch_live_share": round(
+                p["kv_attended"] / p["kv_fetched"], 4
+            ) if p["kv_fetched"] else None,
             # the same two sums split by layer kind: the layers with a
             # window, and the rest (whole-context layers)
             "by_kind": {
@@ -1986,6 +2019,11 @@ class DecodeEngine:
         ctr("mlcomp_engine_attention_kv_tokens_live_total",
             "Context tokens held by live rows, summed over layers and "
             "dispatches", p["kv_live"])
+        ctr("mlcomp_engine_attention_kv_tokens_fetched_total",
+            "Tokens the dense int8 cache's decode attention moved from "
+            "HBM for the attended ones (whole lane blocks, rounded up "
+            "to the walk's rungs), summed like kv_tokens_attended",
+            p["kv_fetched"])
         ctr("mlcomp_engine_attention_kv_tokens_attended_window_total",
             "The part of kv_tokens_attended on layers with a window",
             p["kv_attended_window"])
@@ -4550,6 +4588,24 @@ class DecodeEngine:
         p["kv_attended"] += full + seen_w
         p["kv_live_window"] += live_w
         p["kv_attended_window"] += seen_w
+        if self._kv_walk is not None and ctx:
+            # what the walk moves for those windows in the dispatch's
+            # first step: [start, cursor], raised to a layer's window
+            from mlcomp_tpu.ops.pallas.decode_attention import (
+                kv_tokens_fetched,
+            )
+
+            lo, hi = (np.array(x) for x in zip(*(
+                (sl.start, sl.cursor + 1)
+                for sl in self._host if sl is not None
+            )))
+            for w, layers in self._attn_window_layers.items():
+                p["kv_fetched"] += layers * int(
+                    kv_tokens_fetched(
+                        lo if w is None else np.maximum(lo, hi - w), hi,
+                        *self._kv_walk,
+                    ).sum()
+                )
         if len(self._inflight) > p["peak_inflight"]:
             p["peak_inflight"] = len(self._inflight)
         # the dispatch's LIFETIME (issue -> outputs read) as an async

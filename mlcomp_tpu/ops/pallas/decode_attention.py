@@ -38,28 +38,36 @@ a jnp ``k8 * ks`` prefix materializes the bf16 copy in HBM every step
   cache stays in HBM, one grid step holds a block of rows' queries and
   outputs, and a loop a row brings the window's granules
   (``auto_block_kv`` tokens each) through a double buffer — granule i+1, or the
-  next live row's first, lands while granule i computes.  A row whose
-  window is empty (the engine hands one to every slot that holds no
-  request) starts no copy and computes nothing.  The engine's step
-  also WRITES through it (``append``, PR 29): the row's new token is
-  patched into its last granule in VMEM, attended there, and the tile
-  that holds it copied back in place, so the cache takes one device
-  operation a layer whose cost follows the live rows, where a loop of
-  update-slices over every slot row was a quarter of the step (ledger,
-  PR 28 against PR 29).  The multi-query chunk
-  kernel and the paged kernel still sweep a (B, L/BLK) BlockSpec grid
-  of fat blocks (``KV_BLOCK_BUDGET``): blocks outside a row's window
-  are clamped in the index maps to the nearest live block of that row,
-  so the pipeline elides the copy, and their compute is
-  pl.when-skipped.
+  next live row's first, lands while granule i computes.  A trip moves
+  and attends only the 128-token lane blocks of its granule that the
+  window touches (PR 36): a granule inside the window goes whole, the
+  window's first and last are trimmed to the smallest width of a short
+  static ladder (``fetch_ladder``; copy sizes are static) that covers
+  their live blocks, so a thin window in a fat granule (a
+  ``batch-offline`` row holds ~240 live tokens of a 640-token granule)
+  is not paid for at the granule's width, and a long one runs whole
+  trips as before.  A row whose window is empty (the engine hands one
+  to every slot that holds no request) starts no copy and computes
+  nothing.  The engine's step also WRITES through it (``append``,
+  PR 29): the row's new token is patched into its last trip's columns
+  in VMEM, attended there, and the tile that holds it copied back in
+  place, so the cache takes one device operation a layer whose cost
+  follows the live rows, where a loop of update-slices over every slot
+  row was a quarter of the step (ledger, PR 28 against PR 29).  The
+  multi-query chunk kernel and the paged kernel still sweep a
+  (B, L/BLK) BlockSpec grid of fat blocks (``KV_BLOCK_BUDGET``): blocks
+  outside a row's window are clamped in the index maps to the nearest
+  live block of that row, so the pipeline elides the copy, and their
+  compute is pl.when-skipped.
 
 Measured on one v5e through the benchmark (InternLM2-1.8B serve cells:
 B 48, H 16, Hkv 8, dh 128, L 2560; ``kv8_decode_attn_roofline`` counts
 the LIVE keys and values): the (B, L/640) sweep this kernel replaced
 read 12.3% of that roofline in ``batch-offline`` and took the same
 ~205-215 us a layer call with 10 of 48 rows live as with all 48
-(ledger, PR 24 and PR 25).  This kernel's readings, and the granule
-sweep behind ``KV_BLOCK_BUDGET``: PERF.md, PR 26.
+(ledger, PR 24 and PR 25).  This kernel's readings, the granule sweep
+behind ``KV_BLOCK_BUDGET`` and what a trip costs: PERF.md, PR 26 and
+PR 36.
 
 The upstream reference has no decode path at all (its infer stage is a
 batch forward); this kernel is part of the serving surface the TPU
@@ -74,6 +82,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -85,18 +94,23 @@ SUBLANES = 8
 
 # K+V bytes of one block: a grid step of the BlockSpec-swept kernels
 # (chunk and paged) and one GRANULE of the single-token kernel's walk,
-# single-buffered.  For the walk, measured on one v5e at B 48 / Hkv 8 /
-# L 2560 (PERF.md, PR 26): a loop trip costs ~0.42 us + ~2.2 ns a token
-# whatever the granule, so thin granules lose on trips what they save
-# on a window's edges: 128 / 256 / 512 / 640 tokens took 46 / 39 / 36 /
-# 33 us at 10 live rows of ~560 tokens, 106 / 100 / 82 / 98 us at 48
-# rows of ~240, and 672 / 470 / 375 / 360 us over the whole buffer
-# (377 for the block sweep it replaced); at Hkv 16 / L 2304, whole
-# buffer, 128 / 256 / 384 / 768 took 147 / 115 / 106 / 106 us (109).
-# The largest block under ~2 MB (640 and 384 there) is never slower a
-# token than that sweep and within 17% of the best granule elsewhere.
-# For the chunk and paged kernels the value comes from another
-# toolchain's sweeps and is not measured on this chip.
+# single-buffered.  For the walk, measured on one v5e with ``append``
+# and trips trimmed to their windows' lane blocks (tools/exp_decattn.py;
+# PERF.md, PR 36), us a layer call at granules of 128 / 256 / 512 / 640
+# tokens, B 48 / Hkv 8 / L 2560: 50.7 / 41.4 / 38.5 / 34.1 at 10 live
+# rows of ~560 tokens, 118.9 / 104.4 / 86.5 / 86.2 at 48 rows of ~240,
+# and 702 / 488 / 380 / 373 over the whole buffer; at Hkv 16 / L 2304,
+# whole buffer, 128 / 256 / 384 / 768 took 153 / 119 / 111 / 109.  A
+# trip costs ~1.3 us whatever it moves (eight copies started and waited
+# for, the flash update's fixed part, the appended tile) and ~1.5 ns a
+# token at 128-384 tokens, 1.94 us whole at 640 (copies alone 1.81,
+# flash update alone 1.40): thin granules lose on trips what they save
+# on a window's edges, and a fat granule's edges are trimmed by the
+# ladder.  The largest block under ~2 MB (640 and 384 there) stays
+# within 2% of the block sweep the walk replaced over a whole buffer
+# (377 and 109 us, PR 26).  For the chunk and paged kernels the
+# value comes from another toolchain's sweeps and is not measured on
+# this chip.
 KV_BLOCK_BUDGET = 2 * 1024 * 1024 + 128 * 1024
 
 # rows whose queries and outputs ride one grid step of that kernel
@@ -111,6 +125,58 @@ def auto_block_kv(l_buf: int, h_kv: int, dh: int) -> int:
          if l_buf % bl == 0 and 2 * h_kv * bl * dh <= KV_BLOCK_BUDGET),
         default=LANES,
     )
+
+
+def fetch_ladder(granule: int) -> Tuple[int, ...]:
+    """The widths, in tokens, one trip of the single-token walk may
+    move: at most five lane multiples spaced geometrically from one
+    lane block to the granule (640 -> 128, 256, 384, 640; 2176 -> 128,
+    256, 512, 1024, 2176).  A copy's size is static, so a trip takes
+    the smallest rung that covers the lane blocks its window touches,
+    and each rung is one compiled body of the kernel: the spacing
+    bounds what a trip moves beyond its live blocks to the ratio of
+    two rungs, the count bounds the kernel's code."""
+    blocks = granule // LANES
+    rungs = min(5, 1 + (blocks - 1).bit_length())
+    if rungs == 1:
+        return (granule,)
+    return tuple(sorted({
+        LANES * round(blocks ** (i / (rungs - 1))) for i in range(rungs)
+    }))
+
+
+def trip_fetch(lo, hi, g, granule: int, xp=jnp):
+    """``(first column, width)`` of what the walk's trip over granule
+    ``g`` moves for the window ``[lo, hi)``, which touches that granule:
+    the smallest rung of :func:`fetch_ladder` that covers the lane
+    blocks of the granule the window touches, starting at the first of
+    them, or earlier where the rung would pass the granule's end.  A
+    granule the window covers is moved whole.  Pure arithmetic over
+    ``xp`` (traced scalars in the kernel, numpy arrays on the host):
+    the kernel's copies, its flash update and
+    :func:`kv_tokens_fetched` all read it, so they cannot disagree."""
+    ladder = fetch_ladder(granule)
+    first = xp.maximum(lo, g * granule) // LANES * LANES
+    end = (xp.minimum(hi, (g + 1) * granule) + LANES - 1) // LANES * LANES
+    width = ladder[0] + sum(
+        (end - first > a) * (b - a) for a, b in zip(ladder, ladder[1:])
+    )
+    return xp.minimum(first, (g + 1) * granule - width), width
+
+
+def kv_tokens_fetched(lo, hi, l_buf: int, granule: int):
+    """Tokens of K and V the single-token walk moves from HBM for each
+    window ``[lo, hi)`` (arrays, one entry a row) of an ``l_buf``-slot
+    buffer walked in granules of ``granule``: the sum of
+    :func:`trip_fetch`'s widths over the granules a window touches; 0
+    for an empty window.  The engine's ``kv_tokens_fetched`` counter."""
+    lo = np.maximum(np.asarray(lo, np.int64), 0)
+    hi = np.minimum(np.asarray(hi, np.int64), l_buf)
+    total = np.zeros(lo.shape, np.int64)
+    for g in range(l_buf // granule):
+        touched = (hi > lo) & (lo < (g + 1) * granule) & (hi > g * granule)
+        total += np.where(touched, trip_fetch(lo, hi, g, granule, np)[1], 0)
+    return total
 
 
 def pick_buffer_len(s: int, h_kv: int, dh: int) -> int:
@@ -206,20 +272,28 @@ def _kernel(
 ):
     """One grid step a BLOCK OF ROWS, whose queries and outputs sit in
     VMEM; K, V and their scales stay in HBM.  Each row walks the
-    granules its window covers and nothing else: granule i+1's copies
-    fly while granule i's flash update runs, and a row's last trip
-    starts the first granule of the next row that has one, so a
-    one-granule row does not expose its fetch either.  A row whose
-    window is empty starts no copy, computes nothing and costs one
-    trip of a scalar loop.
+    granules its window covers, one trip a granule, and a trip moves
+    and attends only the lane blocks of its granule that the window
+    touches (:func:`trip_fetch`: a granule inside the window whole, the
+    window's first and last granule trimmed to a rung of
+    :func:`fetch_ladder`).  Granule i+1's copies fly while granule i's
+    flash update runs, and a row's last trip starts the first fetch of
+    the next row that has one, so a one-granule row does not expose its
+    fetch either.  A row whose window is empty starts no copy, computes
+    nothing and costs one trip of a scalar loop.
+
+    A fetch lands at the front of its slot and the flash update runs
+    over the fetch's width and no column more: the slot's other columns
+    hold whatever an earlier trip left (a stale scale can be NaN, and
+    0 x NaN would pass the mask), so they never reach the arithmetic.
 
     With ``append`` the row's new token (``hi - 1``, the window's last
-    column, always in the last granule) is not in the cache yet: the
-    last trip patches it into the landed granule in VMEM, attends the
-    patched granule, and copies the aligned tile that holds the column
-    back to HBM (:data:`APPEND_TILE` int8 rows a head for K and V, one
-    lane group of the scales) while the flash update runs.  The cache
-    operands are the outputs' aliases, so that is the whole write."""
+    column, always in the last trip's fetch) is not in the cache yet:
+    the last trip patches it into the landed columns in VMEM, attends
+    them, and copies the aligned tile that holds the column back to HBM
+    (:data:`APPEND_TILE` int8 rows a head for K and V, one lane group
+    of the scales) while the flash update runs.  The cache operands are
+    the outputs' aliases, so that is the whole write."""
     if append:
         (kn_ref, ksn_ref, vn_ref, vsn_ref,
          o_ref, k_out, ks_out, v_out, vs_out,
@@ -231,6 +305,7 @@ def _kernel(
     nb, _, l_buf, _ = k_hbm.shape
     rows = q_ref.shape[0]
     row0 = pl.program_id(0) * rows
+    ladder = fetch_ladder(granule)
 
     def span(r):
         """Row r's clamped window and the granules it covers:
@@ -248,34 +323,66 @@ def _kernel(
             lambda i: i + 1, r,
         )
 
-    def copies(r, g, slot):
-        # the start and the wait halves build the SAME descriptors, so
-        # each slot's semaphore always balances
-        cols = pl.ds(pl.multiple_of(g * granule, granule), granule)
+    def fetch(lo, hi, g):
+        """:func:`trip_fetch`; a granule inside the window (every one
+        of a long window but its first and last) skips the arithmetic."""
+        return jax.lax.cond(
+            (lo <= g * granule) & (hi >= (g + 1) * granule),
+            lambda: (g * granule, jnp.int32(granule)),
+            lambda: trip_fetch(lo, hi, g, granule),
+        )
+
+    def at_rung(width, body):
+        """``body(w)`` for the one rung ``w`` the traced ``width`` is:
+        a copy's size and a block's shape are static, so each rung is
+        its own code.  The granule itself is asked for first: a whole
+        trip pays one comparison."""
+        def ask(rungs):
+            if len(rungs) == 1:
+                return body(rungs[0])
+            jax.lax.cond(width == rungs[0],
+                         lambda: body(rungs[0]), lambda: ask(rungs[1:]))
+
+        ask(ladder[::-1])
+
+    def copies(r, col, w, slot):
+        # the start and the wait halves build the SAME descriptors
+        # (trip_fetch is a pure function of the row's window and the
+        # granule), so each slot's semaphore always balances
+        cols = pl.ds(pl.multiple_of(col, LANES), w)
         return [
-            pltpu.make_async_copy(src, dst.at[slot], sem.at[slot])
+            pltpu.make_async_copy(src, dst, sem.at[slot])
             for src, dst in (
-                (k_hbm.at[r, :, cols, :], k_buf),
-                (v_hbm.at[r, :, cols, :], v_buf),
-                (ks_hbm.at[r, :, cols], ks_buf),
-                (vs_hbm.at[r, :, cols], vs_buf),
+                (k_hbm.at[r, :, cols, :], k_buf.at[slot, :, pl.ds(0, w), :]),
+                (v_hbm.at[r, :, cols, :], v_buf.at[slot, :, pl.ds(0, w), :]),
+                (ks_hbm.at[r, :, cols], ks_buf.at[slot, :, pl.ds(0, w)]),
+                (vs_hbm.at[r, :, cols], vs_buf.at[slot, :, pl.ds(0, w)]),
             )
         ]
+
+    def start(r, lo, hi, g, slot):
+        col, width = fetch(lo, hi, g)
+
+        def go(w):
+            for cp in copies(r, col, w, slot):
+                cp.start()
+
+        at_rung(width, go)
 
     def start_first(r, slot):
         @pl.when(r < nb)
         def _start():
-            for cp in copies(r, span(r)[2], slot):
-                cp.start()
+            lo, hi, g0, _ = span(r)
+            start(r, lo, hi, g0, slot)
 
     def tile_of(c):
         return pl.multiple_of(c // APPEND_TILE * APPEND_TILE, APPEND_TILE)
 
-    def patch(j, c, slot):
-        """The new token into column ``c`` of the landed granule.  K
-        and V: a select over the one int8 tile that holds the row, in
+    def patch(j, c, w, slot):
+        """The new token into column ``c`` of the ``w`` landed columns.
+        K and V: a select over the one int8 tile that holds the row, in
         int32 (a one-row int8 store would split a packed sublane); the
-        scales: a select over the slot's (Hkv, granule) block."""
+        scales: a select over the slot's (Hkv, w) columns."""
         rows32 = pl.ds(tile_of(c), APPEND_TILE)
         for new_ref, buf in ((kn_ref, k_buf), (vn_ref, v_buf)):
             tile = buf[slot, :, rows32, :].astype(jnp.int32)
@@ -286,30 +393,31 @@ def _kernel(
                 hit, new_ref[j][:, None, :], tile
             ).astype(buf.dtype)
         for new_ref, buf in ((ksn_ref, ks_buf), (vsn_ref, vs_buf)):
-            block = buf[slot].astype(jnp.float32)
+            block = buf[slot, :, pl.ds(0, w)].astype(jnp.float32)
             hit = jax.lax.broadcasted_iota(jnp.int32, block.shape, 1) == c
-            buf[slot] = jnp.where(
+            buf[slot, :, pl.ds(0, w)] = jnp.where(
                 hit, new_ref[j][:, :1], block
             ).astype(buf.dtype)
 
-    def write_backs(r, g, c, slot):
+    def write_backs(r, col, c, slot):
         """Copies VMEM -> HBM of the tile and the lane group that hold
-        column ``c`` of granule ``g``; built alike to start and to wait
-        for them, on one semaphore."""
+        column ``c`` of the fetch that starts at buffer column ``col``;
+        built alike to start and to wait for them, on one semaphore."""
         t0 = tile_of(c)
         l0 = pl.multiple_of(c // LANES * LANES, LANES)
-        at = g * granule
+        to_t0 = pl.multiple_of(col + t0, APPEND_TILE)
+        to_l0 = pl.multiple_of(col + l0, LANES)
         return [
             pltpu.make_async_copy(src, dst, wsem.at[0])
             for src, dst in (
                 (k_buf.at[slot, :, pl.ds(t0, APPEND_TILE), :],
-                 k_out.at[r, :, pl.ds(at + t0, APPEND_TILE), :]),
+                 k_out.at[r, :, pl.ds(to_t0, APPEND_TILE), :]),
                 (v_buf.at[slot, :, pl.ds(t0, APPEND_TILE), :],
-                 v_out.at[r, :, pl.ds(at + t0, APPEND_TILE), :]),
+                 v_out.at[r, :, pl.ds(to_t0, APPEND_TILE), :]),
                 (ks_buf.at[slot, :, pl.ds(l0, LANES)],
-                 ks_out.at[r, :, pl.ds(at + l0, LANES)]),
+                 ks_out.at[r, :, pl.ds(to_l0, LANES)]),
                 (vs_buf.at[slot, :, pl.ds(l0, LANES)],
-                 vs_out.at[r, :, pl.ds(at + l0, LANES)]),
+                 vs_out.at[r, :, pl.ds(to_l0, LANES)]),
             )
         ]
 
@@ -338,49 +446,52 @@ def _kernel(
                 slot = jax.lax.rem(slot0 + i, 2)
                 g = g0 + i
                 last = i + 1 == n
-                c = hi - 1 - g * granule   # the new token's column
+                col, width = fetch(lo, hi, g)
+                c = hi - 1 - col           # the new token's column
 
                 @pl.when(i + 1 < n)
                 def _next_granule():
-                    for cp in copies(r, g + 1, 1 - slot):
-                        cp.start()
+                    start(r, lo, hi, g + 1, 1 - slot)
 
                 @pl.when(last)
                 def _next_row():
                     start_first(next_row(r + 1), 1 - slot)
 
-                for cp in copies(r, g, slot):
-                    cp.wait()
+                def attend(w):
+                    for cp in copies(r, col, w, slot):
+                        cp.wait()
 
-                if append:
-                    @pl.when(last)
-                    def _append():
-                        patch(j, c, slot)
-                        for cp in write_backs(r, g, c, slot):
-                            cp.start()
+                    if append:
+                        @pl.when(last)
+                        def _append():
+                            patch(j, c, w, slot)
+                            for cp in write_backs(r, col, c, slot):
+                                cp.start()
 
-                def mask_fn(shape):
-                    cols = g * granule + jax.lax.broadcasted_iota(
-                        jnp.int32, shape, 2
+                    def mask_fn(shape):
+                        cols = col + jax.lax.broadcasted_iota(
+                            jnp.int32, shape, 2
+                        )
+                        return (cols >= lo) & (cols < hi)
+
+                    attend_cols = pl.ds(0, w)
+                    _flash_block_update(
+                        q, k_buf[slot, :, attend_cols, :].astype(q.dtype),
+                        ks_buf[slot, :, attend_cols][:, None, :],
+                        v_buf[slot, :, attend_cols, :].astype(q.dtype),
+                        vs_buf[slot, :, attend_cols][:, None, :],
+                        mask_fn, scale, acc_ref, m_ref, l_ref,
                     )
-                    return (cols >= lo) & (cols < hi)
 
-                _flash_block_update(
-                    q, k_buf[slot].astype(q.dtype),
-                    ks_buf[slot][:, None, :],
-                    v_buf[slot].astype(q.dtype),
-                    vs_buf[slot][:, None, :],
-                    mask_fn, scale, acc_ref, m_ref, l_ref,
-                )
+                    if append:
+                        # the tile has left this slot before the next
+                        # row's first trip starts a fetch into it
+                        @pl.when(last)
+                        def _written():
+                            for cp in write_backs(r, col, c, slot):
+                                cp.wait()
 
-                if append:
-                    # the tile has left this slot before the next
-                    # row's first trip starts a fetch into it
-                    @pl.when(last)
-                    def _written():
-                        for cp in write_backs(r, g, c, slot):
-                            cp.wait()
-
+                at_rung(width, attend)
                 return carry
 
             jax.lax.fori_loop(0, n, trip, 0)
@@ -415,7 +526,9 @@ def decode_attention(
     kv_start/kv_stop: (B,) int32 valid-slot windows (default: the whole
     buffer).  Cost follows the windows: a row is fetched and computed
     in granules of ``block_kv`` tokens (default :func:`auto_block_kv`)
-    from its window's first to its last, and a row whose window is
+    from its window's first to its last, the first and the last
+    trimmed to the lane blocks the window touches
+    (:func:`fetch_ladder`), and a row whose window is
     empty (start >= stop) costs nothing and returns zeros.  L and dh
     must be lane multiples (the cache allocator rounds L up; dh pads).
     Returns (B, H, dh) in q.dtype.
@@ -460,13 +573,6 @@ def decode_attention(
                 f"(>= {LANES}) dividing the cache length {l_buf}"
             )
 
-    rep = h // h_kv
-    gp = max(SUBLANES, -(-rep // SUBLANES) * SUBLANES)
-    # (B, H, dh) -> (B, Hkv, Gp, dh): group axis = sublanes of one block
-    qg = q.reshape(b, h_kv, rep, dh)
-    if gp != rep:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - rep), (0, 0)))
-
     start = (
         jnp.zeros((b,), jnp.int32) if kv_start is None
         else kv_start.astype(jnp.int32)
@@ -475,6 +581,38 @@ def decode_attention(
         jnp.full((b,), l_buf, jnp.int32) if kv_stop is None
         else jnp.broadcast_to(kv_stop, (b,)).astype(jnp.int32)
     )
+    if append is not None:
+        kq, ks_new = append[:2]
+        if kq.shape != (b, h_kv, dh) or ks_new.shape != (b, h_kv):
+            raise ValueError(
+                f"append must be (B, Hkv, dh) = {(b, h_kv, dh)} values and "
+                f"(B, Hkv) scales; got {kq.shape}, {ks_new.shape}"
+            )
+    out = _walk(
+        q, k8, ks, v8, vs, start, stop, *(append or ()),
+        scale=scale, granule=granule, interpret=interpret,
+    )
+    return out[0] if append is None else out
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "granule", "interpret"))
+def _walk(q, k8, ks, v8, vs, start, stop, *new, scale, granule, interpret):
+    """:func:`decode_attention`'s kernel call, a jitted function of its
+    own.  A model calls it once a layer with the same shapes, and the
+    kernel's body holds a flash update a rung: a jitted callee is
+    traced once a process and lowered once a program, where the bare
+    call was traced and lowered again for every layer of every program
+    (24 calls: 4.8 s a program before the rungs, 14 s with them, here,
+    for a described v5e; 0.6 s as one callee).  XLA inlines the call:
+    the op, its name and its aliases are what they were."""
+    b, h, dh = q.shape
+    _, h_kv, l_buf, _ = k8.shape
+    rep = h // h_kv
+    gp = max(SUBLANES, -(-rep // SUBLANES) * SUBLANES)
+    # (B, H, dh) -> (B, Hkv, Gp, dh): group axis = sublanes of one block
+    qg = q.reshape(b, h_kv, rep, dh)
+    if gp != rep:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - rep), (0, 0)))
 
     # rows a grid step: their queries and outputs are one VMEM block
     # (16 KB a row at Hkv 8), so a row costs no pipeline step of its own
@@ -503,13 +641,8 @@ def decode_attention(
         pltpu.VMEM((h_kv, gp, LANES), jnp.float32),
     ]
     aliases = {}
-    if append is not None:
-        kq, ks_new, vq, vs_new = append
-        if kq.shape != (b, h_kv, dh) or ks_new.shape != (b, h_kv):
-            raise ValueError(
-                f"append must be (B, Hkv, dh) = {(b, h_kv, dh)} values and "
-                f"(B, Hkv) scales; got {kq.shape}, {ks_new.shape}"
-            )
+    if new:
+        kq, ks_new, vq, vs_new = new
         # the new token rides in whole 32-bit tiles: int32 values (a
         # head a sublane), and each scale, rounded to the cache's
         # dtype as a plain write would round it, across one lane group
@@ -532,7 +665,7 @@ def decode_attention(
         aliases = {3 + i: 1 + i for i in range(4)}
     out = pl.pallas_call(
         functools.partial(
-            _kernel, scale=scale, granule=granule, append=append is not None
+            _kernel, scale=scale, granule=granule, append=bool(new)
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -551,8 +684,8 @@ def decode_attention(
         interpret=interpret,
         name="decode_attention",
     )(start, stop, *operands)
-    if append is None:
-        return out[:, :, :rep].reshape(b, h, dh)
+    if not new:
+        return (out[:, :, :rep].reshape(b, h, dh),)
     out, k8, ks3, v8, vs3 = out
     return (out[:, :, :rep].reshape(b, h, dh), k8, ks3.reshape(ks.shape),
             v8, vs3.reshape(vs.shape))

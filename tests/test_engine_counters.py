@@ -145,6 +145,11 @@ def test_the_counts_by_layer_kind_and_by_call_class_are_the_hand_counts(
     }
     assert att["kv_tokens_live"] == 3 * held
     assert att["kv_tokens_attended"] == held + 48
+    # a 33-slot buffer rounds to one lane block, and the int8 walk moves
+    # it whole: a layer a dispatch, window or none; no walk, no count
+    assert att["kv_tokens_fetched"] == (3 * layers * 128 if kv_quant else 0)
+    assert att["kv_fetch_live_share"] == (
+        round((held + 48) / (9 * 128), 4) if kv_quant else None)
     moe = st["moe"]
     chunk_calls, step_calls = 2 * layers, 3 * k * layers
     assert moe["by_class"] == {
@@ -300,3 +305,71 @@ def test_kv_rows_written_is_live_rows_times_steps(monkeypatch):
     assert set(seen) == {1}
     assert (f"mlcomp_engine_attention_kv_rows_written_total "
             f"{att['kv_rows_written']}") in text
+
+
+def test_kv_tokens_fetched_is_the_walks_count_over_the_mirror(monkeypatch):
+    """One request alone, 100 prompt tokens left-padded to a bucket of
+    256 in a 640-slot buffer walked as one granule: a step's window
+    [156, cursor] touches lane blocks 1 and 2, so the kernel moves 256
+    tokens a layer where the whole granule is 640.  The counter is
+    ``kv_tokens_fetched`` over the host's mirror at issue, and over the
+    windows the kernels were handed in each dispatch's first step."""
+    import mlcomp_tpu.ops.pallas.decode_attention as da
+
+    seen = []
+    real = da.decode_attention
+
+    def spy(q, k8, ks, v8, vs, kv_start=None, kv_stop=None, **kw):
+        jax.debug.callback(
+            lambda a, b: seen.append((np.asarray(a), np.asarray(b))),
+            kv_start, kv_stop, ordered=True,
+        )
+        return real(q, k8, ks, v8, vs, kv_start=kv_start, kv_stop=kv_stop,
+                    **kw)
+
+    monkeypatch.setattr(da, "decode_attention", spy)
+    model, params = _build(DENSE)
+    k, layers = 4, DENSE["layers"]
+    eng = DecodeEngine(model, {"params": params}, slots=3,
+                       prompt_buckets=(256,), max_new_cap=300,
+                       steps_per_dispatch=k, pipeline_depth=1)
+    try:
+        assert eng._kv_walk == (640, 640)
+        out = eng.submit(list(range(1, 51)) * 2, 2 * k).result(timeout=300)
+        st = eng.stats()
+        text = eng.metrics.render()
+    finally:
+        eng.close()
+    assert len(out["ids"]) == 2 * k and st["pipeline"]["issued"] == 2
+    att = st["attention"]
+    # a model that names no windows counts as ONE layer of attention
+    assert att["kv_tokens_attended"] == 100 + 104
+    assert att["kv_tokens_fetched"] == 2 * 256
+    assert att["kv_tokens_fetched"] >= att["kv_tokens_attended"]
+    assert att["kv_fetch_live_share"] == round(
+        att["kv_tokens_attended"] / att["kv_tokens_fetched"], 4)
+    # what the device saw: a kernel call a layer a step, every layer
+    # the same windows; a dispatch's first call is what the mirror counts
+    assert len(seen) == 2 * k * layers
+    assert att["kv_tokens_fetched"] == sum(
+        int(da.kv_tokens_fetched(*seen[d * k * layers], 640, 640).sum())
+        for d in range(2)
+    )
+    assert (f"mlcomp_engine_attention_kv_tokens_fetched_total "
+            f"{att['kv_tokens_fetched']}") in text
+
+
+def test_no_dense_int8_walk_no_tokens_fetched():
+    """A bfloat16 cache is not walked by the int8 kernel: the counter
+    stays 0 and its share says nothing."""
+    model, params = _build({**DENSE, "kv_quant": False})
+    eng = _engine(model, params)
+    try:
+        assert eng._kv_walk is None
+        eng.submit([3, 14, 15], 4).result(timeout=300)
+        att = eng.stats()["attention"]
+    finally:
+        eng.close()
+    assert att["kv_tokens_fetched"] == 0
+    assert att["kv_fetch_live_share"] is None
+    assert att["kv_tokens_attended"] > 0

@@ -115,10 +115,15 @@ def test_chunk_kernel_matches_reference(h, h_kv, dh, s_q):
 
 
 def test_chunk_kernel_agrees_with_single_token_kernel():
-    """S == 1 chunk against decode_attention: the same arithmetic core
-    and, by default, the same partition of the online softmax (the
-    walk's granule is the chunk kernel's block), so BIT-equal; equal to
-    float rounding when the walk is handed a thinner granule."""
+    """S == 1 chunk against decode_attention: the same arithmetic core.
+    A row whose window touches every lane block of the walk's granule
+    is moved and attended whole, in the chunk kernel's block (by
+    default the walk's granule): the same partition of the online
+    softmax, so BIT-equal.  A row whose trip is trimmed to the blocks
+    its window touches (PR 36) reduces over fewer columns than the
+    chunk kernel's block, and a walk handed a thinner granule over
+    more blocks: the partition is no longer the same by construction,
+    and both are held to float rounding."""
     from mlcomp_tpu.ops.pallas.decode_attention import (
         decode_attention_chunk,
     )
@@ -131,13 +136,16 @@ def test_chunk_kernel_agrees_with_single_token_kernel():
     k8, ks = quantize_kv(k)
     v8, vs = quantize_kv(v)
     start = jnp.asarray([0, 11], jnp.int32)
-    stop = jnp.asarray([897, 64], jnp.int32)
+    stop = jnp.asarray([897, 64], jnp.int32)   # all 8 blocks; one block
     operands = (k8, ks[:, :, None, :], v8, vs[:, :, None, :])
     c = decode_attention_chunk(
         q[:, None], *operands, kv_start=start, kv_stop0=stop,
     )[:, 0]
     default = decode_attention(q, *operands, kv_start=start, kv_stop=stop)
-    np.testing.assert_array_equal(np.asarray(default), np.asarray(c))
+    np.testing.assert_array_equal(np.asarray(default[0]), np.asarray(c[0]))
+    np.testing.assert_allclose(
+        np.asarray(default[1]), np.asarray(c[1]), atol=1e-5
+    )
     thin = decode_attention(
         q, *operands, kv_start=start, kv_stop=stop, block_kv=128,
     )
@@ -145,8 +153,9 @@ def test_chunk_kernel_agrees_with_single_token_kernel():
     np.testing.assert_allclose(np.asarray(thin), np.asarray(c), atol=1e-5)
 
 
-# rows of (start, stop) against a 2560-slot buffer; G is the kernel's
-# granule there (640 at 8 KV heads, 512 at 16), L the buffer
+# rows of (start, stop) against an L-slot buffer; G is the kernel's
+# granule there (640 at 8 KV heads and L 2560, 512 at 16; 2176 at 4 KV
+# heads and L 13056), B = 128 the lane block a trip is trimmed to
 _RAGGED = {
     "empty_between_live": lambda G, L: [
         (3, 700), (L, 41), (G, G + 1), (90, 90), (500, 20), (2 * G, L),
@@ -159,24 +168,65 @@ _RAGGED = {
         (0, G), (2 * G, 2 * G + 1), (L - G, L),
     ],
     "whole_buffer": lambda G, L: [(0, L), (0, L)],
+    # what trimming a trip to its window's lane blocks creates (PR 36):
+    # a window inside one lane block: the buffer's first, an inner one,
+    # a granule's last, the buffer's last
+    "one_lane_block": lambda G, L: [
+        (5, 100), (G + 130, G + 250), (2 * G - 127, 2 * G - 1),
+        (L - 100, L - 3), (G + 128, G + 256),
+    ],
+    # a window whose first block is its granule's block 0, whose last
+    # is its granule's last, both, and neither
+    "first_and_last_block": lambda G, L: [
+        (G, G + 129), (G + 1, 2 * G), (2 * G - 129, 2 * G),
+        (G + 128, 2 * G - 128), (G + 127, 2 * G - 127),
+    ],
+    # windows that end exactly on a block's edge and on a granule's,
+    # and start on one
+    "block_and_granule_ends": lambda G, L: [
+        (G + 5, G + 256), (7, G), (G - 128, G), (3, 2 * G),
+        (G + 256, 2 * G + 128), (128, 129),
+    ],
+    # longer than a granule, both edges trimmed (whole granules between)
+    "both_edges_trimmed": lambda G, L: [
+        (G - 200, 2 * G + 300), (G - 1, 2 * G + 1), (G - 128, 3 * G + 128),
+        (2 * G - 257, L - G + 140),
+    ],
+    # a window layer's read at long contexts: the last 4,096 tokens
+    "window_4096": lambda G, L: [
+        (hi - 4096, hi)
+        for hi in (L - 756, 4097, L, 3 * G, 4500)
+    ],
 }
+# (id, query heads, KV heads, buffer): the InternLM2 cells' geometry,
+# GQA and MHA, and SmallThinker's (groups of 7 over 4 KV heads, a
+# granule of 17 lane blocks)
+_RAGGED_PARAMS = [
+    pytest.param(h, h_kv, l_buf, case, id=f"{geo}-{case}")
+    for geo, h, h_kv, l_buf, cases in (
+        ("gqa", 16, 8, 2560, sorted(set(_RAGGED) - {"window_4096"})),
+        ("mha", 16, 16, 2560, sorted(set(_RAGGED) - {"window_4096"})),
+        ("hkv4_l13056", 28, 4, 13056, ["both_edges_trimmed", "window_4096"]),
+    )
+    for case in cases
+]
+# the cells' own geometries (the append and the poison test run these)
+_RAGGED_GQA = [p for p in _RAGGED_PARAMS if p.id.startswith(("gqa", "hkv4"))]
 
 
-@pytest.mark.parametrize("case", sorted(_RAGGED))
-@pytest.mark.parametrize("h,h_kv", [(16, 8), (16, 16)], ids=["gqa", "mha"])
-def test_decode_kernel_walks_ragged_windows(h, h_kv, case):
-    """The kernel walks each row's own granules: every column of every
-    window is attended (float reference over the same int8 bytes), a
-    row with an empty window returns exact zeros whatever its bounds,
-    and what its neighbours return does not depend on it."""
+def _ragged_case(h, h_kv, l_buf, case):
     from mlcomp_tpu.ops.pallas.decode_attention import auto_block_kv
 
-    dh, l_buf = 128, 2560
-    granule = auto_block_kv(l_buf, h_kv, dh)
+    granule = auto_block_kv(l_buf, h_kv, 128)
     assert granule <= l_buf // 4
-    win = _RAGGED[case](granule, l_buf)
-    b = len(win)
-    rng = np.random.default_rng(3)
+    return granule, _RAGGED[case](granule, l_buf)
+
+
+def _ragged_operands(h, h_kv, l_buf, win, seed):
+    """q, int8 K and V with their scales as the cache stores them
+    (bfloat16, (B, Hkv, L)), and the windows as arrays."""
+    b, dh = len(win), 128
+    rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.normal(size=(b, h, dh)), jnp.bfloat16)
     k8, ks = quantize_kv(
         jnp.asarray(rng.normal(size=(b, h_kv, l_buf, dh)), jnp.float32)
@@ -184,10 +234,23 @@ def test_decode_kernel_walks_ragged_windows(h, h_kv, case):
     v8, vs = quantize_kv(
         jnp.asarray(rng.normal(size=(b, h_kv, l_buf, dh)), jnp.float32)
     )
-    ks, vs = ks.astype(jnp.bfloat16), vs.astype(jnp.bfloat16)
-    operands = (k8, ks[:, :, None, :], v8, vs[:, :, None, :])
     start, stop = (jnp.asarray(x, jnp.int32) for x in zip(*win))
-    scale = 1.0 / dh**0.5
+    return (q, k8, ks.astype(jnp.bfloat16), v8, vs.astype(jnp.bfloat16),
+            start, stop)
+
+
+@pytest.mark.parametrize("h,h_kv,l_buf,case", _RAGGED_PARAMS)
+def test_decode_kernel_walks_ragged_windows(h, h_kv, l_buf, case):
+    """The kernel walks each row's own granules: every column of every
+    window is attended (float reference over the same int8 bytes), a
+    row with an empty window returns exact zeros whatever its bounds,
+    and what its neighbours return does not depend on it."""
+    _, win = _ragged_case(h, h_kv, l_buf, case)
+    q, k8, ks, v8, vs, start, stop = _ragged_operands(
+        h, h_kv, l_buf, win, seed=3
+    )
+    operands = (k8, ks[:, :, None, :], v8, vs[:, :, None, :])
+    scale = 1.0 / 128**0.5
     out = np.asarray(decode_attention(
         q, *operands, kv_start=start, kv_stop=stop, scale=scale,
     ).astype(jnp.float32))
@@ -205,6 +268,108 @@ def test_decode_kernel_walks_ragged_windows(h, h_kv, case):
             kv_stop=jnp.where(empty, l_buf, stop), scale=scale,
         ).astype(jnp.float32))
         np.testing.assert_array_equal(out[~empty], filled[~empty])
+
+
+def _fetched_columns(win, l_buf, granule):
+    """(B, L) bool: the columns the walk moves for each window, by the
+    kernel module's own account of a trip (``trip_fetch``)."""
+    from mlcomp_tpu.ops.pallas.decode_attention import trip_fetch
+
+    moved = np.zeros((len(win), l_buf), bool)
+    for r, (lo, hi) in enumerate(win):
+        lo, hi = max(lo, 0), min(hi, l_buf)
+        for g in range(l_buf // granule) if hi > lo else ():
+            if lo < (g + 1) * granule and hi > g * granule:
+                col, width = trip_fetch(lo, hi, g, granule, np)
+                moved[r, col:col + width] = True
+    return moved
+
+
+@pytest.mark.parametrize("h,h_kv,l_buf,case", _RAGGED_GQA)
+def test_decode_kernel_reads_no_column_it_did_not_fetch(h, h_kv, l_buf, case):
+    """Every column the walk does NOT move (``trip_fetch``'s account of
+    its trips: the lane blocks a window touches, rounded up to a rung)
+    holds NaN scales in HBM, as a slot of VMEM scratch may hold from an
+    earlier trip: outputs stay finite and equal to the float reference.
+    0 x NaN would pass the mask (``p * vs``), so this holds only if a
+    trip copies no more than the account says and attends no more than
+    it copied; the walk that moved whole granules fails it.  The
+    account covers every live column."""
+    granule, win = _ragged_case(h, h_kv, l_buf, case)
+    q, k8, ks, v8, vs, start, stop = _ragged_operands(
+        h, h_kv, l_buf, win, seed=5
+    )
+    moved = _fetched_columns(win, l_buf, granule)
+    cols = np.arange(l_buf)
+    for r, (lo, hi) in enumerate(win):
+        assert moved[r][(cols >= lo) & (cols < hi)].all()
+    keep = jnp.asarray(moved)[:, None, :]
+    poisoned = [
+        jnp.where(keep, x, jnp.nan)[:, :, None, :] for x in (ks, vs)
+    ]
+    scale = 1.0 / 128**0.5
+    out = np.asarray(decode_attention(
+        q, k8, poisoned[0], v8, poisoned[1], kv_start=start, kv_stop=stop,
+        scale=scale,
+    ).astype(jnp.float32))
+    assert np.isfinite(out).all()
+    ref = np.asarray(_reference(
+        q, k8, ks.astype(jnp.float32), v8, vs.astype(jnp.float32),
+        start, stop, scale,
+    ))
+    np.testing.assert_allclose(out, ref, atol=2e-2)
+
+
+def test_fetch_ladder_and_the_tokens_a_walk_moves():
+    """``fetch_ladder``: lane multiples from one block to the granule,
+    at most five.  ``kv_tokens_fetched`` (the engine's counter) against
+    a brute-force count: a granule a window touches, the smallest rung
+    that covers the lane blocks it touches there."""
+    from mlcomp_tpu.ops.pallas.decode_attention import (
+        fetch_ladder,
+        kv_tokens_fetched,
+        trip_fetch,
+    )
+
+    assert fetch_ladder(640) == (128, 256, 384, 640)
+    assert fetch_ladder(2176) == (128, 256, 512, 1024, 2176)
+    assert fetch_ladder(384) == (128, 256, 384)
+    assert fetch_ladder(128) == (128,)
+    for granule in range(128, 4096 + 1, 128):
+        ladder = fetch_ladder(granule)
+        assert ladder[0] == 128 and ladder[-1] == granule
+        assert len(ladder) <= 5 and list(ladder) == sorted(set(ladder))
+        assert all(w % 128 == 0 for w in ladder)
+    rng = np.random.default_rng(36)
+    for granule, l_buf in ((640, 2560), (384, 1152), (2176, 13056),
+                           (1024, 1024)):
+        ladder = fetch_ladder(granule)
+        lo = rng.integers(-5, l_buf + 5, 400)
+        hi = rng.integers(-5, l_buf + 200, 400)
+        lo[:40], hi[:40] = hi[:40] - 128, hi[:40]      # thin windows
+        want = []
+        for a, z in zip(lo, hi):
+            a, z = max(a, 0), min(z, l_buf)
+            total = 0
+            for g in range(l_buf // granule):
+                blocks = [
+                    blk for blk in range(g * granule // 128,
+                                         (g + 1) * granule // 128)
+                    if blk * 128 < z and (blk + 1) * 128 > a and z > a
+                ]
+                if blocks:
+                    need = 128 * (blocks[-1] - blocks[0] + 1)
+                    rung = min(w for w in ladder if w >= need)
+                    total += rung
+                    # the trip's fetch covers them, inside the granule
+                    col, width = trip_fetch(a, z, g, granule, np)
+                    assert width == rung and col % 128 == 0
+                    assert g * granule <= col <= blocks[0] * 128
+                    assert (blocks[-1] + 1) * 128 <= col + width \
+                        <= (g + 1) * granule
+            want.append(total)
+        got = kv_tokens_fetched(lo, hi, l_buf, granule)
+        np.testing.assert_array_equal(got, np.asarray(want))
 
 
 def _append_case(h, h_kv, dh, l_buf, sdt, cursors, starts, seed=1):
@@ -285,6 +450,36 @@ def test_decode_kernel_appends_in_place(h, h_kv, dh, sdt, case):
     for new_buf, old_write, old_buf in zip(got, want, caches):
         assert new_buf.shape == old_buf.shape
         assert new_buf.dtype == old_buf.dtype
+        np.testing.assert_array_equal(
+            bits(new_buf)[live], bits(old_write)[live]
+        )
+        np.testing.assert_array_equal(
+            bits(new_buf)[~live], bits(old_buf)[~live]
+        )
+
+
+@pytest.mark.parametrize("h,h_kv,l_buf,case", _RAGGED_GQA)
+def test_decode_kernel_appends_at_ragged_windows(h, h_kv, l_buf, case):
+    """``append`` over the windows of
+    ``test_decode_kernel_walks_ragged_windows``, the new token at each
+    window's last column: a trimmed trip patches the token relative to
+    where its fetch starts and writes the tile back where the whole-
+    granule trip wrote it.  Output and all four buffers bit-equal to
+    the write it replaced in every live row (so a column of the granule
+    that was never fetched is untouched too), an empty row's bytes
+    untouched."""
+    _, win = _ragged_case(h, h_kv, l_buf, case)
+    q, caches, new, windows, want = _append_case(
+        h, h_kv, 128, l_buf, jnp.bfloat16,
+        [hi - 1 for _, hi in win], [lo for lo, _ in win],
+    )
+    out, *got = decode_attention(q, *caches, append=new, **windows)
+    ref = decode_attention(q, *want, **windows)
+    bits = lambda x: np.asarray(x.astype(jnp.float32))
+    np.testing.assert_array_equal(bits(out), bits(ref))
+    live = np.asarray(windows["kv_start"] < windows["kv_stop"])
+    assert (bits(out)[~live] == 0.0).all()
+    for new_buf, old_write, old_buf in zip(got, want, caches):
         np.testing.assert_array_equal(
             bits(new_buf)[live], bits(old_write)[live]
         )

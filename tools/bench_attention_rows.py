@@ -3,7 +3,11 @@ engine's ``stats()["attention"]`` block at every ``stats()`` call the
 harness makes (one before the timed window, one after the drain), so
 ``rows_attended_share`` of the window is the difference of the two, and
 ``kv_rows_written`` over ``rows_total`` x K is the share of the slot
-rows a step's kernels wrote a token into.  A second line carries the
+rows a step's kernels wrote a token into; ``kv_tokens_fetched`` is what
+the decode kernel's walk moved for the ``kv_tokens_attended`` it read
+(``kv_fetch_live_share``: attended over fetched, of the whole run up to
+the call; difference the two calls' counts for the window's).  A second
+line carries the
 ``pipeline`` block and ``prefills``: ``inserts_behind_dispatch`` over
 ``prefills`` is the share of admissions whose insert found a dispatch
 in flight, ``occupancy`` the mean in-flight depth after an issue:

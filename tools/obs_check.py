@@ -94,6 +94,7 @@ DOCUMENTED_SERVE_METRICS = [
     "mlcomp_engine_attention_kv_rows_written_total",
     "mlcomp_engine_attention_kv_tokens_attended_total",
     "mlcomp_engine_attention_kv_tokens_live_total",
+    "mlcomp_engine_attention_kv_tokens_fetched_total",
     "mlcomp_engine_attention_kv_tokens_attended_window_total",
     "mlcomp_engine_attention_kv_tokens_live_window_total",
     "mlcomp_engine_dispatch_k",
