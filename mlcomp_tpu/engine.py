@@ -210,34 +210,79 @@ class ProfileBusy(RuntimeError):
     status = "profile_busy"
 
 
-# what a routed expert layer sows a call (models/moe.py RoutedExperts):
-# assignments made, assignments whose expert is held here, experts a
-# token reached, 1 (the call), experts held
-_COUNT_METRICS = (
-    ("assignments", "Token-to-expert assignments routed"),
-    ("assignments_held", "Assignments whose expert this chip holds"),
-    ("experts_touched", "Experts a call's tokens reached, summed over calls"),
-    ("expert_layer_calls", "Expert-layer calls (layers x steps, and chunks)"),
-    ("experts_held", "Experts held, summed over calls"),
-    # the first four again, over the chunk calls alone (more than one
-    # token a row: prefill chunks); sum less chunk is the single-token
-    # class, the decode steps
-    ("chunk_assignments", "Assignments routed by chunk calls"),
-    ("chunk_assignments_held", "Chunk calls' assignments held here"),
-    ("chunk_experts_touched", "Experts reached, summed over chunk calls"),
-    ("chunk_expert_layer_calls", "Expert-layer calls that were chunks"),
-)
-N_COUNTS = len(_COUNT_METRICS)
-_CLASS_COUNTS = tuple(name for name, _ in _COUNT_METRICS[:4])
+# what a layer may sow into the ``counters`` collection, by the name
+# it sows under: one float32 vector a call, an entry a line below.  A
+# program hands back the groups its model's layers sow, joined in this
+# order, as the tail of its packed token buffer; the metric of an entry
+# is ``mlcomp_engine_<group>_<entry>_total``.
+_COUNT_GROUPS = {
+    # models/moe.py RoutedExperts: assignments made, assignments whose
+    # expert is held here, experts a token reached, 1 (the call),
+    # experts held
+    "moe": (
+        ("assignments", "Token-to-expert assignments routed"),
+        ("assignments_held", "Assignments whose expert this chip holds"),
+        ("experts_touched",
+         "Experts a call's tokens reached, summed over calls"),
+        ("expert_layer_calls",
+         "Expert-layer calls (layers x steps, and chunks)"),
+        ("experts_held", "Experts held, summed over calls"),
+        # the first four again, over the chunk calls alone (more than
+        # one token a row: prefill chunks); sum less chunk is the
+        # single-token class, the decode steps
+        ("chunk_assignments", "Assignments routed by chunk calls"),
+        ("chunk_assignments_held", "Chunk calls' assignments held here"),
+        ("chunk_experts_touched",
+         "Experts reached, summed over chunk calls"),
+        ("chunk_expert_layer_calls", "Expert-layer calls that were chunks"),
+    ),
+    # models/retention.py PowerRetention (its COUNTS)
+    "retention": (
+        ("state_rows",
+         "Rows whose state a single-token step updated, summed over "
+         "layers and steps"),
+        ("state_bytes",
+         "Bytes those walks moved (ops/pallas/retention.py "
+         "state_bytes_moved): each row's state read and written once"),
+        ("chunk_tokens",
+         "Tokens chunk calls absorbed into a state, summed over layers"),
+        ("layer_calls", "Retention-layer calls (layers x steps, and chunks)"),
+    ),
+}
+_CLASS_COUNTS = tuple(name for name, _ in _COUNT_GROUPS["moe"][:4])
+
+
+def _sown_by_group(counters) -> Dict[str, List[Any]]:
+    """The leaves of a ``counters`` collection (or of its shapes), one a
+    layer, by the group of ``_COUNT_GROUPS`` they are sown under, in
+    that order."""
+    import jax
+
+    from mlcomp_tpu.cache.kv_store import _leaf_name
+
+    found: Dict[str, List[Any]] = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(counters):
+        found.setdefault(_leaf_name(path), []).append(leaf)
+    unknown = sorted(set(found) - set(_COUNT_GROUPS))
+    if unknown:
+        raise ValueError(
+            f"a layer sows counters under {unknown}: name its entries in "
+            "engine._COUNT_GROUPS"
+        )
+    return {g: found[g] for g in _COUNT_GROUPS if g in found}
 
 
 def _sown_counts(upd):
-    """The ``counters`` collection of one model call summed over its
-    layers, (N_COUNTS,) float32; None for a model that sows nothing."""
-    import jax
+    """The ``counters`` collection of one model call, each group summed
+    over its layers and the groups joined (``_COUNT_GROUPS``' order),
+    float32; None for a model that sows nothing."""
+    import jax.numpy as jnp
 
-    leaves = jax.tree_util.tree_leaves(upd.get("counters", {}))
-    return sum(leaves[1:], leaves[0]) if leaves else None
+    sums = [sum(leaves[1:], leaves[0])
+            for leaves in _sown_by_group(upd.get("counters", {})).values()]
+    if not sums:
+        return None
+    return sums[0] if len(sums) == 1 else jnp.concatenate(sums)
 
 
 def _pack_counts(packed, counts):
@@ -572,6 +617,52 @@ class DecodeEngine:
         self.l_buf = self.prompt_buckets[-1] + self.max_new_cap + 1
         self.vocab = int(getattr(model, "vocab_size"))
         self._jax, self._jnp = jax, jnp
+        # what one row of the model's cache holds, and what its layers
+        # count: shapes only.  The carry stays a pytree the engine does
+        # not look into; it reads the leaves' NAMES here, once, to
+        # refuse what a cache of this kind cannot do and to size the
+        # counters' channel.
+        from mlcomp_tpu.cache.kv_store import SLOT_AXES, _leaf_name
+        from mlcomp_tpu.models.generation import decode_shapes
+
+        shapes = decode_shapes(self.model, 1, self.l_buf)
+        cache_abs = shapes["cache"]
+        cache_leaves = {
+            _leaf_name(path): leaf for path, leaf
+            in jax.tree_util.tree_leaves_with_path(cache_abs)
+        }
+        # a leaf with no slot axis is per-slot STATE of fixed size (a
+        # recurrent layer's), not keys and values a token: it cannot be
+        # cut into pages or token spans, so whatever moves KV by pages
+        # or by prefix is refused here, by the leaf
+        state_leaves = sorted(
+            name for name, leaf in cache_leaves.items()
+            if leaf.ndim and name not in SLOT_AXES
+        )
+        if state_leaves:
+            asked = [what for what, on in (
+                ("kv_layout='paged' (and with it the import of a KV "
+                 "handoff)", kv_layout == "paged"),
+                ("prefix_cache", prefix_cache is not None),
+                ("prefill_only (KV export)", self.prefill_only),
+            ) if on]
+            if asked:
+                raise ValueError(
+                    f"{', '.join(asked)}: the model's cache holds per-"
+                    f"slot state {state_leaves} with no token axis, "
+                    "which has no pages and no prefix to share or hand "
+                    "off; serve it with the dense layout and no prefix "
+                    "cache"
+                )
+        self._count_layers = {
+            group: len(leaves) for group, leaves
+            in _sown_by_group(shapes.get("counters", {})).items()
+        }
+        self._count_entries: Tuple[Tuple[str, str, str], ...] = tuple(
+            (group, name, what)
+            for group in self._count_layers
+            for name, what in _COUNT_GROUPS[group]
+        )
 
         # paged device KV (mlcomp_tpu/kvpool, kv_layout="paged"): the
         # cache buffer becomes (num_pages, page_tokens, ...) blocks
@@ -612,8 +703,6 @@ class DecodeEngine:
                 PagedLayout,
                 PagePool,
             )
-            from mlcomp_tpu.models.generation import init_cache
-
             # one chunk width per bucket (the admission geometry):
             # pages must tile every chunk so registry-hit boundaries
             # (chunk-quantized, like the host prefix cache's) land on
@@ -622,9 +711,6 @@ class DecodeEngine:
                 kv_page_tokens,
                 "chunk-aligned prefix boundaries must land on page "
                 "boundaries",
-            )
-            cache_abs = jax.eval_shape(
-                lambda: init_cache(self.model, 1, self.l_buf)
             )
             # num_pages unset: the default pool budget below is itself
             # derived from the layout's max_pages
@@ -774,14 +860,10 @@ class DecodeEngine:
         self._export_leaves = None
         if self.prefill_only:
             from mlcomp_tpu.cache.kv_store import kv_leaf_items
-            from mlcomp_tpu.models.generation import init_cache
 
             self._export_T = self._page_quantum(
                 kv_page_tokens,
                 "handoff pages must tile the admission geometry",
-            )
-            cache_abs = jax.eval_shape(
-                lambda: init_cache(self.model, 1, self.l_buf)
             )
             self._export_leaves = [
                 (keystr, axis, tuple(leaf.shape), leaf.dtype)
@@ -931,25 +1013,18 @@ class DecodeEngine:
         self._attn_window_layers = {   # window -> layers that have it
             w: self._attn_windows.count(w) for w in set(self._attn_windows)
         }
-        if self.kv_layout == "dense":
-            from mlcomp_tpu.cache.kv_store import _leaf_name
-            from mlcomp_tpu.models.generation import init_cache
+        if self.kv_layout == "dense" and "cached_key_q" in cache_leaves:
             from mlcomp_tpu.ops.pallas.decode_attention import auto_block_kv
 
-            leaves = jax.tree_util.tree_leaves_with_path(jax.eval_shape(
-                lambda: init_cache(self.model, 1, self.l_buf)
-            ))
-            shape = next((leaf.shape for path, leaf in leaves
-                          if _leaf_name(path) == "cached_key_q"), None)
-            if shape is not None:
-                # under a mesh each device walks its own KV heads
-                tp = mesh.shape.get("tp", 1) if mesh is not None else 1
-                self._kv_walk = (shape[2], auto_block_kv(
-                    shape[2], max(1, shape[1] // tp), shape[3]))
+            shape = cache_leaves["cached_key_q"].shape
+            # under a mesh each device walks its own KV heads
+            tp = mesh.shape.get("tp", 1) if mesh is not None else 1
+            self._kv_walk = (shape[2], auto_block_kv(
+                shape[2], max(1, shape[1] // tp), shape[3]))
         # what the model's layers sowed, summed over every program
         # read back (_sown_counts' order); a staged chunk's counts wait
         # here for the next read
-        self._counts = np.zeros((N_COUNTS,), np.float64)  # guarded_by: loop [writes]
+        self._counts = np.zeros((len(self._count_entries),), np.float64)  # guarded_by: loop [writes]
         self._counts_pending: Deque[Any] = deque()  # guarded_by: loop [writes]
         self._t_acct = time.perf_counter()  # guarded_by: loop [writes]
         # per-request latency reservoirs (most recent ~2k requests;
@@ -1832,28 +1907,47 @@ class DecodeEngine:
                 },
             },
         }
-        counts = [float(c) for c in self._counts]
-        made, held, touched, calls, here = counts[:5]
-        if calls:
-            # a routed expert layer's own counts, summed over layers,
-            # steps and chunks of every dispatch read back
+        # what the model's layers counted, summed over layers, steps
+        # and chunks of every dispatch read back: a block a group
+        counted = {
+            group: {} for group, _, _ in self._count_entries
+        }
+        for (group, name, _), c in zip(self._count_entries, self._counts):
+            counted[group][name] = float(c)
+        moe = counted.get("moe")
+        if moe and moe["expert_layer_calls"]:
+            calls, touched = moe["expert_layer_calls"], moe["experts_touched"]
+            chunk = {k: moe["chunk_" + k] for k in _CLASS_COUNTS}
             out["moe"] = {
-                "assignments": made,
-                "assignments_held": held,
+                "assignments": moe["assignments"],
+                "assignments_held": moe["assignments_held"],
                 "experts_touched": touched,
                 "expert_layer_calls": calls,
                 "experts_touched_per_call": round(touched / calls, 3),
                 # of the experts held, summed over the same calls
-                "experts_touched_share": round(touched / here, 4),
+                "experts_touched_share": round(
+                    touched / moe["experts_held"], 4
+                ),
                 # the four counts by call class: chunk calls (prefill)
                 # and single-token calls (decode steps)
                 "by_class": {
-                    "chunk": dict(zip(_CLASS_COUNTS, counts[5:9])),
+                    "chunk": chunk,
                     "single_token": {
-                        k: a - c for k, a, c
-                        in zip(_CLASS_COUNTS, counts[:4], counts[5:9])
+                        k: moe[k] - chunk[k] for k in _CLASS_COUNTS
                     },
                 },
+            }
+        ret = counted.get("retention")
+        if ret and ret["layer_calls"]:
+            issued = p["kv_rows_written"] * self._count_layers["retention"]
+            out["retention"] = {
+                **ret,
+                # the device's count of rows over the host mirror's
+                # (rows holding a request at issue x steps x layers):
+                # under 1 by the rows that retired inside a dispatch
+                "state_rows_over_issued": round(
+                    ret["state_rows"] / issued, 4
+                ) if issued else None,
             }
         out["latency"] = {
             # "samples" is the WINDOW the percentiles summarize (the
@@ -2030,9 +2124,10 @@ class DecodeEngine:
         ctr("mlcomp_engine_attention_kv_tokens_live_window_total",
             "The part of kv_tokens_live on layers with a window",
             p["kv_live_window"])
-        if self._counts[3]:  # a routed expert layer has been called
-            for (name, what), value in zip(_COUNT_METRICS, self._counts):
-                ctr(f"mlcomp_engine_moe_{name}_total", what, float(value))
+        for (group, name, what), value in zip(
+            self._count_entries, self._counts
+        ):
+            ctr(f"mlcomp_engine_{group}_{name}_total", what, float(value))
         gau("mlcomp_engine_pipeline_depth", "Configured pipeline depth",
             self.pipeline_depth)
         gau("mlcomp_engine_pipeline_inflight",
@@ -3175,7 +3270,7 @@ class DecodeEngine:
                 )
                 counts = _sown_counts(upd)
                 if counts is not None:
-                    packed = packed.at[-N_COUNTS:].add(counts)
+                    packed = packed.at[-counts.shape[0]:].add(counts)
                 out = self._constrain_carry(out)
                 packed = self._replicate_out(packed)
                 return (out, packed, logits[:, -1].astype(jnp.float32),
@@ -4639,8 +4734,9 @@ class DecodeEngine:
             if arr.ndim == 1:
                 # a model whose layers sow counts: they ride the tail
                 # of the same buffer (_pack_counts)
-                self._counts += arr[-N_COUNTS:]
-                arr = arr[:-N_COUNTS].reshape(3, -1, len(self._host))
+                n = len(self._counts)
+                self._counts += arr[-n:]
+                arr = arr[:-n].reshape(3, -1, len(self._host))
             while self._counts_pending:
                 self._counts += np.asarray(self._counts_pending.popleft())
         finally:

@@ -28,13 +28,11 @@ import jax
 import jax.numpy as jnp
 
 
-def init_cache(model, batch_size: int, max_len: int) -> Dict[str, Any]:
-    """Allocate a zeroed decode cache for ``(batch_size, max_len)``.
-
-    Uses ``jax.eval_shape`` over ``model.init`` so no actual forward pass
-    (or param materialization) happens — only the cache pytree structure
-    is derived, then zeros are allocated.
-    """
+def decode_shapes(model, batch_size: int, max_len: int) -> Dict[str, Any]:
+    """The shapes of every collection the model makes under
+    ``decode=True`` for ``(batch_size, max_len)`` (``cache``, and
+    ``counters`` where its layers sow): ``jax.eval_shape`` over
+    ``model.init``, so no forward pass runs and nothing is allocated."""
     shapes = jax.eval_shape(
         lambda: model.init(
             jax.random.PRNGKey(0),
@@ -48,6 +46,14 @@ def init_cache(model, batch_size: int, max_len: int) -> Dict[str, Any]:
             f"{type(model).__name__} creates no 'cache' collection under "
             "decode=True; generation needs a decode-capable model"
         )
+    return shapes
+
+
+def init_cache(model, batch_size: int, max_len: int) -> Dict[str, Any]:
+    """Allocate a zeroed decode cache for ``(batch_size, max_len)``:
+    the cache pytree's structure from :func:`decode_shapes`, then
+    zeros."""
+    shapes = decode_shapes(model, batch_size, max_len)
     return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes["cache"])
 
 

@@ -5,11 +5,16 @@ numbers.  Here each layer names its attention kind (``"full"`` or
 ``"sliding"``: its own RoPE description, and a window for the sliding
 kind), its number of query heads, and its MLP kind (``"dense"``
 SwiGLU, or ``"sparse"``: dropless top-k routed experts plus a
-shared expert, ``models/moe.py`` ``RoutedExperts``).  Every layer is
-``transformer.SelfAttention`` (a per-head output gate, a head width
-that is a field) followed by its MLP: one attention module, one
+shared expert, ``models/moe.py`` ``RoutedExperts``).  An attention
+layer is ``transformer.SelfAttention`` (a per-head output gate, a head
+width that is a field) followed by its MLP: one attention module, one
 decode-attention kernel family, one cache layout (a whole ``l_buf`` a
 layer; a window layer reads its last ``window`` tokens).
+
+A third kind, ``"retention"``, keeps no keys and values: the layer is
+``models/retention.py`` ``PowerRetention`` (``rope_full``'s rotation,
+``qk_norm``), its cache a recurrent state of fixed size a slot, and its
+MLP the same.  A stack is attention or retention throughout.
 
 What a model may also say: a RoPE description whose ``rotary_dim`` is
 0 rotates nothing (a layer without positional embedding);
@@ -30,6 +35,7 @@ import jax.numpy as jnp
 
 from mlcomp_tpu.models import MODELS
 from mlcomp_tpu.models.moe import RoutedExperts
+from mlcomp_tpu.models.retention import PowerRetention
 from mlcomp_tpu.models.transformer import (
     RMSNorm,
     RopeSpec,
@@ -62,17 +68,29 @@ class MixedLayer(nn.Module):
     # the router scores the attention's normed input, not the experts'
     early_router: bool = False
     expert_gate: str = "silu"
+    # power retention in the attention's place (and its q/k norm)
+    retention: bool = False
+    qk_norm: bool = False
 
     @nn.compact
     def __call__(self, x, positions, decode=False, kv_mask=None,
                  cache_cursor=None):
-        x = SelfAttention(
-            self.hidden, self.heads, self.kv_heads, self.dtype,
-            kv_quant=self.kv_quant, head_dim=self.head_dim, rope=self.rope,
-            window=self.window, head_gate=self.head_gate,
-            return_normed=self.early_router, name="attn",
-        )(x, positions, decode=decode, kv_mask=kv_mask,
-          cache_cursor=cache_cursor)
+        if self.retention:
+            mixer = PowerRetention(
+                self.hidden, self.heads, self.kv_heads, self.head_dim,
+                self.dtype, rope=self.rope, qk_norm=self.qk_norm,
+                name="attn",
+            )
+        else:
+            mixer = SelfAttention(
+                self.hidden, self.heads, self.kv_heads, self.dtype,
+                kv_quant=self.kv_quant, head_dim=self.head_dim,
+                rope=self.rope, window=self.window,
+                head_gate=self.head_gate,
+                return_normed=self.early_router, name="attn",
+            )
+        x = mixer(x, positions, decode=decode, kv_mask=kv_mask,
+                  cache_cursor=cache_cursor)
         x, pre = x if self.early_router else (x, None)
         h = RMSNorm(self.dtype)(x)
         if self.mlp_dim is not None:
@@ -114,6 +132,8 @@ class MixedLayerLM(nn.Module):
     shared_width: int = 0
     early_router: bool = False
     expert_gate: str = "silu"
+    # a retention layer's q and k are RMS-normed a head before RoPE
+    qk_norm: bool = False
     dtype: str = "bfloat16"
     kv_quant: bool = False
     # the head's matmul operands (accumulation and logits stay float32):
@@ -121,11 +141,12 @@ class MixedLayerLM(nn.Module):
     head_dtype: str = "float32"
 
     def attention_windows(self) -> Tuple[Optional[int], ...]:
-        """Each layer's window (None: the whole context), for the
-        engine's count of the context tokens attention reads."""
+        """Each attention layer's window (None: the whole context),
+        for the engine's count of the context tokens attention reads;
+        a retention layer reads no context tokens and has no entry."""
         return tuple(
             self.window if kind == "sliding" else None
-            for kind in self.layer_types
+            for kind in self.layer_types if kind != "retention"
         )
 
     @nn.compact
@@ -159,6 +180,7 @@ class MixedLayerLM(nn.Module):
                 shared_width=self.shared_width,
                 early_router=self.early_router,
                 expert_gate=self.expert_gate,
+                retention=kind == "retention", qk_norm=self.qk_norm,
                 name=f"layer_{i}",
             )(h, positions, decode, kv_mask, cache_cursor)
         h = RMSNorm(dtype)(h)
@@ -177,11 +199,35 @@ def mixed_layer_lm(**cfg: Any) -> MixedLayerLM:
     n = {len(cfg[k]) for k in lists}
     if len(n) != 1:
         raise ValueError(f"{lists} must be one entry a layer, got lengths {n}")
-    for kind, allowed in (("layer_types", ("full", "sliding")),
+    for kind, allowed in (("layer_types", ("full", "sliding", "retention")),
                           ("mlp_layer_types", ("dense", "sparse"))):
         bad = sorted(set(cfg[kind]) - set(allowed))
         if bad:
             raise ValueError(f"{kind}: {bad} not among {allowed}")
+    kinds = set(cfg["layer_types"])
+    if "retention" in kinds:
+        # a retention layer keeps a state, not keys and values
+        for key, why in (
+            ("kv_quant", "there are no keys and values to quantize"),
+            ("window", "its gates do the forgetting"),
+            ("head_gate", "its output has no gate"),
+            ("early_router", "it hands no normed input on"),
+        ):
+            if cfg.get(key):
+                raise ValueError(
+                    f"{key} on a retention layer: {why}; layer_types "
+                    f"{list(cfg['layer_types'])}"
+                )
+        if kinds != {"retention"}:
+            raise ValueError(
+                "retention beside attention in one stack is not served "
+                f"yet; layer_types {list(cfg['layer_types'])}"
+            )
+    elif cfg.get("qk_norm"):
+        raise ValueError(
+            "qk_norm: only a retention layer norms its q and k; "
+            f"layer_types {list(cfg['layer_types'])}"
+        )
     if cfg.get("early_router") and "dense" in cfg["mlp_layer_types"]:
         raise ValueError(
             "early_router: a dense MLP has no router to move before the "

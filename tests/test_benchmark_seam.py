@@ -4,11 +4,13 @@ numbers rest on) and ``benchmark/tests/test_loop_spans.py`` (the readers
 of the engine's spans and ``stats()``, on hand-made events and on a live
 rehearsal-width service), and ``benchmark/tests/test_mixedlen_readers.py``
 (the readers of the counts by layer kind and by call class, on
-hand-made ops and ``stats()``).  A program PR that renames a span or drops a
+hand-made ops and ``stats()``) and
+``benchmark/tests/test_retention_readers.py`` (the retention step's
+roofline arithmetic and the readers of the layer's counters).  A program PR that renames a span or drops a
 ``stats()`` key fails here, not as a ``null`` per-layer metric after a
 chip run.  The tests are the benchmark's own, imported; nothing under
 ``benchmark/`` is edited.  Not ``test_correct.py``, ``test_laguna.py`` or
-``test_smallthinker.py``: they take minutes (``pytest benchmark/tests``
+``test_smallthinker.py`` or ``test_brumby.py``: they take minutes (``pytest benchmark/tests``
 runs them all)."""
 
 import os
@@ -18,6 +20,7 @@ import pytest
 from benchmark.tests.conftest import rehearse  # noqa: F401  (a fixture)
 from benchmark.tests.test_loop_spans import *  # noqa: F401,F403
 from benchmark.tests.test_mixedlen_readers import *  # noqa: F401,F403
+from benchmark.tests.test_retention_readers import *  # noqa: F401,F403
 from benchmark.tests.test_yardstick import *  # noqa: F401,F403
 
 _CACHE_OPTIONS = (

@@ -294,3 +294,32 @@ def test_grouped_matmul_held_experts(chip, tokens, e, h, f, k, gate):
     )
     # the benchmark's roofline reader matches the op by this name
     assert "grouped_matmul" in text
+
+
+# Brumby-14B's cell (continuation-offline): 20 slots, 5 query heads a
+# KV head over 8 KV heads of 128, a float32 state of 8,320 x 128 a head
+@pytest.mark.parametrize("product", ["bfloat16", "float32"])
+def test_retention_step_walks_the_state_in_place(chip, product):
+    from mlcomp_tpu.ops.pallas.retention import (
+        expanded_width,
+        retention_step,
+    )
+
+    b, n, g = 20, 8, 5
+    width = expanded_width(DH)
+
+    def step(q, k, v, log_g, live, state, norm):
+        return retention_step(q, k, v, log_g, live, state, norm, eps=1e-6,
+                              product_dtype=product, interpret=False)
+
+    args = (chip((b, n, g, DH), jnp.bfloat16), chip((b, n, DH), jnp.bfloat16),
+            chip((b, n, DH), jnp.bfloat16), chip((b, n), jnp.float32),
+            chip((b,), jnp.bool_), chip((b, n, width, DH), jnp.float32),
+            chip((b, n, width), jnp.float32))
+    compiled = jax.jit(step, donate_argnums=(5, 6)).lower(*args).compile()
+    text = compiled.as_text()
+    # the benchmark's readers match the op by this name
+    assert "tpu_custom_call" in text and "retention_step" in text
+    # the state and the normaliser are updated where they are
+    state_bytes = b * n * width * (DH + 1) * 4
+    assert compiled.memory_analysis().alias_size_in_bytes >= state_bytes

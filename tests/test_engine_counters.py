@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from mlcomp_tpu.engine import N_COUNTS, DecodeEngine
+from mlcomp_tpu.engine import _COUNT_GROUPS, DecodeEngine
 from mlcomp_tpu.models import create_model
 from mlcomp_tpu.train.state import init_model
 
@@ -102,7 +102,7 @@ def test_the_counts_ride_the_tail_of_the_packed_buffer():
             eng._dispatch_fn(), eng.variables, eng._dstate)
     finally:
         eng.close()
-    assert packed.shape == (3 * 2 * 2 + N_COUNTS,)
+    assert packed.shape == (3 * 2 * 2 + len(_COUNT_GROUPS["moe"]),)
 
 
 # a router before the attention, ReLU experts, a first layer that rotates
@@ -373,3 +373,60 @@ def test_no_dense_int8_walk_no_tokens_fetched():
     assert att["kv_tokens_fetched"] == 0
     assert att["kv_fetch_live_share"] is None
     assert att["kv_tokens_attended"] > 0
+
+
+# ---- a second group in the one channel: a retention layer's counts ----
+
+RETENTION = {
+    "name": "mixed_layer_lm", "vocab_size": 64, "hidden": 64, "head_dim": 16,
+    "kv_heads": 2, "layer_types": ["retention"] * 2,
+    "heads_per_layer": [4, 4], "mlp_layer_types": ["dense"] * 2,
+    "mlp_dim": 128, "rope_full": {"base": 1000000.0}, "qk_norm": True,
+    "dtype": "float32",
+}
+
+
+def test_a_retention_layers_counts_are_the_hand_counts():
+    """One request alone on three slots: 12 prompt tokens in a bucket of
+    16 are two chunks of 8 (four pads in the first), then three
+    dispatches of K = 2 steps, the one live row through two layers."""
+    from mlcomp_tpu.models.retention import COUNTS
+    from mlcomp_tpu.ops.pallas.retention import state_bytes_moved
+
+    assert tuple(n for n, _ in _COUNT_GROUPS["retention"]) == COUNTS
+    model, params = _build(RETENTION)
+    k, layers = 2, 2
+    eng = DecodeEngine(model, {"params": params}, slots=3,
+                       prompt_buckets=(16,), max_new_cap=16,
+                       steps_per_dispatch=k, prefill_chunk=8,
+                       pipeline_depth=1)
+    try:
+        _, packed = jax.eval_shape(
+            eng._dispatch_fn(), eng.variables, eng._dstate)
+        assert packed.shape == (3 * k * 3 + len(COUNTS),)
+        out = eng.submit(list(range(1, 13)), 3 * k).result(timeout=300)
+        st = eng.stats()
+        text = eng.metrics.render()
+    finally:
+        eng.close()
+    assert len(out["ids"]) == 3 * k and st["pipeline"]["issued"] == 3
+    # greedy through the engine is greedy under the full forward pass
+    seq = jnp.asarray([list(range(1, 13)) + out["ids"]])
+    want = np.asarray(jnp.argmax(
+        model.apply({"params": params}, seq)[0, 11:-1], -1)).tolist()
+    assert out["ids"] == want
+    rows = 3 * k * layers
+    assert "moe" not in st
+    assert st["retention"] == {
+        "state_rows": rows,
+        "state_bytes": state_bytes_moved(rows, 2, 16),
+        "chunk_tokens": 12 * layers,
+        "layer_calls": (2 + 3 * k) * layers,
+        "state_rows_over_issued": 1.0,
+    }
+    assert st["attention"]["kv_rows_written"] == 3 * k
+    assert st["attention"]["kv_tokens_live"] == 0
+    assert st["attention"]["kv_tokens_attended_share"] is None
+    assert f"mlcomp_engine_retention_state_rows_total {rows}" in text
+    assert f"mlcomp_engine_retention_chunk_tokens_total {12 * layers}" in text
+    assert "mlcomp_engine_moe_" not in text

@@ -505,3 +505,129 @@ def test_the_other_served_models_build_what_they_built(name):
     assert programs == {
         ("dispatch", 4), ("dispatch_core", 4), ("fused_dispatch", chunk, 4),
         ("prefill_chunk", chunk), "insert", "prefill_init"}
+
+
+# ---- what Brumby-14B adds: a third layer kind that keeps a recurrent
+# state and no keys and values (models/retention.py) ----
+
+def _brumby():
+    cfg = _cfg("_rehearsal/brumby-14b-serve.json")
+    arch = cells.architecture(cfg)
+    model = {**cfg["model"], "dtype": "float32", "head_dtype": "float32"}
+    return arch, arch.dims_of(cfg), model
+
+
+def test_a_retention_stack_is_assembled_from_the_lists():
+    arch, d, kw = _brumby()
+    assert arch.layer_kinds(d) == ["retention", "retention"]
+    model = create_model(dict(kw))
+    # no attention layer reads context tokens
+    assert model.attention_windows() == ()
+    params = W.program_params(arch, 7, d, jnp.float32)
+    abstract = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
+    W.check_layout(params, abstract)
+    assert set(params["layer_0"]) == {"attn", "RMSNorm_0", "gate", "up",
+                                      "down"}
+    assert set(params["layer_0"]["attn"]) == {
+        "RMSNorm_0", "q", "k", "v", "out", "gate", "q_norm", "k_norm"}
+    np.testing.assert_allclose(
+        params["layer_1"]["attn"]["gate"]["bias"], [3.0, 8.0])
+    # the cache is state of a fixed size, whatever the buffer's length
+    for l_buf in (24, 4353):
+        cache = jax.eval_shape(lambda: init_cache(model, 3, l_buf))
+        assert {k: v.shape for k, v in cache["layer_0"]["attn"].items()} == {
+            "state": (3, 2, 9 * 16, 16), "norm": (3, 2, 9 * 16),
+            "cache_index": ()}
+
+
+@pytest.mark.parametrize("asked,refusal", [
+    ({"kv_quant": True}, "kv_quant on a retention layer"),
+    ({"window": 16}, "window on a retention layer"),
+    ({"layer_types": ["retention", "full"]},
+     "retention beside attention in one stack"),
+    ({"layer_types": ["full", "full"]}, "qk_norm: only a retention layer"),
+], ids=["kv_quant", "window", "beside_attention", "qk_norm_on_attention"])
+def test_what_a_retention_stack_cannot_be_is_refused_at_create_model(
+        asked, refusal):
+    _, _, kw = _brumby()
+    with pytest.raises(ValueError, match=refusal):
+        create_model({**kw, **asked})
+
+
+def _served_logits(model, params, ids, n_prompt, bucket=32, chunk=8, l_buf=65):
+    """The engine's contract on one row: a LEFT-padded prompt in chunks
+    (pads and tokens share a chunk), then single-token steps at a
+    cursor; the logits of the real positions."""
+    pad = bucket - n_prompt
+    row = np.zeros((1, bucket), np.int32)
+    row[0, pad:] = ids[0, :n_prompt]
+    positions = np.maximum(np.arange(bucket) - pad, 0)[None].astype(np.int32)
+    kv_mask = jnp.asarray((np.arange(l_buf) >= pad)[None])
+    cache = init_cache(model, 1, l_buf)
+    out = []
+    for lo in range(0, bucket, chunk):
+        lg, upd = model.apply(
+            {"params": params, "cache": cache},
+            jnp.asarray(row[:, lo:lo + chunk]), decode=True,
+            positions=jnp.asarray(positions[:, lo:lo + chunk]),
+            kv_mask=kv_mask, mutable=["cache", "counters"])
+        cache = upd["cache"]
+        out.append(np.asarray(lg))
+    out = [np.concatenate(out, 1)[:, pad:]]
+    for t in range(n_prompt, ids.shape[1]):
+        lg, upd = model.apply(
+            {"params": params, "cache": cache},
+            jnp.asarray(ids[:, t:t + 1]), decode=True,
+            positions=jnp.full((1, 1), t, jnp.int32), kv_mask=kv_mask,
+            cache_cursor=jnp.array([bucket + t - n_prompt], jnp.int32),
+            mutable=["cache", "counters"])
+        cache = upd["cache"]
+        out.append(np.asarray(lg))
+    return np.concatenate(out, 1)
+
+
+@pytest.mark.parametrize("reference", [
+    "as_published", "gates_of_one", "no_normaliser", "degree_one"])
+def test_the_three_forms_are_brumbys_layer_and_no_other(
+        reference, monkeypatch):
+    """The full forward (the quadratic form), and chunks then single
+    steps through the state, against ``reference/brumby.py``; the
+    reference with its gate, its normaliser or its degree undone does
+    not agree."""
+    arch, d, kw = _brumby()
+    model = create_model(dict(kw))
+    params = W.program_params(arch, 7, d, jnp.float32)
+    ids = np.asarray(jax.random.randint(jax.random.PRNGKey(0), (1, 50), 1, 512))
+    with jax.default_matmul_precision("highest"):
+        whole = np.asarray(model.apply({"params": params}, jnp.asarray(ids)))
+        served = _served_logits(model, params, ids, n_prompt=21)
+    np.testing.assert_allclose(served, whole, atol=2e-4)
+    patch = {
+        "gates_of_one": ("log_gate", jnp.zeros_like),
+        "no_normaliser": ("normalised", lambda num, den: num),
+        "degree_one": ("power", lambda dots: dots),
+    }.get(reference)
+    if patch:
+        monkeypatch.setattr(arch, *patch)
+    err = np.abs(served - _reference_logits(arch, d, 7, ids)).max()
+    if reference == "as_published":
+        assert err < 2e-4
+    else:
+        assert err > 0.05
+
+
+def test_a_chunk_that_decays_through_its_pads_is_not_the_layer(monkeypatch):
+    """The wrong PROGRAM: left pads that decay the state (and add their
+    keys) give other logits than the reference's."""
+    from mlcomp_tpu.models.retention import PowerRetention
+
+    arch, d, kw = _brumby()
+    model = create_model(dict(kw))
+    params = W.program_params(arch, 7, d, jnp.float32)
+    ids = np.asarray(jax.random.randint(jax.random.PRNGKey(0), (1, 50), 1, 512))
+    monkeypatch.setattr(PowerRetention, "_masked",
+                        staticmethod(lambda k, log_g, valid: (k, log_g)))
+    with jax.default_matmul_precision("highest"):
+        served = _served_logits(model, params, ids, n_prompt=21)
+    assert np.abs(served - _reference_logits(arch, d, 7, ids)).max() > 0.05

@@ -163,6 +163,11 @@ CONDITIONAL_METRICS = {
     "mlcomp_engine_moe_chunk_assignments_held_total",
     "mlcomp_engine_moe_chunk_experts_touched_total",
     "mlcomp_engine_moe_chunk_expert_layer_calls_total",
+    # models with a retention layer only (PowerRetention sows them)
+    "mlcomp_engine_retention_state_rows_total",
+    "mlcomp_engine_retention_state_bytes_total",
+    "mlcomp_engine_retention_chunk_tokens_total",
+    "mlcomp_engine_retention_layer_calls_total",
 }
 
 MUTATOR_METHODS = {
