@@ -961,9 +961,17 @@ class TransformerLM(nn.Module):
     dtype: str = "bfloat16"
     seq_parallel: "bool | str" = False
     # rematerialize each decoder layer in the backward pass: activation
-    # memory drops from O(layers * S * hidden * ~10 tensors) to one
-    # residual per layer, at ~1/3 extra matmul FLOPs — the standard trade
-    # for long-S training (HBM is the scarce resource, MXU has headroom)
+    # memory drops from O(layers * S * hidden * ~10 tensors) to the
+    # layer's input and, where attention takes the flash kernel, that
+    # kernel's five residuals: q, k, v as they enter it, its output and
+    # one float32 logsumexp a row (four hidden-wide tensors a token with
+    # the input, at kv_heads = heads / 2: k and v make one between them).
+    # Done twice: both norms, the o / gate / up projections and the
+    # SwiGLU product (~1/5 extra matmul FLOPs).  Done once: the q / k / v
+    # projections, RoPE, the transposes and the flash forward kernel,
+    # whose S^2 work costs far more time a byte kept than any matmul.
+    # Below the kernel's lengths (ops/attention.py _flash_covers) no name
+    # is bound and the whole layer is recomputed
     remat: bool = False
     # compute the next-token CE inside the model via the chunked fused
     # head (ops/fused_ce.py) instead of materializing (B, S, V) fp32
@@ -1023,8 +1031,17 @@ class TransformerLM(nn.Module):
         h = nn.Embed(self.vocab_size, self.hidden, dtype=dtype, name="emb")(ids)
         layer_cls = DecoderLayer
         if self.remat and not decode:
-            # static_argnums counts self as 0: decode is arg 3
-            layer_cls = nn.remat(DecoderLayer, static_argnums=(3,))
+            from mlcomp_tpu.ops.pallas import flash_attention
+
+            # static_argnums counts self as 0: decode is arg 3.  The
+            # policy keeps what the flash kernel's backward reads (no
+            # name is bound where attention takes the XLA path)
+            layer_cls = nn.remat(
+                DecoderLayer, static_argnums=(3,),
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    *flash_attention.REMAT_SAVED_NAMES
+                ),
+            )
         for i in range(self.layers):
             # explicit names keep param paths identical with and without
             # remat (nn.remat would auto-name "CheckpointDecoderLayer_i",

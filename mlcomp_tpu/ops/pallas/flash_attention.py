@@ -48,6 +48,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -1055,6 +1056,9 @@ def _flash_bwd(scale, causal, block_q, block_kv, interpret, res, g,
     nq, nk = s_q // block_q, s_k // block_kv
     do = g.astype(q.dtype)
     bounded = kv_lo is not None
+    # the residual is one number a row, (B, H, S); the kernels read it
+    # broadcast over a 128-lane minor dim (TPU block tiling)
+    lse = jnp.broadcast_to(lse[..., None], (*lse.shape, LANES))
 
     # delta_i = sum_d dO_i * O_i — tiny elementwise reduce; XLA fuses it.
     # An lse cotangent folds in here exactly: dL/ds_ij has the out-path
@@ -1187,11 +1191,39 @@ def _flash(q, k, v, kv_lo, kv_hi, scale, causal, block_q, block_kv, interpret):
     return out
 
 
-def _flash_vjp_fwd(q, k, v, kv_lo, kv_hi, scale, causal, block_q, block_kv, interpret):
+# The names a rematerialised layer keeps (models/transformer.py hands
+# them to ``save_only_these_names``): the kernel's own residuals, so the
+# backward pass neither calls the forward kernel a second time nor redoes
+# the projections, RoPE and transposes in front of it.  Outside
+# ``jax.checkpoint`` a name is an identity.
+REMAT_SAVED_NAMES = ("flash_q", "flash_k", "flash_v", "flash_out", "flash_lse")
+
+
+def _named_fwd(q, k, v, kv_lo, kv_hi, scale, causal, block_q, block_kv,
+               interpret):
+    """The forward kernel with its five residuals under
+    ``REMAT_SAVED_NAMES``.  ``out`` is named ONCE and that value is both
+    the primal output and the residual: a name on the residual alone
+    leaves the recompute in need of ``out`` downstream (the o projection)
+    and the second kernel call stays.  ``lse`` is kept as (B, H, S): a
+    trailing axis of 1 would be padded back to 128 lanes by the chip's
+    tiled layout."""
+    q = checkpoint_name(q, "flash_q")
+    k = checkpoint_name(k, "flash_k")
+    v = checkpoint_name(v, "flash_v")
     out, lse = _flash_fwd(
         q, k, v, kv_lo, kv_hi, scale, causal, block_q, block_kv, interpret
     )
-    return out, (q, k, v, kv_lo, kv_hi, out, lse)
+    out = checkpoint_name(out, "flash_out")
+    lse = checkpoint_name(lse[..., 0], "flash_lse")
+    return out, lse, (q, k, v, kv_lo, kv_hi, out, lse)
+
+
+def _flash_vjp_fwd(q, k, v, kv_lo, kv_hi, scale, causal, block_q, block_kv, interpret):
+    out, _, res = _named_fwd(
+        q, k, v, kv_lo, kv_hi, scale, causal, block_q, block_kv, interpret
+    )
+    return out, res
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_bwd)
@@ -1216,10 +1248,10 @@ def _flash_pair(q, k, v, kv_lo, kv_hi, scale, causal, block_q, block_kv,
 
 def _flash_pair_vjp_fwd(q, k, v, kv_lo, kv_hi, scale, causal, block_q,
                         block_kv, interpret):
-    out, lse = _flash_fwd(
+    out, lse, res = _named_fwd(
         q, k, v, kv_lo, kv_hi, scale, causal, block_q, block_kv, interpret
     )
-    return (out, lse[..., 0]), (q, k, v, kv_lo, kv_hi, out, lse)
+    return (out, lse), res
 
 
 def _flash_pair_bwd(scale, causal, block_q, block_kv, interpret, res, gs):

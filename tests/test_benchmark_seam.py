@@ -6,7 +6,9 @@ rehearsal-width service), and ``benchmark/tests/test_mixedlen_readers.py``
 (the readers of the counts by layer kind and by call class, on
 hand-made ops and ``stats()``) and
 ``benchmark/tests/test_retention_readers.py`` (the retention step's
-roofline arithmetic and the readers of the layer's counters).  A program PR that renames a span or drops a
+roofline arithmetic and the readers of the layer's counters) and
+``benchmark/tests/test_flash_fwd_calls.py`` (the count of flash forward
+calls a backward call, on hand-made ops).  A program PR that renames a span or drops a
 ``stats()`` key fails here, not as a ``null`` per-layer metric after a
 chip run.  The tests are the benchmark's own, imported; nothing under
 ``benchmark/`` is edited.  Not ``test_correct.py``, ``test_laguna.py`` or
@@ -18,6 +20,7 @@ import os
 import pytest
 
 from benchmark.tests.conftest import rehearse  # noqa: F401  (a fixture)
+from benchmark.tests.test_flash_fwd_calls import *  # noqa: F401,F403
 from benchmark.tests.test_loop_spans import *  # noqa: F401,F403
 from benchmark.tests.test_mixedlen_readers import *  # noqa: F401,F403
 from benchmark.tests.test_retention_readers import *  # noqa: F401,F403

@@ -367,3 +367,71 @@ def test_dispatch_under_mesh_is_a_shard_map_island(monkeypatch, bounded):
             np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5)
     finally:
         set_current_mesh(None)
+
+
+@pytest.mark.parametrize("bounded", [False, True])
+def test_backward_from_one_lane_lse_equals_the_128_lane_one(bounded):
+    """The residual ``lse`` is one number a row, (B, H, S); broadcast back
+    in ``_flash_bwd`` it gives, bit for bit, what the dq / dkv kernels
+    gave from the forward kernel's own 128-lane buffer: the causal
+    triangular grids, and a bounded window's scheduled grids."""
+    from mlcomp_tpu.ops.pallas import flash_attention as fa
+
+    b, h, s, d = 2, 2, 256, 128
+    q, k, v, do = (_rand((b, h, s, d), 40 + i) for i in range(4))
+    lo = jnp.asarray([5, 0], jnp.int32) if bounded else None
+    hi = jnp.asarray([256, 130], jnp.int32) if bounded else None
+    causal, scale, blk = not bounded, d ** -0.5, 128
+    out, lse = fa._flash_fwd(q, k, v, lo, hi, scale, causal, blk, blk, True)
+    assert lse.shape == (b, h, s, fa.LANES)
+
+    _, res = fa._flash_vjp_fwd(q, k, v, lo, hi, scale, causal, blk, blk, True)
+    assert res[-1].shape == (b, h, s)
+    np.testing.assert_array_equal(res[-1], lse[..., 0])
+    got = fa._flash_bwd(scale, causal, blk, blk, True, res, do)[:3]
+
+    delta = jnp.sum(do * out, axis=-1)
+    delta = jnp.broadcast_to(delta[..., None], (*delta.shape, fa.LANES))
+    if bounded:
+        want = fa._flash_bwd_bsched(scale, blk, blk, True, q, k, v, lo, hi,
+                                    do, lse, delta, causal=False)[:3]
+    else:
+        want = fa._flash_bwd_tri(scale, blk, blk, True, q, k, v, do, lse,
+                                 delta)[:3]
+    for a, b_ in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_lse_cotangent_grads_match_reference(causal):
+    """``flash_attention_lse``'s lse output is differentiable (ring
+    attention's merge weights): its pair rule shares ``_flash``'s one-lane
+    residual and the delta shift in ``_flash_bwd``."""
+    from mlcomp_tpu.ops.pallas.flash_attention import flash_attention_lse
+
+    b, s, h, d = 1, 128, 2, 64
+    q, k, v, w = (_rand((b, s, h, d), 50 + i) for i in range(4))
+    u = _rand((b, s, h), 54)
+
+    def ref_pair(q, k, v):
+        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * d ** -0.5
+        if causal:
+            logits = jnp.where(jnp.tril(jnp.ones((s, s), bool)), logits, -1e30)
+        lse = jax.nn.logsumexp(logits, axis=-1)            # (B, H, S)
+        out = jnp.einsum("bhqk,bkhd->bqhd", jnp.exp(logits - lse[..., None]), v)
+        return out, jnp.swapaxes(lse, 1, 2)
+
+    def loss(fn):
+        def f(q, k, v):
+            out, lse = fn(q, k, v)
+            return jnp.sum(out * w) + jnp.sum(lse * u)
+        return f
+
+    flash = lambda q, k, v: flash_attention_lse(  # noqa: E731
+        q, k, v, causal=causal, block_q=128, block_kv=128)
+    for a, b_ in zip(flash(q, k, v), ref_pair(q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=2e-5)
+    gf = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(loss(ref_pair), argnums=(0, 1, 2))(q, k, v)
+    for a, b_ in zip(gf, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=2e-4)

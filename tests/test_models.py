@@ -165,23 +165,33 @@ def test_lars_optimizer_builds():
     assert tx is not None
 
 
-def test_transformer_remat_matches_plain():
-    """remat=True changes memory, not math: forward and gradients match."""
-    import jax
-    import numpy as np
-    from mlcomp_tpu.models import create_model
+def _remat_lm(monkeypatch, through_flash=True, **over):
+    """A two-layer LM with and without ``remat``, its parameters and a
+    loss over them; ``through_flash`` at a length the flash kernel takes
+    (interpret mode on the CPU), else on the XLA path."""
     from mlcomp_tpu.train.state import init_model
 
-    cfg = {"name": "transformer_lm", "vocab_size": 32, "hidden": 16,
-           "layers": 2, "heads": 2, "dtype": "float32"}
-    x = jnp.asarray(np.random.RandomState(0).randint(1, 32, (2, 8)))
+    if through_flash:
+        monkeypatch.setenv("MLCOMP_TPU_FLASH", "1")
+    cfg = {"name": "transformer_lm", "vocab_size": 32, "hidden": 32,
+           "layers": 2, "heads": 2, "kv_heads": 1, "dtype": "float32", **over}
+    x = jnp.asarray(np.random.RandomState(0).randint(
+        1, 32, (2, 128 if through_flash else 8)))
     plain = create_model(cfg)
-    remat = create_model({**cfg, "remat": True})
     params, _ = init_model(plain, {"x": x}, jax.random.PRNGKey(0))
 
     def loss(m, p):
         return jnp.sum(m.apply({"params": p}, x) ** 2)
 
+    return plain, create_model({**cfg, "remat": True}), params, loss
+
+
+@pytest.mark.parametrize("through_flash", [False, True])
+def test_transformer_remat_matches_plain(monkeypatch, through_flash):
+    """remat=True changes memory, not math: forward and gradients match,
+    where the whole layer is recomputed and where it keeps the flash
+    kernel's residuals."""
+    plain, remat, params, loss = _remat_lm(monkeypatch, through_flash)
     np.testing.assert_allclose(
         float(loss(plain, params)), float(loss(remat, params)), rtol=1e-6
     )
@@ -189,3 +199,61 @@ def test_transformer_remat_matches_plain():
     gr = jax.grad(lambda p: loss(remat, p))(params)
     for a, b in zip(jax.tree.leaves(gp), jax.tree.leaves(gr)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+
+
+def _pallas_calls(jaxpr, inside=()):
+    """(enclosing primitives, kernel name) of every ``pallas_call`` in a
+    jaxpr and the jaxprs its equations carry."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append((inside, str(eqn.params["name"])))
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _pallas_calls(sub, inside + (eqn.primitive.name,))
+    return found
+
+
+@pytest.mark.parametrize("names, fwd_calls_a_layer", [
+    (None, 1),          # the list that ships
+    (("flash_out", "flash_lse"), 1),
+    (("flash_lse",), 2),  # out is needed downstream: the call comes back
+    ((), 2),            # a plain remat runs the forward kernel again
+])
+def test_remat_keeps_the_flash_kernels_outputs(monkeypatch, names,
+                                               fwd_calls_a_layer):
+    """One forward kernel call a layer in the whole gradient program,
+    none of them in the rematerialised part; a policy without the
+    kernel's names (what a later edit that drops one gives) has two."""
+    from mlcomp_tpu.ops.pallas import flash_attention
+
+    if names is not None:
+        monkeypatch.setattr(flash_attention, "REMAT_SAVED_NAMES", names)
+    _, remat, params, loss = _remat_lm(monkeypatch)
+    calls = _pallas_calls(
+        jax.make_jaxpr(jax.grad(lambda p: loss(remat, p)))(params).jaxpr
+    )
+    fwd = [c for c in calls if c[1].startswith("flash_fwd")]
+    bwd = [c for c in calls if c[1].startswith("flash_dq")]
+    assert len(bwd) == 2 and all("remat2" in c[0] for c in bwd)
+    assert len(fwd) == 2 * fwd_calls_a_layer
+    again = [c for c in fwd if "remat2" in c[0]]
+    assert len(again) == 2 * (fwd_calls_a_layer - 1)
+
+
+def test_remat_keeps_the_flash_kernels_outputs_under_a_mesh(monkeypatch):
+    """Under a dp/tp mesh the kernel sits in a shard_map island; the
+    layer's policy reaches the names inside it."""
+    from mlcomp_tpu.parallel.mesh import MeshSpec, make_mesh, set_current_mesh
+
+    set_current_mesh(make_mesh(MeshSpec(dp=2, tp=2), devices=jax.devices()[:4]))
+    try:
+        _, remat, params, loss = _remat_lm(monkeypatch, kv_heads=2)
+        calls = _pallas_calls(
+            jax.make_jaxpr(jax.grad(lambda p: loss(remat, p)))(params).jaxpr
+        )
+    finally:
+        set_current_mesh(None)
+    assert all("shard_map" in c[0] for c in calls) and len(calls) == 6
+    fwd = [c for c in calls if c[1].startswith("flash_fwd")]
+    assert len(fwd) == 2 and not any("remat2" in c[0] for c in fwd)
