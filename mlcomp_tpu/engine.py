@@ -351,7 +351,8 @@ class _Admission:
     __slots__ = ("req", "s_bucket", "chunk", "n_chunks", "next_chunk",
                  "row", "positions", "kv_mask", "cache", "last_logits",
                  "capture_lo", "skip_capture", "chunks_run", "fused_chunks",
-                 "stall_ms", "page_lease", "handoff")
+                 "stall_ms", "page_lease", "handoff", "t_admit", "t_booked",
+                 "boundary0")
 
     def __init__(self, req, s_bucket, chunk, first_chunk):
         self.req = req
@@ -382,6 +383,11 @@ class _Admission:
         self.handoff = None             # IMPORT admission (decode side
         # of a disaggregated handoff): the parsed payload — no chunks
         # run; the completion boundary writes pages + inserts the slot
+        # the lane's books (_take_lane sets them): the stamp of the
+        # ``admit`` instant, the stamp lane_busy_ms is booked up to, and
+        # the loop iteration the admission began in
+        self.t_admit = self.t_booked = 0.0
+        self.boundary0 = 0
 
 
 class DecodeEngine:
@@ -989,7 +995,16 @@ class DecodeEngine:
         # kv_rows_written is those rows times the dispatch's steps;
         # inserts_behind_dispatch is the admissions whose insert was
         # enqueued with a dispatch still unresolved (over prefills:
-        # how often a completion found the pipeline running)
+        # how often a completion found the pipeline running).
+        # The admission lane's books: rows_starved is the rows of
+        # rows_total that stood empty at issue while a request waited
+        # for one (queued, or mid-prefill in the lane); lane_busy_ms is
+        # the admit -> inserted stretches, from the stamps of the
+        # ``admission`` span; blocked_{slot,lane,pages}_ms are the
+        # boundaries' lengths by why that boundary's admission tick
+        # left the queue's head waiting (_book_boundary); lane_boundaries
+        # over lane_admissions is the loop iterations an admission held
+        # the lane
         self._pstats = {  # guarded_by: loop [writes]
             "issued": 0, "host_ms": 0.0, "hidden_ms": 0.0, "wait_ms": 0.0,
             "inflight_sum": 0, "peak_inflight": 0,
@@ -997,7 +1012,23 @@ class DecodeEngine:
             "rows_attended": 0, "rows_total": 0, "kv_rows_written": 0,
             "kv_attended": 0, "kv_live": 0,
             "kv_attended_window": 0, "kv_live_window": 0, "kv_fetched": 0,
+            "rows_starved": 0, "lane_busy_ms": 0.0,
+            "blocked_slot_ms": 0.0, "blocked_lane_ms": 0.0,
+            "blocked_pages_ms": 0.0,
+            "lane_boundaries": 0, "lane_admissions": 0,
         }
+        # loop iterations closed, the stamp the blocked_*_ms books are
+        # closed up to (a boundary's opening, or the end of its
+        # idle_wait), and the _pstats key this boundary's admission tick
+        # named for the queue's head (None: nobody waits, or the head
+        # goes in at the next tick)
+        self._boundary_n = 0  # guarded_by: loop [writes]
+        self._t_lane = time.perf_counter()  # guarded_by: loop [writes]
+        self._head_blocked: Optional[str] = None  # guarded_by: loop [writes]
+        # the admission whose ``admission`` span is open (_take_lane ->
+        # _leave_lane): ``_adm``, but for the stretch of
+        # _start_admission in which ``_adm`` is not yet set
+        self._in_lane: Optional[_Admission] = None  # guarded_by: loop [writes]
         # one entry an attention layer: its window, None where it
         # reads the whole context (a model that does not say is one
         # layer of full attention: the share then reads 1)
@@ -1041,12 +1072,21 @@ class DecodeEngine:
         # flight recorder: an always-on bounded ring of dispatch /
         # admission / prefix-cache / request-lifecycle events, exported
         # on demand (serve's GET /trace).  0/None disables; overhead is
-        # a dict append per event (its share of dispatch wall is not
-        # measured on the chip)
+        # a dict append per event: on against off, chat-steady's
+        # tpot_p90_ms and ttft_p90_ms read the same within their
+        # run-to-run spread on a v5e (PERF.md section 6, PR 39)
         self.recorder: Tracer = (
             Tracer(max_events=int(flight_recorder_events))
             if flight_recorder_events else null_tracer()
         )
+        # which boundary compiled: the process's one compile listener
+        # puts a ``compile`` instant on this recorder's engine.compile
+        # track as each backend compile ends, on the thread that paid
+        # for it, so it lies inside the loop span that did
+        from mlcomp_tpu.utils import compiles
+
+        self._compiles = compiles
+        compiles.watch(self.recorder)
         self._rid = itertools.count(1)       # request-lifecycle trace ids
         self._dispatch_seq = itertools.count(1)
         if prefix_cache is not None:
@@ -1868,6 +1908,12 @@ class DecodeEngine:
             "rows_attended_share": round(
                 p["rows_attended"] / p["rows_total"], 4
             ) if p["rows_total"] else None,
+            # of the rows that went out empty, those a request was
+            # waiting for at that issue (queued, or mid-prefill in the
+            # admission lane): min(empty rows, requests waiting), summed
+            # like the two above; total less attended less starved is
+            # the rows nobody asked for
+            "rows_starved": p["rows_starved"],
             # row writes of a token's K and V a layer: the attended
             # rows times the steps of their dispatch.  The int8 cache's
             # decode attention appends where it attends, so a row
@@ -1907,6 +1953,31 @@ class DecodeEngine:
                 },
             },
         }
+        out["admission"] = {
+            # the one admission lane: ms it was held (the ``admission``
+            # spans of the engine.lane track, summed: admit ->
+            # inserted), the admissions that left it (inserted,
+            # exported, failed or cancelled) and the loop iterations
+            # they held it for, first and last included
+            "lane_busy_ms": round(p["lane_busy_ms"], 3),
+            "admissions": p["lane_admissions"],
+            "boundaries": p["lane_boundaries"],
+            # ms of boundaries at whose admission tick a request stayed
+            # queued, by what the queue's head waited for: a slot (no
+            # free row beyond the one the admission in the lane will
+            # take), the lane (a row is free, the lane is another
+            # request's), pages (both free, the page budget deferred
+            # the head).  An ``admit`` instant's ``blocked_ms`` is the
+            # same sums over that request's wait
+            "blocked_ms": {
+                "lane": round(p["blocked_lane_ms"], 3),
+                "slot": round(p["blocked_slot_ms"], 3),
+                "pages": round(p["blocked_pages_ms"], 3),
+            },
+        }
+        # what this process compiled (utils/compiles.py: one listener a
+        # process, so an engine built later starts above zero)
+        out["programs"] = self._compiles.totals()
         # what the model's layers counted, summed over layers, steps
         # and chunks of every dispatch read back: a block a group
         counted = {
@@ -2103,6 +2174,29 @@ class DecodeEngine:
         ctr("mlcomp_engine_attention_rows_total",
             "Slot rows in the carry at issue, summed over dispatches",
             p["rows_total"])
+        ctr("mlcomp_engine_attention_rows_starved_total",
+            "Slot rows that went out empty while a request waited for "
+            "one (queued, or mid-prefill in the admission lane), "
+            "summed over dispatches", p["rows_starved"])
+        ctr("mlcomp_engine_admission_lane_busy_ms_total",
+            "Ms the one admission lane was held, admit -> inserted "
+            "(the engine.lane track's admission spans)",
+            p["lane_busy_ms"])
+        blocked = m.counter(
+            "mlcomp_engine_admission_blocked_ms_total",
+            "Ms of loop boundaries at whose admission tick a request "
+            "stayed queued, by what the queue's head waited for",
+            labelnames=("reason",),
+        )
+        for reason in ("lane", "slot", "pages"):
+            blocked.set_total(p[f"blocked_{reason}_ms"], reason=reason)
+        made = self._compiles.totals()
+        ctr("mlcomp_engine_programs_compiled_total",
+            "Programs this process handed to the backend compiler (a "
+            "persistent-cache hit counts, with its fetch time)",
+            made["compiled"])
+        ctr("mlcomp_engine_programs_compile_seconds_total",
+            "Seconds of those backend compiles", made["compile_seconds"])
         ctr("mlcomp_engine_attention_kv_rows_written_total",
             "Rows whose new token a step's attention appended to the KV "
             "cache in each layer: rows holding a request at issue x the "
@@ -2318,6 +2412,7 @@ class DecodeEngine:
         if self._adm is None:
             return
         adm, self._adm = self._adm, None
+        self._leave_lane(adm, time.perf_counter(), error=True)
         if adm.page_lease is not None:
             # a registry hit retained its source pages for the gather
             # + shared mapping; a dead admission must not pin them
@@ -3419,11 +3514,7 @@ class DecodeEngine:
             )
             adm.next_chunk = adm.n_chunks  # nothing to prefill
             adm.handoff = req["handoff"]
-            if req.get("rid"):
-                self.recorder.async_instant(
-                    "admit", req["rid"], cat="req", bucket=s_bucket,
-                    imported=True, trace_id=req.get("trace_id"),
-                )
+            self._take_lane(adm, time.perf_counter(), imported=True)
             req["cache_hit_tokens"] = 0
             self._adm = adm
             return
@@ -3458,13 +3549,10 @@ class DecodeEngine:
         # (an extra admission state) is the open follow-up.
         rid = req.get("rid", 0)
         tid = req.get("trace_id")
-        if rid:
-            self.recorder.async_instant(
-                "admit", rid, cat="req", bucket=s_bucket, trace_id=tid,
-            )
+        t_lookup = time.perf_counter()
+        self._take_lane(adm, t_lookup)
         hit_tokens = 0
         cache_faulted = False
-        t_lookup = time.perf_counter()
         if self._pool is not None and not req.get("warmup"):
             # DEVICE prefix-page registry (kvpool): a placement-exact
             # hit maps the registered prompt-prefix pages straight into
@@ -3597,6 +3685,84 @@ class DecodeEngine:
         adm.capture_lo = adm.next_chunk * c
         self._adm = adm
 
+    def _take_lane(self, adm: _Admission, t_admit: float,
+                   **args) -> None:  # graftcheck: runs-on(loop)
+        """``adm`` has the lane from ``t_admit`` on: the ``admit``
+        instant, with how long the request sat in ``_pending``
+        (``queued_ms``: from the stamp ``_park`` kept) and what that
+        wait is booked under (``blocked_ms``: the cumulative sums less
+        what ``_park`` found; they cover whole boundaries, so they sum
+        to ``queued_ms`` less the stretch of this boundary before the
+        admit).  A request handed straight to ``_start_admission``
+        (tools, tests) was never parked and carries neither."""
+        req = adm.req
+        adm.t_admit = adm.t_booked = t_admit
+        adm.boundary0 = self._boundary_n
+        self._in_lane = adm
+        if not req.get("rid"):
+            return
+        if "blocked0" in req:
+            p = self._pstats
+            lane0, slot0, pages0 = req["blocked0"]
+            args.update(
+                queued_ms=round((t_admit - req["t_parked"]) * 1e3, 3),
+                blocked_ms={
+                    "lane": round(p["blocked_lane_ms"] - lane0, 3),
+                    "slot": round(p["blocked_slot_ms"] - slot0, 3),
+                    "pages": round(p["blocked_pages_ms"] - pages0, 3),
+                },
+            )
+        self.recorder.async_instant(
+            "admit", req["rid"], cat="req", bucket=adm.s_bucket,
+            trace_id=req.get("trace_id"), **args,
+        )
+
+    def _leave_lane(self, adm: _Admission, t_close: float,
+                    **args) -> int:  # graftcheck: runs-on(loop)
+        """``adm`` gives the lane up at ``t_close`` (inserted, exported,
+        failed or cancelled): one ``admission`` span on the
+        ``engine.lane`` track from the ``admit`` stamp, so the track IS
+        the lane and its gaps are the lane standing free; the same two
+        stamps close ``lane_busy_ms``.  Returns the loop iterations the
+        admission held the lane, first and last included."""
+        boundaries = self._boundary_n - adm.boundary0 + 1
+        self._in_lane = None
+        p = self._pstats
+        p["lane_busy_ms"] += (t_close - adm.t_booked) * 1e3
+        p["lane_admissions"] += 1
+        p["lane_boundaries"] += boundaries
+        req = adm.req
+        rid = req.get("rid", 0)
+        rec = self.recorder
+        rec.complete(
+            "admission", rec.to_trace_us(adm.t_admit),
+            (t_close - adm.t_admit) * 1e6, track="engine.lane",
+            rid=rid, trace_id=req.get("trace_id"), bucket=adm.s_bucket,
+            chunks=adm.chunks_run, fused_chunks=adm.fused_chunks,
+            boundaries=boundaries, caused_by=str(rid) if rid else None,
+            **args,
+        )
+        return boundaries
+
+    def _book_boundary(self, now: float) -> None:  # graftcheck: runs-on(loop)
+        """Close the lane's books on the boundary that ends at ``now``
+        (the stamp that closed its span): its length, less an
+        ``idle_wait`` at its head, goes under what its admission tick
+        named for the queue's head, and the lane's busy time is booked
+        up to here so that a reader between two boundaries sees an
+        admission still in the lane."""
+        dt = (now - self._t_lane) * 1e3
+        self._t_lane = now
+        self._boundary_n += 1
+        p = self._pstats
+        if self._head_blocked is not None:
+            p[self._head_blocked] += dt
+            self._head_blocked = None
+        adm = self._adm
+        if adm is not None:
+            p["lane_busy_ms"] += (now - adm.t_booked) * 1e3
+            adm.t_booked = now
+
     def _run_admission_chunk(self) -> None:  # graftcheck: runs-on(loop)
         """Run ONE STAGED prefill chunk — its own dispatch at a drained
         boundary, the pre-fused behavior (``fused_admission=False``,
@@ -3708,7 +3874,8 @@ class DecodeEngine:
         try:
             yield
         finally:
-            self._account(self._loop_span("idle_wait", t), None)
+            self._t_lane = self._loop_span("idle_wait", t)
+            self._account(self._t_lane, None)
 
     def _admission_complete_span(self, adm: _Admission):
         """The ``admission_complete`` span: the admission's last
@@ -4134,15 +4301,20 @@ class DecodeEngine:
                 self._insert_admission(jnp, adm, req, s_bucket)
         finally:
             self._busy_since = None
+        t1 = time.perf_counter()
+        boundaries = self._leave_lane(adm, t1)
         if req.get("rid") and not exported:
             # the row is on the device carry: admit -> inserted is how
             # long this request held the engine's one admission lane
+            # (``of`` less ``chunks`` is what all-pad chunks and a
+            # prefix hit skipped)
             self.recorder.async_instant(
                 "inserted", req["rid"], cat="req", chunks=adm.chunks_run,
-                fused_chunks=adm.fused_chunks,
+                fused_chunks=adm.fused_chunks, boundaries=boundaries,
+                of=adm.n_chunks,
             )
         if decoding:
-            adm.stall_ms += (time.perf_counter() - t0) * 1e3
+            adm.stall_ms += (t1 - t0) * 1e3
         self._hist_stall.observe(adm.stall_ms)
         if adm.fused_chunks:
             self._stats["admissions_overlapped"] += 1
@@ -4672,6 +4844,13 @@ class DecodeEngine:
         ctx = [sl.position for sl in self._host if sl is not None]
         p["rows_attended"] += len(ctx)
         p["rows_total"] += len(self._host)
+        # of the empty rows, those a request is waiting for: everyone
+        # queued and the one mid-prefill in the lane, whose row goes
+        # out empty once more; the rest nobody asked for
+        p["rows_starved"] += min(
+            len(self._host) - len(ctx),
+            len(self._pending) + (self._adm is not None),
+        )
         p["kv_rows_written"] += len(ctx) * self.steps_per_dispatch
         # tokens of context the live rows hold, over the layers, and
         # the part of them a layer's window lets its attention read
@@ -4855,12 +5034,24 @@ class DecodeEngine:
                 if item is not _POISON and "ctrl" in item:
                     ctrls.append(item)
                 elif item is not _POISON and not item["future"].done():
-                    self._pending.append(item)
+                    self._park(item)
                     new.append(item)
                 item = self._queue.get_nowait()
         except queue.Empty:
             pass
         return new, ctrls
+
+    def _park(self, req: Dict[str, Any]) -> None:  # graftcheck: runs-on(loop)
+        """``req`` joins the FIFO behind the lane.  Everyone queued
+        waits for what the head waits for, so one set of cumulative
+        blocked_*_ms serves them all: the request keeps the sums as it
+        found them, with the stamp they are booked up to, and
+        ``_take_lane`` subtracts."""
+        p = self._pstats
+        req["t_parked"] = self._t_lane
+        req["blocked0"] = (p["blocked_lane_ms"], p["blocked_slot_ms"],
+                           p["blocked_pages_ms"])
+        self._pending.append(req)
 
     def _retire_check(
         self, req: Dict[str, Any], now: Optional[float] = None,
@@ -5133,7 +5324,7 @@ class DecodeEngine:
         if rec.get("stop"):
             return False
         for w in rec.get("new", ()):
-            self._pending.append(self._wire_in(w))
+            self._park(self._wire_in(w))
         self._apply_retired(rec.get("retired", ()))
         k2 = int(rec.get("k", self.steps_per_dispatch))
         if k2 != self.steps_per_dispatch:
@@ -5157,6 +5348,7 @@ class DecodeEngine:
         with no rows ever active, chunks run staged, nothing fuses,
         and the decode legs of the loop stay inert.  Returns True when
         a fused chunk issued this boundary's dispatch."""
+        deferred = False
         if (self._adm is None and None in self._host
                 and self._pending):
             # STAGED join drain only: fused admissions start
@@ -5169,7 +5361,10 @@ class DecodeEngine:
             # learns one boundary later.  The paged layout may
             # DEFER the head (free-page budget) — see
             # _pop_admittable.
+            head = self._pending[0]
             req = self._pop_admittable()
+            deferred = (req is None and bool(self._pending)
+                        and self._pending[0] is head)
             if req is not None:
                 if not self.fused_admission:
                     self._drain_inflight()
@@ -5183,7 +5378,25 @@ class DecodeEngine:
                     ):
                         self._start_admission(req)
                 except Exception as e:
+                    if self._in_lane is not None:
+                        # it had taken the lane: close its span
+                        self._leave_lane(
+                            self._in_lane, time.perf_counter(), error=True)
                     self._fail_queued(req, e)
+        if self._pending:
+            # the lane had its one chance this boundary, and the
+            # queue's head is still queued: no row beyond the one the
+            # admission in the lane will take (a free lane would not
+            # help), else the lane is another request's, else the page
+            # budget deferred the head; _book_boundary books the
+            # boundary's length under it.  None of the three: the head
+            # only waits for the next tick (the one before it failed)
+            free = self._host.count(None) - (self._adm is not None)
+            self._head_blocked = (
+                "blocked_slot_ms" if free <= 0
+                else "blocked_lane_ms" if self._adm is not None
+                else "blocked_pages_ms" if deferred else None
+            )
         if self._adm is not None and self._dist is None:
             # a cancel/deadline landing mid-prefill retires the
             # admission between its chunks.  Distributed gangs
@@ -5249,7 +5462,7 @@ class DecodeEngine:
         # issue | (resolve unpack)*: each child opens at the stamp that
         # closed the one before (``t``), and the next boundary opens
         # where this one closed (``t0``)
-        t0 = self._t_acct = time.perf_counter()
+        t0 = self._t_acct = self._t_lane = time.perf_counter()
         while not (self._stop.is_set() or self._exit_loop.is_set()):
             if self._broken is not None:
                 # engine-level failure (donated buffers may be gone):
@@ -5314,6 +5527,7 @@ class DecodeEngine:
                 while len(self._inflight) > keep:
                     t = self._process_oldest(t_open=t)
                 t0 = self._loop_span("boundary", t0, t)
+                self._book_boundary(t0)
             except Exception as e:  # engine-level failure
                 self._broken = e
                 if self._unhealthy_reason is None:

@@ -8,12 +8,17 @@ hand-made ops and ``stats()``) and
 ``benchmark/tests/test_retention_readers.py`` (the retention step's
 roofline arithmetic and the readers of the layer's counters) and
 ``benchmark/tests/test_flash_fwd_calls.py`` (the count of flash forward
-calls a backward call, on hand-made ops).  A program PR that renames a span or drops a
+calls a backward call, on hand-made ops) and
+``benchmark/tests/test_lane_readers.py`` (the readers of the admission
+lane's books: the row ledger, the ``engine.lane`` track, the ``admit``
+and ``inserted`` instants' arguments, on hand-made events and ``stats()``
+pairs and on a live rehearsal-width service).  A program PR that renames a span or drops a
 ``stats()`` key fails here, not as a ``null`` per-layer metric after a
 chip run.  The tests are the benchmark's own, imported; nothing under
 ``benchmark/`` is edited.  Not ``test_correct.py``, ``test_laguna.py`` or
 ``test_smallthinker.py`` or ``test_brumby.py``: they take minutes (``pytest benchmark/tests``
-runs them all)."""
+runs them all).  One imported test is redefined below, and its
+docstring says why."""
 
 import os
 
@@ -21,10 +26,44 @@ import pytest
 
 from benchmark.tests.conftest import rehearse  # noqa: F401  (a fixture)
 from benchmark.tests.test_flash_fwd_calls import *  # noqa: F401,F403
+from benchmark.tests.test_lane_readers import *  # noqa: F401,F403
 from benchmark.tests.test_loop_spans import *  # noqa: F401,F403
 from benchmark.tests.test_mixedlen_readers import *  # noqa: F401,F403
 from benchmark.tests.test_retention_readers import *  # noqa: F401,F403
 from benchmark.tests.test_yardstick import *  # noqa: F401,F403
+
+
+
+def test_the_entries_name_the_cell_and_its_files():  # noqa: F811
+    """``test_retention_readers.py``'s test of this name, but for its
+    count: it pins the entries that list ``continuation-offline`` alone at
+    the eight of PR 37, and PR 39 added four (the row ledger's two
+    shares, the lane's busy share and boundaries).  A PR may add entries
+    to ``BENCHMARK.json`` and may not edit a file under ``benchmark/``,
+    so tier-1 holds the eight to what they were, asks every later entry
+    for a reader, and leaves the count to the next ``benchmark`` PR."""
+    import json
+
+    from benchmark import cells
+
+    spec = cells.benchmark_spec()
+    mine = [m for m in spec["per_layer"]
+            if m.get("workloads") == ["continuation-offline"]]
+    assert [m["name"] for m in mine[:8]] == [
+        "fused_dispatch_ms.continuation", "dispatch_gap_ms.continuation",
+        "device_idle.continuation", "host_ms_per_dispatch.continuation",
+        "admit_boundary_idle.continuation",
+        "retention_time_share.continuation", "retention_step_roofline",
+        "state_bytes_per_token.continuation"]
+    assert all(m["moves"] == "serve_tokens_per_s" for m in mine)
+    assert all(cells.layer_reader(m["name"]) is not None for m in mine)
+    cell = cells.Cell("continuation-offline")
+    assert cell.config["reference"] == "brumby" and cell.chips == 1
+    assert [c["reduced"] for c in spec["configs"]
+            if c["name"] == "brumby-14b-serve"] == [["num_hidden_layers"]]
+    with open(cells.ROOT / "benchmark/configs/brumby-14b-serve.json") as f:
+        assert json.load(f)["num_hidden_layers"] == 5
+
 
 _CACHE_OPTIONS = (
     "jax_compilation_cache_dir",

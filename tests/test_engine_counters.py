@@ -430,3 +430,278 @@ def test_a_retention_layers_counts_are_the_hand_counts():
     assert f"mlcomp_engine_retention_state_rows_total {rows}" in text
     assert f"mlcomp_engine_retention_chunk_tokens_total {12 * layers}" in text
     assert "mlcomp_engine_moe_" not in text
+
+
+# ---- the admission lane's books -------------------------------------
+
+PLAIN = {"name": "transformer_lm", "vocab_size": 64, "hidden": 32,
+         "layers": 1, "heads": 2, "mlp_dim": 64, "dtype": "float32"}
+ONE_CHUNK = [5, 6, 7]              # 3 tokens left-padded to 32: 1 chunk of 8
+THREE_CHUNKS = list(range(1, 21))  # 20 tokens: 3 chunks, one all-pad skipped
+
+
+def _lane_engine(slots, **kw):
+    model, params = _build(PLAIN)
+    return DecodeEngine(model, {"params": params}, slots=slots,
+                        prompt_buckets=(32,), max_new_cap=64,
+                        steps_per_dispatch=2, prefill_chunk=8, **kw)
+
+
+def _decoding(eng, ids, n_new):
+    """Submit and wait for the first token: the row is on the carry."""
+    import queue
+
+    stream: "queue.Queue" = queue.Queue()
+    fut = eng.submit(ids, n_new, stream=stream)
+    assert stream.get(timeout=300) is not None
+    return fut
+
+
+def _req_events(eng, name):
+    return {int(e["id"]): e for e in eng.recorder.events
+            if e.get("cat") == "req" and e["name"] == name}
+
+
+def _track(eng, track, ph="X"):
+    tid = eng.recorder._tracks.get(track)
+    return sorted((e for e in eng.recorder.events
+                   if e["tid"] == tid and e["ph"] == ph),
+                  key=lambda e: e["ts"])
+
+
+@pytest.fixture(scope="module")
+def lane_run():
+    """Four slots: A decodes in one, B's three-chunk admission holds the
+    lane with C queued behind it, and nobody asks for the fourth.  Every
+    dispatch sleeps 50 ms while B and C are submitted, so one pump parks
+    both.  ``_issue`` is wrapped to keep, dispatch by dispatch, the
+    mirror it saw and what it booked."""
+    from mlcomp_tpu.utils import faults
+
+    eng = _lane_engine(4)
+    issued = []
+    real = eng._issue
+
+    def spy(seq, fused):
+        live = sum(s is not None for s in eng._host)
+        waiting = len(eng._pending) + (eng._adm is not None)
+        before = dict(eng._pstats)
+        real(seq, fused)
+        issued.append((live, waiting) + tuple(
+            eng._pstats[k] - before[k]
+            for k in ("rows_attended", "rows_starved", "rows_total")))
+
+    try:
+        idle = eng.stats()["admission"]
+        fa = _decoding(eng, ONE_CHUNK, 60)
+        alone = eng.stats()["admission"]
+        eng._issue = spy
+        faults.arm("engine.dispatch", flavor="sleep", times=-1, seconds=0.05)
+        fb = eng.submit(THREE_CHUNKS, 4)
+        fc = eng.submit(ONE_CHUNK, 4)
+        fb.result(timeout=300), fc.result(timeout=300)
+        faults.disarm_all()
+        fa.result(timeout=300)
+        yield {"issued": issued, "stats": eng.stats(), "eng": eng,
+               "idle": idle, "alone": alone,
+               "rids": (fa.rid, fb.rid, fc.rid),
+               "text": eng.metrics.render()}
+    finally:
+        faults.disarm_all()
+        eng.close()
+
+
+def test_every_dispatch_splits_its_rows_three_ways(lane_run):
+    issued, att = lane_run["issued"], lane_run["stats"]["attention"]
+    assert issued
+    for live, waiting, attended, starved, total in issued:
+        assert total == 4 and attended == live
+        assert starved == min(total - live, waiting)
+        assert attended + starved <= total   # the rest nobody asked for
+    # A's row attended, B's (mid-prefill) and C's (queued) starved, one
+    # row nobody asked for: the split of B's fused chunk dispatches
+    assert (1, 2, 1, 2, 4) in issued
+    # once everyone is in, the last row is merely unasked
+    assert (3, 0, 3, 0, 4) in issued
+    assert att["rows_starved"] >= sum(row[3] for row in issued) > 0
+    assert att["rows_attended"] + att["rows_starved"] <= att["rows_total"]
+    assert (f"mlcomp_engine_attention_rows_starved_total "
+            f"{att['rows_starved']}") in lane_run["text"]
+
+
+def test_the_head_of_line_books_say_what_the_queue_waited_for(lane_run):
+    zero = {"lane": 0.0, "slot": 0.0, "pages": 0.0}
+    # an idle engine books nothing, and neither does a request alone
+    assert lane_run["idle"] == {"lane_busy_ms": 0.0, "admissions": 0,
+                                "boundaries": 0, "blocked_ms": zero}
+    assert lane_run["alone"]["blocked_ms"] == zero
+    assert lane_run["alone"]["admissions"] == 1
+    adm = lane_run["stats"]["admission"]
+    # C sat behind B's admission with rows free: the lane, not a slot
+    assert adm["blocked_ms"]["lane"] >= 100.0   # two boundaries of 50 ms
+    assert adm["blocked_ms"]["slot"] == adm["blocked_ms"]["pages"] == 0.0
+    assert adm["admissions"] == 3 and adm["boundaries"] == 1 + 3 + 1
+    _, rid_b, rid_c = lane_run["rids"]
+    admit = _req_events(lane_run["eng"], "admit")
+    assert admit[rid_b]["args"]["blocked_ms"] == zero
+    assert admit[rid_c]["args"]["blocked_ms"]["lane"] == pytest.approx(
+        adm["blocked_ms"]["lane"], abs=0.01)
+    text = lane_run["text"]
+    assert 'mlcomp_engine_admission_blocked_ms_total{reason="lane"}' in text
+    assert "mlcomp_engine_admission_lane_busy_ms_total" in text
+
+
+def test_an_admits_blocked_ms_sums_to_its_queued_ms_within_a_boundary(
+        lane_run):
+    eng = lane_run["eng"]
+    longest = max(e["dur"] for e in _track(eng, "engine.loop")
+                  if e["name"] == "boundary") / 1e3
+    admits = _req_events(eng, "admit")
+    assert len(admits) == 3
+    for ev in admits.values():
+        booked = sum(ev["args"]["blocked_ms"].values())
+        assert -0.01 <= ev["args"]["queued_ms"] - booked <= longest
+    assert admits[lane_run["rids"][2]]["args"]["queued_ms"] >= 100.0
+
+
+def test_the_lane_track_is_the_lane(lane_run):
+    eng, adm = lane_run["eng"], lane_run["stats"]["admission"]
+    spans = _track(eng, "engine.lane")
+    assert [s["name"] for s in spans] == ["admission"] * 3
+    for a, b in zip(spans, spans[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"]      # one lane: no overlap
+    # the same stamps close the counter and the spans
+    assert adm["lane_busy_ms"] == pytest.approx(
+        sum(s["dur"] for s in spans) / 1e3, abs=0.01)
+    inserted = _req_events(eng, "inserted")
+    for s in spans:
+        a = s["args"]
+        assert a["caused_by"] == str(a["rid"]) and a["bucket"] == 32
+        ins = inserted[a["rid"]]["args"]
+        assert (a["chunks"], a["fused_chunks"], a["boundaries"]) == (
+            ins["chunks"], ins["fused_chunks"], ins["boundaries"])
+    by_rid = {s["args"]["rid"]: s["args"] for s in spans}
+    rid_a, rid_b, rid_c = lane_run["rids"]
+    assert by_rid[rid_a]["fused_chunks"] == 0      # nothing to ride
+    assert (by_rid[rid_b]["chunks"], by_rid[rid_b]["fused_chunks"],
+            by_rid[rid_b]["boundaries"]) == (3, 3, 3)
+    assert by_rid[rid_c]["boundaries"] == 1
+
+
+def test_a_compile_instant_lies_in_the_span_that_paid_for_it(lane_run):
+    eng = lane_run["eng"]
+    compiles = _track(eng, "engine.compile", ph="i")
+    assert compiles and all(c["name"] == "compile" and
+                            c["args"]["seconds"] >= 0 for c in compiles)
+    paid = [s for s in _track(eng, "engine.loop") if s["name"] in (
+        "issue", "prefill_chunk", "insert", "admission_start")]
+    inside = [c for c in compiles if any(
+        s["ts"] <= c["ts"] <= s["ts"] + s["dur"] for s in paid)]
+    # the first staged chunk, the insert, the plain and the fused
+    # dispatch programs were all first used on the loop
+    assert len(inside) >= 4
+    programs = lane_run["stats"]["programs"]
+    assert programs["compiled"] >= len(compiles)
+    assert programs["compile_seconds"] >= sum(
+        c["args"]["seconds"] for c in compiles) - 1e-3
+    assert "mlcomp_engine_programs_compiled_total" in lane_run["text"]
+    assert "mlcomp_engine_programs_compile_seconds_total" in lane_run["text"]
+
+
+@pytest.mark.parametrize("fused", [True, False])
+def test_inserted_boundaries_is_the_hand_count(fused):
+    """A prompt of one chunk is admitted and inserted inside one loop
+    iteration; one of three chunks runs a chunk an iteration, fused onto
+    A's dispatches or staged between them."""
+    eng = _lane_engine(3, fused_admission=fused)
+    try:
+        _decoding(eng, ONE_CHUNK, 60)
+        one = eng.submit(ONE_CHUNK, 2)
+        one.result(timeout=300)
+        three = eng.submit(THREE_CHUNKS, 2)
+        three.result(timeout=300)
+        inserted = _req_events(eng, "inserted")
+    finally:
+        eng.close()
+    for fut, chunks in ((one, 1), (three, 3)):
+        assert inserted[fut.rid]["args"] == {
+            "chunks": chunks, "fused_chunks": chunks if fused else 0,
+            "boundaries": chunks, "of": 4}
+
+
+def test_blocked_on_a_slot_and_a_cancelled_admission_closes_its_span():
+    """Both slots taken: B queues for a slot, not for the lane.  When
+    the short request retires, B's chunks ride the long one's dispatches
+    (100 ms each), and B is cancelled between them: its span on the
+    lane's track closes with ``error``, and C's follows it without
+    overlap."""
+    import time
+
+    from mlcomp_tpu.engine import RequestCancelled
+    from mlcomp_tpu.utils import faults
+
+    eng = _lane_engine(2)
+    try:
+        fa = _decoding(eng, ONE_CHUNK, 60)
+        _decoding(eng, ONE_CHUNK, 12)
+        faults.arm("engine.dispatch", flavor="sleep", times=-1, seconds=0.1)
+        fb = eng.submit(THREE_CHUNKS, 4)
+        for _ in range(3000):
+            adm = eng._adm
+            if adm is not None and adm.req["rid"] == fb.rid:
+                break
+            time.sleep(0.01)
+        assert eng.cancel(fb.rid)
+        with pytest.raises(RequestCancelled):
+            fb.result(timeout=60)
+        faults.disarm_all()
+        eng.submit(ONE_CHUNK, 2).result(timeout=300)
+        fa.result(timeout=300)
+        st = eng.stats()["admission"]
+        spans = _track(eng, "engine.lane")
+        admit = _req_events(eng, "admit")
+    finally:
+        faults.disarm_all()
+        eng.close()
+    assert st["blocked_ms"]["slot"] >= 100.0
+    assert st["blocked_ms"]["lane"] == st["blocked_ms"]["pages"] == 0.0
+    assert admit[fb.rid]["args"]["blocked_ms"]["slot"] == pytest.approx(
+        st["blocked_ms"]["slot"], abs=0.01)
+    assert [bool(s["args"].get("error")) for s in spans] == [
+        False, False, True, False]
+    assert spans[2]["args"]["rid"] == fb.rid
+    assert spans[2]["args"]["chunks"] < 3
+    for a, b in zip(spans, spans[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"]
+    assert st["admissions"] == 4
+
+
+def test_an_admission_that_fails_as_it_starts_closes_its_span():
+    """``_start_admission`` raises after the ``admit`` (the fresh prefill
+    cache cannot be made): the request fails, and the lane's track and
+    books hold the admission all the same, closed with ``error``; the
+    next request finds the lane free."""
+    eng = _lane_engine(2)
+    real = eng._prefill_init_fn
+
+    def broken():
+        eng._prefill_init_fn = real
+        raise RuntimeError("no prefill cache")
+
+    try:
+        eng._prefill_init_fn = broken
+        bad = eng.submit(ONE_CHUNK, 2)
+        with pytest.raises(RuntimeError, match="no prefill cache"):
+            bad.result(timeout=300)
+        good = eng.submit(ONE_CHUNK, 2)
+        good.result(timeout=300)
+        st = eng.stats()["admission"]
+        spans = _track(eng, "engine.lane")
+        admit = _req_events(eng, "admit")
+    finally:
+        eng.close()
+    assert sorted(admit) == [bad.rid, good.rid]
+    assert [(s["args"]["rid"], bool(s["args"].get("error")))
+            for s in spans] == [(bad.rid, True), (good.rid, False)]
+    assert spans[0]["ts"] + spans[0]["dur"] <= spans[1]["ts"]
+    assert st["admissions"] == 2 and eng._in_lane is None

@@ -555,8 +555,9 @@ def test_idle_wait_only_on_idle_boundaries(depth, fused):
 @pytest.mark.parametrize("depth,fused", _RUNS)
 def test_request_lifecycle_admit_inserted_first_token(depth, fused):
     """Every finished request carries admit < inserted <= first_token
-    on its lifecycle track, and ``inserted`` repeats the admission's
-    chunk counts."""
+    on its lifecycle track, ``inserted`` repeats the admission's
+    chunk counts and says how many loop iterations it held the lane,
+    and ``admit`` what its queue wait was booked under."""
     events, _ = _traced_run(depth, fused)
     life = {}
     for e in events:
@@ -574,9 +575,15 @@ def test_request_lifecycle_admit_inserted_first_token(depth, fused):
             if e["name"] == "admission_complete"}
     for rid, got in life.items():
         want = done[int(rid)]
-        assert got["inserted"]["args"] == {
+        ins = got["inserted"]["args"]
+        # a chunk a loop iteration, and ``of`` the bucket's chunks
+        assert ins == {
             "chunks": want["chunks"], "fused_chunks": want["fused_chunks"],
+            "boundaries": want["chunks"], "of": ins["of"],
         }
+        assert ins["of"] >= ins["chunks"]
+        assert set(got["admit"]["args"]["blocked_ms"]) == {
+            "lane", "slot", "pages"}
     assert life["2"]["inserted"]["args"]["chunks"] == 2
 
 

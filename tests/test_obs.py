@@ -302,11 +302,20 @@ def test_serve_metrics_and_trace_http_round_trip():
         assert {"issue", "resolve", "dispatch", "request",
                 "first_token", "prefill_chunk", "insert",
                 "prefix_cache.lookup"} <= names
-        # dispatch lifetime spans balance begin/end
-        bs = [e for e in trace["traceEvents"]
-              if e["name"] == "dispatch" and e["ph"] == "b"]
-        es = [e for e in trace["traceEvents"]
-              if e["name"] == "dispatch" and e["ph"] == "e"]
+        # dispatch lifetime spans balance begin/end once the pipeline
+        # has drained: the last request returns at its last token, with
+        # a dispatch that carries nothing for it still in flight
+        for _ in range(100):
+            bs = [e for e in trace["traceEvents"]
+                  if e["name"] == "dispatch" and e["ph"] == "b"]
+            es = [e for e in trace["traceEvents"]
+                  if e["name"] == "dispatch" and e["ph"] == "e"]
+            if len(bs) == len(es):
+                break
+            time.sleep(0.05)
+            trace = json.loads(urllib.request.urlopen(
+                f"http://127.0.0.1:{port}/trace?last_ms=600000"
+            ).read())
         assert bs and len(bs) == len(es)
         # malformed last_ms -> 400, not a stack dump
         with pytest.raises(urllib.error.HTTPError) as ei:

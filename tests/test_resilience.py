@@ -137,6 +137,12 @@ def test_cancel_queued_vs_inflight():
         qa: "queue.Queue" = queue.Queue()
         fa = eng.submit([5, 6, 7], 32, stream=qa)
         qa.get(timeout=300)  # A holds the one slot, decoding
+        # hold A in flight for certain: its other 31 one-step
+        # dispatches take a quarter of a second each from here on, so
+        # it cannot run out before the cancels below land (unslowed it
+        # finished first on a loaded CPU, and fa.result() returned)
+        faults.arm("engine.dispatch", flavor="sleep", times=-1,
+                   seconds=0.25)
         fb = eng.submit([5, 6, 8], 4)   # queued behind A
         prefills0 = eng.stats()["prefills"]
         assert eng.cancel(fb.rid)
@@ -147,6 +153,7 @@ def test_cancel_queued_vs_inflight():
         assert eng.cancel(fa.rid)
         with pytest.raises(RequestCancelled):
             fa.result(timeout=60)
+        faults.disarm_all()
         # slot freed: a fresh request decodes exactly
         got = eng.submit([5, 6, 8], 4).result(timeout=300)
         assert got["ids"] == _reference(model, params, [5, 6, 8], 4)

@@ -77,6 +77,12 @@ def main() -> int:
                 "memory_after_window": D.memory(cell.chips),
                 "rows_attended_share":
                     eng["attention"]["rows_attended_share"],
+                # since the process began, warm-up included: starved
+                # rows, and the lane's own books (busy ms, what the
+                # queue's head waited for)
+                "rows_starved": eng["attention"].get("rows_starved"),
+                "rows_total": eng["attention"]["rows_total"],
+                "admission": eng.get("admission"),
                 "pipeline_occupancy": eng["pipeline"].get("occupancy"),
             }), flush=True)
             service.close()

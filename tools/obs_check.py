@@ -91,6 +91,11 @@ DOCUMENTED_SERVE_METRICS = [
     "mlcomp_engine_pipeline_overlap_efficiency",
     "mlcomp_engine_attention_rows_attended_total",
     "mlcomp_engine_attention_rows_total",
+    "mlcomp_engine_attention_rows_starved_total",
+    "mlcomp_engine_admission_lane_busy_ms_total",
+    "mlcomp_engine_admission_blocked_ms_total",
+    "mlcomp_engine_programs_compiled_total",
+    "mlcomp_engine_programs_compile_seconds_total",
     "mlcomp_engine_attention_kv_rows_written_total",
     "mlcomp_engine_attention_kv_tokens_attended_total",
     "mlcomp_engine_attention_kv_tokens_live_total",
@@ -478,9 +483,10 @@ def run(n_requests: int = 3) -> dict:
         for want in ("boundary", "maintenance", "admission_tick",
                      "issue", "resolve", "unpack", "admission_start",
                      "admission_complete",
-                     "request", "inserted", "first_token",
+                     "request", "admit", "inserted", "first_token",
                      "prefill_chunk", "insert", "prefix_cache.lookup",
-                     "kv_registry.lookup", "clock_sync"):
+                     "kv_registry.lookup", "clock_sync",
+                     "admission", "compile"):
             assert want in names, f"missing trace span {want!r}"
         # the /profile capture merged a DEVICE track: a named
         # engine.device thread whose complete spans sit inside the
@@ -490,6 +496,25 @@ def run(n_requests: int = 3) -> dict:
             if e["ph"] == "M" and e["name"] == "thread_name"
         }
         assert "engine.device" in track_tids, sorted(track_tids)
+        # the admission lane's own row: one span an admission, never
+        # two at once, each naming the request span it belongs to; and
+        # every ``admit`` says what its queue wait was booked under
+        lane = sorted(
+            (e for e in evs if e.get("tid") == track_tids["engine.lane"]
+             and e["ph"] == "X"), key=lambda e: e["ts"],
+        )
+        assert lane and all(e["name"] == "admission" for e in lane)
+        for a, b in zip(lane, lane[1:]):
+            assert a["ts"] + a["dur"] <= b["ts"], (a, b)
+        assert all(
+            e["args"]["boundaries"] >= 1 and "caused_by" in e["args"]
+            for e in lane
+        )
+        for e in evs:
+            if e["name"] == "admit":
+                assert set(e["args"]["blocked_ms"]) == {
+                    "lane", "slot", "pages"}, e
+        assert "engine.compile" in track_tids, sorted(track_tids)
         dev_evs = [
             e for e in evs
             if e.get("tid") == track_tids["engine.device"]
