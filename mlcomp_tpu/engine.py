@@ -2715,7 +2715,12 @@ class DecodeEngine:
         carried cache (the model's decode path handles i>0 chunked
         attention); returns the chunk's last-token logits + the cache.
         One program per distinct chunk width serves every chunk index
-        and every prompt bucket that width divides."""
+        and every prompt bucket that width divides.  The model computes
+        logits for the chunk's last position only
+        (``last_logits_only``): the whole (c, vocab) product was c
+        times the work for the one row kept.  A non-last chunk needs
+        no logits at all and still computes that row: dropping it
+        would take a second program a width."""
         key = ("prefill_chunk", c)
         if key not in self._fns:
             jax, jnp = self._jax, self._jnp
@@ -2724,7 +2729,7 @@ class DecodeEngine:
                 logits, upd = self._apply(
                     {**variables, "cache": cache}, chunk, decode=True,
                     positions=positions, kv_mask=kv_mask,
-                    mutable=["cache", "counters"],
+                    mutable=["cache", "counters"], last_logits_only=True,
                 )
                 counts = _sown_counts(upd)
                 out = (logits[:, -1].astype(jnp.float32), upd["cache"])
@@ -3347,7 +3352,10 @@ class DecodeEngine:
         at a drained boundary.  One program per distinct chunk width
         per scan K (one per ladder rung on adaptive engines) — the
         same compile budget shape as
-        the staged ``_prefill_chunk_fn``."""
+        the staged ``_prefill_chunk_fn``, and like it the chunk half
+        computes logits for its last position only, on a non-last
+        chunk too (a program without them would be a second one a
+        width and K)."""
         if k is None:
             k = self.steps_per_dispatch
         key = ("fused_dispatch", c, k)
@@ -3361,7 +3369,7 @@ class DecodeEngine:
                 logits, upd = self._apply(
                     {**variables, "cache": adm_cache}, chunk, decode=True,
                     positions=positions, kv_mask=kv_mask,
-                    mutable=["cache", "counters"],
+                    mutable=["cache", "counters"], last_logits_only=True,
                 )
                 counts = _sown_counts(upd)
                 if counts is not None:

@@ -382,6 +382,7 @@ def generate(
         positions=positions,
         kv_mask=kv_mask,
         mutable=["cache"],
+        last_logits_only=True,
     )
     cache = updated["cache"]
     last_logits = logits[:, -1]

@@ -151,7 +151,13 @@ class MixedLayerLM(nn.Module):
 
     @nn.compact
     def __call__(self, x, train: bool = False, decode: bool = False,
-                 positions=None, kv_mask=None, cache_cursor=None):
+                 positions=None, kv_mask=None, cache_cursor=None,
+                 last_logits_only: bool = False):
+        """``last_logits_only`` (static): the caller keeps the last
+        position's logits alone, so the final norm and the head run on
+        ``h[:, -1:]`` and return (B, 1, vocab); the layers, their caches
+        and their counters see the whole sequence
+        (``TransformerLM.__call__``)."""
         if train:
             raise NotImplementedError(
                 "mixed_layer_lm is served, not trained: its expert layer "
@@ -183,6 +189,8 @@ class MixedLayerLM(nn.Module):
                 retention=kind == "retention", qk_norm=self.qk_norm,
                 name=f"layer_{i}",
             )(h, positions, decode, kv_mask, cache_cursor)
+        if last_logits_only:
+            h = h[:, -1:]
         h = RMSNorm(dtype)(h)
         return _LMHead(
             self.vocab_size, self.hidden, compute_dtype=self.head_dtype,

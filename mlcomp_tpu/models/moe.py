@@ -385,12 +385,16 @@ class MoELM(nn.Module):
         positions=None,
         kv_mask=None,
         cache_cursor=None,
+        last_logits_only: bool = False,
     ):
         """``decode=True`` runs incremental decoding against the "cache"
         collection (see models/generation.py); the MoE FFN is stateless
         per token, so only the attention layers carry cache state.
         ``cache_cursor`` (B,) selects per-row write offsets (the
-        continuous-batching engine's contract, transformer.py)."""
+        continuous-batching engine's contract, transformer.py).
+        ``last_logits_only`` (static): final norm and head on
+        ``h[:, -1:]`` alone, (B, 1, vocab) out
+        (``TransformerLM.__call__``)."""
         from mlcomp_tpu.models.transformer import resolve_positions
 
         dtype = jnp.dtype(self.dtype)
@@ -414,6 +418,8 @@ class MoELM(nn.Module):
                     seq_parallel=self.seq_parallel, kv_quant=self.kv_quant,
                 )(h, positions, decode=decode, kv_mask=kv_mask,
                   cache_cursor=cache_cursor)
+        if last_logits_only:
+            h = h[:, -1:]
         h = RMSNorm(dtype)(h)
         return nn.Dense(self.vocab_size, use_bias=False, dtype=jnp.float32,
                         name="lm_head")(h)

@@ -1014,6 +1014,7 @@ class TransformerLM(nn.Module):
         positions=None,
         kv_mask=None,
         cache_cursor=None,
+        last_logits_only: bool = False,
     ):
         """Forward pass.  ``decode=True`` switches to incremental decoding
         against a mutable "cache" collection (see models/generation.py);
@@ -1021,7 +1022,13 @@ class TransformerLM(nn.Module):
         position, and ``kv_mask`` (B, max_len) masks out invalid
         (left-pad) cache slots.  ``cache_cursor`` (B,) int32 selects
         per-row cache write offsets for single-token steps (the
-        continuous-batching engine's contract, see SelfAttention)."""
+        continuous-batching engine's contract, see SelfAttention).
+        ``last_logits_only`` (static) is a prefill's caller saying it
+        keeps the last position's logits alone: every layer still sees
+        the whole sequence (the cache is written as ever), but the
+        final norm and the head run on ``h[:, -1:]`` and the result is
+        (B, 1, vocab) — XLA does not narrow a (B, S, vocab) product to
+        the row a caller slices from it."""
         dtype = jnp.dtype(self.dtype)
         ids = x.astype(jnp.int32)
         positions = resolve_positions(ids, decode, positions)
@@ -1052,6 +1059,8 @@ class TransformerLM(nn.Module):
                 decode_fused=self.decode_fused,
                 name=f"DecoderLayer_{i}",
             )(h, positions, decode, kv_mask, cache_cursor)
+        if last_logits_only:
+            h = h[:, -1:]
         h = RMSNorm(dtype)(h)
         head = _LMHead(
             self.vocab_size, self.hidden, compute_dtype=self.head_dtype,

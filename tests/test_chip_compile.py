@@ -187,6 +187,20 @@ def test_quant_matmul(chip, d, n, norm):
     )
 
 
+def test_quant_matmul_one_row_head_with_the_norm_folded(chip):
+    """A prefill chunk's logits in the int8 configuration: the chunk's
+    last row alone through the 92,544-wide head, the final norm in the
+    kernel's prologue (``last_logits_only`` under ``fold_norms``)."""
+    from mlcomp_tpu.ops.pallas.quant_matmul import quant_matmul
+
+    _compiles_to_a_kernel(
+        lambda x, q8, scale, g: quant_matmul(
+            x, q8, scale, interpret=False, norm_scale=g),
+        chip((1, HIDDEN), jnp.bfloat16), chip((HIDDEN, 92544), jnp.int8),
+        chip((92544,), jnp.float32), chip((HIDDEN,), jnp.float32),
+    )
+
+
 def _paged_pool(chip):
     mp = L // T
     pages = B * mp + 2          # + the reserved NULL and GRAVE pages

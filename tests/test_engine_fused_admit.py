@@ -9,6 +9,7 @@ fault inside the fused prep fails ONLY the admitting request."""
 
 import functools
 import queue
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +22,7 @@ from mlcomp_tpu.models.generation import generate
 from mlcomp_tpu.serve import GenerationService
 from mlcomp_tpu.train.state import init_model
 from mlcomp_tpu.utils import faults
+from test_engine_counters import MIXED
 
 
 @functools.lru_cache(maxsize=None)
@@ -316,3 +318,100 @@ def test_staged_flag_plumbing_and_metrics():
             assert ("fused_dispatch", 16, k) in svc.engine._fns
     finally:
         svc.close()
+
+
+# ---- a chunk's program multiplies the head for the one row it keeps ----
+
+# vocabulary 96 and chunk 8 are widths nothing else in these models has,
+# so a (…, 8, 96) tensor can only be a whole chunk's logits
+_VOCAB, _CHUNK, _K = 96, 8, 2
+_ONE_ROW_MODELS = {
+    "transformer_lm": {
+        "name": "transformer_lm", "vocab_size": _VOCAB, "hidden": 64,
+        "layers": 2, "heads": 2, "mlp_dim": 128, "dtype": "float32",
+    },
+    # a window of the bucket: ``generate`` prefills it in one piece
+    "mixed_layer_lm": {**MIXED, "vocab_size": _VOCAB, "window": 16},
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _one_row_model(name, kv_quant=False):
+    model = create_model({**_ONE_ROW_MODELS[name], "kv_quant": kv_quant})
+    prompt = jnp.asarray(np.random.RandomState(0).randint(1, _VOCAB, (1, 8)))
+    params, _ = init_model(model, {"x": prompt}, jax.random.PRNGKey(0))
+    return model, params
+
+
+def _one_row_engine(name, kv_quant=False, **kw):
+    model, params = _one_row_model(name, kv_quant)
+    return DecodeEngine(model, {"params": params}, slots=3,
+                        prompt_buckets=(16,), max_new_cap=48,
+                        steps_per_dispatch=_K, prefill_chunk=_CHUNK, **kw)
+
+
+def _tensor_shapes(lowered_text):
+    """Every tensor type of a lowered (StableHLO) program, as tuples."""
+    return {
+        tuple(int(d) for d in m.group(1).split("x"))
+        for m in re.finditer(r"tensor<((?:\d+x)*\d+)x[a-z]\w*>", lowered_text)
+    }
+
+
+@pytest.mark.parametrize("program", ["staged", "fused"])
+@pytest.mark.parametrize("name", sorted(_ONE_ROW_MODELS))
+def test_a_chunks_program_multiplies_the_head_for_one_row(name, program):
+    """The structural witness: no tensor of either chunk program has the
+    shape (…, chunk, vocab) — XLA does not narrow a whole chunk's logits
+    to the row the program slices from them, so they must never be asked
+    for — while the one row, (1, 1, vocab) → (1, vocab), is there."""
+    eng = _one_row_engine(name)
+    try:
+        i32 = jnp.int32
+        chunk = (jax.ShapeDtypeStruct((1, _CHUNK), i32),
+                 jax.ShapeDtypeStruct((1, _CHUNK), i32),
+                 jax.ShapeDtypeStruct((1, eng.l_buf), bool))
+        adm = jax.eval_shape(eng._prefill_init_fn(),
+                             jax.ShapeDtypeStruct((), i32))
+        if program == "staged":
+            lowered = eng._prefill_chunk_fn(_CHUNK).lower(
+                eng.variables, adm, *chunk)
+        else:
+            lowered = eng._fused_dispatch_fn(_CHUNK, _K).lower(
+                eng.variables, jax.eval_shape(eng._fresh_dstate), adm, *chunk)
+        shapes = _tensor_shapes(lowered.as_text())
+    finally:
+        eng.close()
+    whole = {s for s in shapes if s[-2:] == (_CHUNK, _VOCAB)}
+    assert not whole, f"a whole chunk's logits are formed: {sorted(whole)}"
+    assert (1, 1, _VOCAB) in shapes and (1, _VOCAB) in shapes
+    # the witness can see: the chunk's hidden states are in the program
+    assert any(s[-2] == _CHUNK for s in shapes if len(s) >= 2)
+
+
+@pytest.mark.parametrize("fused", [True, False])
+@pytest.mark.parametrize("name, kv_quant", [
+    ("transformer_lm", False), ("transformer_lm", True),
+    ("mixed_layer_lm", False), ("mixed_layer_lm", True)])
+def test_a_multi_chunk_admissions_tokens_are_generates(name, kv_quant, fused):
+    """The first token of an admission comes from the last chunk's one
+    row of logits: B's two chunks (fused: riding A's dispatches) and
+    A's own end in the tokens ``generate`` emits for the full prompt."""
+    model, params = _one_row_model(name, kv_quant)
+    eng = _share_fns(
+        _one_row_engine(name, kv_quant=kv_quant, fused_admission=fused),
+        ("one_row", name, kv_quant))
+    ids_a = [3, 14, 15, 9, 2, 7, 7, 30, 2, 1]
+    ids_b = [7, 3, 44, 5, 6, 21, 8, 8, 50, 13, 4]
+    try:
+        fa = eng.submit(ids_a, N_A)
+        fb = eng.submit(ids_b, 6)
+        ra, rb = fa.result(timeout=300), fb.result(timeout=300)
+        st = eng.stats()
+    finally:
+        _FNS[("one_row", name, kv_quant)].update(eng._fns)
+        eng.close()
+    assert st["prefill_chunks"] == 4          # two chunks each
+    assert (st["fused_chunks"] >= 1) is fused
+    assert ra["ids"] == _reference(model, params, ids_a, N_A)
+    assert rb["ids"] == _reference(model, params, ids_b, 6)

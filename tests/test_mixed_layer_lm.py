@@ -14,6 +14,7 @@ import pytest
 
 from benchmark import cells
 from benchmark import weights as W
+from helpers_last_logits import assert_last_logits_only_is_the_last_row
 from mlcomp_tpu.models import create_model
 from mlcomp_tpu.models.generation import init_cache
 from mlcomp_tpu.models.moe import RoutedExperts
@@ -631,3 +632,30 @@ def test_a_chunk_that_decays_through_its_pads_is_not_the_layer(monkeypatch):
     with jax.default_matmul_precision("highest"):
         served = _served_logits(model, params, ids, n_prompt=21)
     assert np.abs(served - _reference_logits(arch, d, 7, ids)).max() > 0.05
+
+
+# ---- last_logits_only: the final norm and the head on the row kept ----
+
+@pytest.mark.parametrize("served, kv_quant", [
+    ("laguna-s-2_1", False), ("laguna-s-2_1", True),
+    ("smallthinker-21ba3b", False), ("smallthinker-21ba3b", True),
+    # a retention stack keeps a state, not keys and values: one form
+    ("brumby-14b", False)])
+def test_last_logits_only_is_the_full_calls_last_row(served, kv_quant):
+    """Each served stack, two chunks of 16 (window and full attention
+    with a cache behind the second; the experts' and the retention
+    layers' sown counts; a recurrent state for a cache): the keyword
+    cuts the sequence AFTER the last layer, so nothing but the logits'
+    shape can tell the two calls apart."""
+    cfg = _cfg(f"_rehearsal/{served}-serve.json")
+    arch = cells.architecture(cfg)
+    d = arch.dims_of(cfg)
+    model = create_model({**cfg["model"], "dtype": "float32",
+                          "head_dtype": "float32", "kv_quant": kv_quant})
+    params = W.program_params(arch, 11, d, jnp.float32)
+    ids = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 1,
+                             cfg["vocab_size"])
+    full, _ = assert_last_logits_only_is_the_last_row(
+        model, {"params": params}, ids, chunk=16, l_buf=48)
+    sown = jax.tree_util.tree_leaves(full[-1][1].get("counters", {}))
+    assert sown, "these stacks count: the counters' channel was compared"

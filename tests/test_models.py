@@ -257,3 +257,51 @@ def test_remat_keeps_the_flash_kernels_outputs_under_a_mesh(monkeypatch):
     assert all("shard_map" in c[0] for c in calls) and len(calls) == 6
     fwd = [c for c in calls if c[1].startswith("flash_fwd")]
     assert len(fwd) == 2 and not any("remat2" in c[0] for c in fwd)
+
+
+# ---- last_logits_only: the final norm and the head on the row kept ----
+
+@pytest.mark.parametrize("int8_fold_norms", [False, True])
+@pytest.mark.parametrize("kv_quant", [False, True])
+def test_transformer_lm_last_logits_only(kv_quant, int8_fold_norms):
+    """Two chunks of 2 x 40 rows: over 64 rows the full call's final
+    norm runs on its own, while the keyword's 2 rows take the folded
+    norm in the int8 head's prologue — the served case.  That pair is
+    held to the folded kernel's own tolerance (bfloat16 products,
+    another reduce order), the float32 head to float32's."""
+    from mlcomp_tpu.ops.quant import quantize_params
+    from mlcomp_tpu.train.state import init_model
+    from helpers_last_logits import assert_last_logits_only_is_the_last_row
+
+    model = create_model({
+        "name": "transformer_lm", "vocab_size": 256, "hidden": 128,
+        "layers": 2, "heads": 2, "mlp_dim": 256, "dtype": "float32",
+        "kv_quant": kv_quant,
+    })
+    ids = jnp.asarray(np.random.RandomState(3).randint(1, 256, (2, 80)))
+    params, _ = init_model(model, {"x": ids[:, :8]}, jax.random.PRNGKey(0))
+    if int8_fold_norms:
+        assert type(model).fold_norms_eligible
+        params = quantize_params(params, min_size=1024)
+    tol = 2e-2 if int8_fold_norms else 1e-5
+    assert_last_logits_only_is_the_last_row(
+        model, {"params": params}, ids, chunk=40, l_buf=96,
+        intercept=int8_fold_norms, rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("kv_quant", [False, True])
+def test_moe_lm_last_logits_only(kv_quant):
+    """The third decoder ``generate`` and the engine serve takes the
+    keyword too: they pass it to whatever model they are given."""
+    from helpers_last_logits import assert_last_logits_only_is_the_last_row
+    from mlcomp_tpu.train.state import init_model
+
+    model = create_model({
+        "name": "moe_lm", "vocab_size": 64, "hidden": 32, "layers": 2,
+        "heads": 2, "n_experts": 4, "moe_every": 2, "dtype": "float32",
+        "kv_quant": kv_quant,
+    })
+    ids = jnp.asarray(np.random.RandomState(4).randint(1, 64, (2, 16)))
+    params, _ = init_model(model, {"x": ids[:, :8]}, jax.random.PRNGKey(0))
+    assert_last_logits_only_is_the_last_row(
+        model, {"params": params}, ids, chunk=8, l_buf=24)
