@@ -13,7 +13,9 @@ Layouts handled (leaf name -> slot axis), matching both cache families
   slot axis 1;
 - int8 kv8 cache: ``cached_key_q`` / ``cached_value_q``
   (B, Hkv, L, dhp) int8 at slot axis 2, plus ``cached_key_scale`` /
-  ``cached_value_scale`` (B, Hkv, 1, L) bf16 at slot axis 3.
+  ``cached_value_scale`` (B, Hkv, 1, L) bf16 at slot axis 3;
+- latent cache: ``cached_latent`` (B, L, width), slot axis 1, no head
+  axis (``models/latent_attention.py``).
 
 ``cache_index`` is the one non-KV cache leaf; it is engine-owned and
 never captured.
@@ -43,6 +45,8 @@ SLOT_AXES = {
     "cached_value_q": 2,
     "cached_key_scale": 3,
     "cached_value_scale": 3,
+    # models/latent_attention.py: one latent a token for all heads
+    "cached_latent": 1,
 }
 
 # leaf name -> axis holding the KV-head dimension — the axis sharded

@@ -248,6 +248,33 @@ _COUNT_GROUPS = {
          "Tokens chunk calls absorbed into a state, summed over layers"),
         ("layer_calls", "Retention-layer calls (layers x steps, and chunks)"),
     ),
+    # models/kda.py KimiDeltaAttention (its COUNTS)
+    "kda": (
+        ("state_rows",
+         "Rows whose delta-rule state a single-token step updated, "
+         "summed over layers and steps"),
+        ("state_bytes",
+         "Bytes those passes moved (ops/pallas/kda.py "
+         "state_bytes_moved): each row's states read and written once"),
+        ("chunk_tokens",
+         "Tokens chunk calls absorbed into a state, summed over layers"),
+        ("layer_calls", "KDA-layer calls (layers x steps, and chunks)"),
+    ),
+    # models/latent_attention.py LatentAttention (its COUNTS)
+    "latent": (
+        ("tokens_attended",
+         "Cached latent tokens single-token steps attended (each row's "
+         "window), summed over layers and steps"),
+        ("bytes_read",
+         "Bytes of the latent blocks those steps fetched (whole blocks "
+         "of ops/pallas/latent_attention.py, each once for keys and "
+         "values alike)"),
+        ("chunk_tokens",
+         "Tokens chunk calls wrote into a latent cache, summed over "
+         "layers"),
+        ("layer_calls",
+         "Latent-attention calls (layers x steps, and chunks)"),
+    ),
 }
 _CLASS_COUNTS = tuple(name for name, _ in _COUNT_GROUPS["moe"][:4])
 
@@ -2008,17 +2035,29 @@ class DecodeEngine:
                     },
                 },
             }
-        ret = counted.get("retention")
-        if ret and ret["layer_calls"]:
-            issued = p["kv_rows_written"] * self._count_layers["retention"]
-            out["retention"] = {
-                **ret,
+        for group in ("retention", "kda"):
+            got = counted.get(group)
+            if not (got and got["layer_calls"]):
+                continue
+            issued = p["kv_rows_written"] * self._count_layers[group]
+            out[group] = {
+                **got,
                 # the device's count of rows over the host mirror's
                 # (rows holding a request at issue x steps x layers):
                 # under 1 by the rows that retired inside a dispatch
                 "state_rows_over_issued": round(
-                    ret["state_rows"] / issued, 4
+                    got["state_rows"] / issued, 4
                 ) if issued else None,
+            }
+        lat = counted.get("latent")
+        if lat and lat["layer_calls"]:
+            out["latent"] = {
+                **lat,
+                # of the bytes fetched, the part the windows needed:
+                # under 1 by the blocks' edges and the leaf's pad lanes
+                "tokens_per_fetched_kb": round(
+                    lat["tokens_attended"] / (lat["bytes_read"] / 1024), 4
+                ) if lat["bytes_read"] else None,
             }
         out["latency"] = {
             # "samples" is the WINDOW the percentiles summarize (the

@@ -11,10 +11,16 @@ width that is a field) followed by its MLP: one attention module, one
 decode-attention kernel family, one cache layout (a whole ``l_buf`` a
 layer; a window layer reads its last ``window`` tokens).
 
-A third kind, ``"retention"``, keeps no keys and values: the layer is
-``models/retention.py`` ``PowerRetention`` (``rope_full``'s rotation,
-``qk_norm``), its cache a recurrent state of fixed size a slot, and its
-MLP the same.  A stack is attention or retention throughout.
+Three more kinds keep no keys and values (``MIXERS`` has the table):
+``"retention"`` is ``models/retention.py`` ``PowerRetention``
+(``rope_full``'s rotation, ``qk_norm``) and ``"kda"`` is
+``models/kda.py`` ``KimiDeltaAttention`` (``conv_taps``), each with a
+recurrent state of fixed size a slot as its cache; ``"latent"`` is
+``models/latent_attention.py`` ``LatentAttention`` (``latent_dims``),
+whose cache is one latent a token for all heads.  The MLP is the same
+for every kind.  A stack may mix ``"full"`` with ``"sliding"``, and
+``"kda"`` (a state) with ``"latent"`` (a token axis): one slot's carry
+then holds both.  ``"retention"`` is served alone.
 
 What a model may also say: a RoPE description whose ``rotary_dim`` is
 0 rotates nothing (a layer without positional embedding);
@@ -34,7 +40,9 @@ import flax.linen as nn
 import jax.numpy as jnp
 
 from mlcomp_tpu.models import MODELS
-from mlcomp_tpu.models.moe import RoutedExperts
+from mlcomp_tpu.models.kda import KimiDeltaAttention
+from mlcomp_tpu.models.latent_attention import LatentAttention
+from mlcomp_tpu.models.moe import ROUTER_SCORES, RoutedExperts
 from mlcomp_tpu.models.retention import PowerRetention
 from mlcomp_tpu.models.transformer import (
     RMSNorm,
@@ -45,8 +53,71 @@ from mlcomp_tpu.models.transformer import (
 )
 
 
+def _attention(layer: "MixedLayer") -> nn.Module:
+    return SelfAttention(
+        layer.hidden, layer.heads, layer.kv_heads, layer.dtype,
+        kv_quant=layer.kv_quant, head_dim=layer.head_dim, rope=layer.rope,
+        window=layer.window, head_gate=layer.head_gate,
+        return_normed=layer.early_router, name="attn",
+    )
+
+
+def _retention(layer: "MixedLayer") -> nn.Module:
+    return PowerRetention(
+        layer.hidden, layer.heads, layer.kv_heads, layer.head_dim,
+        layer.dtype, rope=layer.rope, qk_norm=layer.qk_norm, name="attn",
+    )
+
+
+def _kda(layer: "MixedLayer") -> nn.Module:
+    return KimiDeltaAttention(
+        layer.hidden, layer.heads, layer.head_dim, layer.dtype,
+        conv=layer.conv_taps, name="attn",
+    )
+
+
+def _latent(layer: "MixedLayer") -> nn.Module:
+    return LatentAttention(
+        layer.hidden, layer.heads, layer.dtype, *layer.latent_dims,
+        name="attn",
+    )
+
+
+# a layer kind's mixer, all with ``SelfAttention``'s call signature
+MIXERS = {
+    "full": _attention, "sliding": _attention, "retention": _retention,
+    "kda": _kda, "latent": _latent,
+}
+# the kinds whose cache is a state of fixed size: they read no context
+# tokens
+STATE_KINDS = ("retention", "kda")
+# the kinds one stack may hold together
+SERVED_TOGETHER = (("full", "sliding"), ("retention",), ("kda", "latent"))
+# what only ``SelfAttention`` has, and why each other kind refuses it
+ATTENTION_ONLY = {
+    "kv_quant": {
+        "retention": "there are no keys and values to quantize",
+        "kda": "there are no keys and values to quantize",
+        "latent": "the latent is kept as it is, not as int8",
+    },
+    "window": {
+        "retention": "its gates do the forgetting",
+        "kda": "its decays do the forgetting",
+        "latent": "it reads the whole context",
+    },
+    "head_gate": {
+        "retention": "its output has no gate",
+        "kda": "its output gate is its own, a number a channel",
+        "latent": "its output has no gate",
+    },
+    "early_router": dict.fromkeys(
+        ("retention", "kda", "latent"), "it hands no normed input on"
+    ),
+}
+
+
 class MixedLayer(nn.Module):
-    """One decoder layer: attention of its kind, then its MLP."""
+    """One decoder layer: the mixer of its kind, then its MLP."""
 
     hidden: int
     heads: int
@@ -68,29 +139,21 @@ class MixedLayer(nn.Module):
     # the router scores the attention's normed input, not the experts'
     early_router: bool = False
     expert_gate: str = "silu"
-    # power retention in the attention's place (and its q/k norm)
-    retention: bool = False
+    router_score: str = "softmax"
+    selection_bias: bool = False
+    # the mixer's kind (``MIXERS``) and what only some kinds read
+    kind: str = "full"
     qk_norm: bool = False
+    conv_taps: int = 4
+    latent_dims: Tuple[int, int, int, int] = (128, 64, 128, 512)
 
     @nn.compact
     def __call__(self, x, positions, decode=False, kv_mask=None,
                  cache_cursor=None):
-        if self.retention:
-            mixer = PowerRetention(
-                self.hidden, self.heads, self.kv_heads, self.head_dim,
-                self.dtype, rope=self.rope, qk_norm=self.qk_norm,
-                name="attn",
-            )
-        else:
-            mixer = SelfAttention(
-                self.hidden, self.heads, self.kv_heads, self.dtype,
-                kv_quant=self.kv_quant, head_dim=self.head_dim,
-                rope=self.rope, window=self.window,
-                head_gate=self.head_gate,
-                return_normed=self.early_router, name="attn",
-            )
-        x = mixer(x, positions, decode=decode, kv_mask=kv_mask,
-                  cache_cursor=cache_cursor)
+        x = MIXERS[self.kind](self)(
+            x, positions, decode=decode, kv_mask=kv_mask,
+            cache_cursor=cache_cursor,
+        )
         x, pre = x if self.early_router else (x, None)
         h = RMSNorm(self.dtype)(x)
         if self.mlp_dim is not None:
@@ -106,7 +169,8 @@ class MixedLayer(nn.Module):
             d_ff=self.expert_width, k=self.experts_per_token,
             experts_held=self.experts_held, routed_scale=self.routed_scale,
             shared_width=self.shared_width, dtype=self.dtype,
-            gate=self.expert_gate, name="moe",
+            gate=self.expert_gate, router_score=self.router_score,
+            selection_bias=self.selection_bias, name="moe",
         )(h, router_input=pre)
 
 
@@ -132,8 +196,16 @@ class MixedLayerLM(nn.Module):
     shared_width: int = 0
     early_router: bool = False
     expert_gate: str = "silu"
+    # how the router scores (``moe.ROUTER_SCORES``), and whether a
+    # learned bias an expert joins the scores for the choice alone
+    router_score: str = "softmax"
+    selection_bias: bool = False
     # a retention layer's q and k are RMS-normed a head before RoPE
     qk_norm: bool = False
+    # a KDA layer's convolution; a latent layer's widths: a head's
+    # unrotated and shared key parts, its value, the latent's rank
+    conv_taps: int = 4
+    latent_dims: Tuple[int, int, int, int] = (128, 64, 128, 512)
     dtype: str = "bfloat16"
     kv_quant: bool = False
     # the head's matmul operands (accumulation and logits stay float32):
@@ -141,12 +213,13 @@ class MixedLayerLM(nn.Module):
     head_dtype: str = "float32"
 
     def attention_windows(self) -> Tuple[Optional[int], ...]:
-        """Each attention layer's window (None: the whole context),
-        for the engine's count of the context tokens attention reads;
-        a retention layer reads no context tokens and has no entry."""
+        """The window of each layer that reads context tokens (None:
+        the whole context, a ``"latent"`` layer's too), for the
+        engine's count of the context tokens attention reads; a layer
+        that reads a state (``STATE_KINDS``) has no entry."""
         return tuple(
             self.window if kind == "sliding" else None
-            for kind in self.layer_types if kind != "retention"
+            for kind in self.layer_types if kind not in STATE_KINDS
         )
 
     @nn.compact
@@ -186,7 +259,10 @@ class MixedLayerLM(nn.Module):
                 shared_width=self.shared_width,
                 early_router=self.early_router,
                 expert_gate=self.expert_gate,
-                retention=kind == "retention", qk_norm=self.qk_norm,
+                router_score=self.router_score,
+                selection_bias=self.selection_bias,
+                kind=kind, qk_norm=self.qk_norm,
+                conv_taps=self.conv_taps, latent_dims=self.latent_dims,
                 name=f"layer_{i}",
             )(h, positions, decode, kv_mask, cache_cursor)
         if last_logits_only:
@@ -207,31 +283,30 @@ def mixed_layer_lm(**cfg: Any) -> MixedLayerLM:
     n = {len(cfg[k]) for k in lists}
     if len(n) != 1:
         raise ValueError(f"{lists} must be one entry a layer, got lengths {n}")
-    for kind, allowed in (("layer_types", ("full", "sliding", "retention")),
+    for kind, allowed in (("layer_types", tuple(MIXERS)),
                           ("mlp_layer_types", ("dense", "sparse"))):
         bad = sorted(set(cfg[kind]) - set(allowed))
         if bad:
             raise ValueError(f"{kind}: {bad} not among {allowed}")
     kinds = set(cfg["layer_types"])
-    if "retention" in kinds:
-        # a retention layer keeps a state, not keys and values
-        for key, why in (
-            ("kv_quant", "there are no keys and values to quantize"),
-            ("window", "its gates do the forgetting"),
-            ("head_gate", "its output has no gate"),
-            ("early_router", "it hands no normed input on"),
-        ):
+    if not any(kinds <= set(group) for group in SERVED_TOGETHER):
+        raise ValueError(
+            f"layer_types {list(cfg['layer_types'])}: one stack holds "
+            f"kinds of one of {SERVED_TOGETHER}; no other mix is served yet"
+        )
+    for key, whys in ATTENTION_ONLY.items():
+        for kind in sorted(kinds & set(whys)):
             if cfg.get(key):
                 raise ValueError(
-                    f"{key} on a retention layer: {why}; layer_types "
+                    f"{key} on a {kind} layer: {whys[kind]}; layer_types "
                     f"{list(cfg['layer_types'])}"
                 )
-        if kinds != {"retention"}:
-            raise ValueError(
-                "retention beside attention in one stack is not served "
-                f"yet; layer_types {list(cfg['layer_types'])}"
-            )
-    elif cfg.get("qk_norm"):
+    if cfg.get("router_score", "softmax") not in ROUTER_SCORES:
+        raise ValueError(
+            f"router_score {cfg['router_score']!r}: the router scores by "
+            f"one of {sorted(ROUTER_SCORES)}"
+        )
+    if cfg.get("qk_norm") and "retention" not in kinds:
         raise ValueError(
             "qk_norm: only a retention layer norms its q and k; "
             f"layer_types {list(cfg['layer_types'])}"
@@ -250,8 +325,9 @@ def mixed_layer_lm(**cfg: Any) -> MixedLayerLM:
         )
     for k in lists:
         cfg[k] = tuple(cfg[k])
-    if cfg.get("experts_held") is not None:
-        cfg["experts_held"] = tuple(int(v) for v in cfg["experts_held"])
+    for k in ("experts_held", "latent_dims"):
+        if cfg.get(k) is not None:
+            cfg[k] = tuple(int(v) for v in cfg[k])
     for k in ("rope_full", "rope_sliding"):
         cfg[k] = RopeSpec.of(cfg.get(k))
     return MixedLayerLM(**cfg)

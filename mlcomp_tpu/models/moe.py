@@ -193,14 +193,25 @@ class MoEBlock(nn.Module):
         return out.reshape(b, s, d)
 
 
+# how a router's logits become an expert's score
+ROUTER_SCORES = {
+    "softmax": lambda logits: jax.nn.softmax(logits, axis=-1),
+    "sigmoid": jax.nn.sigmoid,
+}
+
+
 class RoutedExperts(nn.Module):
     """Dropless top-k routing over gated experts (``gate``: ``"silu"``,
     SwiGLU, or ``"relu"``, ReGLU), of which this chip may hold a share
     (expert parallelism's one-chip half), plus an optional shared
     expert of the same form on every token.
 
-    The float32 router scores all ``n_experts`` by softmax; a token's
-    top ``k`` are renormalised to sum 1 and scaled by ``routed_scale``.
+    The float32 router scores all ``n_experts`` (``router_score``:
+    ``"softmax"`` over the experts, or ``"sigmoid"``, each expert's
+    own); a token's top ``k`` are renormalised to sum 1 and scaled by
+    ``routed_scale``.  With ``selection_bias`` a learned number an
+    expert (``router_bias``) is added to the scores for the CHOICE of
+    the top ``k`` alone: the weights are the scores themselves.
     ``experts_held = (first, count)`` says which experts' weights are
     here (None: all).  The assignments whose expert is held are sorted
     by expert (``group_layout``'s counting sort), run through the
@@ -234,6 +245,8 @@ class RoutedExperts(nn.Module):
     shared_width: int = 0
     dtype: jnp.dtype = jnp.bfloat16
     gate: str = "silu"
+    router_score: str = "softmax"
+    selection_bias: bool = False
 
     @nn.compact
     def __call__(self, x, router_input=None):
@@ -262,7 +275,16 @@ class RoutedExperts(nn.Module):
                 self.n_experts, use_bias=False, dtype=jnp.float32,
                 name="router",
             )(scored.astype(jnp.float32))
-            topv, topi = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), self.k)
+            scores = ROUTER_SCORES[self.router_score](logits)
+            if self.selection_bias:
+                bias = self.param(
+                    "router_bias", nn.initializers.zeros,
+                    (self.n_experts,), jnp.float32,
+                )
+                _, topi = jax.lax.top_k(scores + bias, self.k)
+                topv = jnp.take_along_axis(scores, topi, axis=-1)
+            else:
+                topv, topi = jax.lax.top_k(scores, self.k)
             gates = topv / jnp.sum(topv, axis=-1, keepdims=True)
             gates = gates * self.routed_scale                     # (T, k)
             local = (topi - first).reshape(t * self.k)

@@ -337,3 +337,55 @@ def test_retention_step_walks_the_state_in_place(chip, product):
     # the state and the normaliser are updated where they are
     state_bytes = b * n * width * (DH + 1) * 4
     assert compiled.memory_analysis().alias_size_in_bytes >= state_bytes
+
+
+# Kimi-Linear's cell (reasoning-offline): 112 slots, 32 KDA heads with a
+# float32 state of 128 x 128 each; a latent buffer of 9,729 slots kept
+# as 10,240 x 640 bfloat16, 32 heads' absorbed queries against it
+def test_kda_step_updates_the_states_in_place(chip):
+    from mlcomp_tpu.ops.pallas.kda import kda_step
+
+    b, n = 112, 32
+
+    def step(q, k, v, log_a, beta, live, state):
+        return kda_step(q, k, v, log_a, beta, live, state, interpret=False)
+
+    args = (chip((b, n, DH), jnp.float32), chip((b, n, DH), jnp.float32),
+            chip((b, n, DH), jnp.float32), chip((b, n, DH), jnp.float32),
+            chip((b, n), jnp.float32), chip((b,), jnp.bool_),
+            chip((b, n, DH, DH), jnp.float32))
+    compiled = jax.jit(step, donate_argnums=(6,)).lower(*args).compile()
+    text = compiled.as_text()
+    # the benchmark's readers match the op by this name
+    assert "tpu_custom_call" in text and "kda_step" in text
+    # the states are updated where they are
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= b * n * DH * DH * 4
+
+
+def test_latent_decode_appends_in_place_and_reads_a_block_once(chip):
+    from mlcomp_tpu.ops.pallas.latent_attention import (
+        buffer_len,
+        latent_decode,
+    )
+
+    b, n, dc, width = 112, 32, 512, 640
+    length = buffer_len(8192 + 1536 + 1)
+    assert length == 10240
+
+    def step(q, new, cache, start, stop):
+        return latent_decode(q, new, cache, start, stop, dc=dc,
+                             interpret=False)
+
+    args = (chip((b, n, width), jnp.bfloat16), chip((b, width), jnp.bfloat16),
+            chip((b, length, width), jnp.bfloat16), chip((b,), jnp.int32),
+            chip((b,), jnp.int32))
+    compiled = jax.jit(step, donate_argnums=(2,)).lower(*args).compile()
+    text = compiled.as_text()
+    # the benchmark's readers match the op by this name
+    assert "tpu_custom_call" in text and "latent_decode" in text
+    # the cache is written where it is: no second buffer of 1.47 GB
+    cache_bytes = b * length * width * 2
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes >= cache_bytes
+    assert stats.temp_size_in_bytes < cache_bytes // 100

@@ -432,6 +432,93 @@ def test_a_retention_layers_counts_are_the_hand_counts():
     assert "mlcomp_engine_moe_" not in text
 
 
+# ---- two more groups, in ONE stack: a delta-rule state's counts beside
+# a latent cache's (models/kda.py, models/latent_attention.py) ----
+
+STATES_AND_LATENTS = {
+    "name": "mixed_layer_lm", "vocab_size": 64, "hidden": 64, "head_dim": 16,
+    "kv_heads": 4, "layer_types": ["kda", "latent", "kda"],
+    "heads_per_layer": [4, 4, 4], "mlp_layer_types": ["dense"] * 3,
+    "mlp_dim": 128, "latent_dims": [16, 8, 16, 32], "dtype": "float32",
+}
+
+
+def test_the_state_and_the_latent_layers_counts_are_the_hand_counts():
+    """One request alone on three slots: 12 prompt tokens in a bucket of
+    16 are two chunks of 8 (four pads in the first), then three
+    dispatches of K = 2 steps, the one live row through two KDA layers
+    and one latent layer whose window grows a token a step."""
+    from mlcomp_tpu.models import kda, latent_attention
+    from mlcomp_tpu.ops.pallas.kda import state_bytes_moved
+
+    for group, mod in (("kda", kda), ("latent", latent_attention)):
+        assert tuple(n for n, _ in _COUNT_GROUPS[group]) == mod.COUNTS
+    model, params = _build(STATES_AND_LATENTS)
+    k, n_kda = 2, 2
+    eng = DecodeEngine(model, {"params": params}, slots=3,
+                       prompt_buckets=(16,), max_new_cap=16,
+                       steps_per_dispatch=k, prefill_chunk=8,
+                       pipeline_depth=1)
+    try:
+        _, packed = jax.eval_shape(
+            eng._dispatch_fn(), eng.variables, eng._dstate)
+        assert packed.shape == (3 * k * 3 + 4 + 4,)
+        assert eng._count_layers == {"kda": 2, "latent": 1}
+        out = eng.submit(list(range(1, 13)), 3 * k).result(timeout=300)
+        st = eng.stats()
+        text = eng.metrics.render()
+    finally:
+        eng.close()
+    assert len(out["ids"]) == 3 * k and st["pipeline"]["issued"] == 3
+    # greedy through the engine is greedy under the full forward pass
+    seq = jnp.asarray([list(range(1, 13)) + out["ids"]])
+    want = np.asarray(jnp.argmax(
+        model.apply({"params": params}, seq)[0, 11:-1], -1)).tolist()
+    assert out["ids"] == want
+    steps = 3 * k
+    assert "moe" not in st and "retention" not in st
+    assert st["kda"] == {
+        "state_rows": steps * n_kda,
+        "state_bytes": state_bytes_moved(steps * n_kda, 4, 16, 16),
+        "chunk_tokens": 12 * n_kda,
+        "layer_calls": (2 + steps) * n_kda,
+        "state_rows_over_issued": 1.0,
+    }
+    # step j attends the 12 prompt tokens and its own j + 1; the buffer
+    # of 33 slots is one block of 48, in a leaf 128 lanes wide
+    attended = sum(12 + j + 1 for j in range(steps))
+    fetched = steps * 48 * 128 * 4
+    assert st["latent"] == {
+        "tokens_attended": attended, "bytes_read": fetched,
+        "chunk_tokens": 12, "layer_calls": 2 + steps,
+        "tokens_per_fetched_kb": round(attended / (fetched / 1024), 4),
+    }
+    # the engine's own count of context tokens: the one layer that
+    # reads them, the whole context
+    assert st["attention"]["kv_tokens_attended_share"] == 1.0
+    for line in (f"mlcomp_engine_kda_state_rows_total {steps * n_kda}",
+                 f"mlcomp_engine_kda_chunk_tokens_total {12 * n_kda}",
+                 f"mlcomp_engine_latent_tokens_attended_total {attended}",
+                 f"mlcomp_engine_latent_bytes_read_total {fetched}"):
+        assert line in text
+    assert "mlcomp_engine_retention_" not in text
+
+
+def test_a_cache_with_a_state_refuses_pages_and_prefixes_by_the_leaf():
+    """The latent leaf has a token axis (``SLOT_AXES``), the state and
+    the convolution's tail have none: whatever moves KV by pages or by
+    prefix is refused by those leaves' names, as for retention."""
+    from mlcomp_tpu.cache.kv_store import HEAD_AXES, SLOT_AXES
+
+    assert SLOT_AXES["cached_latent"] == 1 and "cached_latent" not in HEAD_AXES
+    model, params = _build(STATES_AND_LATENTS)
+    with pytest.raises(ValueError, match=r"slot state \['conv', 'state'\]"):
+        DecodeEngine(model, {"params": params}, slots=2,
+                     prompt_buckets=(16,), max_new_cap=16,
+                     steps_per_dispatch=2, prefill_chunk=8,
+                     kv_layout="paged", kv_page_tokens=8)
+
+
 # ---- the admission lane's books -------------------------------------
 
 PLAIN = {"name": "transformer_lm", "vocab_size": 64, "hidden": 32,

@@ -467,6 +467,10 @@ def test_the_full_forward_is_smallthinkers_and_no_other_models(
 AS_IT_WAS = {
     "internlm2-1_8b-serve": (21, "e68fcc57a2bb16bc", 32),
     "laguna-s-2_1-serve": (41, "9b07763613eed0be", 16),
+    # as the commit before Kimi-Linear built them (the mixer table, the
+    # router's score and bias are fields those models leave alone)
+    "smallthinker-21ba3b-serve": (23, "ba207b5d14ac2654", 16),
+    "brumby-14b-serve": (29, "873959174c3da4c8", 16),
 }
 
 
@@ -546,7 +550,7 @@ def test_a_retention_stack_is_assembled_from_the_lists():
     ({"kv_quant": True}, "kv_quant on a retention layer"),
     ({"window": 16}, "window on a retention layer"),
     ({"layer_types": ["retention", "full"]},
-     "retention beside attention in one stack"),
+     "one stack holds kinds of one of"),
     ({"layer_types": ["full", "full"]}, "qk_norm: only a retention layer"),
 ], ids=["kv_quant", "window", "beside_attention", "qk_norm_on_attention"])
 def test_what_a_retention_stack_cannot_be_is_refused_at_create_model(
@@ -640,7 +644,9 @@ def test_a_chunk_that_decays_through_its_pads_is_not_the_layer(monkeypatch):
     ("laguna-s-2_1", False), ("laguna-s-2_1", True),
     ("smallthinker-21ba3b", False), ("smallthinker-21ba3b", True),
     # a retention stack keeps a state, not keys and values: one form
-    ("brumby-14b", False)])
+    ("brumby-14b", False),
+    # states beside a latent: one form too
+    ("kimi-linear-48b-a3b", False)])
 def test_last_logits_only_is_the_full_calls_last_row(served, kv_quant):
     """Each served stack, two chunks of 16 (window and full attention
     with a cache behind the second; the experts' and the retention
@@ -659,3 +665,4 @@ def test_last_logits_only_is_the_full_calls_last_row(served, kv_quant):
         model, {"params": params}, ids, chunk=16, l_buf=48)
     sown = jax.tree_util.tree_leaves(full[-1][1].get("counters", {}))
     assert sown, "these stacks count: the counters' channel was compared"
+

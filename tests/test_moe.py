@@ -93,3 +93,54 @@ def test_moe_dense_einsum_matches_scan_to_tolerance():
         np.asarray(out_einsum), np.asarray(out_scan[:, :32]),
         rtol=2e-5, atol=2e-5,
     )
+
+
+def test_routed_experts_default_router_is_the_softmax_it_was():
+    """``router_score="softmax"`` without a selection bias, the
+    defaults, is the program ``RoutedExperts`` was before the field:
+    the same parameters (no ``router_bias``), the same jaxpr as the
+    module given the two defaults by name, the sigmoid router's one
+    logistic function not in it, and the old rule (the top k of the softmax, renormalised) written
+    out densely."""
+    from mlcomp_tpu.models.moe import ROUTER_SCORES, RoutedExperts
+
+    kw = dict(n_experts=8, d_model=128, d_ff=128, k=2, routed_scale=2.5,
+              shared_width=128, dtype=jnp.float32)
+    u = jax.random.normal(jax.random.PRNGKey(0), (2, 9, 128))
+    as_it_was, named = RoutedExperts(**kw), RoutedExperts(
+        **kw, router_score="softmax", selection_bias=False)
+    params = as_it_was.init(jax.random.PRNGKey(1), u)["params"]
+    assert sorted(params) == [
+        "experts_down", "experts_gate", "experts_up", "router",
+        "shared_down", "shared_gate", "shared_up"]
+    assert sorted(ROUTER_SCORES) == ["sigmoid", "softmax"]
+    import re
+
+    def program(layer):
+        # a kernel's jaxpr prints its functions' addresses: not the program
+        return re.sub(r"0x[0-9a-f]+", "", str(jax.make_jaxpr(
+            lambda p, x: layer.apply({"params": p}, x))(params, u)))
+
+    text = program(as_it_was)
+    assert text == program(named)
+    sig = program(RoutedExperts(**kw, router_score="sigmoid"))
+    # the logistic function is in both (SiLU): once more where it scores
+    assert sig.count("logistic") == text.count("logistic") + 1
+    assert "softmax" not in sig and "exp" in text
+    with jax.default_matmul_precision("highest"):
+        got = as_it_was.apply({"params": params}, u)
+        np.testing.assert_array_equal(got, named.apply({"params": params}, u))
+        probs = jax.nn.softmax(u @ params["router"]["kernel"], axis=-1)
+        topv, topi = jax.lax.top_k(probs, 2)
+        gates = topv / topv.sum(-1, keepdims=True) * 2.5
+        weight = (jax.nn.one_hot(topi, 8) * gates[..., None]).sum(-2)
+        unit = lambda x, g, up, down: (  # noqa: E731
+            jax.nn.silu(x @ g) * (x @ up)) @ down
+        want = sum(
+            weight[..., e:e + 1] * unit(u, params["experts_gate"][e],
+                                        params["experts_up"][e],
+                                        params["experts_down"][e])
+            for e in range(8)
+        ) + unit(u, *(params[f"shared_{n}"]["kernel"]
+                      for n in ("gate", "up", "down")))
+    np.testing.assert_allclose(got, want, atol=2e-5)

@@ -168,6 +168,16 @@ CONDITIONAL_METRICS = {
     "mlcomp_engine_retention_state_bytes_total",
     "mlcomp_engine_retention_chunk_tokens_total",
     "mlcomp_engine_retention_layer_calls_total",
+    # models with a KDA layer only (KimiDeltaAttention sows them)
+    "mlcomp_engine_kda_state_rows_total",
+    "mlcomp_engine_kda_state_bytes_total",
+    "mlcomp_engine_kda_chunk_tokens_total",
+    "mlcomp_engine_kda_layer_calls_total",
+    # models with a latent-attention layer only (LatentAttention sows them)
+    "mlcomp_engine_latent_tokens_attended_total",
+    "mlcomp_engine_latent_bytes_read_total",
+    "mlcomp_engine_latent_chunk_tokens_total",
+    "mlcomp_engine_latent_layer_calls_total",
 }
 
 MUTATOR_METHODS = {
