@@ -235,6 +235,11 @@ _COUNT_GROUPS = {
         ("chunk_experts_touched",
          "Experts reached, summed over chunk calls"),
         ("chunk_expert_layer_calls", "Expert-layer calls that were chunks"),
+        # what the grouped matmul multiplied: tiles used x rows a tile
+        # (the tile follows the call's shapes: auto_row_tile), and the
+        # chunk calls' part; assignments held over it is the tiles' fill
+        ("tile_rows", "Rows of the row tiles the expert layout used"),
+        ("chunk_tile_rows", "Chunk calls' rows of row tiles used"),
     ),
     # models/retention.py PowerRetention (its COUNTS)
     "retention": (
@@ -276,7 +281,11 @@ _COUNT_GROUPS = {
          "Latent-attention calls (layers x steps, and chunks)"),
     ),
 }
-_CLASS_COUNTS = tuple(name for name, _ in _COUNT_GROUPS["moe"][:4])
+# the moe entries that have a ``chunk_`` twin: counted by call class
+_CLASS_COUNTS = tuple(
+    name[len("chunk_"):] for name, _ in _COUNT_GROUPS["moe"]
+    if name.startswith("chunk_")
+)
 
 
 def _sown_by_group(counters) -> Dict[str, List[Any]]:
@@ -2021,13 +2030,14 @@ class DecodeEngine:
                 "assignments_held": moe["assignments_held"],
                 "experts_touched": touched,
                 "expert_layer_calls": calls,
+                "tile_rows": moe["tile_rows"],
                 "experts_touched_per_call": round(touched / calls, 3),
                 # of the experts held, summed over the same calls
                 "experts_touched_share": round(
                     touched / moe["experts_held"], 4
                 ),
-                # the four counts by call class: chunk calls (prefill)
-                # and single-token calls (decode steps)
+                # the counts by call class: chunk calls (prefill) and
+                # single-token calls (decode steps)
                 "by_class": {
                     "chunk": chunk,
                     "single_token": {

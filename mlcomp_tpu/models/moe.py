@@ -227,13 +227,22 @@ class RoutedExperts(nn.Module):
     attention hands the attention's normed input here and the
     post-attention normed state as ``x``.
 
+    The layout's row tile follows the call's own static shapes
+    (``auto_row_tile``: the rows an expert can expect, tokens x ``k``
+    over ``n_experts``): a decode step's few rows an expert sit in
+    16-row tiles, a 2,048-token chunk's in 64- or 128-row ones.  A
+    row's product does not depend on its tile.
+
     Each call sows ``[assignments made, assignments held, experts
     touched, 1, experts held]`` into the ``counters`` collection, and
     after them the first four again if the call is a chunk (more than
     one token a row) and zeros if it is a single-token step: the sums
     stay what they were, and sum minus chunk is the single-token class
     (summed where a caller makes the collection mutable; the decode
-    engine does).
+    engine does).  Two more close the vector: the rows of the tiles the
+    layout used (tiles x rows a tile: what the kernel multiplied, pad
+    rows included; assignments held over it is the tiles' fill), and
+    that again if the call is a chunk.
     """
 
     n_experts: int
@@ -252,7 +261,7 @@ class RoutedExperts(nn.Module):
     def __call__(self, x, router_input=None):
         from mlcomp_tpu.ops.pallas.grouped_matmul import (
             GATES,
-            ROW_TILE,
+            auto_row_tile,
             group_layout,
             grouped_matmul,
         )
@@ -288,8 +297,11 @@ class RoutedExperts(nn.Module):
             gates = topv / jnp.sum(topv, axis=-1, keepdims=True)
             gates = gates * self.routed_scale                     # (T, k)
             local = (topi - first).reshape(t * self.k)
+            # the rows an expert can expect are the same on every chip
+            # of an expert-parallel layer: the published expert count
+            tm = auto_row_tile(t, self.k, self.n_experts)
             lay = group_layout(
-                local, count, ROW_TILE,
+                local, count, tm,
                 source=jnp.arange(t * self.k, dtype=jnp.int32) // self.k,
             )
             held = lay.dest < lay.row_source.shape[0]
@@ -300,11 +312,15 @@ class RoutedExperts(nn.Module):
                 jnp.float32(1.0),
                 jnp.float32(count),
             ])
-            as_chunk = counts[:4] if s > 1 else jnp.zeros((4,), jnp.float32)
+            tile_rows = (lay.tiles_used * tm).astype(jnp.float32)
+            mine = jnp.concatenate([counts[:4], tile_rows])
+            as_chunk = mine if s > 1 else jnp.zeros_like(mine)
             self.sow(
-                "counters", "moe", jnp.concatenate([counts, as_chunk]),
+                "counters", "moe",
+                jnp.concatenate(
+                    [counts, as_chunk[:4], tile_rows, as_chunk[4:]]),
                 reduce_fn=lambda a, c: a + c,
-                init_fn=lambda: jnp.zeros((9,), jnp.float32),
+                init_fn=lambda: jnp.zeros((11,), jnp.float32),
             )
 
         with jax.named_scope("moe.experts"):

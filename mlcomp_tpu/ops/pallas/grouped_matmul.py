@@ -20,9 +20,21 @@ One kernel serves the plain product ``x @ w[g]`` and, with a second
 stack ``w2``, a gated unit's front half ``act(x @ w[g]) * (x @ w2[g])``
 (one read of the rows, one write of the product), ``act`` one of
 :data:`GATES` (SiLU: SwiGLU; ReLU: ReGLU).  The contraction is
-whole in one block (3072 or 1024 wide at the served widths), so there
-is no accumulator and no K loop; the grid is (output blocks, row
-tiles) with the row tiles innermost.
+whole in one block (the model's hidden size in the front half, the
+expert width in the back: 3072 / 1024, 2560 / 768 and 2304 / 1024 at
+the three served configurations), so there is no accumulator and no K
+loop, and a row's product does not depend on its tile; the grid is
+(output blocks, row tiles) with the row tiles innermost.
+
+The row tile is the caller's (:func:`auto_row_tile`): a grid step
+fetches the next step's blocks while it multiplies, one step ahead, so
+a group's weight block (2 to 6 MB with both stacks) hides only behind a
+step that multiplies long enough.  A decode step's few rows an expert
+are bound by the weights' bytes whatever the tile, and a larger tile is
+padding; a prefill chunk's hundreds of rows an expert in 16-row tiles
+were hundreds of grid steps of 0.3 us of products each (3,312 a layer
+at 2,048 tokens x 6 over 64 experts, at 36% of the call's roofline on
+the chip).
 """
 
 from __future__ import annotations
@@ -40,12 +52,28 @@ from mlcomp_tpu.ops.pallas import interpret_default
 LANES = 128
 # bf16 rows pack 16 to a sublane tile: the smallest row tile
 ROW_TILE = 16
+# the row tiles a layout is given (auto_row_tile).  On the chip (tools/
+# exp_grouped_matmul.py, PR 42; ms a layer call at tiles of 16 / 32 / 64
+# / 128 / 256, routing uniform, a Zipf-like draw within 3% of it):
+# 2,048 tokens x 6 over 64 experts (192 rows an expert; 2560, 768) 3.88
+# / 2.94 / 2.67 / 2.63 / 3.07; 2,048 x 8 over 256 of which 32 held (64
+# rows; 2304, 1024) 2.23 / 1.90 / 1.69 / 1.59 / 1.58; 256 x 10 over 256
+# of which 128 held (10 rows; 3072, 1024) 3.53 / 3.54 / 3.81 / 4.34 /
+# refused; decode steps (2 to 3.5 rows) level from 16 to 32, then 2-5%
+# slower a doubling.  No 256: its two kernel calls are the fastest at
+# 192 rows an expert (1.57 ms against 2.01), but the padded buffers the
+# layer gathers into and picks from grow with the tile (28,672 rows for
+# 12,288 assignments) and cost more than that, and beside (3072, 512)
+# weight blocks a 256-row tile does not fit the kernel's 16 MiB of VMEM
+# (128 rows: 12 MiB of weight blocks, 1.5 of rows, 0.75 of products)
+ROW_TILES = (16, 32, 64, 128)
 # one weight block in VMEM (double-buffered, and twice over for SwiGLU's
 # two stacks: 12 MiB of the 16 MiB a kernel may take by default).  On
-# the chip, at the served widths over 128 experts (tools/
-# exp_grouped_matmul.py, PR 28): (3072, 512) / (1024, 1536) blocks took
-# 2.84 ms a layer call at 48 tokens where (3072, 256) / (1024, 1024)
-# took 3.05 and (3072, 128) / (1024, 512) 3.08
+# the chip, at Laguna's widths over 128 experts (tools/
+# exp_grouped_matmul.py --blocks 256x1024,128x512,512x1536, PR 28):
+# (3072, 512) / (1024, 1536) blocks took 2.84 ms a layer call at 48
+# tokens where (3072, 256) / (1024, 1024) took 3.05 and (3072, 128) /
+# (1024, 512) 3.08
 WEIGHT_BLOCK_BYTES = 3 * 1024 * 1024
 # the gate's activation in the fused front half, by name
 GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
@@ -129,6 +157,17 @@ def auto_block_n(k: int, n: int, itemsize: int) -> int:
             f"{WEIGHT_BLOCK_BYTES} bytes"
         )
     return fits[-1]
+
+
+def auto_row_tile(tokens: int, k: int, experts: int) -> int:
+    """The row tile for a call that routes ``tokens`` tokens to ``k`` of
+    ``experts`` experts each: the largest of :data:`ROW_TILES` that the
+    rows an expert can expect, ``tokens * k / experts``, fill; never
+    under :data:`ROW_TILE`.  ``experts`` is the PUBLISHED count: a chip
+    that holds a share of them sees that share of the assignments, so
+    the rows a held expert gets do not depend on the share."""
+    expected = tokens * k // experts
+    return max([tm for tm in ROW_TILES if tm <= expected], default=ROW_TILE)
 
 
 def grouped_matmul(

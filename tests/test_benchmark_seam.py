@@ -69,6 +69,39 @@ def test_the_entries_name_the_cell_and_its_files():  # noqa: F811
         assert json.load(f)["num_hidden_layers"] == 5
 
 
+def test_the_tile_fill_share_is_the_chunk_classs_rows_over_its_tiles():
+    """``expert_tile_fill_share.mixedlen`` (PR 42) on hand-made
+    ``stats()`` pairs: the chunk class's assignments held over its
+    ``tile_rows``, after less before; no number from a program that
+    does not count its tiles (the parent) or that ran no chunk."""
+    from benchmark import cells
+
+    read = cells.layer_reader("expert_tile_fill_share.mixedlen")
+
+    def stats(held, rows, step_rows=0.0):
+        chunk = {"assignments_held": held}
+        if rows is not None:
+            chunk["tile_rows"] = rows
+        return {"engine": {"moe": {"by_class": {
+            "chunk": chunk,
+            "single_token": {"assignments_held": 192.0,
+                             "tile_rows": step_rows}}}}}
+
+    ctx = {"stats0": stats(12288.0, 16384.0), "stats1": stats(
+        12288.0 * 3, 16384.0 + 2 * 16000.0, step_rows=992.0)}
+    assert read("expert_tile_fill_share.mixedlen", ctx) == pytest.approx(
+        100.0 * 24576 / 32000)
+    assert read("x", {"stats0": stats(0.0, None),
+                      "stats1": stats(24576.0, None)}) is None
+    assert read("x", {"stats0": stats(5.0, 16.0),
+                      "stats1": stats(5.0, 16.0, step_rows=64.0)}) is None
+    assert read("x", {"stats0": None, "stats1": {"engine": {}}}) is None
+    [entry] = [m for m in cells.benchmark_spec()["per_layer"]
+               if m["name"] == "expert_tile_fill_share.mixedlen"]
+    assert entry["workloads"] == ["mixed-length-offline"]
+    assert entry["layer"] == "expert layer"
+
+
 _CACHE_OPTIONS = (
     "jax_compilation_cache_dir",
     "jax_enable_compilation_cache",

@@ -279,22 +279,36 @@ def test_decode_attention_chunk_window_2048_queries_12k_cache(chip):
     )
 
 
-# Laguna-S-2.1's share (128 experts held of 256, top 10, SwiGLU) and
-# SmallThinker's whole layer (64 experts, top 6, ReGLU), a decode step
-# and an admission chunk each
-@pytest.mark.parametrize("tokens,e,h,f,k,gate", [
-    (48, 128, 3072, 1024, 10, "silu"), (256, 128, 3072, 1024, 10, "silu"),
-    (32, 64, 2560, 768, 6, "relu"), (2048, 64, 2560, 768, 6, "relu"),
-], ids=["decode", "chunk", "smallthinker_decode", "smallthinker_chunk"])
-def test_grouped_matmul_held_experts(chip, tokens, e, h, f, k, gate):
+# Laguna-S-2.1's share (128 experts held of 256, top 10, SwiGLU),
+# SmallThinker's whole layer (64 experts, top 6, ReGLU) and
+# Kimi-Linear's share (32 held of 256, top 8), a decode step and an
+# admission chunk each, in the row tile the layer gives the call
+# (auto_row_tile: 16 but for the two 2,048-token chunks' 128 and 64);
+# and the largest tile at the widest blocks served (Laguna's widths, a
+# 4,096-token chunk), which is what the rule's cap has to fit in VMEM
+@pytest.mark.parametrize("tokens,published,e,h,f,k,gate,tile", [
+    (48, 256, 128, 3072, 1024, 10, "silu", 16),
+    (256, 256, 128, 3072, 1024, 10, "silu", 16),
+    (32, 64, 64, 2560, 768, 6, "relu", 16),
+    (2048, 64, 64, 2560, 768, 6, "relu", 128),
+    (112, 256, 32, 2304, 1024, 8, "silu", 16),
+    (2048, 256, 32, 2304, 1024, 8, "silu", 64),
+    (4096, 256, 128, 3072, 1024, 10, "silu", 128),
+], ids=["decode", "chunk", "smallthinker_decode", "smallthinker_chunk",
+        "kimi_decode", "kimi_chunk", "largest_tile_widest_blocks"])
+def test_grouped_matmul_held_experts(chip, tokens, published, e, h, f, k,
+                                     gate, tile):
     from mlcomp_tpu.ops.pallas.grouped_matmul import (
-        ROW_TILE,
+        ROW_TILES,
+        auto_row_tile,
         grouped_matmul,
         padded_rows,
     )
 
-    rows = padded_rows(tokens * k, e, ROW_TILE)
-    tiles = (chip((rows // ROW_TILE,), jnp.int32), chip((1,), jnp.int32))
+    tm = auto_row_tile(tokens, k, published)
+    assert tm == tile <= ROW_TILES[-1]
+    rows = padded_rows(tokens * k, e, tm)
+    tiles = (chip((rows // tm,), jnp.int32), chip((1,), jnp.int32))
 
     def experts(x, w_gate, w_up, w_down, tile_group, used):
         act = grouped_matmul(x, w_gate, tile_group, used, w2=w_up,

@@ -70,6 +70,15 @@ def test_a_mixed_layer_model_is_served_and_counted(kv_quant, fused):
     assert 0 < moe["experts_touched"] <= calls * 4
     assert moe["experts_touched_share"] == pytest.approx(
         moe["experts_touched"] / (calls * 4), abs=1e-4)
+    # a fused dispatch's chunk and steps, a staged chunk, a plain
+    # dispatch: at most 8 rows an expert a call, so every expert a call
+    # reached is one 16-row tile, in either class
+    by_class = moe["by_class"]
+    assert moe["tile_rows"] == 16 * moe["experts_touched"] == sum(
+        c["tile_rows"] for c in by_class.values())
+    for c in by_class.values():
+        assert c["tile_rows"] == 16 * c["experts_touched"] > 0
+        assert c["assignments_held"] <= c["tile_rows"]
     att = st["attention"]
     # contexts pass the window of 8 in one layer of three
     assert 0 < att["kv_tokens_attended"] < att["kv_tokens_live"]
@@ -156,16 +165,21 @@ def test_the_counts_by_layer_kind_and_by_call_class_are_the_hand_counts(
         "chunk": {"assignments": 8 * top_k * chunk_calls,
                   "assignments_held": 8 * top_k * chunk_calls,
                   "experts_touched": moe["by_class"]["chunk"]["experts_touched"],
-                  "expert_layer_calls": chunk_calls},
+                  "expert_layer_calls": chunk_calls,
+                  "tile_rows":
+                      16 * moe["by_class"]["chunk"]["experts_touched"]},
         # a step routes every slot's token, the two empty rows' too
         "single_token": {
             "assignments": slots * top_k * step_calls,
             "assignments_held": slots * top_k * step_calls,
             "experts_touched":
                 moe["by_class"]["single_token"]["experts_touched"],
-            "expert_layer_calls": step_calls},
+            "expert_layer_calls": step_calls,
+            "tile_rows":
+                16 * moe["by_class"]["single_token"]["experts_touched"]},
     }
-    for key in ("assignments", "experts_touched", "expert_layer_calls"):
+    for key in ("assignments", "experts_touched", "expert_layer_calls",
+                "tile_rows"):
         assert moe[key] == sum(c[key] for c in moe["by_class"].values())
     assert chunk_calls <= moe["by_class"]["chunk"]["experts_touched"] \
         <= 8 * chunk_calls
@@ -174,6 +188,24 @@ def test_the_counts_by_layer_kind_and_by_call_class_are_the_hand_counts(
         in text
     assert f"mlcomp_engine_moe_chunk_expert_layer_calls_total {chunk_calls}" \
         in text
+    for name, rows in (("tile_rows", moe["tile_rows"]),
+                       ("chunk_tile_rows", moe["by_class"]["chunk"]["tile_rows"])):
+        assert f"mlcomp_engine_moe_{name}_total {int(rows)}" in text
+
+
+def test_the_tile_counts_close_the_expert_layers_vector():
+    """The nine entries every reader indexes keep their places; the two
+    tile counts come after them, and the classes are the entries with a
+    ``chunk_`` twin."""
+    from mlcomp_tpu.engine import _CLASS_COUNTS
+
+    names = [n for n, _ in _COUNT_GROUPS["moe"]]
+    assert names == [
+        "assignments", "assignments_held", "experts_touched",
+        "expert_layer_calls", "experts_held", "chunk_assignments",
+        "chunk_assignments_held", "chunk_experts_touched",
+        "chunk_expert_layer_calls", "tile_rows", "chunk_tile_rows"]
+    assert _CLASS_COUNTS == tuple(names[:4]) + ("tile_rows",)
 
 
 # ---- the int8 cache's single-token step appends inside the kernel ----

@@ -144,3 +144,92 @@ def test_routed_experts_default_router_is_the_softmax_it_was():
         ) + unit(u, *(params[f"shared_{n}"]["kernel"]
                       for n in ("gate", "up", "down")))
     np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def _grouped_matmul_grids(layer, params, x):
+    """(grid, rows-block shape) of every ``grouped_matmul`` kernel call
+    in the traced layer call."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                gm = eqn.params["grid_mapping"]
+                found.append((tuple(gm.grid), tuple(
+                    getattr(b, "block_size", b)
+                    for b in gm.block_mappings[0].block_shape)))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(
+        lambda p, u: layer.apply({"params": p}, u))(params, x).jaxpr)
+    return found
+
+
+def test_routed_experts_in_large_tiles_is_its_dense_reference():
+    """256 tokens to 2 of 4 experts are 128 rows an expert: the layout
+    takes 128-row tiles (``auto_row_tile``), and the layer is still the
+    dense rule written out: every token's top two, renormalised."""
+    from mlcomp_tpu.models.moe import RoutedExperts
+    from mlcomp_tpu.ops.pallas.grouped_matmul import auto_row_tile
+
+    layer = RoutedExperts(n_experts=4, d_model=128, d_ff=128, k=2,
+                          dtype=jnp.float32)
+    u = jax.random.normal(jax.random.PRNGKey(0), (2, 128, 128))
+    params = layer.init(jax.random.PRNGKey(1), u)["params"]
+    assert auto_row_tile(256, 2, 4) == 128
+    grids = _grouped_matmul_grids(layer, params, u)
+    assert len(grids) == 2 and all(
+        block[0] == 128 and grid[1] * 128 == 512 + 4 * 128
+        for grid, block in grids)
+    with jax.default_matmul_precision("highest"):
+        got, upd = layer.apply({"params": params}, u, mutable=["counters"])
+        probs = jax.nn.softmax(u @ params["router"]["kernel"], axis=-1)
+        topv, topi = jax.lax.top_k(probs, 2)
+        gates = topv / topv.sum(-1, keepdims=True)
+        weight = (jax.nn.one_hot(topi, 4) * gates[..., None]).sum(-2)
+        want = sum(
+            weight[..., e:e + 1] * (
+                (jax.nn.silu(u @ params["experts_gate"][e])
+                 * (u @ params["experts_up"][e])) @ params["experts_down"][e])
+            for e in range(4))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    # the tiles it used, counted: each expert's rows rounded up to 128
+    sizes = np.bincount(np.asarray(topi).reshape(-1), minlength=4)
+    counts = np.asarray(upd["counters"]["moe"])
+    assert counts.shape == (11,)
+    assert counts[9] == counts[10] == sum(-(-s // 128) * 128 for s in sizes)
+    assert counts[1] == counts[6] == 512
+
+
+def test_a_laguna_shaped_call_is_laid_out_as_it_was(monkeypatch):
+    """A decode step (48 tokens) and a 256-token chunk, 10 of 256
+    experts of which 128 are held: 2 and 10 rows an expert keep the
+    16-row tiles and the buffers they had, and the traced program is the
+    one a constant 16 gives, word for word."""
+    import re
+
+    from mlcomp_tpu.models.moe import RoutedExperts
+    from mlcomp_tpu.ops.pallas import grouped_matmul as gm
+
+    layer = RoutedExperts(n_experts=256, d_model=128, d_ff=128, k=10,
+                          experts_held=(0, 128), shared_width=128,
+                          dtype=jnp.float32)
+
+    def program(u, params):
+        return re.sub(r"0x[0-9a-f]+", "", str(jax.make_jaxpr(
+            lambda p, x: layer.apply({"params": p}, x))(params, u)))
+
+    for shape in ((48, 1, 128), (1, 256, 128)):
+        u = jax.random.normal(jax.random.PRNGKey(0), shape)
+        params = jax.eval_shape(
+            lambda: layer.init(jax.random.PRNGKey(1), u))["params"]
+        a = shape[0] * shape[1] * 10
+        grids = _grouped_matmul_grids(layer, params, u)
+        assert len(grids) == 2 and all(
+            block[0] == 16 and grid[1] * 16 == gm.padded_rows(a, 128, 16)
+            for grid, block in grids)
+        text = program(u, params)
+        with monkeypatch.context() as m:
+            m.setattr(gm, "auto_row_tile", lambda *_: gm.ROW_TILE)
+            assert program(u, params) == text

@@ -163,6 +163,8 @@ CONDITIONAL_METRICS = {
     "mlcomp_engine_moe_chunk_assignments_held_total",
     "mlcomp_engine_moe_chunk_experts_touched_total",
     "mlcomp_engine_moe_chunk_expert_layer_calls_total",
+    "mlcomp_engine_moe_tile_rows_total",
+    "mlcomp_engine_moe_chunk_tile_rows_total",
     # models with a retention layer only (PowerRetention sows them)
     "mlcomp_engine_retention_state_rows_total",
     "mlcomp_engine_retention_state_bytes_total",
