@@ -265,6 +265,17 @@ _COUNT_GROUPS = {
          "Tokens chunk calls absorbed into a state, summed over layers"),
         ("layer_calls", "KDA-layer calls (layers x steps, and chunks)"),
     ),
+    # models/short_conv.py GatedShortConv (its COUNTS)
+    "conv": (
+        ("state_rows",
+         "Rows whose convolution tail a single-token step moved on, "
+         "summed over layers and steps"),
+        ("state_bytes",
+         "Bytes of those tails, each read and written once"),
+        ("chunk_tokens",
+         "Tokens chunk calls passed through a tail, summed over layers"),
+        ("layer_calls", "Conv-layer calls (layers x steps, and chunks)"),
+    ),
     # models/latent_attention.py LatentAttention (its COUNTS)
     "latent": (
         ("tokens_attended",
@@ -2045,7 +2056,7 @@ class DecodeEngine:
                     },
                 },
             }
-        for group in ("retention", "kda"):
+        for group in ("retention", "kda", "conv"):
             got = counted.get(group)
             if not (got and got["layer_calls"]):
                 continue

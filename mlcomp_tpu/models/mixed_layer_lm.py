@@ -11,16 +11,22 @@ width that is a field) followed by its MLP: one attention module, one
 decode-attention kernel family, one cache layout (a whole ``l_buf`` a
 layer; a window layer reads its last ``window`` tokens).
 
-Three more kinds keep no keys and values (``MIXERS`` has the table):
+Four more kinds keep no keys and values (``MIXERS`` has the table):
 ``"retention"`` is ``models/retention.py`` ``PowerRetention``
 (``rope_full``'s rotation, ``qk_norm``) and ``"kda"`` is
 ``models/kda.py`` ``KimiDeltaAttention`` (``conv_taps``), each with a
-recurrent state of fixed size a slot as its cache; ``"latent"`` is
+recurrent state of fixed size a slot as its cache; ``"conv"`` is
+``models/short_conv.py`` ``GatedShortConv`` (``conv_taps``), whose
+cache is its last ``conv_taps - 1`` inputs; ``"latent"`` is
 ``models/latent_attention.py`` ``LatentAttention`` (``latent_dims``),
 whose cache is one latent a token for all heads.  The MLP is the same
-for every kind.  A stack may mix ``"full"`` with ``"sliding"``, and
-``"kda"`` (a state) with ``"latent"`` (a token axis): one slot's carry
-then holds both.  ``"retention"`` is served alone.
+for every kind.  A stack may mix ``"full"`` with ``"sliding"``,
+``"kda"`` (a state) with ``"latent"`` (a token axis), and ``"full"``
+with ``"conv"`` (keys and values, int8 under ``kv_quant``, beside a
+tail of fixed size): one slot's carry then holds both (the four groups
+are ``SERVED_TOGETHER``).  ``"retention"`` is served alone.  With
+``qk_norm`` an attention layer (``"full"``, ``"sliding"``) norms its q
+and k a head before the rotation, as a retention layer does.
 
 What a model may also say: a RoPE description whose ``rotary_dim`` is
 0 rotates nothing (a layer without positional embedding);
@@ -58,7 +64,8 @@ def _attention(layer: "MixedLayer") -> nn.Module:
         layer.hidden, layer.heads, layer.kv_heads, layer.dtype,
         kv_quant=layer.kv_quant, head_dim=layer.head_dim, rope=layer.rope,
         window=layer.window, head_gate=layer.head_gate,
-        return_normed=layer.early_router, name="attn",
+        return_normed=layer.early_router, qk_norm=layer.qk_norm,
+        name="attn",
     )
 
 
@@ -83,17 +90,34 @@ def _latent(layer: "MixedLayer") -> nn.Module:
     )
 
 
+def _conv(layer: "MixedLayer") -> nn.Module:
+    # imported where a stack first has the kind: the stacks without it
+    # load what they loaded
+    from mlcomp_tpu.models.short_conv import GatedShortConv
+
+    return GatedShortConv(
+        layer.hidden, layer.dtype, taps=layer.conv_taps, name="attn",
+    )
+
+
 # a layer kind's mixer, all with ``SelfAttention``'s call signature
 MIXERS = {
     "full": _attention, "sliding": _attention, "retention": _retention,
-    "kda": _kda, "latent": _latent,
+    "kda": _kda, "latent": _latent, "conv": _conv,
 }
-# the kinds whose cache is a state of fixed size: they read no context
-# tokens
-STATE_KINDS = ("retention", "kda")
+# the kinds that are ``SelfAttention``: keys and values a token
+ATTENTION_KINDS = ("full", "sliding")
+# the kinds whose cache is a state of fixed size (a recurrent state, a
+# convolution's tail): they read no context tokens
+STATE_KINDS = ("retention", "kda", "conv")
 # the kinds one stack may hold together
-SERVED_TOGETHER = (("full", "sliding"), ("retention",), ("kda", "latent"))
-# what only ``SelfAttention`` has, and why each other kind refuses it
+SERVED_TOGETHER = (
+    ("full", "sliding"), ("retention",), ("kda", "latent"), ("full", "conv"),
+)
+# what only ``SelfAttention`` has, and why each other kind refuses it.
+# ``kv_quant`` has no ``"conv"`` row: beside attention layers it means
+# their keys and values alone (the convolution's tail stays as it is),
+# and a stack without any is refused below
 ATTENTION_ONLY = {
     "kv_quant": {
         "retention": "there are no keys and values to quantize",
@@ -104,14 +128,16 @@ ATTENTION_ONLY = {
         "retention": "its gates do the forgetting",
         "kda": "its decays do the forgetting",
         "latent": "it reads the whole context",
+        "conv": "it reads its last taps and nothing else",
     },
     "head_gate": {
         "retention": "its output has no gate",
         "kda": "its output gate is its own, a number a channel",
         "latent": "its output has no gate",
+        "conv": "its output gate is its own, a number a channel",
     },
     "early_router": dict.fromkeys(
-        ("retention", "kda", "latent"), "it hands no normed input on"
+        ("retention", "kda", "latent", "conv"), "it hands no normed input on"
     ),
 }
 
@@ -200,10 +226,12 @@ class MixedLayerLM(nn.Module):
     # learned bias an expert joins the scores for the choice alone
     router_score: str = "softmax"
     selection_bias: bool = False
-    # a retention layer's q and k are RMS-normed a head before RoPE
+    # a retention layer's and an attention layer's q and k are
+    # RMS-normed a head before RoPE
     qk_norm: bool = False
-    # a KDA layer's convolution; a latent layer's widths: a head's
-    # unrotated and shared key parts, its value, the latent's rank
+    # the taps of a KDA layer's convolution on q, k and v, and of a
+    # conv layer's own; a latent layer's widths: a head's unrotated and
+    # shared key parts, its value, the latent's rank
     conv_taps: int = 4
     latent_dims: Tuple[int, int, int, int] = (128, 64, 128, 512)
     dtype: str = "bfloat16"
@@ -216,7 +244,10 @@ class MixedLayerLM(nn.Module):
         """The window of each layer that reads context tokens (None:
         the whole context, a ``"latent"`` layer's too), for the
         engine's count of the context tokens attention reads; a layer
-        that reads a state (``STATE_KINDS``) has no entry."""
+        that reads a state of fixed size (``STATE_KINDS``: retention,
+        KDA, a convolution's tail) has no entry, so a stack of
+        ``"full"`` beside ``"conv"`` counts its attention layers
+        alone."""
         return tuple(
             self.window if kind == "sliding" else None
             for kind in self.layer_types if kind not in STATE_KINDS
@@ -298,18 +329,26 @@ def mixed_layer_lm(**cfg: Any) -> MixedLayerLM:
         for kind in sorted(kinds & set(whys)):
             if cfg.get(key):
                 raise ValueError(
-                    f"{key} on a {kind} layer: {whys[kind]}; layer_types "
+                    f"{key} on a {kind} layer: {whys[kind]} ({key} is the "
+                    f"attention layers', {ATTENTION_KINDS}); layer_types "
                     f"{list(cfg['layer_types'])}"
                 )
+    if cfg.get("kv_quant") and not kinds & set(ATTENTION_KINDS):
+        raise ValueError(
+            "kv_quant: the attention layers' keys and values "
+            f"{ATTENTION_KINDS} are what it quantizes, and this stack has "
+            f"none; layer_types {list(cfg['layer_types'])}"
+        )
     if cfg.get("router_score", "softmax") not in ROUTER_SCORES:
         raise ValueError(
             f"router_score {cfg['router_score']!r}: the router scores by "
             f"one of {sorted(ROUTER_SCORES)}"
         )
-    if cfg.get("qk_norm") and "retention" not in kinds:
+    if cfg.get("qk_norm") and not kinds & {"retention", *ATTENTION_KINDS}:
         raise ValueError(
-            "qk_norm: only a retention layer norms its q and k; "
-            f"layer_types {list(cfg['layer_types'])}"
+            "qk_norm: only a retention layer and an attention layer "
+            f"{ATTENTION_KINDS} norm their q and k a head, and this stack "
+            f"has neither; layer_types {list(cfg['layer_types'])}"
         )
     if cfg.get("early_router") and "dense" in cfg["mlp_layer_types"]:
         raise ValueError(

@@ -220,11 +220,15 @@ class SelfAttention(nn.Module):
     # normed layer input.  ``return_normed``: the call returns
     # ``(output, normed input)``, for a layer whose router reads what
     # the attention reads (the norm's parameter stays here).
+    # ``qk_norm``: q and k are RMS-normed a head over its channels, each
+    # with a learned vector a head width ("q_norm", "k_norm"), BEFORE
+    # the rotation: the keys the cache holds are normed and rotated.
     head_dim: Optional[int] = None
     rope: Optional[RopeSpec] = None
     window: Optional[int] = None
     head_gate: bool = False
     return_normed: bool = False
+    qk_norm: bool = False
 
     def _window_lo(self, lo, stop):
         """``lo`` raised to the window's lower bound for keys ending
@@ -273,6 +277,13 @@ class SelfAttention(nn.Module):
             q = nn.DenseGeneral((self.heads, d_head), use_bias=False, dtype=self.dtype, name="q")(h)
             k = nn.DenseGeneral((self.kv_heads, d_head), use_bias=False, dtype=self.dtype, name="k")(h)
             v = nn.DenseGeneral((self.kv_heads, d_head), use_bias=False, dtype=self.dtype, name="v")(h)
+        if self.qk_norm:
+            with jax.named_scope("attn.qk_norm"):
+                scale = lambda name: self.param(  # noqa: E731
+                    name, nn.initializers.ones, (d_head,), jnp.float32
+                )
+                q = rmsnorm(q, scale("q_norm"), self.dtype)
+                k = rmsnorm(k, scale("k_norm"), self.dtype)
         with jax.named_scope("attn.rope"):
             if self.rope is None:
                 q = apply_rope(q, positions)

@@ -10,6 +10,9 @@ roofline arithmetic and the readers of the layer's counters) and
 ``benchmark/tests/test_kimi_linear_readers.py`` (the KDA step's and the
 latent decode's roofline arithmetic and the readers of the two layers'
 counters) and
+``benchmark/tests/test_lfm2_moe_readers.py`` (the decode attention's
+roofline at a head narrower than a lane tile and the readers of the
+bytes of cache read a token) and
 ``benchmark/tests/test_flash_fwd_calls.py`` (the count of flash forward
 calls a backward call, on hand-made ops) and
 ``benchmark/tests/test_lane_readers.py`` (the readers of the admission
@@ -19,7 +22,8 @@ pairs and on a live rehearsal-width service).  A program PR that renames a span 
 ``stats()`` key fails here, not as a ``null`` per-layer metric after a
 chip run.  The tests are the benchmark's own, imported; nothing under
 ``benchmark/`` is edited.  Not ``test_correct.py``, ``test_laguna.py`` or
-``test_smallthinker.py``, ``test_brumby.py`` or ``test_kimi_linear.py``: they take minutes (``pytest benchmark/tests``
+``test_smallthinker.py``, ``test_brumby.py``, ``test_kimi_linear.py`` or
+``test_lfm2_moe.py``: they take minutes (``pytest benchmark/tests``
 runs them all).  One imported test is redefined below, and its
 docstring says why."""
 
@@ -31,6 +35,7 @@ from benchmark.tests.conftest import rehearse  # noqa: F401  (a fixture)
 from benchmark.tests.test_flash_fwd_calls import *  # noqa: F401,F403
 from benchmark.tests.test_kimi_linear_readers import *  # noqa: F401,F403
 from benchmark.tests.test_lane_readers import *  # noqa: F401,F403
+from benchmark.tests.test_lfm2_moe_readers import *  # noqa: F401,F403
 from benchmark.tests.test_loop_spans import *  # noqa: F401,F403
 from benchmark.tests.test_mixedlen_readers import *  # noqa: F401,F403
 from benchmark.tests.test_retention_readers import *  # noqa: F401,F403

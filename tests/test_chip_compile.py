@@ -403,3 +403,67 @@ def test_latent_decode_appends_in_place_and_reads_a_block_once(chip):
     stats = compiled.memory_analysis()
     assert stats.alias_size_in_bytes >= cache_bytes
     assert stats.temp_size_in_bytes < cache_bytes // 100
+
+
+# LFM2-24B-A2B's cell (synthesis-offline): 224 slots, 32 query heads
+# over 8 KV heads of 64 stored in 128 lanes (the int8 cache pads a head
+# to a lane tile: the kernel sees a head of 128), a buffer of 2,048 +
+# 1,536 + 1 tokens rounded to 3,840; its chunk form at 2,048 queries;
+# and the experts at a step's 896 and a chunk's 8,192 assignments over
+# 64 experts of 2,048 x 1,536
+LFM2_B, LFM2_H, LFM2_HKV, LFM2_L = 224, 32, 8, 3840
+
+
+def test_decode_attention_224_rows_of_64_wide_heads_in_128_lanes(chip):
+    from mlcomp_tpu.ops.pallas.decode_attention import (
+        decode_attention,
+        pick_buffer_len,
+    )
+
+    assert pick_buffer_len(2048 + 1536 + 1, LFM2_HKV, DH) == LFM2_L
+    text = _compiles_to_a_kernel(
+        functools.partial(decode_attention, interpret=False),
+        chip((LFM2_B, LFM2_H, DH), jnp.bfloat16),
+        *_dense_cache(chip, LFM2_B, LFM2_HKV, LFM2_L),
+    )
+    # gqa64_decode_attn_roofline matches the op by this name
+    assert "decode_attention" in text
+
+
+def test_decode_attention_chunk_2048_queries_of_4_heads_a_kv_head(chip):
+    from mlcomp_tpu.ops.pallas.decode_attention import decode_attention_chunk
+
+    _compiles_to_a_kernel(
+        functools.partial(decode_attention_chunk, interpret=False),
+        chip((1, 2048, LFM2_H, DH), jnp.bfloat16),
+        *_dense_cache(chip, 1, LFM2_HKV, LFM2_L),
+    )
+
+
+@pytest.mark.parametrize("tokens,tile", [(224, 16), (2048, 128)],
+                         ids=["lfm2_decode", "lfm2_chunk"])
+def test_grouped_matmul_64_experts_of_2048_by_1536(chip, tokens, tile):
+    from mlcomp_tpu.ops.pallas.grouped_matmul import (
+        auto_row_tile,
+        grouped_matmul,
+        padded_rows,
+    )
+
+    e, h, f, k = 64, 2048, 1536, 4
+    assert tokens * k in (896, 8192)
+    tm = auto_row_tile(tokens, k, e)
+    assert tm == tile
+    rows = padded_rows(tokens * k, e, tm)
+    tiles = (chip((rows // tm,), jnp.int32), chip((1,), jnp.int32))
+
+    def experts(x, w_gate, w_up, w_down, tile_group, used):
+        act = grouped_matmul(x, w_gate, tile_group, used, w2=w_up,
+                             interpret=False, gate="silu")
+        return grouped_matmul(act, w_down, tile_group, used, interpret=False)
+
+    text = _compiles_to_a_kernel(
+        experts, chip((rows, h), jnp.bfloat16),
+        chip((e, h, f), jnp.bfloat16), chip((e, h, f), jnp.bfloat16),
+        chip((e, f, h), jnp.bfloat16), *tiles,
+    )
+    assert "grouped_matmul" in text

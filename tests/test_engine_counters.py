@@ -824,3 +824,80 @@ def test_an_admission_that_fails_as_it_starts_closes_its_span():
             for s in spans] == [(bad.rid, True), (good.rid, False)]
     assert spans[0]["ts"] + spans[0]["dur"] <= spans[1]["ts"]
     assert st["admissions"] == 2 and eng._in_lane is None
+
+
+# ---- a conv layer's group, beside int8 keys and values in one carry
+# (models/short_conv.py) ----
+
+TAILS_AND_KEYS = {
+    "name": "mixed_layer_lm", "vocab_size": 64, "hidden": 64, "head_dim": 16,
+    "kv_heads": 2, "layer_types": ["conv", "full", "conv"],
+    "heads_per_layer": [4, 4, 4], "mlp_layer_types": ["dense"] * 3,
+    "mlp_dim": 128, "conv_taps": 3, "qk_norm": True, "kv_quant": True,
+    "rope_full": {"base": 1000000.0}, "dtype": "float32",
+}
+
+
+def test_a_conv_layers_counts_are_the_hand_counts():
+    """One request alone on three slots: 12 prompt tokens in a bucket of
+    16 are two chunks of 8 (four pads in the first), then three
+    dispatches of K = 2 steps, the one live row through two conv layers
+    and one attention layer, whose context tokens are counted for that
+    layer alone."""
+    from mlcomp_tpu.models.short_conv import COUNTS
+
+    assert tuple(n for n, _ in _COUNT_GROUPS["conv"]) == COUNTS
+    model, params = _build(TAILS_AND_KEYS)
+    assert model.attention_windows() == (None,)
+    k, n_conv = 2, 2
+    eng = DecodeEngine(model, {"params": params}, slots=3,
+                       prompt_buckets=(16,), max_new_cap=16,
+                       steps_per_dispatch=k, prefill_chunk=8,
+                       pipeline_depth=1)
+    try:
+        _, packed = jax.eval_shape(
+            eng._dispatch_fn(), eng.variables, eng._dstate)
+        assert packed.shape == (3 * k * 3 + len(COUNTS),)
+        assert eng._count_layers == {"conv": 2}
+        out = eng.submit(list(range(1, 13)), 3 * k).result(timeout=300)
+        st = eng.stats()
+        text = eng.metrics.render()
+    finally:
+        eng.close()
+    assert len(out["ids"]) == 3 * k and st["pipeline"]["issued"] == 3
+    steps = 3 * k
+    # a tail is two tokens of 64 channels, float32 here, read and written
+    a_tail = 2 * 2 * 64 * 4
+    assert "moe" not in st and "kda" not in st
+    assert st["conv"] == {
+        "state_rows": steps * n_conv,
+        "state_bytes": steps * n_conv * a_tail,
+        "chunk_tokens": 12 * n_conv,
+        "layer_calls": (2 + steps) * n_conv,
+        "state_rows_over_issued": 1.0,
+    }
+    # the engine's own count of context tokens: the ONE layer that
+    # reads them (12, 14 and 16 tokens at the three issues), not three
+    att = st["attention"]
+    assert att["kv_tokens_live"] == att["kv_tokens_attended"] == 12 + 14 + 16
+    assert att["kv_tokens_fetched"] >= att["kv_tokens_attended"]
+    for line in (f"mlcomp_engine_conv_state_rows_total {steps * n_conv}",
+                 f"mlcomp_engine_conv_state_bytes_total "
+                 f"{steps * n_conv * a_tail}",
+                 f"mlcomp_engine_conv_chunk_tokens_total {12 * n_conv}",
+                 f"mlcomp_engine_conv_layer_calls_total "
+                 f"{(2 + steps) * n_conv}"):
+        assert line in text
+    assert "mlcomp_engine_kda_" not in text
+
+
+def test_a_conv_tail_refuses_pages_and_prefixes_by_the_leaf():
+    """The int8 keys and values have a token axis, the tail beside them
+    has none: whatever moves KV by pages or by prefix is refused by the
+    tail's name, though the attention layer alone could be paged."""
+    model, params = _build(TAILS_AND_KEYS)
+    with pytest.raises(ValueError, match=r"slot state \['conv'\]"):
+        DecodeEngine(model, {"params": params}, slots=2,
+                     prompt_buckets=(16,), max_new_cap=16,
+                     steps_per_dispatch=2, prefill_chunk=8,
+                     kv_layout="paged", kv_page_tokens=8)

@@ -551,8 +551,8 @@ def test_a_retention_stack_is_assembled_from_the_lists():
     ({"window": 16}, "window on a retention layer"),
     ({"layer_types": ["retention", "full"]},
      "one stack holds kinds of one of"),
-    ({"layer_types": ["full", "full"]}, "qk_norm: only a retention layer"),
-], ids=["kv_quant", "window", "beside_attention", "qk_norm_on_attention"])
+    ({"layer_types": ["kda", "kda"]}, "qk_norm: only a retention layer"),
+], ids=["kv_quant", "window", "beside_attention", "qk_norm_on_kda"])
 def test_what_a_retention_stack_cannot_be_is_refused_at_create_model(
         asked, refusal):
     _, _, kw = _brumby()
@@ -646,7 +646,9 @@ def test_a_chunk_that_decays_through_its_pads_is_not_the_layer(monkeypatch):
     # a retention stack keeps a state, not keys and values: one form
     ("brumby-14b", False),
     # states beside a latent: one form too
-    ("kimi-linear-48b-a3b", False)])
+    ("kimi-linear-48b-a3b", False),
+    # a convolution's tail beside keys and values, bfloat16 and int8
+    ("lfm2-24b-a2b", False), ("lfm2-24b-a2b", True)])
 def test_last_logits_only_is_the_full_calls_last_row(served, kv_quant):
     """Each served stack, two chunks of 16 (window and full attention
     with a cache behind the second; the experts' and the retention
@@ -666,3 +668,100 @@ def test_last_logits_only_is_the_full_calls_last_row(served, kv_quant):
     sown = jax.tree_util.tree_leaves(full[-1][1].get("counters", {}))
     assert sown, "these stacks count: the counters' channel was compared"
 
+
+
+# ---- what LFM2-24B-A2B adds: a fourth group of kinds one stack may
+# hold, a convolution's tail beside keys and values
+# (models/short_conv.py; the model's own tests are test_lfm2_moe.py) ----
+
+CONV_AND_FULL = {
+    "name": "mixed_layer_lm", "vocab_size": 64, "hidden": 64, "head_dim": 16,
+    "kv_heads": 2, "layer_types": ["conv", "full", "conv"],
+    "heads_per_layer": [4, 4, 4], "mlp_layer_types": ["dense"] * 3,
+    "mlp_dim": 128, "conv_taps": 3, "dtype": "float32",
+}
+
+
+@pytest.mark.parametrize("asked", [
+    {}, {"kv_quant": True}, {"qk_norm": True},
+    {"kv_quant": True, "qk_norm": True},
+    {"layer_types": ["conv", "conv", "conv"]},
+    {"layer_types": ["full", "full", "full"], "qk_norm": True}],
+    ids=["plain", "kv_quant", "qk_norm", "both", "conv_alone",
+         "qk_norm_on_attention_alone"])
+def test_full_beside_conv_builds(asked):
+    from mlcomp_tpu.models.mixed_layer_lm import (
+        SERVED_TOGETHER,
+        STATE_KINDS,
+    )
+
+    assert ("full", "conv") in SERVED_TOGETHER and "conv" in STATE_KINDS
+    model = create_model({**CONV_AND_FULL, **asked})
+    kinds = list(model.layer_types)
+    assert model.attention_windows() == (None,) * kinds.count("full")
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
+    for i, kind in enumerate(kinds):
+        attn = set(params[f"layer_{i}"]["attn"])
+        if kind == "conv":
+            assert attn == {"RMSNorm_0", "in", "conv", "out"}
+        else:
+            assert ("q_norm" in attn) == bool(asked.get("qk_norm"))
+    cache = jax.eval_shape(lambda: init_cache(model, 2, 24))
+    for i, kind in enumerate(kinds):
+        leaves = set(cache[f"layer_{i}"]["attn"])
+        if kind == "conv":
+            assert leaves == {"conv", "cache_index"}
+        else:
+            assert ("cached_key_q" in leaves) == bool(asked.get("kv_quant"))
+
+
+@pytest.mark.parametrize("asked,refusal", [
+    ({"layer_types": ["sliding", "conv", "conv"], "window": 8},
+     "one stack holds kinds of one of"),
+    ({"layer_types": ["kda", "conv", "conv"]},
+     "one stack holds kinds of one of"),
+    ({"layer_types": ["conv", "latent", "conv"]},
+     "one stack holds kinds of one of"),
+    ({"layer_types": ["conv", "retention", "conv"]},
+     "one stack holds kinds of one of"),
+    ({"layer_types": ["conv", "conv", "conv"], "kv_quant": True},
+     r"kv_quant: the attention layers' keys and values \('full', "
+     r"'sliding'\) are what it quantizes, and this stack has none"),
+    ({"layer_types": ["conv", "conv", "conv"], "qk_norm": True},
+     "qk_norm: only a retention layer and an attention layer"),
+    ({"window": 8},
+     r"window on a conv layer: it reads its last taps and nothing else "
+     r"\(window is the attention layers', \('full', 'sliding'\)\)"),
+    ({"head_gate": True}, "head_gate on a conv layer"),
+], ids=["sliding_beside_conv", "kda_beside_conv", "latent_beside_conv",
+        "retention_beside_conv", "kv_quant_without_keys_and_values",
+        "qk_norm_without_q_and_k", "window", "head_gate"])
+def test_what_full_beside_conv_cannot_be_is_refused_by_name(asked, refusal):
+    with pytest.raises(ValueError, match=refusal):
+        create_model({**CONV_AND_FULL, **asked})
+
+
+def test_self_attention_with_qk_norm_norms_a_head_before_the_rotation():
+    """``qk_norm`` is two learned vectors a head width and nothing
+    else: RMS-normed q and k, by hand, through the module without the
+    field is not possible (the norm sits between the projection and the
+    rotation), so the formula is held to ``reference/lfm2_moe.py`` in
+    ``test_lfm2_moe.py``; here: the field adds the two leaves, moves
+    the output, and the scales move it again."""
+    kw = dict(hidden=64, heads=4, kv_heads=2, dtype=jnp.float32,
+              head_dim=16, rope=RopeSpec(base=1e6))
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, 12, 64))
+    pos = jnp.arange(12)[None]
+    plain = SelfAttention(**kw)
+    normed = SelfAttention(**kw, qk_norm=True)
+    p0 = plain.init(jax.random.PRNGKey(1), x, pos)["params"]
+    p1 = normed.init(jax.random.PRNGKey(1), x, pos)["params"]
+    assert set(p1) - set(p0) == {"q_norm", "k_norm"}
+    assert p1["q_norm"].shape == (16,) and p1["k_norm"].dtype == jnp.float32
+    drawn = {**p1, "q_norm": 1 + 0.3 * jnp.sin(jnp.arange(16.0)),
+             "k_norm": 1 + 0.3 * jnp.cos(jnp.arange(16.0))}
+    outs = [np.asarray(m.apply({"params": p}, x, pos))
+            for m, p in ((plain, p0), (normed, p1), (normed, drawn))]
+    assert np.abs(outs[0] - outs[1]).max() > 1e-3
+    assert np.abs(outs[1] - outs[2]).max() > 1e-3

@@ -175,6 +175,11 @@ CONDITIONAL_METRICS = {
     "mlcomp_engine_kda_state_bytes_total",
     "mlcomp_engine_kda_chunk_tokens_total",
     "mlcomp_engine_kda_layer_calls_total",
+    # models with a conv layer only (GatedShortConv sows them)
+    "mlcomp_engine_conv_state_rows_total",
+    "mlcomp_engine_conv_state_bytes_total",
+    "mlcomp_engine_conv_chunk_tokens_total",
+    "mlcomp_engine_conv_layer_calls_total",
     # models with a latent-attention layer only (LatentAttention sows them)
     "mlcomp_engine_latent_tokens_attended_total",
     "mlcomp_engine_latent_bytes_read_total",
