@@ -30,6 +30,9 @@ Design follows the canonical TPU flash recipe:
 - backward = custom VJP with two kernels (dq over KV blocks; dk/dv over
   Q blocks with the GQA group folded into the sequential grid axis),
   recomputing p from the saved logsumexp instead of storing S×S weights.
+  The causal-unbounded backward is ONE kernel on the dk/dv grid where a
+  KV head's float32 dq fits VMEM beside dk and dv (DQ_RESIDENT_BUDGET):
+  scores, p and dP are then computed once a block pair, not twice.
 
 Ragged sequence lengths (S % 128 != 0) stay on the kernel path: the
 wrapper zero-pads S up to a lane multiple and folds the padded keys into
@@ -624,30 +627,27 @@ def _flash_fwd(q, k, v, kv_lo, kv_hi, scale, causal, block_q, block_kv, interpre
 # --------------------------------------------------------------------------
 
 
-def _dq_update(q_blk, k_blk, v_blk, do_blk, lse_row, delta_row, dq_acc,
-               scale, guarded_s=None, s=None):
-    """One dq accumulation step — shared by the rectangular and triangular
-    dq kernels.  ``s`` is the (masked) logits block; pass ``guarded_s``
-    (same block) to zero probabilities on fully-masked columns."""
+def _bwd_update(q_blk, k_blk, v_blk, do_blk, lse_row, delta_row, scale, s,
+                guard_masked=False, dq_acc=None, dkv_acc=None):
+    """One backward accumulation step — the ONE definition every dq,
+    dk/dv and fused kernel uses.  ``s`` is the (masked) logits block;
+    ``guard_masked`` zeroes probabilities on fully-masked columns (the
+    bounded paths, as in _softmax_update).  p, dP and dS are computed
+    once and feed whichever accumulators the kernel holds: ``dq_acc``
+    (float32, the q block's rows) and ``dkv_acc`` = (dk_acc, dv_acc)."""
     p = jnp.exp(s - lse_row)
-    if guarded_s is not None:
-        p = jnp.where(guarded_s > NEG_INF / 2, p, 0.0)
+    if guard_masked:
+        p = jnp.where(s > NEG_INF / 2, p, 0.0)
+    if dkv_acc is not None:
+        dk_acc, dv_acc = dkv_acc
+        dv_acc[:] += _dot(p.astype(do_blk.dtype).T, do_blk)
     dp = _dot(do_blk, v_blk, trans_b=True)
-    ds = p * (dp - delta_row) * scale
-    dq_acc[:] += _dot(ds.astype(k_blk.dtype), k_blk)
-
-
-def _dkv_update(q_blk, v_blk, do_blk, lse_row, delta_row, dk_acc, dv_acc,
-                scale, guarded_s=None, s=None):
-    """One dk/dv accumulation step — shared by the rectangular and
-    triangular dk/dv kernels (same guard contract as _dq_update)."""
-    p = jnp.exp(s - lse_row)
-    if guarded_s is not None:
-        p = jnp.where(guarded_s > NEG_INF / 2, p, 0.0)
-    dv_acc[:] += _dot(p.astype(do_blk.dtype).T, do_blk)
-    dp = _dot(do_blk, v_blk, trans_b=True)
-    ds = p * (dp - delta_row) * scale
-    dk_acc[:] += _dot(ds.astype(q_blk.dtype).T, q_blk)
+    # q, k, v and dO arrive in one dtype: one cast serves both products
+    ds = (p * (dp - delta_row) * scale).astype(q_blk.dtype)
+    if dkv_acc is not None:
+        dk_acc[:] += _dot(ds.T, q_blk)
+    if dq_acc is not None:
+        dq_acc[:] += _dot(ds, k_blk)
 
 
 def _dq_kernel(
@@ -677,10 +677,10 @@ def _dq_kernel(
         if bounded:
             s = _bounds_mask(s, j, block_kv, lo, hi)
         # bounded: empty-window rows carry lse == NEG_INF and must not
-        # contribute — _dq_update zeroes their masked probabilities
-        _dq_update(q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0],
-                   lse_ref[0, 0][:, :1], delta_ref[0, 0][:, :1], dq_acc,
-                   scale, guarded_s=s if bounded else None, s=s)
+        # contribute — the guard zeroes their masked probabilities
+        _bwd_update(q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0],
+                    lse_ref[0, 0][:, :1], delta_ref[0, 0][:, :1], scale, s,
+                    guard_masked=bounded, dq_acc=dq_acc)
 
     @pl.when(j == nk - 1)
     def _finalize():
@@ -717,10 +717,9 @@ def _dkv_kernel(
             s = _causal_mask(s, i, j, block_q, block_kv)
         if bounded:
             s = _bounds_mask(s, j, block_kv, lo, hi)
-        _dkv_update(q_ref[0, 0], v_ref[0, 0], do_ref[0, 0],
-                    lse_ref[0, 0][:, :1], delta_ref[0, 0][:, :1],
-                    dk_acc, dv_acc, scale,
-                    guarded_s=s if bounded else None, s=s)
+        _bwd_update(q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0],
+                    lse_ref[0, 0][:, :1], delta_ref[0, 0][:, :1], scale, s,
+                    guard_masked=bounded, dkv_acc=(dk_acc, dv_acc))
 
     @pl.when(t == nt - 1)
     def _finalize():
@@ -763,9 +762,9 @@ def _dq_kernel_tri(
 
     s = _dot(q_ref[0, 0], k_ref[0, 0], trans_b=True) * scale
     s = _causal_mask(s, i, j, block_q, block_kv)
-    _dq_update(q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0],
-               lse_ref[0, 0][:, :1], delta_ref[0, 0][:, :1], dq_acc,
-               scale, s=s)
+    _bwd_update(q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0],
+                lse_ref[0, 0][:, :1], delta_ref[0, 0][:, :1], scale, s,
+                dq_acc=dq_acc)
 
     @pl.when(lst_ref[t] == 1)
     def _finalize():
@@ -788,14 +787,77 @@ def _dkv_kernel_tri(
 
     s = _dot(q_ref[0, 0], k_ref[0, 0], trans_b=True) * scale
     s = _causal_mask(s, i, j, block_q, block_kv)
-    _dkv_update(q_ref[0, 0], v_ref[0, 0], do_ref[0, 0],
-                lse_ref[0, 0][:, :1], delta_ref[0, 0][:, :1],
-                dk_acc, dv_acc, scale, s=s)
+    _bwd_update(q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0],
+                lse_ref[0, 0][:, :1], delta_ref[0, 0][:, :1], scale, s,
+                dkv_acc=(dk_acc, dv_acc))
 
     @pl.when(lst_ref[t] == 1)
     def _finalize():
         dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _dq_dkv_kernel_tri(
+    jm_ref, gm_ref, im_ref, fst_ref, lst_ref, dqo_ref, q_ref, k_ref, v_ref,
+    do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dq_hbm, dk_acc, dv_acc,
+    dq_acc, dq_out, dq_sem, *, scale, block_q, block_kv, rep,
+):
+    """The whole causal backward on _dkv_schedule's grid: a step computes
+    the scores, p, dP and dS once for all three gradients.  dk/dv
+    accumulate over a KV block's (g, i) steps as in _dkv_kernel_tri; dq
+    accumulates in ``dq_acc`` (rep, S_q, D) float32, resident over the
+    whole sequential axis of one (b, h_kv).  A q block's rows are zeroed
+    at KV block 0 (live for every q block) and leave at the last KV block
+    under its diagonal (``dqo_ref[t]`` >= 0: the block's ordinal among
+    those of its (b, h_kv)): per q block the sums arrive in ascending j,
+    the order _dq_kernel_tri adds them in.  They leave cast, through ONE
+    staging block and a copy to ``dq_hbm`` that the next leaving block
+    (or the group's last step) waits for: a BlockSpec'd dq would hold
+    rep x S_q x D twice more in VMEM, and the kernel has to fit the
+    default scoped limit (see DQ_RESIDENT_BUDGET)."""
+    t = pl.program_id(2)
+    g = gm_ref[t]
+    i = im_ref[t]
+    j = jm_ref[t]
+    leaving = dqo_ref[t]
+    rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+    dq_copy = pltpu.make_async_copy(
+        dq_out, dq_hbm.at[pl.program_id(0), pl.program_id(1) * rep + g, rows],
+        dq_sem.at[0],
+    )
+
+    @pl.when(fst_ref[t] == 1)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    @pl.when(j == 0)
+    def _init_dq():
+        dq_acc[g, rows] = jnp.zeros(dq_out.shape, dq_acc.dtype)
+
+    s = _dot(q_ref[0, 0], k_ref[0, 0], trans_b=True) * scale
+    s = _causal_mask(s, i, j, block_q, block_kv)
+    _bwd_update(q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0],
+                lse_ref[0, 0][:, :1], delta_ref[0, 0][:, :1], scale, s,
+                dq_acc=dq_acc.at[g, rows], dkv_acc=(dk_acc, dv_acc))
+
+    @pl.when(leaving >= 0)
+    def _finalize_dq():
+        @pl.when(leaving > 0)
+        def _staging_free():
+            dq_copy.wait()
+
+        dq_out[:] = dq_acc[g, rows].astype(dq_out.dtype)
+        dq_copy.start()
+
+    @pl.when(lst_ref[t] == 1)
+    def _finalize():
+        dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
+
+    @pl.when(t == pl.num_programs(2) - 1)
+    def _drain():
+        dq_copy.wait()
 
 
 def _dq_kernel_bsched(
@@ -815,9 +877,9 @@ def _dq_kernel_bsched(
     s = _bounds_mask(s, j, block_kv, lo_ref[b], hi_ref[b])
     if causal:
         s = _causal_mask(s, im_ref[t], j, block_q, block_kv)
-    _dq_update(q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0],
-               lse_ref[0, 0][:, :1], delta_ref[0, 0][:, :1], dq_acc,
-               scale, guarded_s=s, s=s)
+    _bwd_update(q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0],
+                lse_ref[0, 0][:, :1], delta_ref[0, 0][:, :1], scale, s,
+                guard_masked=True, dq_acc=dq_acc)
 
     @pl.when(lst_ref[t] == 1)
     def _finalize():
@@ -842,9 +904,9 @@ def _dkv_kernel_bsched(
     s = _bounds_mask(s, j, block_kv, lo_ref[b], hi_ref[b])
     if causal:
         s = _causal_mask(s, im_ref[t], j, block_q, block_kv)
-    _dkv_update(q_ref[0, 0], v_ref[0, 0], do_ref[0, 0],
-                lse_ref[0, 0][:, :1], delta_ref[0, 0][:, :1],
-                dk_acc, dv_acc, scale, guarded_s=s, s=s)
+    _bwd_update(q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0],
+                lse_ref[0, 0][:, :1], delta_ref[0, 0][:, :1], scale, s,
+                guard_masked=True, dkv_acc=(dk_acc, dv_acc))
 
     @pl.when(lst_ref[t] == 1)
     def _finalize():
@@ -960,11 +1022,9 @@ def _flash_bwd_bsched(scale, block_q, block_kv, interpret, q, k, v, kv_lo,
     return dq, dk, dv, z(kv_lo), z(kv_hi)
 
 
-def _flash_bwd_tri(scale, block_q, block_kv, interpret, q, k, v, do, lse,
-                   delta):
-    """Causal-unbounded backward on triangular grids (see _causal_schedule
-    — the same per-step-overhead argument as the forward, applied to the
-    dq pass and the dk/dv pass)."""
+def _flash_dq_tri(scale, block_q, block_kv, interpret, q, k, v, do, lse,
+                  delta):
+    """The causal-unbounded dq pass alone, on _causal_schedule's grid."""
     b, h, s_q, d = q.shape
     h_kv, s_k = k.shape[1], k.shape[2]
     rep = h // h_kv
@@ -981,7 +1041,7 @@ def _flash_bwd_tri(scale, block_q, block_kv, interpret, q, k, v, do, lse,
     dq_kernel = functools.partial(
         _dq_kernel_tri, scale=scale, block_q=block_q, block_kv=block_kv
     )
-    dq = pl.pallas_call(
+    return pl.pallas_call(
         dq_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
@@ -1003,22 +1063,80 @@ def _flash_bwd_tri(scale, block_q, block_kv, interpret, q, k, v, do, lse,
     )(jnp.asarray(im), jnp.asarray(jm), jnp.asarray(fst), jnp.asarray(lst),
       q, k, v, do, lse, delta)
 
-    jm2, gm2, im2, fst2, lst2 = _dkv_schedule(nq, nk, rep, block_q, block_kv)
-    dkv_kernel = functools.partial(
-        _dkv_kernel_tri, scale=scale, block_q=block_q, block_kv=block_kv
-    )
 
-    def qh(b_, hkv, t, jm, gm, im, f, l):
+# The fused causal backward keeps one KV head's whole dq, float32, in
+# VMEM: rep x S_q x D x 4 B.  4 MiB (S <= 4096 at a group of two heads of
+# 128) is what fits the chip's DEFAULT scoped VMEM beside a step's blocks
+# and products (15.75-16 MiB of 16 at 1024 / 1024 blocks, by the
+# compiler's refusals); beyond it the backward runs the dq and the dk/dv
+# kernel.  Not raised with ``vmem_limit_bytes``: a custom call that
+# carries a limit makes XLA write a scoped-VMEM configuration on EVERY
+# op of the program, and the train step's matmul fusions then lose what
+# the kernel gains (PERF.md section 6, PR 45).
+DQ_RESIDENT_BUDGET = 4 << 20
+
+
+def _flash_bwd_tri(scale, block_q, block_kv, interpret, q, k, v, do, lse,
+                   delta):
+    """Causal-unbounded backward on triangular grids (see _causal_schedule
+    — the same per-step-overhead argument as the forward).  Where a KV
+    head's float32 dq fits DQ_RESIDENT_BUDGET, ONE kernel on the dk/dv
+    grid gives dq too (_dq_dkv_kernel_tri: five S x S x D products a live
+    block pair); else the dq pass and the dk/dv pass (seven), which give
+    the same bits."""
+    b, h, s_q, d = q.shape
+    h_kv, s_k = k.shape[1], k.shape[2]
+    rep = h // h_kv
+    nq, nk = s_q // block_q, s_k // block_kv
+    fused = rep * s_q * d * 4 <= DQ_RESIDENT_BUDGET
+
+    jm, gm, im, fst, lst = _dkv_schedule(nq, nk, rep, block_q, block_kv)
+    schedule = [jm, gm, im, fst, lst]
+
+    def qh(b_, hkv, t, jm, gm, im, *_):
         return (b_, hkv * rep + gm[t], im[t], 0)
 
-    def kvh(b_, hkv, t, jm, gm, im, f, l):
+    def kvh(b_, hkv, t, jm, *_):
         return (b_, hkv, jm[t], 0)
 
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
+    kernel = functools.partial(
+        _dkv_kernel_tri, scale=scale, block_q=block_q, block_kv=block_kv
+    )
+    out_specs = [pl.BlockSpec((1, 1, block_kv, d), kvh)] * 2
+    out_shape = [
+        jax.ShapeDtypeStruct(k.shape, k.dtype),
+        jax.ShapeDtypeStruct(v.shape, v.dtype),
+    ]
+    scratch = [pltpu.VMEM((block_kv, d), jnp.float32)] * 2
+    if fused:
+        kernel = functools.partial(
+            _dq_dkv_kernel_tri, rep=rep, **kernel.keywords
+        )
+        # a q block leaves at the last KV block under its diagonal: its
+        # ordinal among the q blocks of one (b, h_kv), -1 at other steps
+        leaves = jm == np.minimum(
+            nk - 1, (im * block_q + block_q - 1) // block_kv
+        )
+        schedule.append(
+            np.where(leaves, np.cumsum(leaves) - 1, -1).astype(np.int32)
+        )
+        out_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        out_shape.append(jax.ShapeDtypeStruct(q.shape, q.dtype))
+        scratch += [
+            pltpu.VMEM((rep, s_q, d), jnp.float32),
+            pltpu.VMEM((block_q, d), q.dtype),
+            pltpu.SemaphoreType.DMA((1,)),
+        ]
+    else:
+        dq = _flash_dq_tri(
+            scale, block_q, block_kv, interpret, q, k, v, do, lse, delta
+        )
+
+    outs = pl.pallas_call(
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
-            grid=(b, h_kv, len(jm2)),
+            num_scalar_prefetch=len(schedule),
+            grid=(b, h_kv, len(jm)),
             in_specs=[
                 pl.BlockSpec((1, 1, block_q, d), qh),
                 pl.BlockSpec((1, 1, block_kv, d), kvh),
@@ -1027,23 +1145,16 @@ def _flash_bwd_tri(scale, block_q, block_kv, interpret, q, k, v, do, lse,
                 pl.BlockSpec((1, 1, block_q, LANES), qh),
                 pl.BlockSpec((1, 1, block_q, LANES), qh),
             ],
-            out_specs=[
-                pl.BlockSpec((1, 1, block_kv, d), kvh),
-                pl.BlockSpec((1, 1, block_kv, d), kvh),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((block_kv, d), jnp.float32),
-                pltpu.VMEM((block_kv, d), jnp.float32),
-            ],
+            out_specs=out_specs,
+            scratch_shapes=scratch,
         ),
-        out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
-        ],
+        out_shape=out_shape,
         interpret=interpret,
-        name=_kernel_name(dkv_kernel),
-    )(jnp.asarray(jm2), jnp.asarray(gm2), jnp.asarray(im2),
-      jnp.asarray(fst2), jnp.asarray(lst2), q, k, v, do, lse, delta)
+        name=_kernel_name(kernel),
+    )(*map(jnp.asarray, schedule), q, k, v, do, lse, delta)
+    dk, dv = outs[:2]
+    if fused:
+        dq = outs[2]
     return dq, dk, dv, None, None
 
 

@@ -63,6 +63,21 @@ def loop_write_kv(caches, new, cur):
     )
 
 
+def pallas_calls(jaxpr, inside=()):
+    """(enclosing primitives, kernel name) of every ``pallas_call`` in a
+    jaxpr and the jaxprs its equations carry."""
+    import jax
+
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append((inside, str(eqn.params["name"])))
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += pallas_calls(sub, inside + (eqn.primitive.name,))
+    return found
+
+
 def share_engine_fns(eng, key):
     pool = ENGINE_FNS_POOL.setdefault(key, {})
     eng._fns.update(pool)

@@ -4,6 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import pallas_calls
 
 from mlcomp_tpu.ops.attention import reference_attention
 from mlcomp_tpu.ops.pallas.flash_attention import flash_attention
@@ -400,6 +401,78 @@ def test_backward_from_one_lane_lse_equals_the_128_lane_one(bounded):
                                  delta)[:3]
     for a, b_ in zip(got, want):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
+
+
+@pytest.mark.parametrize("h, h_kv, s, d, block_q, block_kv, with_g_lse, dtype", [
+    (2, 2, 256, 128, 128, 128, False, jnp.float32),    # MHA
+    (4, 2, 256, 128, 128, 128, False, jnp.float32),    # GQA, groups of 2
+    (4, 1, 256, 128, 128, 128, False, jnp.float32),    # groups of 4
+    (4, 2, 512, 128, 128, 256, False, jnp.float32),    # a KV block spans
+    (4, 2, 512, 128, 256, 128, False, jnp.float32),    # ... and is spanned
+    (4, 2, 256, 64, 128, 128, False, jnp.float32),     # lane-padded heads
+    (4, 2, 256, 128, 128, 128, True, jnp.float32),     # an lse cotangent
+    (4, 2, 256, 128, 128, 128, False, jnp.bfloat16),   # the train dtype
+], ids=["mha", "gqa2", "gqa4", "bq128_bkv256", "bq256_bkv128", "d64",
+        "g_lse", "bf16"])
+def test_fused_backward_equals_the_two_kernel_backward(
+    monkeypatch, h, h_kv, s, d, block_q, block_kv, with_g_lse, dtype
+):
+    """``_flash_bwd_tri``'s one kernel (dq resident in VMEM beside dk and
+    dv) returns, bit for bit, what its dq kernel and its dk/dv kernel
+    return: the same operands, and every sum in the same order."""
+    from mlcomp_tpu.ops.pallas import flash_attention as fa
+
+    b, scale = 2, d ** -0.5
+    (q, k, v), _ = fa._kernel_layout(
+        _rand((b, s, h, d), 60, dtype), _rand((b, s, h_kv, d), 61, dtype),
+        _rand((b, s, h_kv, d), 62, dtype), d,
+    )
+    do = jnp.swapaxes(_rand((b, s, h, q.shape[-1]), 63, dtype), 1, 2)
+    out, lse = fa._flash_fwd(q, k, v, None, None, scale, True, block_q,
+                             block_kv, True)
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), -1)
+    if with_g_lse:
+        delta = delta - _rand((b, h, s), 64)
+    delta = jnp.broadcast_to(delta[..., None], (*delta.shape, fa.LANES))
+
+    def backward():
+        return fa._flash_bwd_tri(scale, block_q, block_kv, True, q, k, v,
+                                 do, lse, delta)[:3]
+
+    # a new function a trace: make_jaxpr remembers the one it has seen
+    names = lambda: {c[1] for c in pallas_calls(  # noqa: E731
+        jax.make_jaxpr(lambda: backward())().jaxpr)}
+    assert names() == {"flash_dq_dkv_kernel_tri"}
+    got = backward()
+    monkeypatch.setattr(fa, "DQ_RESIDENT_BUDGET", 0)
+    assert names() == {"flash_dq_kernel_tri", "flash_dkv_kernel_tri"}
+    want = backward()
+    for a, b_ in zip(got, want):
+        assert a.dtype == b_.dtype == dtype
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b_, np.float32))
+
+
+@pytest.mark.parametrize("s, h, h_kv, kernels", [
+    # rep x S x D x 4 B of float32 dq a KV head, against 4 MiB
+    (2048, 4, 2, {"flash_dq_dkv_kernel_tri"}),                      # 2 MiB
+    (4096, 4, 2, {"flash_dq_dkv_kernel_tri"}),                      # 4 MiB
+    (4096, 4, 1, {"flash_dq_kernel_tri", "flash_dkv_kernel_tri"}),  # 8 MiB
+], ids=["under", "at", "over"])
+def test_backward_kernels_follow_the_resident_dq_budget(s, h, h_kv, kernels):
+    """The causal backward is one kernel where a KV head's float32 dq
+    fits ``DQ_RESIDENT_BUDGET`` and the dq + dk/dv pair beyond it: chosen
+    from the shapes, read here off the gradient program's kernel names
+    (nothing runs)."""
+    qkv = [jax.ShapeDtypeStruct((1, s, n, 128), jnp.bfloat16)
+           for n in (h, h_kv, h_kv)]
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
+
+    calls = pallas_calls(
+        jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(*qkv).jaxpr)
+    assert {c[1] for c in calls} == kernels | {"flash_fwd_kernel_tri"}
 
 
 @pytest.mark.parametrize("causal", [False, True])

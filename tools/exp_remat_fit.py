@@ -22,7 +22,9 @@ import argparse
 import json
 import re
 
-KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
+# the backward is ``flash_dq_dkv_kernel*`` where a KV head's float32 dq
+# fits VMEM (``flash_attention.DQ_RESIDENT_BUDGET``), else the dq + dkv pair
+KERNELS = ("flash_fwd", "flash_dq_dkv", "flash_dq_kernel", "flash_dkv")
 
 
 def kernel_calls(text: str) -> dict:
