@@ -22,7 +22,7 @@ never captured.
 
 Why token-indexed blocks transplant across requests at all: a cached
 row holds K/V AFTER RoPE, and the serving path's LEFT-pad contract
-(``serve.left_pad_row`` + cumsum positions) gives real token j position
+(``engine.left_pad_row`` + cumsum positions) gives real token j position
 j regardless of bucket or pad width — so row j of a prefix is the same
 bytes wherever the prefix lands, and inserting it at the new request's
 ``start_pad + j`` slot is exact.  Captured rows round-trip device ->

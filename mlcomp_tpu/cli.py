@@ -398,7 +398,6 @@ def _cmd_serve(args: argparse.Namespace) -> int:
         max_new_buckets=tuple(
             int(x) for x in args.max_new_buckets.split(",")
         ),
-        batch_window_ms=args.batch_window_ms,
         temperature=args.temperature,
         top_k=args.top_k,
         top_p=args.top_p,
@@ -406,10 +405,8 @@ def _cmd_serve(args: argparse.Namespace) -> int:
         eos_id=args.eos_id,
         pad_id=args.pad_id,
         quantize=args.quantize or False,
-        batcher=args.batcher,
         steps_per_dispatch=args.steps_per_dispatch,
         prefill_chunk=args.prefill_chunk,
-        engine_pipeline_depth=args.engine_pipeline_depth,
         engine_fused_admission=(
             False if args.engine_staged_admission else None
         ),
@@ -850,7 +847,6 @@ def main(argv=None) -> int:
     sv.add_argument("--batch-sizes", default="1,2,4,8")
     sv.add_argument("--prompt-buckets", default="128,256,512,1024")
     sv.add_argument("--max-new-buckets", default="32,128")
-    sv.add_argument("--batch-window-ms", type=float, default=10.0)
     sv.add_argument("--temperature", type=float, default=0.0)
     sv.add_argument("--top-k", type=int, default=None)
     sv.add_argument("--top-p", type=float, default=None)
@@ -898,18 +894,8 @@ def main(argv=None) -> int:
         " port + 1)",
     )
     sv.add_argument(
-        "--batcher", default="auto",
-        choices=("auto", "continuous", "window"),
-        help="'continuous' (the default, mesh or not): fixed decode"
-        " slots, requests join a running decode at a dispatch"
-        " boundary, finished rows free their slot, tokens stream"
-        " (POST /generate with \"stream\": true -> SSE).  'window':"
-        " the request-granularity batcher (one generate per arrival"
-        " window — offline batch generation)",
-    )
-    sv.add_argument(
         "--steps-per-dispatch", type=_steps_per_dispatch, default=None,
-        help="continuous batcher: decode steps per compiled dispatch"
+        help="decode steps per compiled dispatch"
         " (K) — one host dispatch per K tokens; joins land at dispatch"
         " boundaries, so K bounds the extra join latency.  Default"
         " 'adaptive': the drive loop picks K per boundary from the"
@@ -919,23 +905,8 @@ def main(argv=None) -> int:
         " schedule).  An integer PINS K — the bisect override",
     )
     sv.add_argument(
-        "--engine-pipeline-depth", type=int, default=None,
-        help="continuous batcher: in-flight dispatch pipeline depth D"
-        " (default 2) — dispatch N+1 is issued with the donated decode"
-        " carry before dispatch N's tokens are read back, so the"
-        " host's per-dispatch overhead hides behind device compute."
-        " 1 = the old synchronous loop (the debug/bisect mode:"
-        " outputs are bit-identical, only slower).  Admissions ride"
-        " the in-flight dispatches (fused prefill+decode) and the"
-        " final insert is enqueued behind the last of them, so a join"
-        " never drains the pipeline.  Composes with --mesh: SPMD"
-        " dispatches chain the donated sharded carry on the device"
-        " stream exactly like single-chip (depth 2 is the default"
-        " there too)",
-    )
-    sv.add_argument(
         "--engine-staged-admission", action="store_true",
-        help="continuous batcher: force the STAGED admission path —"
+        help="force the STAGED admission path —"
         " every prefill chunk runs as its own dispatch at a drained"
         " pipeline boundary (the pre-fused behavior; bisect/debug"
         " mode, outputs bit-identical).  Default: a pending"
@@ -944,8 +915,8 @@ def main(argv=None) -> int:
     )
     sv.add_argument(
         "--prefix-cache", action="store_true",
-        help="host-RAM prefix KV cache (continuous batcher,"
-        " single-chip): requests sharing a cached prompt prefix fetch"
+        help="host-RAM prefix KV cache (single-chip): requests"
+        " sharing a cached prompt prefix fetch"
         " its K/V rows from host memory and prefill only the uncached"
         " suffix; responses carry cache_hit_tokens and GET"
         " /cache/stats reports hit/miss/eviction counters",
@@ -957,14 +928,14 @@ def main(argv=None) -> int:
     )
     sv.add_argument(
         "--prefill-chunk", type=int, default=256,
-        help="continuous batcher: admission prefill chunk (tokens) —"
+        help="admission prefill chunk (tokens) —"
         " a joiner prefills one chunk per dispatch boundary (fused"
         " into the decode dispatch by default); all-pad chunks are"
         " skipped",
     )
     sv.add_argument(
         "--kv-layout", default="dense", choices=("dense", "paged"),
-        help="continuous batcher: device KV layout. 'paged' stores KV"
+        help="device KV layout. 'paged' stores KV"
         " as fixed-size pages gathered through per-slot page tables"
         " (mlcomp_tpu/kvpool): sequence length is paid per page,"
         " admission is gated by FREE PAGES instead of worst-case slot"
@@ -1004,7 +975,7 @@ def main(argv=None) -> int:
     )
     sv.add_argument(
         "--flight-recorder-events", type=int, default=32768,
-        help="continuous batcher: bound on the engine flight recorder's"
+        help="bound on the engine flight recorder's"
         " event ring (GET /trace exports it as Perfetto-loadable Chrome"
         " trace JSON; GET /metrics is always on).  0 disables recording"
         " (its overhead is a dict append per event; not measured on the"
@@ -1021,20 +992,20 @@ def main(argv=None) -> int:
     )
     sv.add_argument(
         "--max-queue-depth", type=int, default=0,
-        help="continuous batcher: bound on requests waiting for a slot"
+        help="bound on requests waiting for a slot"
         " — past it submits fast-fail with 429 + Retry-After derived"
         " from live per-token latency, instead of queueing unboundedly"
         " (0 = unbounded, the historical behavior)",
     )
     sv.add_argument(
         "--max-concurrent-requests", type=int, default=0,
-        help="continuous batcher: bound on total in-flight requests"
+        help="bound on total in-flight requests"
         " (queued + decoding); past it submits fast-fail with 429"
         " (0 = unbounded)",
     )
     sv.add_argument(
         "--dispatch-stall-timeout", type=float, default=300.0,
-        help="continuous batcher: watchdog threshold in seconds — a"
+        help="watchdog threshold in seconds — a"
         " dispatch stuck in the runtime longer than this fails the"
         " in-flight requests, flips /healthz to 503, and (once the"
         " drive loop is provably dead) attempts one bounded restart."
@@ -1062,7 +1033,7 @@ def main(argv=None) -> int:
         help="disaggregated serving role (docs/serving.md"
         " 'Disaggregated serving'): 'prefill' runs the admission core"
         " only and answers POST /prefill with KV-page handoff blobs"
-        " (continuous batcher, dense layout); 'decode' is a paged"
+        " (dense layout); 'decode' is a paged"
         " daemon that additionally admits handoffs via POST /import,"
         " skipping prefill with bit-identical tokens; 'both' (default)"
         " is the monolithic daemon",

@@ -1,11 +1,7 @@
 """Continuous-batching decode engine: token-granularity serving.
 
-The round-3 serving daemon batched at REQUEST granularity: a window
-batcher grouped arrivals, ran one ``generate`` per group, and a
-128-token generation blocked every later arrival for its whole decode
-(round-3 verdict, missing #3).  The building blocks for better were
-already in place — per-row KV windows, per-row sampling knobs, static
-bucketed shapes — this module uses them at their natural granularity:
+Per-row KV windows, per-row sampling knobs and static bucketed shapes,
+used at their natural granularity:
 
 - a fixed pool of ``slots`` decode rows runs ONE compiled decode
   program; every inner step each live row samples, forwards, and its
@@ -18,9 +14,8 @@ bucketed shapes — this module uses them at their natural granularity:
   cursor jumps over them), so admission work scales with the REAL
   prompt length;
 - finished rows free their slot immediately — no drain barrier, and
-  queue order is FIFO over free slots, so the round-3 batcher's
-  starvation window (a request re-queued behind an endless stream of
-  the other bucket) cannot be constructed;
+  queue order is FIFO over free slots, so no request can be starved by
+  a stream of another bucket's arrivals;
 - per-row cache cursors (``cache_cursor``, models/transformer.py) let
   every row sit at a different depth in the shared cache buffers.
 
@@ -98,8 +93,7 @@ prefill/insert/decode programs run as SPMD programs over it — weights
 arrive sharded (Megatron tp layout from the service loader), the
 per-slot KV cache shards by XLA propagation from the tp-sharded K/V
 projections, and the Pallas int8 paths (quant_kernel, kv_quant) run
-inside the same shard_map islands the window batcher certified
-(ops/quant.sharded_quant_matmul,
+inside shard_map islands (ops/quant.sharded_quant_matmul,
 decode_attention.sharded_decode_attention — they read the process
 mesh, which ``serve.load_service`` installs).  The host drives the
 same numpy knob rows; under SPMD they replicate.  The sharded path is
@@ -157,6 +151,7 @@ from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from mlcomp_tpu.models.counts import count_groups
 from mlcomp_tpu.utils.faults import inject as _inject_fault
 from mlcomp_tpu.utils.trace import (
     Tracer,
@@ -210,99 +205,13 @@ class ProfileBusy(RuntimeError):
     status = "profile_busy"
 
 
-# what a layer may sow into the ``counters`` collection, by the name
-# it sows under: one float32 vector a call, an entry a line below.  A
-# program hands back the groups its model's layers sow, joined in this
-# order, as the tail of its packed token buffer; the metric of an entry
-# is ``mlcomp_engine_<group>_<entry>_total``.
-_COUNT_GROUPS = {
-    # models/moe.py RoutedExperts: assignments made, assignments whose
-    # expert is held here, experts a token reached, 1 (the call),
-    # experts held
-    "moe": (
-        ("assignments", "Token-to-expert assignments routed"),
-        ("assignments_held", "Assignments whose expert this chip holds"),
-        ("experts_touched",
-         "Experts a call's tokens reached, summed over calls"),
-        ("expert_layer_calls",
-         "Expert-layer calls (layers x steps, and chunks)"),
-        ("experts_held", "Experts held, summed over calls"),
-        # the first four again, over the chunk calls alone (more than
-        # one token a row: prefill chunks); sum less chunk is the
-        # single-token class, the decode steps
-        ("chunk_assignments", "Assignments routed by chunk calls"),
-        ("chunk_assignments_held", "Chunk calls' assignments held here"),
-        ("chunk_experts_touched",
-         "Experts reached, summed over chunk calls"),
-        ("chunk_expert_layer_calls", "Expert-layer calls that were chunks"),
-        # what the grouped matmul multiplied: tiles used x rows a tile
-        # (the tile follows the call's shapes: auto_row_tile), and the
-        # chunk calls' part; assignments held over it is the tiles' fill
-        ("tile_rows", "Rows of the row tiles the expert layout used"),
-        ("chunk_tile_rows", "Chunk calls' rows of row tiles used"),
-    ),
-    # models/retention.py PowerRetention (its COUNTS)
-    "retention": (
-        ("state_rows",
-         "Rows whose state a single-token step updated, summed over "
-         "layers and steps"),
-        ("state_bytes",
-         "Bytes those walks moved (ops/pallas/retention.py "
-         "state_bytes_moved): each row's state read and written once"),
-        ("chunk_tokens",
-         "Tokens chunk calls absorbed into a state, summed over layers"),
-        ("layer_calls", "Retention-layer calls (layers x steps, and chunks)"),
-    ),
-    # models/kda.py KimiDeltaAttention (its COUNTS)
-    "kda": (
-        ("state_rows",
-         "Rows whose delta-rule state a single-token step updated, "
-         "summed over layers and steps"),
-        ("state_bytes",
-         "Bytes those passes moved (ops/pallas/kda.py "
-         "state_bytes_moved): each row's states read and written once"),
-        ("chunk_tokens",
-         "Tokens chunk calls absorbed into a state, summed over layers"),
-        ("layer_calls", "KDA-layer calls (layers x steps, and chunks)"),
-    ),
-    # models/short_conv.py GatedShortConv (its COUNTS)
-    "conv": (
-        ("state_rows",
-         "Rows whose convolution tail a single-token step moved on, "
-         "summed over layers and steps"),
-        ("state_bytes",
-         "Bytes of those tails, each read and written once"),
-        ("chunk_tokens",
-         "Tokens chunk calls passed through a tail, summed over layers"),
-        ("layer_calls", "Conv-layer calls (layers x steps, and chunks)"),
-    ),
-    # models/latent_attention.py LatentAttention (its COUNTS)
-    "latent": (
-        ("tokens_attended",
-         "Cached latent tokens single-token steps attended (each row's "
-         "window), summed over layers and steps"),
-        ("bytes_read",
-         "Bytes of the latent blocks those steps fetched (whole blocks "
-         "of ops/pallas/latent_attention.py, each once for keys and "
-         "values alike)"),
-        ("chunk_tokens",
-         "Tokens chunk calls wrote into a latent cache, summed over "
-         "layers"),
-        ("layer_calls",
-         "Latent-attention calls (layers x steps, and chunks)"),
-    ),
-}
-# the moe entries that have a ``chunk_`` twin: counted by call class
-_CLASS_COUNTS = tuple(
-    name[len("chunk_"):] for name, _ in _COUNT_GROUPS["moe"]
-    if name.startswith("chunk_")
-)
-
-
 def _sown_by_group(counters) -> Dict[str, List[Any]]:
     """The leaves of a ``counters`` collection (or of its shapes), one a
-    layer, by the group of ``_COUNT_GROUPS`` they are sown under, in
-    that order."""
+    layer, by the group they are sown under: a float32 vector a call,
+    whose entries the layer's file names in a table
+    (models/counts.py).  A program hands back the groups its model's
+    layers sow, joined in the tables' order, as the tail of its packed
+    token buffer."""
     import jax
 
     from mlcomp_tpu.cache.kv_store import _leaf_name
@@ -310,19 +219,13 @@ def _sown_by_group(counters) -> Dict[str, List[Any]]:
     found: Dict[str, List[Any]] = {}
     for path, leaf in jax.tree_util.tree_leaves_with_path(counters):
         found.setdefault(_leaf_name(path), []).append(leaf)
-    unknown = sorted(set(found) - set(_COUNT_GROUPS))
-    if unknown:
-        raise ValueError(
-            f"a layer sows counters under {unknown}: name its entries in "
-            "engine._COUNT_GROUPS"
-        )
-    return {g: found[g] for g in _COUNT_GROUPS if g in found}
+    return {group.name: found[group.name] for group in count_groups(found)}
 
 
 def _sown_counts(upd):
     """The ``counters`` collection of one model call, each group summed
-    over its layers and the groups joined (``_COUNT_GROUPS``' order),
-    float32; None for a model that sows nothing."""
+    over its layers and the groups joined (``_sown_by_group``'s
+    order), float32; None for a model that sows nothing."""
     import jax.numpy as jnp
 
     sums = [sum(leaves[1:], leaves[0])
@@ -338,6 +241,27 @@ def _pack_counts(packed, counts):
     import jax.numpy as jnp
 
     return jnp.concatenate([packed.reshape(-1), counts.astype(packed.dtype)])
+
+
+def bucket(value: int, buckets: Sequence[int], what: str) -> int:
+    """The smallest configured bucket that holds ``value``."""
+    for b in sorted(buckets):
+        if value <= b:
+            return b
+    raise ValueError(
+        f"{what} {value} exceeds the largest configured bucket "
+        f"{max(buckets)}; raise the bucket list"
+    )
+
+
+def left_pad_row(ids: Sequence[int], s_bucket: int, pad_id: int):
+    """The serving LEFT-padding contract, in one place: returns the
+    (s_bucket,) int32 id row and its bool validity mask."""
+    row = np.full(s_bucket, pad_id, np.int32)
+    mask = np.zeros(s_bucket, bool)
+    row[s_bucket - len(ids):] = ids
+    mask[s_bucket - len(ids):] = True
+    return row, mask
 
 
 def _fail_future(fut: Future, err: Exception) -> None:
@@ -707,14 +631,15 @@ class DecodeEngine:
                     "off; serve it with the dense layout and no prefix "
                     "cache"
                 )
+        sown = _sown_by_group(shapes.get("counters", {}))
+        self._count_groups = count_groups(sown)
         self._count_layers = {
-            group: len(leaves) for group, leaves
-            in _sown_by_group(shapes.get("counters", {})).items()
+            group: len(leaves) for group, leaves in sown.items()
         }
         self._count_entries: Tuple[Tuple[str, str, str], ...] = tuple(
-            (group, name, what)
-            for group in self._count_layers
-            for name, what in _COUNT_GROUPS[group]
+            (group.name, name, what)
+            for group in self._count_groups
+            for name, what in group.entries
         )
 
         # paged device KV (mlcomp_tpu/kvpool, kv_layout="paged"): the
@@ -2039,54 +1964,14 @@ class DecodeEngine:
         }
         for (group, name, _), c in zip(self._count_entries, self._counts):
             counted[group][name] = float(c)
-        moe = counted.get("moe")
-        if moe and moe["expert_layer_calls"]:
-            calls, touched = moe["expert_layer_calls"], moe["experts_touched"]
-            chunk = {k: moe["chunk_" + k] for k in _CLASS_COUNTS}
-            out["moe"] = {
-                "assignments": moe["assignments"],
-                "assignments_held": moe["assignments_held"],
-                "experts_touched": touched,
-                "expert_layer_calls": calls,
-                "tile_rows": moe["tile_rows"],
-                "experts_touched_per_call": round(touched / calls, 3),
-                # of the experts held, summed over the same calls
-                "experts_touched_share": round(
-                    touched / moe["experts_held"], 4
-                ),
-                # the counts by call class: chunk calls (prefill) and
-                # single-token calls (decode steps)
-                "by_class": {
-                    "chunk": chunk,
-                    "single_token": {
-                        k: moe[k] - chunk[k] for k in _CLASS_COUNTS
-                    },
-                },
-            }
-        for group in ("retention", "kda", "conv"):
-            got = counted.get(group)
-            if not (got and got["layer_calls"]):
-                continue
-            issued = p["kv_rows_written"] * self._count_layers[group]
-            out[group] = {
-                **got,
-                # the device's count of rows over the host mirror's
-                # (rows holding a request at issue x steps x layers):
-                # under 1 by the rows that retired inside a dispatch
-                "state_rows_over_issued": round(
-                    got["state_rows"] / issued, 4
-                ) if issued else None,
-            }
-        lat = counted.get("latent")
-        if lat and lat["layer_calls"]:
-            out["latent"] = {
-                **lat,
-                # of the bytes fetched, the part the windows needed:
-                # under 1 by the blocks' edges and the leaf's pad lanes
-                "tokens_per_fetched_kb": round(
-                    lat["tokens_attended"] / (lat["bytes_read"] / 1024), 4
-                ) if lat["bytes_read"] else None,
-            }
+        for group in self._count_groups:
+            block = group.block(
+                counted[group.name],
+                # rows holding a request at issue x steps x layers
+                p["kv_rows_written"] * self._count_layers[group.name],
+            )
+            if block is not None:
+                out[group.name] = block
         out["latency"] = {
             # "samples" is the WINDOW the percentiles summarize (the
             # deque, capped at its maxlen); "lifetime_samples" is the
@@ -2534,10 +2419,7 @@ class DecodeEngine:
     # ----------------------------------------------------------- programs
 
     def _bucket(self, n: int) -> int:
-        # the window batcher's bucket policy, shared (serve.py)
-        from mlcomp_tpu.serve import _bucket
-
-        return _bucket(n, self.prompt_buckets, "prompt length")
+        return bucket(n, self.prompt_buckets, "prompt length")
 
     def _chunk_width(self, s_bucket: int) -> int:
         """The admission chunk width for a bucket: the configured
@@ -3582,8 +3464,6 @@ class DecodeEngine:
         complete (``next_chunk == n_chunks``) and the loop's
         completion boundary — which drains the pipeline for an import
         — writes the pages and inserts the slot."""
-        from mlcomp_tpu.serve import left_pad_row
-
         jnp = self._jnp
         ids = req["ids"]
         s_bucket = self._bucket(len(ids))

@@ -212,7 +212,7 @@ class _ProcHandle:
 class SubprocessLauncher:
     """Replicas as ``mlcomp-tpu serve`` children on this host — the
     ``mlcomp-tpu fleet`` single-host shape.  ``serve_argv`` is the flag
-    tail after ``serve`` (model/ckpt/batcher flags); host/port are
+    tail after ``serve`` (model/ckpt/engine flags); host/port are
     appended per replica, so the caller must not pass them.
 
     ``chips`` > 0 pins every replica to its own chips: a TPU chip

@@ -49,13 +49,24 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from mlcomp_tpu.models.counts import count_group, state_rows_block
 from mlcomp_tpu.models.transformer import RMSNorm, rmsnorm
 from mlcomp_tpu.ops.pallas.kda import kda_step, state_bytes_moved
 
-# what a call sows into the ``counters`` collection under the name
-# "kda": rows whose state the single-token kernel updated, the bytes
-# that pass moved, tokens absorbed by chunk calls, 1 (the call)
-COUNTS = ("state_rows", "state_bytes", "chunk_tokens", "layer_calls")
+# what a call sows into the ``counters`` collection: rows whose state
+# the single-token kernel updated, the bytes that pass moved, tokens
+# absorbed by chunk calls, 1 (the call)
+COUNTS = count_group("kda", (
+    ("state_rows",
+     "Rows whose delta-rule state a single-token step updated, "
+     "summed over layers and steps"),
+    ("state_bytes",
+     "Bytes those passes moved (ops/pallas/kda.py "
+     "state_bytes_moved): each row's states read and written once"),
+    ("chunk_tokens",
+     "Tokens chunk calls absorbed into a state, summed over layers"),
+    ("layer_calls", "KDA-layer calls (layers x steps, and chunks)"),
+), block=state_rows_block)
 
 # tokens a block of the chunked form, and rows a sub-block of its
 # triangular solve
@@ -242,7 +253,7 @@ class KimiDeltaAttention(nn.Module):
             # init traces this module at the whole buffer's length only
             # to learn the cache's shapes: the variables exist
             out = jnp.zeros((b, s, n, dh), jnp.float32)
-            counts = jnp.zeros((len(COUNTS),), jnp.float32)
+            counts = jnp.zeros(len(COUNTS.entries), jnp.float32)
         elif not decode:
             valid = None if kv_mask is None else kv_mask[:, :s]
             zeros = jnp.zeros((b, self.conv - 1, 3 * n * dh), self.dtype)
@@ -269,9 +280,9 @@ class KimiDeltaAttention(nn.Module):
             ])
         if decode:
             self.sow(
-                "counters", "kda", counts,
+                "counters", COUNTS.name, counts,
                 reduce_fn=lambda a, c: a + c,
-                init_fn=lambda: jnp.zeros((len(COUNTS),), jnp.float32),
+                init_fn=lambda: jnp.zeros(len(COUNTS.entries), jnp.float32),
             )
         scale = self.param("o_norm", nn.initializers.ones, (dh,), jnp.float32)
         out = rmsnorm(out, scale, self.dtype) * gate

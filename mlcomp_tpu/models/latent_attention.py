@@ -43,6 +43,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from mlcomp_tpu.models.counts import count_group
 from mlcomp_tpu.models.transformer import RMSNorm, _window_start, rmsnorm
 from mlcomp_tpu.ops.pallas.latent_attention import (
     LANES,
@@ -53,11 +54,37 @@ from mlcomp_tpu.ops.pallas.latent_attention import (
     latent_decode,
 )
 
-# what a call sows into the ``counters`` collection under the name
-# "latent": cached tokens the single-token kernel attended (its rows'
-# windows), the bytes of the blocks it fetched for them, tokens chunk
-# calls wrote, 1 (the call)
-COUNTS = ("tokens_attended", "bytes_read", "chunk_tokens", "layer_calls")
+
+def _counts_block(sums, issued):
+    if not sums["layer_calls"]:
+        return None
+    return {
+        **sums,
+        # of the bytes fetched, the part the windows needed: under 1
+        # by the blocks' edges and the leaf's pad lanes
+        "tokens_per_fetched_kb": round(
+            sums["tokens_attended"] / (sums["bytes_read"] / 1024), 4
+        ) if sums["bytes_read"] else None,
+    }
+
+
+# what a call sows into the ``counters`` collection: cached tokens the
+# single-token kernel attended (its rows' windows), the bytes of the
+# blocks it fetched for them, tokens chunk calls wrote, 1 (the call)
+COUNTS = count_group("latent", (
+    ("tokens_attended",
+     "Cached latent tokens single-token steps attended (each row's "
+     "window), summed over layers and steps"),
+    ("bytes_read",
+     "Bytes of the latent blocks those steps fetched (whole blocks "
+     "of ops/pallas/latent_attention.py, each once for keys and "
+     "values alike)"),
+    ("chunk_tokens",
+     "Tokens chunk calls wrote into a latent cache, summed over "
+     "layers"),
+    ("layer_calls",
+     "Latent-attention calls (layers x steps, and chunks)"),
+), block=_counts_block)
 
 # queries a tile of the chunk form: 32 heads x 256 x 512 keys x 4 B =
 # 16.8 MB of scores a row
@@ -188,7 +215,7 @@ class LatentAttention(nn.Module):
             # init traces this module at the whole buffer's length only
             # to learn the cache's shapes: the variables exist
             out = jnp.zeros((b, s, n, dc), jnp.float32)
-            counts = jnp.zeros((len(COUNTS),), jnp.float32)
+            counts = jnp.zeros(len(COUNTS.entries), jnp.float32)
         elif not decode:
             length = buffer_len(s)
             valid = jnp.ones((b, s), bool) if kv_mask is None \
@@ -227,9 +254,9 @@ class LatentAttention(nn.Module):
             ])
         if decode:
             self.sow(
-                "counters", "latent", counts,
+                "counters", COUNTS.name, counts,
                 reduce_fn=lambda a, c: a + c,
-                init_fn=lambda: jnp.zeros((len(COUNTS),), jnp.float32),
+                init_fn=lambda: jnp.zeros(len(COUNTS.entries), jnp.float32),
             )
         with jax.named_scope("mla.project"):
             out = jnp.einsum(

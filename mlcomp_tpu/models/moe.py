@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from mlcomp_tpu.models import MODELS
+from mlcomp_tpu.models.counts import count_group
 from mlcomp_tpu.models.transformer import DecoderLayer, RMSNorm
 
 
@@ -200,6 +201,58 @@ ROUTER_SCORES = {
 }
 
 
+def _counts_block(sums, issued):
+    calls, touched = sums["expert_layer_calls"], sums["experts_touched"]
+    if not calls:
+        return None
+    # the entries that have a ``chunk_`` twin: counted by call class
+    chunk = {
+        name[len("chunk_"):]: c for name, c in sums.items()
+        if name.startswith("chunk_")
+    }
+    return {
+        "assignments": sums["assignments"],
+        "assignments_held": sums["assignments_held"],
+        "experts_touched": touched,
+        "expert_layer_calls": calls,
+        "tile_rows": sums["tile_rows"],
+        "experts_touched_per_call": round(touched / calls, 3),
+        # of the experts held, summed over the same calls
+        "experts_touched_share": round(touched / sums["experts_held"], 4),
+        # the counts by call class: chunk calls (prefill) and
+        # single-token calls (decode steps)
+        "by_class": {
+            "chunk": chunk,
+            "single_token": {k: sums[k] - c for k, c in chunk.items()},
+        },
+    }
+
+
+# what a RoutedExperts call sows into the ``counters`` collection, in
+# the order its ``sow`` joins them
+COUNTS = count_group("moe", (
+    ("assignments", "Token-to-expert assignments routed"),
+    ("assignments_held", "Assignments whose expert this chip holds"),
+    ("experts_touched",
+     "Experts a call's tokens reached, summed over calls"),
+    ("expert_layer_calls",
+     "Expert-layer calls (layers x steps, and chunks)"),
+    ("experts_held", "Experts held, summed over calls"),
+    # the first four again, over the chunk calls alone (more than one
+    # token a row: prefill chunks); sum less chunk is the single-token
+    # class, the decode steps
+    ("chunk_assignments", "Assignments routed by chunk calls"),
+    ("chunk_assignments_held", "Chunk calls' assignments held here"),
+    ("chunk_experts_touched", "Experts reached, summed over chunk calls"),
+    ("chunk_expert_layer_calls", "Expert-layer calls that were chunks"),
+    # what the grouped matmul multiplied: tiles used x rows a tile (the
+    # tile follows the call's shapes: auto_row_tile), and the chunk
+    # calls' part; assignments held over it is the tiles' fill
+    ("tile_rows", "Rows of the row tiles the expert layout used"),
+    ("chunk_tile_rows", "Chunk calls' rows of row tiles used"),
+), block=_counts_block)
+
+
 class RoutedExperts(nn.Module):
     """Dropless top-k routing over gated experts (``gate``: ``"silu"``,
     SwiGLU, or ``"relu"``, ReGLU), of which this chip may hold a share
@@ -316,11 +369,11 @@ class RoutedExperts(nn.Module):
             mine = jnp.concatenate([counts[:4], tile_rows])
             as_chunk = mine if s > 1 else jnp.zeros_like(mine)
             self.sow(
-                "counters", "moe",
+                "counters", COUNTS.name,
                 jnp.concatenate(
                     [counts, as_chunk[:4], tile_rows, as_chunk[4:]]),
                 reduce_fn=lambda a, c: a + c,
-                init_fn=lambda: jnp.zeros((11,), jnp.float32),
+                init_fn=lambda: jnp.zeros(len(COUNTS.entries), jnp.float32),
             )
 
         with jax.named_scope("moe.experts"):

@@ -49,6 +49,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from mlcomp_tpu.models.counts import count_group, state_rows_block
 from mlcomp_tpu.models.transformer import (
     RMSNorm,
     RopeSpec,
@@ -64,10 +65,20 @@ from mlcomp_tpu.ops.pallas.retention import (
     state_bytes_moved,
 )
 
-# what a call sows into the ``counters`` collection under the name
-# "retention": rows whose state the single-token kernel updated, the
-# bytes that walk moved, tokens absorbed by chunk calls, 1 (the call)
-COUNTS = ("state_rows", "state_bytes", "chunk_tokens", "layer_calls")
+# what a call sows into the ``counters`` collection: rows whose state
+# the single-token kernel updated, the bytes that walk moved, tokens
+# absorbed by chunk calls, 1 (the call)
+COUNTS = count_group("retention", (
+    ("state_rows",
+     "Rows whose state a single-token step updated, summed over "
+     "layers and steps"),
+    ("state_bytes",
+     "Bytes those walks moved (ops/pallas/retention.py "
+     "state_bytes_moved): each row's state read and written once"),
+    ("chunk_tokens",
+     "Tokens chunk calls absorbed into a state, summed over layers"),
+    ("layer_calls", "Retention-layer calls (layers x steps, and chunks)"),
+), block=state_rows_block)
 
 EPS = 1e-6
 
@@ -194,9 +205,9 @@ class PowerRetention(nn.Module):
         else:
             out, counts = self._cached(q, k, v, log_g, kv_mask, cache_cursor)
             self.sow(
-                "counters", "retention", counts,
+                "counters", COUNTS.name, counts,
                 reduce_fn=lambda a, c: a + c,
-                init_fn=lambda: jnp.zeros((len(COUNTS),), jnp.float32),
+                init_fn=lambda: jnp.zeros(len(COUNTS.entries), jnp.float32),
             )
         out = out.reshape(b, s, self.heads, dh).astype(self.dtype)
         return x + nn.DenseGeneral(

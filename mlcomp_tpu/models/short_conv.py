@@ -31,14 +31,23 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from mlcomp_tpu.models.counts import count_group, state_rows_block
 from mlcomp_tpu.models.kda import short_conv
 from mlcomp_tpu.models.transformer import RMSNorm
 
-# what a call sows into the ``counters`` collection under the name
-# "conv": rows whose tail a single-token step moved on, the bytes of
-# those tails read and written, tokens absorbed by chunk calls, 1 (the
-# call)
-COUNTS = ("state_rows", "state_bytes", "chunk_tokens", "layer_calls")
+# what a call sows into the ``counters`` collection: rows whose tail a
+# single-token step moved on, the bytes of those tails read and
+# written, tokens absorbed by chunk calls, 1 (the call)
+COUNTS = count_group("conv", (
+    ("state_rows",
+     "Rows whose convolution tail a single-token step moved on, "
+     "summed over layers and steps"),
+    ("state_bytes",
+     "Bytes of those tails, each read and written once"),
+    ("chunk_tokens",
+     "Tokens chunk calls passed through a tail, summed over layers"),
+    ("layer_calls", "Conv-layer calls (layers x steps, and chunks)"),
+), block=state_rows_block)
 
 
 class GatedShortConv(nn.Module):
@@ -77,7 +86,7 @@ class GatedShortConv(nn.Module):
             # init traces this module at the whole buffer's length only
             # to learn the cache's shapes: the variables exist
             mixed = jnp.zeros((b, s, c), jnp.float32)
-            counts = jnp.zeros((len(COUNTS),), jnp.float32)
+            counts = jnp.zeros(len(COUNTS.entries), jnp.float32)
         elif not decode:
             valid = None if kv_mask is None else kv_mask[:, :s]
             mixed, _ = self._chunk(u, zeros, taps, valid)
@@ -96,9 +105,9 @@ class GatedShortConv(nn.Module):
             ])
         if decode:
             self.sow(
-                "counters", "conv", counts,
+                "counters", COUNTS.name, counts,
                 reduce_fn=lambda a, n: a + n,
-                init_fn=lambda: jnp.zeros((len(COUNTS),), jnp.float32),
+                init_fn=lambda: jnp.zeros(len(COUNTS.entries), jnp.float32),
             )
         return x + dense(c, "out")(gate_out * mixed.astype(self.dtype))
 

@@ -62,13 +62,11 @@ DEFAULT_SLOS: Dict[str, Dict[str, Any]] = {
     "reject_rate": {
         "kind": "ratio",
         "bad": "mlcomp_serving_requests_rejected_total",
-        # accepted requests live in the ENGINE counter on the
-        # continuous batcher and the SERVICE counter on the window
-        # one (each daemon publishes exactly one of the two) — sum both so a lone 429 on a window daemon is a ratio,
+        # accepted requests live in the engine's counter: rejected +
+        # accepted is everything that asked, so a lone 429 is a ratio,
         # not a guaranteed 1.0 breach
         "total": ["mlcomp_serving_requests_rejected_total",
-                  "mlcomp_engine_requests_total",
-                  "mlcomp_service_requests_total"],
+                  "mlcomp_engine_requests_total"],
         "budget": 0.01,
     },
     "engine_healthy": {

@@ -887,8 +887,7 @@ class _Handler(BaseHTTPRequestHandler):
         # dashboard, lifted out of the health payload: p50/p95/p99
         # TTFT / per-token percentiles and the engine's pipeline
         # overlap metrics (in-flight depth, host-hidden ms per
-        # dispatch, occupancy).  Absent (None) for window-batcher
-        # daemons — the dashboard shows them only when present.
+        # dispatch, occupancy).
         health = out["health"]
         eng = health.get("engine") or {}
         out["latency"] = health.get("latency") or eng.get("latency")

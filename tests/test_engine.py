@@ -1,6 +1,5 @@
 """Continuous-batching engine: greedy equality with bare generate,
-mid-decode join (the round-3 window batcher made late arrivals wait for
-the whole running batch), token streaming, and knob parity."""
+mid-decode join, token streaming, and knob parity."""
 
 import queue
 import threading
@@ -73,8 +72,7 @@ def test_engine_greedy_matches_generate(kv_quant):
 def test_engine_mid_decode_join_and_no_starvation():
     """A request arriving mid-decode starts within a couple of steps —
     it does NOT wait for the running generation to drain — and a short
-    request finishes before a long one that started earlier (impossible
-    under the window batcher, whose batches run to completion).
+    request finishes before a long one that started earlier.
     K=1 keeps the round-4 per-token join bound; the K>1 bound has its
     own test below.  pipeline_depth=1 + staged admission pin the
     SYNCHRONOUS loop whose tight bound this asserts (the fused default
@@ -178,7 +176,7 @@ def test_service_defaults_to_continuous_and_streams_http():
         model, {"params": params}, batch_sizes=(1, 2),
         prompt_buckets=(8, 16), max_new_buckets=(4, 8),
     )
-    assert svc.batcher == "continuous" and svc.engine is not None
+    assert svc.stats()["batcher"] == "continuous"
     s = socket.socket()
     s.bind(("127.0.0.1", 0))
     port = s.getsockname()[1]
@@ -240,15 +238,6 @@ def test_engine_validation_and_service_window_stream_refusal():
             eng.submit([1] * 20, 4)
     finally:
         eng.close()
-    svc = GenerationService(
-        model, {"params": params}, batcher="window", batch_sizes=(1,),
-        prompt_buckets=(16,), max_new_buckets=(8,),
-    )
-    try:
-        with pytest.raises(ValueError, match="streaming"):
-            svc.submit([1, 2], 4, stream=queue.Queue())
-    finally:
-        svc.close()
 
 
 def test_engine_quant_kernel_matches_generate():

@@ -9,9 +9,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from mlcomp_tpu.engine import _COUNT_GROUPS, DecodeEngine
+from mlcomp_tpu.engine import DecodeEngine
 from mlcomp_tpu.models import create_model
+from mlcomp_tpu.models.moe import COUNTS as MOE_COUNTS
 from mlcomp_tpu.train.state import init_model
+
+# what every per-slot state kind counts, in its vector's order
+STATE_COUNTS = ("state_rows", "state_bytes", "chunk_tokens", "layer_calls")
 
 MIXED = {
     "name": "mixed_layer_lm", "vocab_size": 64, "hidden": 128, "head_dim": 64,
@@ -111,7 +115,7 @@ def test_the_counts_ride_the_tail_of_the_packed_buffer():
             eng._dispatch_fn(), eng.variables, eng._dstate)
     finally:
         eng.close()
-    assert packed.shape == (3 * 2 * 2 + len(_COUNT_GROUPS["moe"]),)
+    assert packed.shape == (3 * 2 * 2 + len(MOE_COUNTS.entries),)
 
 
 # a router before the attention, ReLU experts, a first layer that rotates
@@ -198,15 +202,15 @@ def test_the_tile_counts_close_the_expert_layers_vector():
     """The nine entries every reader indexes keep their places; the two
     tile counts come after them, and the classes are the entries with a
     ``chunk_`` twin."""
-    from mlcomp_tpu.engine import _CLASS_COUNTS
-
-    names = [n for n, _ in _COUNT_GROUPS["moe"]]
+    assert MOE_COUNTS.name == "moe"
+    names = list(MOE_COUNTS.names)
     assert names == [
         "assignments", "assignments_held", "experts_touched",
         "expert_layer_calls", "experts_held", "chunk_assignments",
         "chunk_assignments_held", "chunk_experts_touched",
         "chunk_expert_layer_calls", "tile_rows", "chunk_tile_rows"]
-    assert _CLASS_COUNTS == tuple(names[:4]) + ("tile_rows",)
+    assert tuple(n[len("chunk_"):] for n in names if n.startswith("chunk_")) \
+        == tuple(names[:4]) + ("tile_rows",)
 
 
 # ---- the int8 cache's single-token step appends inside the kernel ----
@@ -432,7 +436,7 @@ def test_a_retention_layers_counts_are_the_hand_counts():
     from mlcomp_tpu.models.retention import COUNTS
     from mlcomp_tpu.ops.pallas.retention import state_bytes_moved
 
-    assert tuple(n for n, _ in _COUNT_GROUPS["retention"]) == COUNTS
+    assert (COUNTS.name, COUNTS.names) == ("retention", STATE_COUNTS)
     model, params = _build(RETENTION)
     k, layers = 2, 2
     eng = DecodeEngine(model, {"params": params}, slots=3,
@@ -442,7 +446,7 @@ def test_a_retention_layers_counts_are_the_hand_counts():
     try:
         _, packed = jax.eval_shape(
             eng._dispatch_fn(), eng.variables, eng._dstate)
-        assert packed.shape == (3 * k * 3 + len(COUNTS),)
+        assert packed.shape == (3 * k * 3 + len(COUNTS.entries),)
         out = eng.submit(list(range(1, 13)), 3 * k).result(timeout=300)
         st = eng.stats()
         text = eng.metrics.render()
@@ -490,8 +494,10 @@ def test_the_state_and_the_latent_layers_counts_are_the_hand_counts():
     from mlcomp_tpu.models import kda, latent_attention
     from mlcomp_tpu.ops.pallas.kda import state_bytes_moved
 
-    for group, mod in (("kda", kda), ("latent", latent_attention)):
-        assert tuple(n for n, _ in _COUNT_GROUPS[group]) == mod.COUNTS
+    assert (kda.COUNTS.name, kda.COUNTS.names) == ("kda", STATE_COUNTS)
+    assert latent_attention.COUNTS.name == "latent"
+    assert latent_attention.COUNTS.names == (
+        "tokens_attended", "bytes_read", "chunk_tokens", "layer_calls")
     model, params = _build(STATES_AND_LATENTS)
     k, n_kda = 2, 2
     eng = DecodeEngine(model, {"params": params}, slots=3,
@@ -853,7 +859,7 @@ def test_a_conv_layers_counts_are_the_hand_counts():
     layer alone."""
     from mlcomp_tpu.models.short_conv import COUNTS
 
-    assert tuple(n for n, _ in _COUNT_GROUPS["conv"]) == COUNTS
+    assert (COUNTS.name, COUNTS.names) == ("conv", STATE_COUNTS)
     model, params = _build(TAILS_AND_KEYS)
     assert model.attention_windows() == (None,)
     k, n_conv = 2, 2
@@ -864,7 +870,7 @@ def test_a_conv_layers_counts_are_the_hand_counts():
     try:
         _, packed = jax.eval_shape(
             eng._dispatch_fn(), eng.variables, eng._dstate)
-        assert packed.shape == (3 * k * 3 + len(COUNTS),)
+        assert packed.shape == (3 * k * 3 + len(COUNTS.entries),)
         assert eng._count_layers == {"conv": 2}
         out = eng.submit(list(range(1, 13)), 3 * k).result(timeout=300)
         st = eng.stats()
@@ -908,3 +914,102 @@ def test_a_conv_tail_refuses_pages_and_prefixes_by_the_leaf():
                      prompt_buckets=(16,), max_new_cap=16,
                      steps_per_dispatch=2, prefill_chunk=8,
                      kv_layout="paged", kv_page_tokens=8)
+
+
+# ---- a layer kind costs the engine no line: its table is its own ----
+
+def _toy_lm(group):
+    """A decoder whose model call sows a vector of its own under
+    ``group``: the positions it was handed, and 1 (the call)."""
+    import flax.linen as nn
+
+    from mlcomp_tpu.models.transformer import TransformerLM
+
+    class ToyLM(TransformerLM):
+        @nn.compact
+        def __call__(self, x, *args, decode=False, **kw):
+            out = super().__call__(x, *args, decode=decode, **kw)
+            if decode:
+                self.sow(
+                    "counters", group,
+                    jnp.asarray([x.size, 1.0], jnp.float32),
+                    reduce_fn=lambda a, c: a + c,
+                    init_fn=lambda: jnp.zeros((2,), jnp.float32),
+                )
+            return out
+
+    model = ToyLM(vocab_size=64, hidden=64, layers=1, heads=2, mlp_dim=128,
+                  dtype=jnp.float32)
+    prompt = jnp.asarray(np.random.RandomState(0).randint(1, 64, (1, 8)))
+    params, _ = init_model(model, {"x": prompt}, jax.random.PRNGKey(0))
+    return model, params
+
+
+def _toy_engine(model, params):
+    return DecodeEngine(model, {"params": params}, slots=3,
+                        prompt_buckets=(16,), max_new_cap=16,
+                        steps_per_dispatch=2, prefill_chunk=8,
+                        pipeline_depth=1)
+
+
+@pytest.mark.parametrize("block", ["sums", "share"])
+def test_a_layer_kind_declared_beside_its_layer_is_served_and_counted(block):
+    """The same traffic as the state kinds' hand counts (two chunks of
+    8, three dispatches of 2 steps on three slots), through a layer
+    kind this file alone knows: ``stats()`` has its block (the sums, or
+    what the table's function makes of them) and ``/metrics`` its
+    counters, help texts and all."""
+    from mlcomp_tpu.models.counts import count_group
+
+    group = f"toy_{block}".lower()
+    table = count_group(group, (
+        ("positions", "Positions the toy model's calls were handed"),
+        ("layer_calls", "Toy-model calls (steps, and chunks)"),
+    ), **({} if block == "sums" else {"block": lambda sums, issued: {
+        **sums, "issued": issued,
+        "positions_per_call": sums["positions"] / sums["layer_calls"],
+    }}))
+    model, params = _toy_lm(group)
+    eng = _toy_engine(model, params)
+    try:
+        assert eng._count_layers == {group: 1}
+        _, packed = jax.eval_shape(
+            eng._dispatch_fn(), eng.variables, eng._dstate)
+        assert packed.shape == (3 * 2 * 3 + len(table.entries),)
+        out = eng.submit(list(range(1, 13)), 6).result(timeout=300)
+        st = eng.stats()
+        text = eng.metrics.render()
+    finally:
+        eng.close()
+    assert len(out["ids"]) == 6 and st["pipeline"]["issued"] == 3
+    sums = {"positions": 2 * 8 + 6 * 3, "layer_calls": 2 + 6}
+    if block == "sums":
+        assert st[group] == sums
+    else:
+        # one live row, six steps, one "layer": what the host issued
+        assert st[group] == {**sums, "issued": 6, "positions_per_call": 4.25}
+    assert f"mlcomp_engine_{group}_positions_total 34" in text
+    assert f"mlcomp_engine_{group}_layer_calls_total 8" in text
+    assert (f"# HELP mlcomp_engine_{group}_positions_total Positions the "
+            "toy model's calls were handed") in text
+
+
+def test_a_layer_kind_without_a_table_is_refused_by_name():
+    model, params = _toy_lm("toy_undeclared")
+    with pytest.raises(ValueError, match=r"\['toy_undeclared'\].*count_group"):
+        _toy_engine(model, params)
+
+
+def test_a_groups_name_is_declared_once():
+    """Declaring the same table again (a module imported twice) is the
+    same table in the same place; other entries under a taken name are
+    another layer kind's, and refused."""
+    from mlcomp_tpu.models.counts import count_group, count_groups
+    from mlcomp_tpu.models.short_conv import COUNTS
+
+    before = [g.name for g in count_groups(["moe", "conv"])]
+    again = count_group(COUNTS.name, COUNTS.entries, block=COUNTS.block)
+    assert again == COUNTS
+    assert [g.name for g in count_groups(["moe", "conv"])] == before
+    with pytest.raises(ValueError, match="already declared"):
+        count_group("conv", (("rows", "Rows"),))

@@ -276,10 +276,9 @@ def test_fused_prefill_fault_fails_only_the_admission():
 
 def test_staged_flag_plumbing_and_metrics():
     """--engine-staged-admission plumbing: the service forwards
-    engine_fused_admission (rejected off the continuous batcher), the
-    engine reports the mode in stats(), and the new admission metrics
-    (fused chunk / overlap counters + the stall histogram) are in the
-    exposition."""
+    engine_fused_admission, the engine reports the mode in stats(), and
+    the new admission metrics (fused chunk / overlap counters + the
+    stall histogram) are in the exposition."""
     model, params = _model_and_params()
     svc = GenerationService(
         model, {"params": params}, batch_sizes=(1, 2),
@@ -297,12 +296,6 @@ def test_staged_flag_plumbing_and_metrics():
             assert name in text, name
     finally:
         svc.close()
-    with pytest.raises(ValueError, match="continuous"):
-        GenerationService(
-            model, {"params": params}, batcher="window", batch_sizes=(1,),
-            prompt_buckets=(16,), max_new_buckets=(8,),
-            engine_fused_admission=False,
-        )
     # default is fused; warmup precompiles the fused program family
     svc = GenerationService(
         model, {"params": params}, batch_sizes=(1, 2),

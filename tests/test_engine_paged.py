@@ -319,10 +319,6 @@ def test_construction_validation():
         _engine("paged", slots=4, max_slots=2)
     with pytest.raises(ValueError, match="worst-case"):
         _engine("paged", kv_pages=RESERVED_PAGES + 1)
-    svc_err = pytest.raises(ValueError, match="continuous")
-    with svc_err:
-        GenerationService(model, {"params": params}, batcher="window",
-                          prompt_buckets=(16,), kv_layout="paged")
 
 
 def test_fatblock_recheck_at_scale():

@@ -194,8 +194,7 @@ def test_pipeline_depth_validation_and_mesh_default():
     """Depth < 1 is rejected at construction; the default is depth 2
     EVERYWHERE — mesh or not, since the sharded-serving PR (the old
     mesh rejection is gone; tests/test_engine_sharded.py pins the
-    sharded equalities); depth > 1 at the service level needs the
-    continuous batcher."""
+    sharded equalities)."""
     model, params = _model_and_params()
     kw = dict(slots=2, prompt_buckets=(16,), max_new_cap=8)
     with pytest.raises(ValueError, match="pipeline_depth"):
@@ -210,12 +209,6 @@ def test_pipeline_depth_validation_and_mesh_default():
         assert eng.pipeline_depth == 2  # single-chip default: pipelined
     finally:
         eng.close()
-    with pytest.raises(ValueError, match="continuous"):
-        GenerationService(
-            model, {"params": params}, batcher="window", batch_sizes=(1,),
-            prompt_buckets=(16,), max_new_buckets=(8,),
-            engine_pipeline_depth=2,
-        )
 
 
 def test_pipeline_overlap_metrics_and_latency_percentiles():

@@ -131,7 +131,7 @@ def _zero_cache(layer, b):
     shapes = jax.eval_shape(lambda: layer.init(
         jax.random.PRNGKey(0), jnp.zeros((b, 4, HIDDEN)),
         jnp.zeros((b, 4), jnp.int32), decode=True))
-    assert shapes["counters"]["kda"].shape == (len(COUNTS),)
+    assert shapes["counters"]["kda"].shape == (len(COUNTS.entries),)
     return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
                         shapes["cache"])
 

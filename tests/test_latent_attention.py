@@ -76,7 +76,7 @@ def _zero_cache(layer, b, length):
     shapes = jax.eval_shape(lambda: layer.init(
         jax.random.PRNGKey(0), jnp.zeros((b, length, HIDDEN)),
         jnp.zeros((b, length), jnp.int32), decode=True))
-    assert shapes["counters"]["latent"].shape == (len(COUNTS),)
+    assert shapes["counters"]["latent"].shape == (len(COUNTS.entries),)
     return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
                         shapes["cache"])
 

@@ -329,28 +329,6 @@ def test_serve_metrics_and_trace_http_round_trip():
         svc.close()
 
 
-def test_trace_404_for_window_batcher():
-    from mlcomp_tpu.serve import make_http_server
-
-    svc = _tiny_service(batcher="window")
-    httpd = make_http_server(svc, "127.0.0.1", 0, "toy")
-    threading.Thread(target=httpd.serve_forever, daemon=True).start()
-    port = httpd.server_address[1]
-    try:
-        with pytest.raises(urllib.error.HTTPError) as ei:
-            urllib.request.urlopen(f"http://127.0.0.1:{port}/trace")
-        assert ei.value.code == 404
-        # /metrics still works (service-level counters)
-        text = urllib.request.urlopen(
-            f"http://127.0.0.1:{port}/metrics"
-        ).read().decode()
-        assert 'mlcomp_service_info{batcher="window"' in text
-    finally:
-        httpd.shutdown()
-        httpd.server_close()
-        svc.close()
-
-
 def test_flight_recorder_and_history_can_be_disabled():
     svc = _tiny_service(
         flight_recorder_events=0, metrics_history_interval=0,

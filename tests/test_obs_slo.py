@@ -72,17 +72,16 @@ def test_disabled_slo_stays_disabled_through_the_engine():
     assert "per_token_p50" not in slo.status()["slos"]
 
 
-def test_reject_rate_uses_the_service_counter_on_window_batchers():
-    # window-batcher daemons count accepted requests in
-    # mlcomp_service_requests_total (the engine family doesn't exist
-    # there): one 429 among many successes must be a RATIO, not a
+def test_reject_rate_is_a_ratio_over_the_engines_counter():
+    # accepted requests are counted in mlcomp_engine_requests_total:
+    # one 429 among many successes must be a RATIO, not a
     # denominator-free guaranteed 1.0 breach
     reg, hist, clock, slo, rec = make_engine()
     reg.counter(
         "mlcomp_serving_requests_rejected_total", "",
         labelnames=("reason",),
     ).inc(1, reason="queue_full")
-    reg.counter("mlcomp_service_requests_total", "").inc(99)
+    reg.counter("mlcomp_engine_requests_total", "").inc(99)
     tick(hist, clock, slo)
     st = slo.status()["slos"]["reject_rate"]
     assert st["value"] == pytest.approx(0.01)

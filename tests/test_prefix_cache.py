@@ -340,10 +340,15 @@ def test_service_prefix_cache_http_stats_and_hit_tokens():
 
 
 def test_service_prefix_cache_validation():
+    """Host row inserts don't compose with a sharded cache: refused at
+    construction, before an engine exists."""
+    from jax.sharding import Mesh
+
     model, params = _model_and_params()
-    with pytest.raises(ValueError, match="continuous"):
+    mesh = Mesh(np.asarray(jax.devices()[:2]), ("dp",))
+    with pytest.raises(ValueError, match="single-chip"):
         GenerationService(
-            model, {"params": params}, batcher="window",
-            batch_sizes=(1,), prompt_buckets=(32,),
+            model, {"params": params}, mesh=mesh,
+            batch_sizes=(2,), prompt_buckets=(32,),
             max_new_buckets=(4,), prefix_cache=True,
         )

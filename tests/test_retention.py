@@ -89,7 +89,8 @@ def test_chunks_that_carry_the_state_are_the_fresh_form(seeded, cuts):
             decode=True, mutable=["cache", "counters"])
         cache = upd["cache"]
         out.append(np.asarray(y))
-        counts = dict(zip(COUNTS, np.asarray(upd["counters"]["retention"])))
+        counts = dict(zip(
+            COUNTS.names, np.asarray(upd["counters"]["retention"])))
         assert counts["layer_calls"] == 1 and counts["state_rows"] == 0
         tokens += counts["chunk_tokens"]
     assert tokens == 3 * 24 and int(cache["cache_index"]) == 24
@@ -144,7 +145,8 @@ def test_single_token_steps_under_cursors_are_the_fresh_form(seeded):
         for leaf in ("state", "norm"):
             assert np.array_equal(np.asarray(upd["cache"][leaf])[~live],
                                   np.asarray(cache[leaf])[~live])
-        counts = dict(zip(COUNTS, np.asarray(upd["counters"]["retention"])))
+        counts = dict(zip(
+            COUNTS.names, np.asarray(upd["counters"]["retention"])))
         assert counts["state_rows"] == live.sum()
         assert counts["state_bytes"] == state_bytes_moved(
             int(live.sum()), KV, DH)

@@ -1,6 +1,6 @@
-"""LM serving daemon: batcher correctness vs direct generate, bucket
-padding exactness, micro-batching of concurrent requests, HTTP round
-trip with token auth."""
+"""LM serving daemon: the engine-backed service vs direct generate,
+bucket padding exactness, concurrent requests, HTTP round trip with
+token auth."""
 
 import json
 import time
@@ -15,7 +15,8 @@ import pytest
 
 from mlcomp_tpu.models import create_model
 from mlcomp_tpu.models.generation import generate
-from mlcomp_tpu.serve import GenerationService, _bucket, load_service
+from mlcomp_tpu.engine import bucket
+from mlcomp_tpu.serve import GenerationService, load_service
 from mlcomp_tpu.train.state import init_model
 
 
@@ -45,7 +46,7 @@ def _service(**kw):
     kw.setdefault("prompt_buckets", (8, 16))
     kw.setdefault("max_new_buckets", (4, 8))
     svc = GenerationService(model, {"params": params, **mstate}, **kw)
-    if share and svc.engine is not None:
+    if share:
         eng = svc.engine
         eng._fns.update(_CONT_FNS)
         orig_close = svc.close
@@ -59,18 +60,18 @@ def _service(**kw):
 
 
 def test_bucket_helper():
-    assert _bucket(3, (4, 8), "x") == 4
-    assert _bucket(4, (4, 8), "x") == 4
-    assert _bucket(5, (4, 8), "x") == 8
+    assert bucket(3, (4, 8), "x") == 4
+    assert bucket(4, (4, 8), "x") == 4
+    assert bucket(5, (4, 8), "x") == 8
     with pytest.raises(ValueError, match="exceeds"):
-        _bucket(9, (4, 8), "x")
+        bucket(9, (4, 8), "x")
 
 
 def test_serve_matches_direct_generate():
-    """A bucketed, left-padded, filler-padded service batch must produce
-    exactly what a direct generate on the bare prompt produces (greedy,
-    so determinism is total)."""
-    model, svc = _service(batcher="window")
+    """A bucketed, left-padded request in a pool of idle slots must
+    produce exactly what a direct generate on the bare prompt produces
+    (greedy, so determinism is total)."""
+    model, svc = _service(batcher="continuous")
     try:
         prompt = [3, 14, 15, 9, 2]  # length 5 -> bucket 8, left-padded
         got = svc.generate(prompt, max_new_tokens=4)
@@ -80,22 +81,20 @@ def test_serve_matches_direct_generate():
         )
         expect = np.asarray(direct)[0, len(prompt):].tolist()
         assert got["ids"] == expect, (got, expect)
-        assert got["batched_with"] == 1
     finally:
         svc.close()
 
 
 def test_serve_batches_concurrent_requests():
-    """Concurrent same-bucket requests decode in ONE batch."""
-    model, svc = _service(batcher="window", batch_window_ms=200.0)
+    """Concurrent requests decode side by side in the slot pool."""
+    model, svc = _service(batcher="continuous")
     try:
         futs = [
             svc.submit([1 + i, 2 + i, 3 + i], max_new_tokens=4)
             for i in range(3)
         ]
         outs = [f.result(timeout=120) for f in futs]
-        assert {o["batched_with"] for o in outs} == {3}
-        assert svc.stats()["batches"] == 1
+        assert svc.stats()["requests"] == 3
         # each row's output equals its own direct generation
         for i, o in enumerate(outs):
             direct = generate(
@@ -109,15 +108,21 @@ def test_serve_batches_concurrent_requests():
 
 def test_serve_warmup_really_compiles():
     """warmup() must RUN the hot bucket programs (lazy jit means merely
-    constructing the wrappers compiles nothing)."""
-    _, svc = _service(batcher="window")
+    constructing the wrappers compiles nothing): the process's compile
+    count rises over it, and a request in a warmed bucket adds none."""
+    _, svc = _service()
+
+    def compiled():
+        return svc.stats()["engine"]["programs"]["compiled"]
+
     try:
-        n = svc.warmup()
-        compiled = svc.stats()["compiled"]
-        # B=1 and the largest batch, largest prompt bucket, per max_new
-        assert n == 4 and len(compiled) == 4
-        assert [1, 16, 4] in [list(c) for c in compiled]
-        assert [4, 16, 8] in [list(c) for c in compiled]
+        cold = compiled()
+        # a dummy request a prompt bucket, then the ladder's programs
+        assert svc.warmup() >= len(svc.prompt_buckets)
+        warm = compiled()
+        assert warm > cold
+        svc.generate([3, 14, 15, 9, 2], 4)
+        assert compiled() == warm
     finally:
         svc.close()
 
@@ -354,20 +359,19 @@ def test_rowwise_sampling_matches_static():
 
 
 def test_serve_per_request_knobs_share_program():
-    """Mixed-knob requests batch into ONE compiled program; greedy
-    requests keep exact determinism while a sampled row differs."""
-    model, svc = _service(batcher="window", batch_window_ms=4000.0, batch_sizes=(1, 2))
+    """Mixed-knob requests decode through the SAME programs (the knobs
+    ride as per-row arrays: a sampled row beside a greedy one builds no
+    program the all-greedy warmup did not); the greedy row keeps exact
+    determinism."""
+    model, svc = _service(batch_sizes=(1, 2))
     try:
-        import concurrent.futures as cf
-
-        with cf.ThreadPoolExecutor(2) as ex:
-            f1 = ex.submit(svc.generate, [3, 14, 15, 9, 2], 4)  # greedy
-            f2 = ex.submit(
-                svc.generate, [7, 3, 44], 4, temperature=5.0, top_k=32
-            )
-            r1, r2 = f1.result(), f2.result()
-        assert r1["batched_with"] == 2 == r2["batched_with"]
-        assert len(svc.stats()["compiled"]) == 1  # one program for both
+        svc.warmup()  # all greedy: every program the engine builds
+        programs = set(svc.engine._fns)
+        f1 = svc.submit([3, 14, 15, 9, 2], 4)  # greedy
+        f2 = svc.submit([7, 3, 44], 4, temperature=5.0, top_k=32)
+        r1, r2 = f1.result(timeout=120), f2.result(timeout=120)
+        assert len(r2["ids"]) == 4
+        assert set(svc.engine._fns) == programs  # none for the mix
         # the greedy row matches a bare greedy generate exactly
         direct = generate(
             model, svc.variables, jnp.asarray([[3, 14, 15, 9, 2]]), 4
@@ -391,24 +395,18 @@ def test_serve_rejects_bad_knobs():
 
 
 def test_serve_per_request_eos():
-    """A request-level eos_id stops ITS row only; the neutral row runs
-    to its full budget — both in one batch/program."""
-    model, svc = _service(batcher="window", batch_window_ms=4000.0, batch_sizes=(1, 2))
+    """A request-level eos_id stops ITS row only; the neutral row
+    beside it runs to its full budget."""
+    model, svc = _service(batch_sizes=(1, 2))
     try:
         # find what greedy emits first so we can use it as the eos
         probe = svc.generate([3, 14, 15, 9, 2], 4)
         first = probe["ids"][0]
-        import concurrent.futures as cf
-
-        with cf.ThreadPoolExecutor(2) as ex:
-            f1 = ex.submit(
-                svc.generate, [3, 14, 15, 9, 2], 4, eos_id=first
-            )
-            f2 = ex.submit(svc.generate, [7, 3, 44], 4)
-            r1, r2 = f1.result(), f2.result()
+        f1 = svc.submit([3, 14, 15, 9, 2], 4, eos_id=first)
+        f2 = svc.submit([7, 3, 44], 4)
+        r1, r2 = f1.result(timeout=120), f2.result(timeout=120)
         assert r1["ids"] == [first]  # stopped at its own eos
         assert len(r2["ids"]) == 4   # unaffected neighbor
-        assert r1["batched_with"] == 2
     finally:
         svc.close()
 
@@ -508,17 +506,9 @@ def test_serve_decode_fused_from_standard_checkpoint(tmp_path):
 
 
 def test_serve_request_count_single_sourced():
-    """r4 advisor (low): 'requests' is counted in exactly one place per
-    batcher.  Window mode: the service counts.  Continuous mode: the
-    engine counts (service increment skipped), warmup dummies excluded,
-    and the top-level stats number equals the engine's."""
-    _, svc = _service(batcher="window")
-    try:
-        svc.generate([1, 2, 3], 2)
-        svc.generate([1, 2, 3], 2)
-        assert svc.stats()["requests"] == 2
-    finally:
-        svc.close()
+    """'requests' is counted in exactly one place: the engine counts,
+    warmup dummies excluded, and the top-level stats number is the
+    engine's."""
     _, svc = _service(batcher="continuous")
     try:
         svc.warmup()  # dummy submissions must not count
@@ -531,69 +521,21 @@ def test_serve_request_count_single_sourced():
         svc.close()
 
 
-def test_window_batcher_defers_head_first_no_starvation():
-    """r3/r4 starvation case: a request whose max_new bucket mismatches
-    the batch head used to be re-queued at the TAIL, so a sustained
-    stream of the other bucket deferred it forever.  Now it heads the
-    NEXT batch: wait is bounded by one batch per deferral."""
-    from concurrent.futures import Future
+def test_the_window_batcher_is_refused(capsys):
+    """One way to serve: the keyword keeps one meaning (two spellings),
+    anything else is an unknown value, and the flag is gone."""
+    from mlcomp_tpu.cli import main
 
-    _, svc = _service(batcher="window", batch_sizes=(1, 2),
-                      batch_window_ms=50.0)
-    # drive the collection policy deterministically: stop the batcher
-    # thread, then feed the adversarial arrival order by hand
-    svc._stop.set()
-    svc._thread.join(timeout=10)
-    assert not svc._thread.is_alive()
-
-    def item(name, nb):
-        return {"name": name, "bucket_new": nb, "future": Future()}
-
-    b1, a, b2, b3 = item("b1", 4), item("a", 8), item("b2", 4), item("b3", 4)
-    for it in (b1, a, b2, b3):
-        svc._queue.put(it)
-    first = svc._collect()
-    assert [i["name"] for i in first] == ["b1", "b2"]  # a deferred
-    assert [i["name"] for i in svc._deferred] == ["a"]
-    second = svc._collect()
-    assert [i["name"] for i in second] == ["a"]  # deferred heads next
-    third = svc._collect()
-    assert [i["name"] for i in third] == ["b3"]
-    # close() fails whatever is still parked in queue/deferred
-    svc._deferred = [item("late", 4)]
-    late = svc._deferred[0]["future"]
-    svc.close()
-    assert late.done() and isinstance(late.exception(), RuntimeError)
-
-
-def test_window_batcher_starvation_stream_end_to_end():
-    """The adversarial stream through the real service: the mismatched
-    request completes while the stream is still flowing (not last)."""
-    import threading as _th
-
-    _, svc = _service(batcher="window", batch_sizes=(1, 2),
-                      batch_window_ms=150.0, max_new_buckets=(2, 4))
-    done_order = []
-    lock = _th.Lock()
-
-    def track(name, fut):
-        fut.add_done_callback(
-            lambda f: (lock.acquire(), done_order.append(name),
-                       lock.release())
-        )
-        return fut
-
-    try:
-        futs = [track("b0", svc.submit([1, 2, 3], 2))]
-        futs.append(track("victim", svc.submit([1, 2, 3], 4)))
-        for i in range(6):
-            futs.append(track(f"b{i + 1}", svc.submit([1, 2, 3], 2)))
-            time.sleep(0.05)
-        for f in futs:
-            f.result(timeout=600)
-    finally:
-        svc.close()
-    assert done_order.index("victim") < len(done_order) - 1, done_order
+    model = _tiny_model()
+    for gone in ("window", "speculative"):
+        with pytest.raises(ValueError, match="expected 'auto'/'continuous'"):
+            GenerationService(model, {"params": {}}, batcher=gone)
+    for flag in (["--batcher", "window"], ["--batch-window-ms", "10"],
+                 ["--engine-pipeline-depth", "1"]):
+        with pytest.raises(SystemExit) as ei:
+            main(["serve", "--model", "m.yml", *flag])
+        assert ei.value.code == 2
+        assert "unrecognized arguments" in capsys.readouterr().err
 
 
 # ------------------------------------------------- device-profile capture
@@ -605,28 +547,6 @@ def _ephemeral_server(svc):
     httpd = make_http_server(svc, "127.0.0.1", 0, "tiny")
     threading.Thread(target=httpd.serve_forever, daemon=True).start()
     return httpd, f"http://127.0.0.1:{httpd.server_address[1]}"
-
-
-def test_profile_404_on_window_batcher():
-    """GET /profile matches /trace semantics on a batcher without a
-    drive loop: a 404 with a JSON error body, not a bare 404."""
-    _, svc = _service(batcher="window")
-    httpd, base = _ephemeral_server(svc)
-    try:
-        with pytest.raises(urllib.error.HTTPError) as ei:
-            urllib.request.urlopen(f"{base}/profile", timeout=30)
-        assert ei.value.code == 404
-        body = json.loads(ei.value.read())
-        assert "continuous batcher" in body["error"]
-        # /trace answers the same way — the two contracts stay aligned
-        with pytest.raises(urllib.error.HTTPError) as ei:
-            urllib.request.urlopen(f"{base}/trace", timeout=30)
-        assert ei.value.code == 404
-        assert "continuous batcher" in json.loads(ei.value.read())["error"]
-    finally:
-        httpd.shutdown()
-        httpd.server_close()
-        svc.close()
 
 
 def test_profile_bad_dispatches_400():

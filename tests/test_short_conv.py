@@ -80,9 +80,9 @@ def test_chunks_across_boundaries_are_the_plain_convolution(seeded, width):
     for lo in range(0, LEN, width):
         out, cache, counts = _chunk(params, cache, x[:, lo:lo + width])
         got.append(out)
-        tokens += float(counts[COUNTS.index("chunk_tokens")])
-        assert float(counts[COUNTS.index("layer_calls")]) == 1.0
-        assert float(counts[COUNTS.index("state_rows")]) == 0.0
+        tokens += float(counts[COUNTS.names.index("chunk_tokens")])
+        assert float(counts[COUNTS.names.index("layer_calls")]) == 1.0
+        assert float(counts[COUNTS.names.index("state_rows")]) == 0.0
     np.testing.assert_allclose(jnp.concatenate(got, 1), want, atol=2e-5)
     np.testing.assert_allclose(cache["conv"], tail, atol=1e-6)
     assert int(cache["cache_index"]) == LEN and tokens == ROWS * LEN
@@ -104,7 +104,7 @@ def test_left_pads_enter_as_zeros_of_u_not_of_x(seeded, pad):
     for lo in range(0, pad + n, 8):
         out, cache, counts = _chunk(params, cache, row[:, lo:lo + 8], kv_mask)
         got.append(out)
-        tokens += float(counts[COUNTS.index("chunk_tokens")])
+        tokens += float(counts[COUNTS.names.index("chunk_tokens")])
     got = jnp.concatenate(got, 1)[:, pad:]
     np.testing.assert_allclose(got, want[:, :n], atol=2e-5)
     assert tokens == ROWS * n

@@ -71,7 +71,7 @@ Annotations (the lock-discipline vocabulary)::
 
 ``guarded_by`` names either a lock attribute of the same class
 (detected as a ``threading.Lock()/RLock()/Condition()`` assignment) or
-a thread DOMAIN (``loop``, ``worker``, ``batcher`` — the single thread
+a thread DOMAIN (``loop``, ``worker`` — the single thread
 entitled to the state; a watchdog-restart path that has proven the
 loop dead may legitimately carry ``runs-on(loop)``).  ``[writes]``
 enforces writes only — for fields with a documented torn-read
@@ -136,11 +136,12 @@ LOCK_FILES = (
 # metric families docs/observability.md documents as CONDITIONAL on a
 # service configuration the tier-1 obs_check daemon does not run —
 # they are exempt from the "docs ⊆ obs_check enforced list" direction
-# (and only from that direction).  Keep each entry justified.
+# (and only from that direction).  Keep each entry justified.  What a
+# model's layers count (``mlcomp_engine_<group>_<entry>_total``: the
+# tier-1 obs_check daemon serves a dense transformer_lm, whose engine
+# emits none of them) is not listed here: ``collect_count_metrics``
+# reads those names from the layers' own tables.
 CONDITIONAL_METRICS = {
-    # window batcher only (the daemon runs continuous)
-    "mlcomp_service_requests_total",
-    "mlcomp_service_queue_depth",
     # sharded engines only (the tier-1 obs_check daemon is mesh-less)
     "mlcomp_engine_mesh_devices",
     "mlcomp_engine_is_coordinator",
@@ -151,40 +152,6 @@ CONDITIONAL_METRICS = {
     "mlcomp_engine_handoffs_exported_total",
     "mlcomp_engine_kv_pages_exported_total",
     "mlcomp_engine_handoff_bytes_exported_total",
-    # models with a routed expert layer only (RoutedExperts sows the
-    # counts; the tier-1 obs_check daemon serves a dense transformer_lm,
-    # whose engine emits none of them)
-    "mlcomp_engine_moe_assignments_total",
-    "mlcomp_engine_moe_assignments_held_total",
-    "mlcomp_engine_moe_experts_touched_total",
-    "mlcomp_engine_moe_expert_layer_calls_total",
-    "mlcomp_engine_moe_experts_held_total",
-    "mlcomp_engine_moe_chunk_assignments_total",
-    "mlcomp_engine_moe_chunk_assignments_held_total",
-    "mlcomp_engine_moe_chunk_experts_touched_total",
-    "mlcomp_engine_moe_chunk_expert_layer_calls_total",
-    "mlcomp_engine_moe_tile_rows_total",
-    "mlcomp_engine_moe_chunk_tile_rows_total",
-    # models with a retention layer only (PowerRetention sows them)
-    "mlcomp_engine_retention_state_rows_total",
-    "mlcomp_engine_retention_state_bytes_total",
-    "mlcomp_engine_retention_chunk_tokens_total",
-    "mlcomp_engine_retention_layer_calls_total",
-    # models with a KDA layer only (KimiDeltaAttention sows them)
-    "mlcomp_engine_kda_state_rows_total",
-    "mlcomp_engine_kda_state_bytes_total",
-    "mlcomp_engine_kda_chunk_tokens_total",
-    "mlcomp_engine_kda_layer_calls_total",
-    # models with a conv layer only (GatedShortConv sows them)
-    "mlcomp_engine_conv_state_rows_total",
-    "mlcomp_engine_conv_state_bytes_total",
-    "mlcomp_engine_conv_chunk_tokens_total",
-    "mlcomp_engine_conv_layer_calls_total",
-    # models with a latent-attention layer only (LatentAttention sows them)
-    "mlcomp_engine_latent_tokens_attended_total",
-    "mlcomp_engine_latent_bytes_read_total",
-    "mlcomp_engine_latent_chunk_tokens_total",
-    "mlcomp_engine_latent_layer_calls_total",
 }
 
 MUTATOR_METHODS = {
@@ -1187,6 +1154,31 @@ def collect_code_metrics(mods: Dict[str, ModuleInfo]
     return out
 
 
+def collect_count_metrics(mods: Dict[str, ModuleInfo]) -> Set[str]:
+    """The metric of every entry of every ``count_group(name, ((entry,
+    help), ...))`` table a file under mlcomp_tpu/models/ declares (the
+    name and the entries are literals there, for this reader)."""
+    out: Set[str] = set()
+    for rel, mi in mods.items():
+        if not rel.startswith("mlcomp_tpu/models/"):
+            continue
+        for node in ast.walk(mi.tree):
+            if not isinstance(node, ast.Call) or len(node.args) < 2:
+                continue
+            if (dotted(node.func) or "").split(".")[-1] != "count_group":
+                continue
+            group, entries = node.args[0], node.args[1]
+            if not (isinstance(group, ast.Constant)
+                    and isinstance(entries, (ast.Tuple, ast.List))):
+                continue
+            for entry in entries.elts:
+                if isinstance(entry, (ast.Tuple, ast.List)) and entry.elts \
+                        and isinstance(entry.elts[0], ast.Constant):
+                    out.add(f"mlcomp_engine_{group.value}_"
+                            f"{entry.elts[0].value}_total")
+    return out
+
+
 def _glob_match(pattern: str, name: str) -> bool:
     return re.fullmatch(
         ".*".join(re.escape(p) for p in pattern.split("*")), name
@@ -1370,13 +1362,15 @@ def check_drift(root: str,
             f"obs_check enforces {name} but docs/observability.md's "
             "serve-daemon catalog does not document it",
         ))
-    for name in sorted(docs_metrics - enforced - CONDITIONAL_METRICS):
+    conditional = CONDITIONAL_METRICS | collect_count_metrics(code)
+    for name in sorted(docs_metrics - enforced - conditional):
         findings.append(Finding(
             "metric-drift", "tools/obs_check.py", enforced_line or 1,
             f"documented metric {name} is missing from obs_check's "
             "DOCUMENTED_SERVE_METRICS enforcement list (conditional "
             "families belong in graftcheck's CONDITIONAL_METRICS with "
-            "a justification)",
+            "a justification; a layer's counts in a count_group table "
+            "under mlcomp_tpu/models/)",
         ))
 
     # ---- fleet control-plane metrics: the same three-way sync for

@@ -137,8 +137,6 @@ DOCUMENTED_SERVE_METRICS = [
     "mlcomp_cache_degraded_total",
     "mlcomp_serving_requests_rejected_total",
     "mlcomp_service_info",
-    "mlcomp_service_batches_total",
-    "mlcomp_service_batched_rows_total",
     "mlcomp_prefix_cache_lookups_total",
     "mlcomp_prefix_cache_hits_total",
     "mlcomp_prefix_cache_misses_total",
