@@ -4,9 +4,18 @@ A token's keys and values, for ALL heads, are projections of one
 latent: ``[c ; k_pe] = h W_kva`` (``latent_dim`` + ``rope_dim``
 numbers), ``c`` RMS-normed, then a head's ``[k_nope ; v] = c W_kvb``
 and its key ``[k_nope ; k_pe]`` (``k_pe`` shared by the heads).  The
-cache keeps ``[c ; k_pe]`` a token and nothing a head.  Neither ``q``
-nor ``k_pe`` is rotated (NoPE: the model's position information comes
-from its recurrent layers).
+cache keeps ``[c ; k_pe]`` a token and nothing a head.
+
+As Kimi-Linear has it, neither ``q`` nor ``k_pe`` is rotated (NoPE: the
+model's position information comes from its recurrent layers) and ``q``
+is one projection.  What a model may add (LongCat-Flash has all
+three): ``rope``, a rotation by position of the shared ``k_pe`` and of
+each head's ``q_pe``, ``k_pe`` rotated BEFORE its row is cached so that
+the cache holds ``[c ; rotated k_pe]`` and every form below stays what
+it is; ``q_rank``, a low-rank query ``q = W_qb norm(W_qa h)``; and the
+two scales that go with low ranks, ``q_scale`` on the whole query and
+``kv_scale`` on the normed latent (in the cached row: it reaches a
+head's keys and values alike).
 
 Attention runs in the latent space, in all three forms: with a head's
 key up-projection absorbed into its query, ``q_lat = [W_kvb,k^T q_nope
@@ -39,12 +48,20 @@ lanes past 576 are zeros and meet zeros of the query).
 
 from __future__ import annotations
 
+from typing import Optional
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from mlcomp_tpu.models.counts import count_group
-from mlcomp_tpu.models.transformer import RMSNorm, _window_start, rmsnorm
+from mlcomp_tpu.models.transformer import (
+    RMSNorm,
+    RopeSpec,
+    _window_start,
+    apply_rope_spec,
+    rmsnorm,
+)
 from mlcomp_tpu.ops.pallas.latent_attention import (
     LANES,
     NEG_INF,
@@ -150,10 +167,14 @@ def latent_chunk_attention(q, latents, first_slot, valid, dc, precision=None):
 
 class LatentAttention(nn.Module):
     """Pre-norm latent attention with ``SelfAttention``'s call
-    signature: ``q`` (hidden -> heads x (nope + rope)), ``kv_a`` (hidden
-    -> latent + rope), ``kv_norm`` (a learned vector over the latent),
+    signature: ``q`` (hidden -> heads x (nope + rope); with ``q_rank``
+    ``q_a``, hidden -> rank, ``q_norm``, a learned vector over the rank,
+    and ``q_b``, rank -> heads x (nope + rope)), ``kv_a`` (hidden ->
+    latent + rope), ``kv_norm`` (a learned vector over the latent),
     ``kv_b`` (latent -> heads x (nope + v), the stacked kernel itself:
-    both halves are used absorbed) and ``out``."""
+    both halves are used absorbed) and ``out``.  ``positions`` (B, S)
+    are the caller's (a chunk's, a step's per-row ones) and read only
+    under ``rope``."""
 
     hidden: int
     heads: int
@@ -162,11 +183,14 @@ class LatentAttention(nn.Module):
     rope_dim: int = 64
     v_dim: int = 128
     latent_dim: int = 512
+    rope: Optional[RopeSpec] = None
+    q_rank: Optional[int] = None
+    q_scale: float = 1.0
+    kv_scale: float = 1.0
 
     @nn.compact
     def __call__(self, x, positions, decode=False, kv_mask=None,
                  cache_cursor=None):
-        del positions                                    # no rotation
         b, s = x.shape[:2]
         n, dc = self.heads, self.latent_dim
         wide = dc + self.rope_dim
@@ -174,20 +198,38 @@ class LatentAttention(nn.Module):
         precision = jax.lax.Precision.HIGHEST \
             if self.dtype == jnp.float32 else None
         h = RMSNorm(self.dtype)(x)
+        q_in, q_name = h, "q"
+        if self.q_rank:
+            with jax.named_scope("mla.q_lora"):
+                q_in = rmsnorm(
+                    nn.Dense(self.q_rank, use_bias=False, dtype=self.dtype,
+                             name="q_a")(h),
+                    self.param("q_norm", nn.initializers.ones,
+                               (self.q_rank,), jnp.float32),
+                    self.dtype,
+                )
+            q_name = "q_b"
         with jax.named_scope("mla.project"):
             q = nn.DenseGeneral(
                 (n, self.nope_dim + self.rope_dim), use_bias=False,
-                dtype=self.dtype, name="q",
-            )(h)
+                dtype=self.dtype, name=q_name,
+            )(q_in)
             kv = nn.Dense(wide, use_bias=False, dtype=self.dtype,
                           name="kv_a")(h)
             scale = self.param(
                 "kv_norm", nn.initializers.ones, (dc,), jnp.float32
             )
-            latent = jnp.concatenate(
-                [rmsnorm(kv[..., :dc], scale, self.dtype), kv[..., dc:]],
-                axis=-1,
-            )
+            q_pe, k_pe = q[..., self.nope_dim:], kv[..., dc:]
+        if self.rope is not None:
+            with jax.named_scope("mla.rope"):
+                q_pe = apply_rope_spec(q_pe, positions, self.rope)
+                k_pe = apply_rope_spec(
+                    k_pe[:, :, None], positions, self.rope)[:, :, 0]
+        with jax.named_scope("mla.project"):
+            latent = jnp.concatenate([
+                rmsnorm(kv[..., :dc], scale * self.kv_scale, self.dtype),
+                k_pe,
+            ], axis=-1)
             w_b = self.param(
                 "kv_b", nn.initializers.normal(dc ** -0.5),
                 (dc, n, self.nope_dim + self.v_dim), jnp.float32,
@@ -198,8 +240,9 @@ class LatentAttention(nn.Module):
                     "bshd,chd->bshc", q[..., :self.nope_dim], w_k,
                     preferred_element_type=jnp.float32, precision=precision,
                 ),
-                q[..., self.nope_dim:].astype(jnp.float32),
-            ], axis=-1) * (self.nope_dim + self.rope_dim) ** -0.5
+                q_pe.astype(jnp.float32),
+            ], axis=-1) * (
+                self.q_scale * (self.nope_dim + self.rope_dim) ** -0.5)
             lanes = ((0, 0),) * (q_lat.ndim - 1) + ((0, width - wide),)
             q_lat = jnp.pad(q_lat.astype(self.dtype), lanes)
             latent = jnp.pad(latent, lanes[1:])

@@ -213,6 +213,7 @@ def _counts_block(sums, issued):
     return {
         "assignments": sums["assignments"],
         "assignments_held": sums["assignments_held"],
+        "zero_assignments": sums["zero_assignments"],
         "experts_touched": touched,
         "expert_layer_calls": calls,
         "tile_rows": sums["tile_rows"],
@@ -250,6 +251,13 @@ COUNTS = count_group("moe", (
     # calls' part; assignments held over it is the tiles' fill
     ("tile_rows", "Rows of the row tiles the expert layout used"),
     ("chunk_tile_rows", "Chunk calls' rows of row tiles used"),
+    # the assignments that chose a zero-compute expert (an identity:
+    # no weights, no row of the layout), of ``assignments``
+    ("zero_assignments",
+     "Assignments to zero-compute (identity) experts: no expert's "
+     "weights read, no row multiplied"),
+    ("chunk_zero_assignments",
+     "Chunk calls' assignments to zero-compute experts"),
 ), block=_counts_block)
 
 
@@ -261,10 +269,17 @@ class RoutedExperts(nn.Module):
 
     The float32 router scores all ``n_experts`` (``router_score``:
     ``"softmax"`` over the experts, or ``"sigmoid"``, each expert's
-    own); a token's top ``k`` are renormalised to sum 1 and scaled by
-    ``routed_scale``.  With ``selection_bias`` a learned number an
-    expert (``router_bias``) is added to the scores for the CHOICE of
-    the top ``k`` alone: the weights are the scores themselves.
+    own); a token's top ``k`` are renormalised to sum 1 (not with
+    ``renormalise`` false: the weights are then the scores as they
+    are) and scaled by ``routed_scale``.  With ``selection_bias`` a
+    learned number an expert (``router_bias``) is added to the scores
+    for the CHOICE of the top ``k`` alone: the weights are the scores
+    themselves.  ``zero_experts`` more outputs of the router, after the
+    ``n_experts`` real ones, are zero-compute experts: identities with
+    no weights.  A token that chooses one gets ``weight x its input``
+    from it, whole on every chip of an expert-parallel layer (as a
+    shared expert's part is), and the choice takes no row of the
+    layout and reads nothing: the compute a token varies.
     ``experts_held = (first, count)`` says which experts' weights are
     here (None: all).  The assignments whose expert is held are sorted
     by expert (``group_layout``'s counting sort), run through the
@@ -292,10 +307,11 @@ class RoutedExperts(nn.Module):
     one token a row) and zeros if it is a single-token step: the sums
     stay what they were, and sum minus chunk is the single-token class
     (summed where a caller makes the collection mutable; the decode
-    engine does).  Two more close the vector: the rows of the tiles the
-    layout used (tiles x rows a tile: what the kernel multiplied, pad
-    rows included; assignments held over it is the tiles' fill), and
-    that again if the call is a chunk.
+    engine does).  Then the rows of the tiles the layout used (tiles x
+    rows a tile: what the kernel multiplied, pad rows included;
+    assignments held over it is the tiles' fill), and that again if the
+    call is a chunk; and last the assignments that chose a zero-compute
+    expert, and those again if the call is a chunk.
     """
 
     n_experts: int
@@ -309,6 +325,8 @@ class RoutedExperts(nn.Module):
     gate: str = "silu"
     router_score: str = "softmax"
     selection_bias: bool = False
+    zero_experts: int = 0
+    renormalise: bool = True
 
     @nn.compact
     def __call__(self, x, router_input=None):
@@ -322,6 +340,7 @@ class RoutedExperts(nn.Module):
         b, s, d = x.shape
         t = b * s
         first, count = self.experts_held or (0, self.n_experts)
+        routed = self.n_experts + self.zero_experts   # the router's width
         tokens = x.reshape(t, d)
         stack = lambda name, shape: self.param(  # noqa: E731
             name, nn.initializers.normal(0.02), shape, jnp.float32
@@ -334,25 +353,27 @@ class RoutedExperts(nn.Module):
             scored = tokens if router_input is None \
                 else router_input.reshape(t, d)
             logits = nn.Dense(
-                self.n_experts, use_bias=False, dtype=jnp.float32,
-                name="router",
+                routed, use_bias=False, dtype=jnp.float32, name="router",
             )(scored.astype(jnp.float32))
             scores = ROUTER_SCORES[self.router_score](logits)
             if self.selection_bias:
                 bias = self.param(
-                    "router_bias", nn.initializers.zeros,
-                    (self.n_experts,), jnp.float32,
+                    "router_bias", nn.initializers.zeros, (routed,),
+                    jnp.float32,
                 )
                 _, topi = jax.lax.top_k(scores + bias, self.k)
                 topv = jnp.take_along_axis(scores, topi, axis=-1)
             else:
                 topv, topi = jax.lax.top_k(scores, self.k)
-            gates = topv / jnp.sum(topv, axis=-1, keepdims=True)
+            gates = topv / jnp.sum(topv, axis=-1, keepdims=True) \
+                if self.renormalise else topv
             gates = gates * self.routed_scale                     # (T, k)
+            # a zero-compute expert's index lies past every held one: it
+            # gets no row below, like an expert another chip holds
             local = (topi - first).reshape(t * self.k)
             # the rows an expert can expect are the same on every chip
-            # of an expert-parallel layer: the published expert count
-            tm = auto_row_tile(t, self.k, self.n_experts)
+            # of an expert-parallel layer: the router's published width
+            tm = auto_row_tile(t, self.k, routed)
             lay = group_layout(
                 local, count, tm,
                 source=jnp.arange(t * self.k, dtype=jnp.int32) // self.k,
@@ -366,12 +387,17 @@ class RoutedExperts(nn.Module):
                 jnp.float32(count),
             ])
             tile_rows = (lay.tiles_used * tm).astype(jnp.float32)
-            mine = jnp.concatenate([counts[:4], tile_rows])
+            to_zero = topi >= self.n_experts                      # (T, k)
+            zeros = jnp.sum(to_zero).astype(jnp.float32).reshape(1) \
+                if self.zero_experts else jnp.zeros((1,), jnp.float32)
+            mine = jnp.concatenate([counts[:4], tile_rows, zeros])
             as_chunk = mine if s > 1 else jnp.zeros_like(mine)
             self.sow(
                 "counters", COUNTS.name,
-                jnp.concatenate(
-                    [counts, as_chunk[:4], tile_rows, as_chunk[4:]]),
+                jnp.concatenate([
+                    counts, as_chunk[:4], tile_rows, as_chunk[4:5],
+                    zeros, as_chunk[5:],
+                ]),
                 reduce_fn=lambda a, c: a + c,
                 init_fn=lambda: jnp.zeros(len(COUNTS.entries), jnp.float32),
             )
@@ -395,6 +421,12 @@ class RoutedExperts(nn.Module):
             y = jnp.einsum(
                 "tkd,tk->td", picked.astype(jnp.float32), gates
             )
+
+        if self.zero_experts:
+            with jax.named_scope("moe.zero"):
+                y = y + jnp.sum(
+                    jnp.where(to_zero, gates, 0.0), axis=-1, keepdims=True
+                ) * tokens.astype(jnp.float32)
 
         if self.shared_width:
             with jax.named_scope("moe.shared"):

@@ -21,8 +21,9 @@ stack ``w2``, a gated unit's front half ``act(x @ w[g]) * (x @ w2[g])``
 (one read of the rows, one write of the product), ``act`` one of
 :data:`GATES` (SiLU: SwiGLU; ReLU: ReGLU).  The contraction is
 whole in one block (the model's hidden size in the front half, the
-expert width in the back: 3072 / 1024, 2560 / 768 and 2304 / 1024 at
-the three served configurations), so there is no accumulator and no K
+expert width in the back: 3072 / 1024, 2560 / 768, 2304 / 1024, 2048 /
+1536 and 6144 / 2048 at the served configurations), so there is no
+accumulator and no K
 loop, and a row's product does not depend on its tile; the grid is
 (output blocks, row tiles) with the row tiles innermost.
 
@@ -163,9 +164,11 @@ def auto_row_tile(tokens: int, k: int, experts: int) -> int:
     """The row tile for a call that routes ``tokens`` tokens to ``k`` of
     ``experts`` experts each: the largest of :data:`ROW_TILES` that the
     rows an expert can expect, ``tokens * k / experts``, fill; never
-    under :data:`ROW_TILE`.  ``experts`` is the PUBLISHED count: a chip
-    that holds a share of them sees that share of the assignments, so
-    the rows a held expert gets do not depend on the share."""
+    under :data:`ROW_TILE`.  ``experts`` is the PUBLISHED count, the
+    router's whole width (zero-compute experts take their part of the
+    choices too): a chip that holds a share of them sees that share of
+    the assignments, so the rows a held expert gets do not depend on
+    the share."""
     expected = tokens * k // experts
     return max([tm for tm in ROW_TILES if tm <= expected], default=ROW_TILE)
 

@@ -18,14 +18,17 @@ calls a backward call, on hand-made ops) and
 ``benchmark/tests/test_lane_readers.py`` (the readers of the admission
 lane's books: the row ledger, the ``engine.lane`` track, the ``admit``
 and ``inserted`` instants' arguments, on hand-made events and ``stats()``
-pairs and on a live rehearsal-width service).  A program PR that renames a span or drops a
+pairs and on a live rehearsal-width service) and
+``benchmark/tests/test_longcat_flash_readers.py`` (the latent decode's
+roofline at 64 heads in a stack with no KDA layer, the zero experts'
+share, the chunk form's ops by their shapes).  A program PR that renames a span or drops a
 ``stats()`` key fails here, not as a ``null`` per-layer metric after a
 chip run.  The tests are the benchmark's own, imported; nothing under
 ``benchmark/`` is edited.  Not ``test_correct.py``, ``test_laguna.py`` or
 ``test_smallthinker.py``, ``test_brumby.py``, ``test_kimi_linear.py`` or
 ``test_lfm2_moe.py``: they take minutes (``pytest benchmark/tests``
-runs them all).  One imported test is redefined below, and its
-docstring says why."""
+runs them all).  Two imported tests are redefined below, and their
+docstrings say why."""
 
 import os
 
@@ -36,6 +39,7 @@ from benchmark.tests.test_flash_fwd_calls import *  # noqa: F401,F403
 from benchmark.tests.test_kimi_linear_readers import *  # noqa: F401,F403
 from benchmark.tests.test_lane_readers import *  # noqa: F401,F403
 from benchmark.tests.test_lfm2_moe_readers import *  # noqa: F401,F403
+from benchmark.tests.test_longcat_flash_readers import *  # noqa: F401,F403
 from benchmark.tests.test_loop_spans import *  # noqa: F401,F403
 from benchmark.tests.test_mixedlen_readers import *  # noqa: F401,F403
 from benchmark.tests.test_retention_readers import *  # noqa: F401,F403
@@ -72,6 +76,47 @@ def test_the_entries_name_the_cell_and_its_files():  # noqa: F811
             if c["name"] == "brumby-14b-serve"] == [["num_hidden_layers"]]
     with open(cells.ROOT / "benchmark/configs/brumby-14b-serve.json") as f:
         assert json.load(f)["num_hidden_layers"] == 5
+
+
+def test_kimi_rows_tokens_and_bytes_a_token_are_run_deltas():  # noqa: F811
+    """``test_kimi_linear_readers.py``'s test of this name, but for its
+    count: it pins the entries of six readers at the six of PR 41, and
+    PR 47 gave ``latent_attn_time_share`` a second cell
+    (``.docqa``).  Tier-1 holds ``reasoning-offline``'s six to what
+    they were, asks every entry of those readers, the new one too, for
+    no number and no raise from a program without the counts, and
+    leaves the count to the next ``benchmark`` PR."""
+    from benchmark import cells
+    from benchmark.layer_metrics.cache_counts import delta
+    from benchmark.tests import test_kimi_linear_readers as kimi
+
+    before = kimi._stats(rows=400.0, state=400.0 * 1e6, tokens=9e4,
+                         fetched=9e4 * 1280, steps=20, emitted=2100)
+    after = kimi._stats(rows=400.0 + 380000.0, steps=1020,
+                        state=(400.0 + 380000.0) * 1e6, tokens=9e4 + 2.85e8,
+                        fetched=(9e4 + 3.135e8) * 1280, emitted=2100 + 95000)
+    got = delta(kimi._ctx(before, after))
+    assert got["kda"]["state_rows"] == 380000.0 and got["steps"] == 1000
+    name = "cache_bytes_per_token.reasoning"
+    assert cells.layer_reader(name)(name, kimi._ctx(before, after)) \
+        == pytest.approx((380000.0 * 1e6 + 3.135e8 * 1280) / 95000 / 1e6)
+    entries = [m for m in cells.benchmark_spec()["per_layer"]
+               if m["name"].split(".")[0] in (
+                   "cache_bytes_per_token", "latent_bytes_share",
+                   "kda_step_roofline", "latent_decode_roofline",
+                   "kda_time_share", "latent_attn_time_share")]
+    assert len([m for m in entries
+                if m["workloads"] == ["reasoning-offline"]]) == 6
+    assert [m["name"] for m in entries
+            if m["workloads"] != ["reasoning-offline"]] == [
+        "latent_attn_time_share.docqa"]
+    for s0, s1 in ((kimi._stats(counted=False),
+                    kimi._stats(counted=False, steps=9)),
+                   (before, before), ({}, {})):
+        ctx = kimi._ctx(s0, s1)
+        assert delta(ctx) is None
+        for m in entries:
+            assert cells.layer_reader(m["name"])(m["name"], ctx) is None
 
 
 def test_the_tile_fill_share_is_the_chunk_classs_rows_over_its_tiles():

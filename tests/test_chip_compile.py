@@ -478,3 +478,98 @@ def test_grouped_matmul_64_experts_of_2048_by_1536(chip, tokens, tile):
         chip((e, f, h), jnp.bfloat16), *tiles,
     )
     assert "grouped_matmul" in text
+
+
+# LongCat-Flash-Chat's cell (document-qa-offline): 24 slots, 64 heads
+# over a latent of 512 + 64 in 640 lanes, a buffer of 8,192 + 256 + 1
+# tokens rounded to 8,704; 16 experts of 6,144 x 2,048 held of a router
+# 768 wide, top 12: a step's 288 assignments and a chunk's 24,576
+LONGCAT_B, LONGCAT_H, LONGCAT_HIDDEN, LONGCAT_F = 24, 64, 6144, 2048
+
+
+def test_latent_decode_at_64_heads_a_row(chip):
+    """``ROWS_PER_STEP`` x 64 x 640 query blocks and a 64 x 512 float32
+    accumulator a row: the constants Kimi-Linear's 32 heads set."""
+    from mlcomp_tpu.ops.pallas.latent_attention import (
+        buffer_len,
+        latent_decode,
+    )
+
+    b, n, dc, width = LONGCAT_B, LONGCAT_H, 512, 640
+    length = buffer_len(8192 + 256 + 1)
+    assert length == 8704
+
+    def step(q, new, cache, start, stop):
+        return latent_decode(q, new, cache, start, stop, dc=dc,
+                             interpret=False)
+
+    args = (chip((b, n, width), jnp.bfloat16), chip((b, width), jnp.bfloat16),
+            chip((b, length, width), jnp.bfloat16), chip((b,), jnp.int32),
+            chip((b,), jnp.int32))
+    compiled = jax.jit(step, donate_argnums=(2,)).lower(*args).compile()
+    text = compiled.as_text()
+    # mla_decode_roofline matches the op by this name
+    assert "tpu_custom_call" in text and "latent_decode" in text
+    cache_bytes = b * length * width * 2
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes >= cache_bytes
+    assert stats.temp_size_in_bytes < cache_bytes // 100
+
+
+@pytest.mark.parametrize("tokens,tile", [(LONGCAT_B, 16), (2048, 32)],
+                         ids=["longcat_decode", "longcat_chunk"])
+def test_grouped_matmul_16_experts_of_6144_by_2048(chip, tokens, tile):
+    """The widest contraction served: (6144, 256) weight blocks in the
+    front half, (2048, 768) in the back, beside the call's row tile."""
+    from mlcomp_tpu.ops.pallas.grouped_matmul import (
+        auto_block_n,
+        auto_row_tile,
+        grouped_matmul,
+        padded_rows,
+    )
+
+    e, h, f, k, routed = 16, LONGCAT_HIDDEN, LONGCAT_F, 12, 512 + 256
+    tm = auto_row_tile(tokens, k, routed)
+    assert tm == tile
+    assert (auto_block_n(h, f, 2), auto_block_n(f, h, 2)) == (256, 768)
+    rows = padded_rows(tokens * k, e, tm)
+    tiles = (chip((rows // tm,), jnp.int32), chip((1,), jnp.int32))
+
+    def experts(x, w_gate, w_up, w_down, tile_group, used):
+        act = grouped_matmul(x, w_gate, tile_group, used, w2=w_up,
+                             interpret=False, gate="silu")
+        return grouped_matmul(act, w_down, tile_group, used, interpret=False)
+
+    text = _compiles_to_a_kernel(
+        experts, chip((rows, h), jnp.bfloat16),
+        chip((e, h, f), jnp.bfloat16), chip((e, h, f), jnp.bfloat16),
+        chip((e, f, h), jnp.bfloat16), *tiles,
+    )
+    assert "grouped_matmul" in text
+
+
+@pytest.mark.parametrize("tokens,tile", [(LONGCAT_B, 16), (2048, 32)],
+                         ids=["longcat_decode", "longcat_chunk"])
+def test_the_held_rows_gather_and_pick_at_6144_wide(chip, tokens, tile):
+    """XLA's own gathers around the expert kernels: the sorted buffer's
+    rows from the tokens, and each assignment's row back from the
+    buffer, bf16[rows, 6144].  The same fusion ran out of scoped VMEM at
+    bf16[1024, 2304] (Kimi-Linear at 128 slots): a refusal shows here."""
+    from mlcomp_tpu.ops.pallas.grouped_matmul import padded_rows
+
+    k, h = 12, LONGCAT_HIDDEN
+    rows = padded_rows(tokens * k, 16, tile)
+
+    def around(x, row_source, out, dest):
+        held = dest < rows
+        picked = jnp.where(
+            held[:, None],
+            jnp.take(out, jnp.where(held, dest, 0), axis=0), 0,
+        ).reshape(tokens, k, h)
+        return jnp.take(x, row_source, axis=0), picked
+
+    text = jax.jit(around).lower(
+        chip((tokens, h), jnp.bfloat16), chip((rows,), jnp.int32),
+        chip((rows, h), jnp.bfloat16), chip((tokens * k,), jnp.int32),
+    ).compile().as_text()
+    assert "gather" in text

@@ -172,7 +172,8 @@ def test_the_counts_by_layer_kind_and_by_call_class_are_the_hand_counts(
                   "experts_touched": moe["by_class"]["chunk"]["experts_touched"],
                   "expert_layer_calls": chunk_calls,
                   "tile_rows":
-                      16 * moe["by_class"]["chunk"]["experts_touched"]},
+                      16 * moe["by_class"]["chunk"]["experts_touched"],
+                  "zero_assignments": 0},
         # a step routes every slot's token, the two empty rows' too
         "single_token": {
             "assignments": slots * top_k * step_calls,
@@ -181,7 +182,8 @@ def test_the_counts_by_layer_kind_and_by_call_class_are_the_hand_counts(
                 moe["by_class"]["single_token"]["experts_touched"],
             "expert_layer_calls": step_calls,
             "tile_rows":
-                16 * moe["by_class"]["single_token"]["experts_touched"]},
+                16 * moe["by_class"]["single_token"]["experts_touched"],
+            "zero_assignments": 0},
     }
     for key in ("assignments", "experts_touched", "expert_layer_calls",
                 "tile_rows"):
@@ -200,17 +202,19 @@ def test_the_counts_by_layer_kind_and_by_call_class_are_the_hand_counts(
 
 def test_the_tile_counts_close_the_expert_layers_vector():
     """The nine entries every reader indexes keep their places; the two
-    tile counts come after them, and the classes are the entries with a
-    ``chunk_`` twin."""
+    tile counts come after them, the two counts of assignments to
+    zero-compute experts after those, and the classes are the entries
+    with a ``chunk_`` twin."""
     assert MOE_COUNTS.name == "moe"
     names = list(MOE_COUNTS.names)
     assert names == [
         "assignments", "assignments_held", "experts_touched",
         "expert_layer_calls", "experts_held", "chunk_assignments",
         "chunk_assignments_held", "chunk_experts_touched",
-        "chunk_expert_layer_calls", "tile_rows", "chunk_tile_rows"]
+        "chunk_expert_layer_calls", "tile_rows", "chunk_tile_rows",
+        "zero_assignments", "chunk_zero_assignments"]
     assert tuple(n[len("chunk_"):] for n in names if n.startswith("chunk_")) \
-        == tuple(names[:4]) + ("tile_rows",)
+        == tuple(names[:4]) + ("tile_rows", "zero_assignments")
 
 
 # ---- the int8 cache's single-token step appends inside the kernel ----
@@ -775,8 +779,11 @@ def test_blocked_on_a_slot_and_a_cancelled_admission_closes_its_span():
     eng = _lane_engine(2)
     try:
         fa = _decoding(eng, ONE_CHUNK, 60)
-        _decoding(eng, ONE_CHUNK, 12)
+        # armed before the short request starts: five of its dispatches
+        # are still to come when its first token lands, however late a
+        # loaded host lets this thread go on
         faults.arm("engine.dispatch", flavor="sleep", times=-1, seconds=0.1)
+        _decoding(eng, ONE_CHUNK, 12)
         fb = eng.submit(THREE_CHUNKS, 4)
         for _ in range(3000):
             adm = eng._adm
@@ -1013,3 +1020,73 @@ def test_a_groups_name_is_declared_once():
     assert [g.name for g in count_groups(["moe", "conv"])] == before
     with pytest.raises(ValueError, match="already declared"):
         count_group("conv", (("rows", "Rows"),))
+
+
+# two shortcut layers of latent attention: a router over 8 real experts
+# (4 held) and 4 zero-compute ones, the weights not renormalised
+SHORTCUT = {
+    "name": "mixed_layer_lm", "vocab_size": 64, "hidden": 128, "head_dim": 16,
+    "kv_heads": 4, "layer_types": ["latent", "latent"],
+    "heads_per_layer": [4, 4], "mlp_layer_types": ["shortcut"] * 2,
+    "mlp_dim": 128, "experts": 8, "zero_experts": 4, "experts_per_token": 3,
+    "experts_held": [0, 4], "routed_scale": 6.0, "renormalise": False,
+    "expert_width": 128, "selection_bias": True,
+    "rope_full": {"base": 1e7}, "latent_dims": [16, 8, 16, 32],
+    "latent_q_rank": 24, "latent_lora_scales": [True, True],
+    "dtype": "float32",
+}
+
+
+def test_the_zero_experts_choices_are_counted_through_stats_and_metrics():
+    """One request alone on three slots, 12 prompt tokens in two chunks
+    of 8 then three dispatches of K = 2: ``zero_assignments`` and its
+    chunk twin close the expert layers' vector, reach
+    ``stats()["engine"]["moe"]`` whole and by call class, and
+    ``/metrics`` under their own names; ``assignments`` stays all three
+    choices a token; and a shortcut layer's two mixers each count as a
+    layer that reads the context."""
+    model, params = _build(SHORTCUT)
+    assert model.attention_windows() == (None,) * 4
+    k, layers, top_k, slots = 2, 2, 3, 3
+    eng = DecodeEngine(model, {"params": params}, slots=slots,
+                       prompt_buckets=(16,), max_new_cap=16,
+                       steps_per_dispatch=k, prefill_chunk=8,
+                       pipeline_depth=1)
+    try:
+        _, packed = jax.eval_shape(
+            eng._dispatch_fn(), eng.variables, eng._dstate)
+        from mlcomp_tpu.models.latent_attention import COUNTS as LATENT
+
+        assert packed.shape == (3 * k * slots + len(MOE_COUNTS.entries)
+                                + len(LATENT.entries),)
+        out = eng.submit(list(range(1, 13)), 3 * k).result(timeout=300)
+        st = eng.stats()
+        text = eng.metrics.render()
+    finally:
+        eng.close()
+    assert len(out["ids"]) == 3 * k
+    moe = st["moe"]
+    chunk_calls, step_calls = 2 * layers, 3 * k * layers
+    chunk, single = moe["by_class"]["chunk"], moe["by_class"]["single_token"]
+    assert chunk["assignments"] == 8 * top_k * chunk_calls
+    assert single["assignments"] == slots * top_k * step_calls
+    # 4 of 12 outputs are zero experts: some choices, not all
+    for c in (chunk, single):
+        assert 0 < c["zero_assignments"] < c["assignments"]
+        # a zero choice is no held assignment
+        assert c["assignments_held"] + c["zero_assignments"] \
+            <= c["assignments"]
+    assert moe["zero_assignments"] == chunk["zero_assignments"] \
+        + single["zero_assignments"]
+    for name, n in (("zero_assignments", moe["zero_assignments"]),
+                    ("chunk_zero_assignments", chunk["zero_assignments"])):
+        assert f"mlcomp_engine_moe_{name}_total {int(n)}" in text
+    assert ("# HELP mlcomp_engine_moe_zero_assignments_total Assignments "
+            "to zero-compute (identity) experts") in text
+    # both mixers of both layers read the context: 12 + 14 + 16 held
+    att = st["attention"]
+    assert att["kv_tokens_live"] == att["kv_tokens_attended"] \
+        == 4 * (12 + 14 + 16)
+    lat = st["latent"]
+    assert lat["layer_calls"] == 2 * (chunk_calls + step_calls)
+    assert lat["chunk_tokens"] == 4 * 12

@@ -742,6 +742,68 @@ def test_what_full_beside_conv_cannot_be_is_refused_by_name(asked, refusal):
         create_model({**CONV_AND_FULL, **asked})
 
 
+# ---- what LongCat-Flash adds: a third MLP kind, the shortcut layer
+# (the model's own tests are test_longcat_flash.py) ----
+
+SHORTCUT = {
+    "name": "mixed_layer_lm", "vocab_size": 64, "hidden": 128, "head_dim": 16,
+    "kv_heads": 4, "layer_types": ["latent", "latent"],
+    "heads_per_layer": [4, 4], "mlp_layer_types": ["shortcut"] * 2,
+    "mlp_dim": 128, "experts": 8, "zero_experts": 4, "experts_per_token": 3,
+    "experts_held": [0, 4], "renormalise": False, "expert_width": 128,
+    "rope_full": {"base": 1e7}, "latent_dims": [16, 8, 16, 32],
+    "latent_q_rank": 24, "latent_lora_scales": [True, True],
+    "dtype": "float32",
+}
+
+
+def test_a_shortcut_layer_builds_beside_the_other_mlp_kinds():
+    from mlcomp_tpu.models.mixed_layer_lm import MLP_KINDS, SHORTCUT_MIXERS
+
+    assert MLP_KINDS == {"dense": 1, "sparse": 1, "shortcut": 2}
+    assert SHORTCUT_MIXERS == ("latent",)
+    model = create_model({**SHORTCUT, "layer_types": ["latent"] * 3,
+                          "heads_per_layer": [4] * 3,
+                          "mlp_layer_types": ["dense", "shortcut", "sparse"]})
+    # one entry a mixer: 1 + 2 + 1
+    assert model.attention_windows() == (None,) * 4
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
+    assert set(params["layer_0"]) == {"attn", "RMSNorm_0", "gate", "up",
+                                      "down"}
+    assert set(params["layer_1"]) == {
+        "attn", "RMSNorm_0", "moe", "gate", "up", "down",
+        "attn_1", "RMSNorm_1", "gate_1", "up_1", "down_1"}
+    assert set(params["layer_2"]) == {"attn", "RMSNorm_0", "moe"}
+    cache = jax.eval_shape(lambda: init_cache(model, 2, 24))
+    assert set(cache["layer_1"]) == {"attn", "attn_1"}
+    assert set(cache["layer_2"]) == {"attn"}
+
+
+@pytest.mark.parametrize("asked,refusal", [
+    ({"early_router": True},
+     "early_router on a latent layer: it hands no normed input on"),
+    ({"kv_quant": True},
+     "kv_quant on a latent layer: the latent is kept as it is"),
+    ({"window": 8}, "window on a latent layer: it reads the whole context"),
+    ({"layer_types": ["full", "full"]},
+     r"a shortcut layer of \['full'\] mixers: its two mixers keep two "
+     r"caches of one kind in one layer's carry, which is served with "
+     r"\('latent',\) alone"),
+    ({"layer_types": ["kda", "latent"]},
+     r"a shortcut layer of \['kda'\] mixers"),
+    ({"mlp_layer_types": ["shortcut", "shortcircuit"]},
+     r"mlp_layer_types: \['shortcircuit'\] not among \('dense', "
+     r"'sparse', 'shortcut'\)"),
+    ({"latent_q_rank": None},
+     "latent_lora_scales: the query's scale is .* no latent_q_rank"),
+], ids=["early_router", "kv_quant", "window", "attention_mixers",
+        "kda_mixers", "unknown_mlp_kind", "a_scale_without_its_rank"])
+def test_what_a_shortcut_layer_cannot_take_is_refused_by_name(asked, refusal):
+    with pytest.raises(ValueError, match=refusal):
+        create_model({**SHORTCUT, **asked})
+
+
 def test_self_attention_with_qk_norm_norms_a_head_before_the_rotation():
     """``qk_norm`` is two learned vectors a head width and nothing
     else: RMS-normed q and k, by hand, through the module without the

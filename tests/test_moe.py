@@ -3,6 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from mlcomp_tpu.models import create_model
 from mlcomp_tpu.models.moe import MoEBlock
@@ -197,9 +198,11 @@ def test_routed_experts_in_large_tiles_is_its_dense_reference():
     # the tiles it used, counted: each expert's rows rounded up to 128
     sizes = np.bincount(np.asarray(topi).reshape(-1), minlength=4)
     counts = np.asarray(upd["counters"]["moe"])
-    assert counts.shape == (11,)
+    assert counts.shape == (13,)
     assert counts[9] == counts[10] == sum(-(-s // 128) * 128 for s in sizes)
     assert counts[1] == counts[6] == 512
+    # no zero-compute expert to choose
+    assert counts[11] == counts[12] == 0
 
 
 def test_a_laguna_shaped_call_is_laid_out_as_it_was(monkeypatch):
@@ -233,3 +236,54 @@ def test_a_laguna_shaped_call_is_laid_out_as_it_was(monkeypatch):
         with monkeypatch.context() as m:
             m.setattr(gm, "auto_row_tile", lambda *_: gm.ROW_TILE)
             assert program(u, params) == text
+
+
+@pytest.mark.parametrize("zero,renormalise", [
+    (0, True), (0, False), (4, True), (4, False)],
+    ids=["as_it_was", "raw_weights", "zero_experts", "longcat"])
+def test_zero_experts_and_raw_weights_are_the_rule_written_out(
+        zero, renormalise):
+    """The router is ``n_experts + zero_experts`` wide; a chosen output
+    past the real experts adds ``weight x input`` and no row; without
+    ``renormalise`` the weights are the scores times the scale.  Against
+    the rule token by token in numpy, of which the first case is the
+    layer as it was (both new fields at their defaults)."""
+    from mlcomp_tpu.models.moe import RoutedExperts
+
+    n, k, h, scale = 8, 3, 128, 6.0
+    fields = {} if (zero, renormalise) == (0, True) else {
+        "zero_experts": zero, "renormalise": renormalise}
+    layer = RoutedExperts(n_experts=n, d_model=h, d_ff=128, k=k,
+                          routed_scale=scale, dtype=jnp.float32,
+                          selection_bias=True, **fields)
+    u = jax.random.normal(jax.random.PRNGKey(0), (1, 12, h))
+    params = layer.init(jax.random.PRNGKey(1), u)["params"]
+    assert params["router"]["kernel"].shape == (h, n + zero)
+    bias = 0.05 * np.sin(np.arange(n + zero, dtype=np.float64))
+    params = {**params, "router_bias": jnp.asarray(bias, jnp.float32)}
+    with jax.default_matmul_precision("highest"):
+        got, upd = layer.apply({"params": params}, u, mutable=["counters"])
+    un = np.asarray(u[0], np.float64)
+    w = {name: np.asarray(v, np.float64) for name, v in params.items()
+         if name.startswith("experts")}
+    logits = un @ np.asarray(params["router"]["kernel"], np.float64)
+    s = np.exp(logits - logits.max(-1, keepdims=True))
+    s /= s.sum(-1, keepdims=True)
+    want, to_zero = np.zeros_like(un), 0
+    for t in range(12):
+        top = np.argsort(-(s[t] + bias))[:k]
+        for e in top:
+            g = scale * s[t, e] / (s[t, top].sum() if renormalise else 1.0)
+            if e >= n:
+                want[t] += g * un[t]
+                to_zero += 1
+                continue
+            gate = un[t] @ w["experts_gate"][e]
+            act = gate / (1.0 + np.exp(-gate)) * (un[t] @ w["experts_up"][e])
+            want[t] += g * (act @ w["experts_down"][e])
+    np.testing.assert_allclose(np.asarray(got)[0], want, atol=2e-5)
+    counts = np.asarray(upd["counters"]["moe"])
+    assert counts[0] == 12 * k and counts[1] == 12 * k - to_zero
+    # a single row of 12 tokens is a chunk call
+    assert counts[11] == counts[12] == to_zero
+    assert (to_zero > 0) == (zero > 0)

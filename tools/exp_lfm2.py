@@ -89,7 +89,10 @@ def _report(tag, lowered):
     }), flush=True)
 
 
-def aot(slots_list) -> None:
+def aot(slots_list, cfg=None):
+    """The two programs at each slot count, of ``cfg`` (default: this
+    cell's); returns the described chip's sharding for what a caller
+    compiles beside them (``tools/exp_longcat.py``)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import topologies
@@ -107,7 +110,7 @@ def aot(slots_list) -> None:
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     chip = SingleDeviceSharding(topo.devices[0])
-    cfg = _cell_config(False)
+    cfg = cfg or _cell_config(False)
     k = int(cfg["service"]["steps_per_dispatch"])
     chunk = int(cfg["service"]["prefill_chunk"])
 
@@ -157,6 +160,7 @@ def aot(slots_list) -> None:
     _report(f"chunk tokens={chunk}",
             jax.jit(one_chunk, donate_argnums=(1,)).lower(
                 params, cache, ids, ids, spec((1, l_buf), jnp.bool_)))
+    return chip
 
 
 def timed(tiny: bool) -> None:
